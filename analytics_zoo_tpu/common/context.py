@@ -100,7 +100,10 @@ def init_orca_context(cluster_mode: str = "local",
     * ``cluster_mode="local"``  — single process, all locally visible chips.
     * ``cluster_mode="tpu"`` / ``"multihost"`` — one process per TPU host;
       calls ``jax.distributed.initialize`` (coordinator/num_processes/
-      process_id taken from args or TPU metadata env).
+      process_id taken from args or TPU metadata env). ``"tpu"`` raises
+      unless every device JAX found is a TPU: with ``JAX_PLATFORMS`` unset
+      JAX itself falls back to the CPU when TPU init fails, and a job that
+      asked for the chip must not train on the host instead.
     * ``cluster_mode="cpu-sim"`` — force the CPU backend (pairs with
       ``XLA_FLAGS=--xla_force_host_platform_device_count=N`` for mesh tests).
 
@@ -108,16 +111,13 @@ def init_orca_context(cluster_mode: str = "local",
     with Spark-era callers; on TPU they do not allocate anything.
 
     ``compile_cache_dir`` (or env ``ZOO_COMPILE_CACHE``) points the
-    compile plane's executable cache at a persistent directory: engines,
-    serving workers and AutoML studies serialize their AOT executables
-    there (plus JAX's own ``jax_compilation_cache_dir`` under ``<dir>/
-    xla``), so warm restarts skip XLA compilation entirely.
+    compile plane's executable cache and JAX's own persistent compilation
+    cache at one directory, so warm restarts skip XLA compilation. Where
+    ``JAX_COMPILATION_CACHE_DIR`` is set that directory wins; on an
+    accelerator with nothing set the cache goes to a fixed path in the
+    checkout (``compile.configure_compile_cache`` has the rule).
     """
     global _current
-    cache_dir = compile_cache_dir or os.environ.get("ZOO_COMPILE_CACHE")
-    if cache_dir:
-        from ..compile import configure_compile_cache
-        configure_compile_cache(cache_dir)
     with _lock:
         if _current is not None and not _current._stopped:
             logger.warning("init_orca_context called twice; returning existing "
@@ -161,6 +161,19 @@ def init_orca_context(cluster_mode: str = "local",
             if jax.config.jax_platforms != "cpu":
                 jax.config.update("jax_platforms", "cpu")
 
+        if cluster_mode == "tpu":
+            platforms = sorted({d.platform for d in jax.devices()})
+            if platforms != ["tpu"]:
+                raise RuntimeError(
+                    f'init_orca_context(cluster_mode="tpu") found '
+                    f"{jax.device_count()} device(s) on platform "
+                    f"{'/'.join(platforms)}, not tpu (JAX_PLATFORMS="
+                    f"{os.environ.get('JAX_PLATFORMS', '')!r}); use "
+                    'cluster_mode="local" to run on whatever is there')
+        # after the backend is known: persistence defaults on only where a
+        # compile costs seconds to minutes (configure_compile_cache)
+        from ..compile import configure_compile_cache
+        configure_compile_cache(compile_cache_dir)
         mesh = create_mesh(cfg.mesh_axes)
         ctx = ClusterContext(cfg, mesh)
         _current = ctx

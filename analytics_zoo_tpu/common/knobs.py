@@ -55,8 +55,9 @@ _KNOBS = [
        "on for non-CPU backends)."),
     # --- compile plane ------------------------------------------------------
     _k("ZOO_COMPILE_CACHE", "str", None, "compile",
-       "Directory for the persistent executable cache (also enables JAX's "
-       "own compilation cache under <dir>/xla)."),
+       "Directory for the persistent executable cache and JAX's own "
+       "compilation cache (JAX_COMPILATION_CACHE_DIR, where set, wins; "
+       "unset on an accelerator: .zoo_compile_cache/ in the checkout)."),
     _k("ZOO_COMPILE_CACHE_DISABLE", "bool", False, "compile",
        "Disable the shared executable cache entirely (every consumer "
        "degrades to private jax.jit)."),
@@ -133,9 +134,6 @@ _KNOBS = [
     _k("ZOO_DISPATCH_TIMEOUT_S", "float", None, "resilience",
        "Watchdog bound on one device dispatch / H2D placement; unset "
        "disables hang detection."),
-    _k("ZOO_SUPERVISOR_REINIT_BACKEND", "bool", False, "resilience",
-       "On classified device loss, additionally clear JAX backends before "
-       "the supervisor rebuilds."),
     _k("ZOO_BROKER_RECONNECT_RETRIES", "int", 4, "serving",
        "Redis broker reconnect attempts before giving up."),
     _k("ZOO_BROKER_RECONNECT_BACKOFF_S", "float", 0.2, "serving",
@@ -263,10 +261,6 @@ _KNOBS = [
     _k("ZOO_COORDINATOR_PORT", "int", 8476, "multihost",
        "Coordinator port scripts/launch_multihost.sh binds when deriving "
        "ZOO_COORDINATOR from the host list."),
-    # --- bench --------------------------------------------------------------
-    _k("ZOO_BENCH_FORCED_CPU", "bool", False, "bench",
-       "Internal marker set by bench.py's guarded re-exec after TPU init "
-       "failure (prevents a retry loop)."),
     # --- observability plane ------------------------------------------------
     _k("ZOO_OBS", "bool", True, "obs",
        "Register plane stats objects (PipelineStats, CkptStats) as "

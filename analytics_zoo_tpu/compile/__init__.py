@@ -23,24 +23,27 @@ This package centralizes compilation:
   learning rates route through ``optax.inject_hyperparams`` so they live
   in ``opt_state`` (a traced argument) instead of being baked constants —
   an entire ASHA rung of scalar-hyperparam trials compiles once.
-* **Persistence**: with a cache dir (``init_orca_context(
-  compile_cache_dir=...)`` or ``ZOO_COMPILE_CACHE``), executables
-  serialize to disk via ``jax.experimental.serialize_executable`` and
-  JAX's own ``jax_compilation_cache_dir`` is enabled, so warm restarts of
+* **Persistence**: executables serialize to disk via
+  ``jax.experimental.serialize_executable`` beside JAX's own persistent
+  compilation cache, in ONE directory: ``JAX_COMPILATION_CACHE_DIR`` where
+  it is set, else ``init_orca_context(compile_cache_dir=...)`` /
+  ``ZOO_COMPILE_CACHE``, else — on an accelerator only — the fixed
+  ``.zoo_compile_cache/`` at the checkout root
+  (:func:`configure_compile_cache`). Warm restarts of ``chip_smoke.py``,
   ``bench.py``, serving workers and resumed studies skip compilation.
-  Any serialization failure degrades silently to plain jit.
 * :func:`compile_stats` — counters (compiles, cache/disk hits, compile
   seconds, estimated seconds saved) surfaced through
   ``data_pipeline_stats()``, serving ``/metrics`` and ``bench.py``.
 """
 
-from .cache import (CachedFunction, ExecutableCache, compile_stats,
-                    configure_compile_cache, get_compile_cache,
+from .cache import (DEFAULT_CACHE_DIR, CachedFunction, ExecutableCache,
+                    compile_stats, configure_compile_cache, get_compile_cache,
                     reset_compile_cache, resolve_cache)
 from .stats import CompileStats
 
 __all__ = [
-    "CachedFunction", "CompileStats", "ExecutableCache", "compile_stats",
+    "DEFAULT_CACHE_DIR", "CachedFunction", "CompileStats", "ExecutableCache",
+    "compile_stats",
     "configure_compile_cache", "get_compile_cache", "reset_compile_cache",
     "resolve_cache",
 ]

@@ -34,7 +34,8 @@ from .stats import CompileStats
 
 logger = logging.getLogger("analytics_zoo_tpu")
 
-__all__ = ["CachedFunction", "ExecutableCache", "compile_stats",
+__all__ = ["DEFAULT_CACHE_DIR", "CachedFunction", "ExecutableCache",
+           "compile_stats",
            "configure_compile_cache", "get_compile_cache",
            "reset_compile_cache", "resolve_cache"]
 
@@ -496,6 +497,20 @@ class ExecutableCache:
 _global_lock = threading.Lock()
 _global_cache: Optional[ExecutableCache] = None
 
+# where the cache goes on an accelerator when nothing names a directory:
+# one fixed, git-ignored path at the checkout root. JAX keys its entries on
+# the directory, so a temp dir, a pid or a timestamp in it would never hit.
+DEFAULT_CACHE_DIR = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__)))), ".zoo_compile_cache")
+
+
+def _env_cache_dir() -> Optional[str]:
+    """The directory the environment names: ``JAX_COMPILATION_CACHE_DIR``
+    (JAX reads it itself) wins over ``ZOO_COMPILE_CACHE``."""
+    return (os.environ.get("JAX_COMPILATION_CACHE_DIR")
+            or os.environ.get("ZOO_COMPILE_CACHE") or None)
+
 
 def get_compile_cache() -> Optional[ExecutableCache]:
     """The process-wide cache (None when ``ZOO_COMPILE_CACHE_DISABLE`` is
@@ -505,8 +520,7 @@ def get_compile_cache() -> Optional[ExecutableCache]:
         return None
     with _global_lock:
         if _global_cache is None:
-            _global_cache = ExecutableCache(
-                cache_dir=os.environ.get("ZOO_COMPILE_CACHE") or None)
+            _global_cache = ExecutableCache(cache_dir=_env_cache_dir())
         return _global_cache
 
 
@@ -520,32 +534,42 @@ def resolve_cache(spec) -> Optional[ExecutableCache]:
     return spec
 
 
-def configure_compile_cache(cache_dir: str) -> Optional[ExecutableCache]:
-    """Point the process-wide cache at a persistent directory and enable
-    JAX's own persistent compilation cache under ``<dir>/xla`` (the
-    backend-level complement: it dedups at the XLA program level even for
-    compiles our AOT serialization can't capture)."""
+def configure_compile_cache(cache_dir: Optional[str] = None
+                            ) -> Optional[str]:
+    """Place the persistent compile cache — the process-wide executable
+    store (``exe-*.pkl``/``aux-*.json``) and JAX's own compilation cache —
+    in ONE directory, and return it (None: nothing persists).
+
+    * ``JAX_COMPILATION_CACHE_DIR`` set: that directory. JAX already reads
+      the variable, so no ``jax_compilation_cache_dir`` update happens here
+      — whoever set it (a machine image, a job launcher) owns the placement.
+    * else ``cache_dir`` (``init_orca_context(compile_cache_dir=)``), else
+      ``ZOO_COMPILE_CACHE``.
+    * else, on an accelerator backend, :data:`DEFAULT_CACHE_DIR`: a compile
+      there costs seconds to minutes and every fresh process would pay it
+      again. On the CPU backend nothing persists by default — compiles are
+      cheap and tests count them.
+
+    Everything JAX compiles is persisted (the size and compile-time
+    thresholds are zeroed): the small programs around a model add up to
+    most of a cold start's program count.
+    """
+    import jax
+    env_dir = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    target = env_dir or cache_dir or os.environ.get("ZOO_COMPILE_CACHE")
+    if not target and jax.default_backend() != "cpu":
+        target = DEFAULT_CACHE_DIR
+    if not target:
+        return None
+    os.makedirs(target, exist_ok=True)
+    if not env_dir:
+        jax.config.update("jax_compilation_cache_dir", target)
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
+    jax.config.update("jax_persistent_cache_min_entry_size_bytes", 0)
     cache = get_compile_cache()
     if cache is not None:
-        cache.set_cache_dir(cache_dir)
-    try:
-        import jax
-        xla_dir = os.path.join(cache_dir, "xla")
-        os.makedirs(xla_dir, exist_ok=True)
-        jax.config.update("jax_compilation_cache_dir", xla_dir)
-        for knob, value in (("jax_persistent_cache_min_compile_time_secs",
-                             0.0),
-                            ("jax_persistent_cache_min_entry_size_bytes",
-                             0)):
-            try:
-                jax.config.update(knob, value)
-            except Exception as e:  # noqa: BLE001 — knob absent on this jax
-                logger.debug("jax config knob %s not set (%s: %s)", knob,
-                             type(e).__name__, e)
-    except Exception as e:  # noqa: BLE001 — persistent cache is best-effort
-        logger.debug("jax_compilation_cache_dir not enabled (%s: %s)",
-                     type(e).__name__, e)
-    return cache
+        cache.set_cache_dir(target)
+    return target
 
 
 def compile_stats(reset: bool = False) -> Dict:
@@ -562,8 +586,7 @@ def compile_stats(reset: bool = False) -> Dict:
 
 
 def reset_compile_cache():
-    """Drop the process-wide cache and its stats (tests, and after
-    ``jax.clear_backends()`` — cached executables reference dead clients)."""
+    """Drop the process-wide cache and its stats (tests)."""
     global _global_cache
     with _global_lock:
         _global_cache = None

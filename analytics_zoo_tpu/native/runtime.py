@@ -1,14 +1,17 @@
 """ctypes bindings for the native host runtime (native/zoo_runtime.cc).
 
-Auto-builds the shared library with g++ on first import (cached under
-native/build/); every binding has a numpy fallback so the package works even
-without a toolchain. This replaces the reference's JNI native layer
+Auto-builds the shared library with g++ on first use (under native/build/,
+stamped with a hash of the source and the compile command so a stale or
+foreign build is never loaded); every binding has a numpy fallback so the
+package works even without a toolchain — ``version()`` says which is in
+use. This replaces the reference's JNI native layer
 (PersistentMemoryAllocator.java:37-43, MTSampleToMiniBatch.scala:139) with a
 C++ layer under the one-Python-process-per-host model."""
 
 from __future__ import annotations
 
 import ctypes
+import hashlib
 import logging
 import os
 import subprocess
@@ -32,36 +35,70 @@ if not os.access(_PKG_DIR, os.W_OK):
                        os.path.join(os.path.expanduser("~"), ".cache")),
         "analytics_zoo_tpu", "native")
 _SO = os.path.join(_BUILD_DIR, "libzoo_runtime.so")
+_STAMP = _SO + ".stamp"
+# no -march=native: the build directory travels with the checkout (a copied
+# disk, a shared filesystem) to hosts with another CPU
+_CXX = ["g++", "-O3", "-std=c++17", "-shared", "-fPIC", "-pthread"]
 
 _lib = None
 _lib_lock = threading.Lock()
 
 
-def _build() -> Optional[str]:
-    os.makedirs(_BUILD_DIR, exist_ok=True)
-    cmd = ["g++", "-O3", "-march=native", "-std=c++17", "-shared", "-fPIC",
-           "-pthread", _SRC, "-o", _SO]
+def _build_stamp() -> str:
+    """Identity of the build the current checkout asks for: the source's
+    content and the compile command."""
+    h = hashlib.sha256(" ".join(_CXX).encode())
+    with open(_SRC, "rb") as f:
+        h.update(f.read())
+    return h.hexdigest()
+
+
+def _stamp_on_disk() -> Optional[str]:
     try:
-        subprocess.run(cmd, check=True, capture_output=True, timeout=180)
+        with open(_STAMP, encoding="utf-8") as f:
+            return f.read().strip()
+    except OSError:
+        return None
+
+
+def _build(stamp: str) -> Optional[str]:
+    os.makedirs(_BUILD_DIR, exist_ok=True)
+    # build beside the target and rename: a concurrent process (fleet
+    # workers start together) never loads a half-written library
+    tmp_so, tmp_stamp = (f"{p}.{os.getpid()}.tmp" for p in (_SO, _STAMP))
+    try:
+        subprocess.run(_CXX + [_SRC, "-o", tmp_so], check=True,
+                       capture_output=True, timeout=180)
+        with open(tmp_stamp, "w", encoding="utf-8") as f:
+            f.write(stamp)
+        os.replace(tmp_so, _SO)
+        os.replace(tmp_stamp, _STAMP)
         return _SO
     except (subprocess.CalledProcessError, subprocess.TimeoutExpired,
-            FileNotFoundError) as e:
+            OSError) as e:
         logger.warning("native runtime build failed (%s); using numpy "
                        "fallbacks", e)
         return None
 
 
 def load() -> Optional[ctypes.CDLL]:
-    """Load (building if needed) the native library; None if unavailable."""
+    """Load (building if needed) the native library; None if unavailable.
+    The library is rebuilt whenever its stamp differs from the one the
+    checkout's source and compile command give."""
     global _lib
     with _lib_lock:
         if _lib is not None:
             return _lib if _lib is not False else None
         path = _SO
-        if not os.path.exists(path) or (
-                os.path.exists(_SRC) and
-                os.path.getmtime(_SRC) > os.path.getmtime(path)):
-            path = _build()
+        try:
+            stamp = _build_stamp()
+        except OSError as e:
+            logger.warning("native runtime source unreadable (%s); using "
+                           "numpy fallbacks", e)
+            path = None
+        else:
+            if not os.path.exists(path) or _stamp_on_disk() != stamp:
+                path = _build(stamp)
         if path is None:
             _lib = False
             return None

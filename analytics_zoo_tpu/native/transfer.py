@@ -1,10 +1,8 @@
 """Sharded host→device transfer plane — the one place H2D placement lives.
 
 Every training/serving path used to stage batches with its own
-``jax.device_put`` incantation; BENCH_DETAIL.json shows that stage, not the
-chip, is the wall (resnet50: 85.6% of baseline *compute* throughput but
-3.6% end-to-end, ``transfer_limited: true``). This module centralizes the
-three levers that fix a bandwidth-bound link:
+``jax.device_put`` incantation. This module centralizes the three levers
+for a bandwidth-bound host→device link:
 
 * **Narrow wire dtypes** (:func:`narrow_wire`) — f64/i64/u64 host arrays are
   pre-narrowed to the dtype JAX would canonicalize them to on device anyway
@@ -157,19 +155,14 @@ def _place(jax, a, sharding):
 def _place_inner(jax, a, sharding):
     if jax.process_count() > 1:
         return jax.make_array_from_process_local_data(sharding, a)
-    try:
-        if a.ndim == 0 or sharding.is_fully_replicated:
-            return jax.device_put(a, sharding)
-        imap = sharding.addressable_devices_indices_map(a.shape)
-        if len(imap) <= 1:
-            return jax.device_put(a, sharding)
-        shards = [jax.device_put(a[idx], d) for d, idx in imap.items()]
-        return jax.make_array_from_single_device_arrays(
-            a.shape, sharding, shards)
-    except Exception:
-        # unexpected sharding shape (uneven divisor, opaque sharding kind):
-        # correctness beats the placement optimization
+    if a.ndim == 0 or sharding.is_fully_replicated:
         return jax.device_put(a, sharding)
+    imap = sharding.addressable_devices_indices_map(a.shape)
+    if len(imap) <= 1:
+        return jax.device_put(a, sharding)
+    shards = [jax.device_put(a[idx], d) for d, idx in imap.items()]
+    return jax.make_array_from_single_device_arrays(
+        a.shape, sharding, shards)
 
 
 def put_tree(leaves: Sequence, shardings: Sequence, stats=None) -> List:
@@ -194,11 +187,8 @@ def staging_enabled() -> bool:
     env = os.environ.get("ZOO_HOST_STAGING", "").strip()
     if env in ("0", "1"):
         return env == "1"
-    try:
-        import jax
-        return jax.default_backend() != "cpu"
-    except Exception:
-        return False
+    import jax
+    return jax.default_backend() != "cpu"
 
 
 class StagingPool:

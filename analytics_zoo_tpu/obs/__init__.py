@@ -17,7 +17,7 @@ Three pieces over every plane built in PRs 1–9:
   perfetto``, ``ZOO_TRACE_PERFETTO=<path>``).
 
 See ``docs/observability.md`` for the metric naming rules, the span
-taxonomy and the Perfetto how-to.
+catalogue and the Perfetto how-to.
 """
 
 from . import trace

@@ -7,7 +7,7 @@ One trace id follows a request through every plane it touches:
 across an estimator teardown/rebuild, and in serving
 ``serving.request → serving.decode → serving.batch → serving.dispatch →
 serving.respond`` across the aiohttp handler, the broker payload and the
-batcher thread. The span taxonomy lives in ``docs/observability.md``.
+batcher thread. The span catalogue lives in ``docs/observability.md``.
 
 Propagation is a contextvar plus an explicit **thread-handoff token**
 (:func:`token` / :func:`span_under` / :func:`adopt`): the infeed lanes,

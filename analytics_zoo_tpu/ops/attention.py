@@ -14,12 +14,23 @@ Shapes follow (batch, seq, heads, head_dim) throughout.
 from __future__ import annotations
 
 import functools
+import logging
 import math
 from typing import Optional
 
 import jax
 import jax.numpy as jnp
 from jax import lax
+
+from ..obs.registry import REGISTRY as _REGISTRY
+
+logger = logging.getLogger("analytics_zoo_tpu")
+
+# the O(S^2) path on the chip is never silent
+_REFERENCE_ON_TPU = _REGISTRY.counter(
+    "zoo_attention_reference_on_tpu_total",
+    "flash_attention call sites traced on a TPU that fell through to "
+    "mha_reference (sequence not tileable, or causal with s_q > s_k)")
 
 NEG_INF = -1e30
 LOG2_E = 1.4426950408889634      # the flash kernel softmaxes in base 2
@@ -231,37 +242,20 @@ def _mosaic_params():
         dimension_semantics=("parallel", "parallel", "arbitrary"))
 
 
-def _sds(shape, dtype, vma):
-    """ShapeDtypeStruct that declares shard_map varying axes where the
-    installed jax supports the ``vma`` kwarg (no-op arg otherwise — older
-    jax has no vma typing to satisfy)."""
-    try:
-        return jax.ShapeDtypeStruct(shape, dtype, vma=vma)
-    except TypeError:
-        return jax.ShapeDtypeStruct(shape, dtype)
-
-
-def _vma_of(a):
-    """Varying-axes set of one array; empty on jax builds without vma
-    typing (no jax.typeof)."""
-    typeof = getattr(jax, "typeof", None)
-    if typeof is None:
-        return frozenset()
-    return getattr(typeof(a), "vma", None) or frozenset()
-
-
-def _input_vma(arrays):
-    """Union of the operands' shard_map varying sets (see _flash_forward)."""
-    vma = frozenset()
+def varying_axes(*arrays) -> frozenset:
+    """Union of the arrays' shard_map varying-axes sets (empty outside
+    shard_map)."""
+    out = frozenset()
     for a in arrays:
-        vma = vma | _vma_of(a)
-    return vma
+        out |= jax.typeof(a).vma
+    return out
 
 
-def _lift_vma(arrays, vma):
-    if not hasattr(jax.lax, "pvary"):
-        return list(arrays)
-    return [jax.lax.pvary(a, tuple(vma - _vma_of(a))) for a in arrays]
+def mark_varying(x, vma):
+    """Lift ``x`` to vary over every axis of ``vma`` it does not vary over
+    yet (device-invariant zeros, a replicated q in cross-attention)."""
+    missing = tuple(frozenset(vma) - jax.typeof(x).vma)
+    return lax.pcast(x, missing, to="varying") if missing else x
 
 
 def _flash_forward(q, k, v, causal, sm_scale, block_q, block_k, interpret,
@@ -297,15 +291,14 @@ def _flash_forward(q, k, v, causal, sm_scale, block_q, block_k, interpret,
     # declare which mesh axes it varies over. Use the union of the inputs'
     # varying sets and lift any less-varying input up to it so mixed-vma
     # call sites (e.g. cross-attention with replicated q) still compile.
-    vma = _input_vma((qf, kf, vf))
-    if vma:
-        qf, kf, vf = _lift_vma((qf, kf, vf), vma)
-    out_shape = [_sds((b * h, s_q, d), q.dtype, vma)]
+    vma = varying_axes(qf, kf, vf)
+    qf, kf, vf = (mark_varying(a, vma) for a in (qf, kf, vf))
+    out_shape = [jax.ShapeDtypeStruct((b * h, s_q, d), q.dtype, vma=vma)]
     out_specs = [pl.BlockSpec((1, block_q, d),
                               lambda bh, qi, ki: (bh, qi, 0))]
     if with_lse:
         out_shape.append(
-            _sds((b * h, s_q, 1), jnp.float32, vma))
+            jax.ShapeDtypeStruct((b * h, s_q, 1), jnp.float32, vma=vma))
         out_specs.append(pl.BlockSpec((1, block_q, 1),
                                       lambda bh, qi, ki: (bh, qi, 0)))
     if causal:
@@ -356,22 +349,25 @@ def _flash_forward(q, k, v, causal, sm_scale, block_q, block_k, interpret,
     return out
 
 
-def _on_tpu() -> bool:
-    try:
-        return jax.default_backend() == "tpu"
-    except Exception:
-        return False
+def _interpret() -> bool:
+    """Whether the Pallas kernels run in interpret mode: on the CPU backend
+    only (tests, the simulated mesh). On a TPU they compile through Mosaic;
+    no other backend has a lowering for them."""
+    platform = jax.default_backend()
+    if platform not in ("tpu", "cpu"):
+        raise NotImplementedError(
+            f"flash_attention has no kernel for platform {platform!r}")
+    return platform == "cpu"
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))
 def _flash_attention(q, k, v, causal, sm_scale, block_q, block_k):
-    interpret = not _on_tpu()
     return _flash_forward(q, k, v, causal, sm_scale, block_q, block_k,
-                          interpret)
+                          _interpret())
 
 
 def _flash_fwd(q, k, v, causal, sm_scale, block_q, block_k):
-    interpret = not _on_tpu()
+    interpret = _interpret()
     out, lse = _flash_forward(q, k, v, causal, sm_scale, block_q, block_k,
                               interpret, with_lse=True)
     return out, (q, k, v, out, lse)
@@ -512,7 +508,7 @@ def _flash_bwd(causal, sm_scale, block_q, block_k, res, g):
     from jax.experimental.pallas import tpu as pltpu
 
     q, k, v, o, lse = res
-    interpret = not _on_tpu()
+    interpret = _interpret()
     b, s_q, h, d = q.shape
     s_k = k.shape[1]
     bq, bk = _bwd_tile_sizes(s_q, s_k, block_q, block_k)
@@ -530,9 +526,9 @@ def _flash_bwd(causal, sm_scale, block_q, block_k, res, g):
     D = jnp.sum(gf.astype(jnp.float32) * of.astype(jnp.float32),
                 axis=-1, keepdims=True)
 
-    vma = _input_vma((q2, kf, vf, gf, lse, D))
-    if vma:
-        q2, kf, vf, gf, lse, D = _lift_vma((q2, kf, vf, gf, lse, D), vma)
+    vma = varying_axes(q2, kf, vf, gf, lse, D)
+    q2, kf, vf, gf, lse, D = (mark_varying(a, vma)
+                              for a in (q2, kf, vf, gf, lse, D))
 
     # --- dQ: grid (bh, nq, nk), k innermost --------------------------------
     dq_kernel = functools.partial(
@@ -550,7 +546,7 @@ def _flash_bwd(causal, sm_scale, block_q, block_k, res, g):
             pl.BlockSpec((1, bq, 1), lambda bhi, qi, ki: (bhi, qi, 0)),
         ],
         out_specs=pl.BlockSpec((1, bq, d), lambda bhi, qi, ki: (bhi, qi, 0)),
-        out_shape=_sds((bh, s_q, d), q.dtype, vma),
+        out_shape=jax.ShapeDtypeStruct((bh, s_q, d), q.dtype, vma=vma),
         scratch_shapes=[pltpu.VMEM((bq, d), jnp.float32)],
         compiler_params=None if interpret else _mosaic_params(),
         interpret=interpret,
@@ -576,8 +572,8 @@ def _flash_bwd(causal, sm_scale, block_q, block_k, res, g):
             pl.BlockSpec((1, bk, d), lambda bhi, ki, qi: (bhi, ki, 0)),
         ],
         out_shape=[
-            _sds((bh, s_k, d), k.dtype, vma),
-            _sds((bh, s_k, d), v.dtype, vma),
+            jax.ShapeDtypeStruct((bh, s_k, d), k.dtype, vma=vma),
+            jax.ShapeDtypeStruct((bh, s_k, d), v.dtype, vma=vma),
         ],
         scratch_shapes=[pltpu.VMEM((bk, d), jnp.float32),
                         pltpu.VMEM((bk, d), jnp.float32)],
@@ -598,7 +594,9 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
                     causal: bool = False, sm_scale: Optional[float] = None,
                     block_q: int = 1024, block_k: int = 1024) -> jax.Array:
     """Flash attention over (B, S, H, D). Uses the Pallas kernel when the
-    sequence tiles evenly (interpret mode off-TPU), else the reference path.
+    sequence tiles evenly (compiled on a TPU, interpret mode on the CPU
+    backend), else the reference path — which on a TPU is logged and
+    counted (``zoo_attention_reference_on_tpu_total``), never silent.
 
     Default 1024x1024 forward tiles: round-4 sweep on a v5e chip at
     S=4096/D=64-128 measured 1024x1024 fastest of {256..2048}x{512,1024}
@@ -624,16 +622,20 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
     # causal s_q < s_k (decode-style) rides the kernel: fwd/bwd both mask
     # bottom-right aligned. s_q > s_k would leave some q rows with no
     # visible key (all -inf) — keep those on the reference path.
+    interpret = _interpret()
     if bq is None or bk is None or (causal and s_q > s_k):
+        if not interpret:
+            _REFERENCE_ON_TPU.inc()
+            logger.warning(
+                "flash_attention: no kernel tile fits q%s k%s causal=%s; "
+                "materializing O(S^2) scores through mha_reference on the "
+                "TPU", tuple(q.shape), tuple(k.shape), causal)
         return mha_reference(q, k, v, causal=causal, sm_scale=sm_scale)
-    block_q, block_k = bq, bk
-    if not _on_tpu():
-        vma = _input_vma((q, k, v))
-        if vma:
-            # Interpret-mode pallas under shard_map is unreliable in jax
-            # 0.9: the HLO interpreter's grid dynamic_slice rejects
-            # varying operands with invariant indices for some (non-causal)
-            # shapes. On-TPU the kernel path handles vma via the union
-            # logic in _flash_forward; off-TPU use the reference math.
-            return mha_reference(q, k, v, causal=causal, sm_scale=sm_scale)
-    return _flash_attention(q, k, v, causal, sm_scale, block_q, block_k)
+    if interpret and varying_axes(q, k, v):
+        # Interpret-mode pallas under shard_map: the HLO interpreter's
+        # grid dynamic_slice rejects varying operands with invariant
+        # indices for some (non-causal) shapes. The compiled kernel
+        # handles vma (the union logic in _flash_forward); the CPU backend
+        # uses the reference math.
+        return mha_reference(q, k, v, causal=causal, sm_scale=sm_scale)
+    return _flash_attention(q, k, v, causal, sm_scale, bq, bk)

@@ -777,9 +777,8 @@ class TrainEngine:
 
     def _comms_train_step(self, params, extra, opt_state, resid, step,
                           x, y, w):
-        from ...parallel._compat import shard_map
         in_specs, out_specs = self._comms_specs(opt_state, resid, x, y, w)
-        return shard_map(self._comms_body, mesh=self.mesh,
+        return jax.shard_map(self._comms_body, mesh=self.mesh,
                          in_specs=in_specs, out_specs=out_specs,
                          check_vma=False)(params, extra, opt_state, resid,
                                           step, x, y, w)

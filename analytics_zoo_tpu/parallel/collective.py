@@ -48,8 +48,7 @@ def axis_index(axis: AxisName = "dp"):
 
 
 def axis_size(axis: AxisName = "dp"):
-    from ._compat import axis_size as _axis_size
-    return _axis_size(axis)
+    return lax.axis_size(axis)
 
 
 def axis_bound(axis: str) -> bool:

@@ -33,7 +33,6 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-from ._compat import shard_map           # jax-version-tolerant facade
 
 
 def stack_expert_params(per_expert) -> Any:
@@ -160,9 +159,9 @@ def moe_apply(expert_fn: Callable, expert_params: Any,
 
     param_specs = jax.tree_util.tree_map(
         lambda l: P(axis, *([None] * (l.ndim - 1))), expert_params)
-    fn = shard_map(ep_body, mesh=mesh,
-                   in_specs=(param_specs, P(), P(axis)),
-                   out_specs=(P(axis), P()),
-                   check_vma=False)
+    fn = jax.shard_map(ep_body, mesh=mesh,
+                       in_specs=(param_specs, P(), P(axis)),
+                       out_specs=(P(axis), P()),
+                       check_vma=False)
     y, aux = fn(expert_params, router_weights, x)
     return y, aux

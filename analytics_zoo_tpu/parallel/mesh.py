@@ -66,9 +66,10 @@ def create_mesh(axes: Optional[Dict[str, int]] = None,
                 devices: Optional[Sequence[jax.Device]] = None) -> Mesh:
     """Build a named-axis Mesh over all (or given) devices.
 
-    Uses ``mesh_utils.create_device_mesh`` when possible so the dp axis is
-    laid out along ICI rings on real TPU topologies; falls back to a plain
-    reshape for virtual/CPU devices.
+    ``mesh_utils.create_device_mesh`` lays the axes out along the physical
+    ICI topology on TPU devices (virtual/CPU devices have none: it reshapes
+    them in id order). A topology it cannot map raises — a silently
+    reshaped mesh would put dp neighbours on distant chips.
     """
     devices = list(devices if devices is not None else jax.devices())
     sizes = resolve_axis_sizes(len(devices), axes or {"dp": -1})
@@ -76,12 +77,8 @@ def create_mesh(axes: Optional[Dict[str, int]] = None,
     # stable; optional pp/ep axes append only when requested
     names = AXIS_ORDER + tuple(a for a in OPTIONAL_AXES if a in sizes)
     shape = tuple(sizes[a] for a in names)
-    try:
-        from jax.experimental import mesh_utils
-        dev_array = mesh_utils.create_device_mesh(shape, devices=devices)
-    except Exception:
-        dev_array = np.asarray(devices).reshape(shape)
-    return Mesh(dev_array, names)
+    from jax.experimental import mesh_utils
+    return Mesh(mesh_utils.create_device_mesh(shape, devices=devices), names)
 
 
 def data_sharding(mesh: Mesh, ndim: int, batch_axes: Tuple[str, ...] = ("dp", "fsdp")

@@ -49,7 +49,6 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-from ._compat import shard_map          # jax-version-tolerant facade
 
 
 def stack_stage_params(per_stage: List[Any]) -> Any:
@@ -145,7 +144,7 @@ def pipeline_apply(stage_fn: Callable, stacked_params: Any, x: jax.Array,
         lambda l: P(axis, *([None] * (l.ndim - 1))), stacked_params)
     # activations are replicated across pp (P()); dp/tp sharding of the
     # batch composes at the caller's jit level as usual
-    fn = shard_map(
+    fn = jax.shard_map(
         pp_body, mesh=mesh,
         in_specs=(param_specs, P()),
         out_specs=P(),

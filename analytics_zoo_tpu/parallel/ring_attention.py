@@ -32,7 +32,8 @@ from jax import lax
 from jax.sharding import Mesh, PartitionSpec as P
 
 from analytics_zoo_tpu.ops.attention import (
-    blockwise_finalize, blockwise_update, flash_attention, mha_reference)
+    blockwise_finalize, blockwise_update, flash_attention, mark_varying,
+    mha_reference, varying_axes)
 
 
 def ring_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
@@ -44,17 +45,14 @@ def ring_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
     """
     if sm_scale is None:
         sm_scale = 1.0 / math.sqrt(q.shape[-1])
-    from ._compat import axis_size
-    n = axis_size(axis_name)
+    n = lax.axis_size(axis_name)
     idx = lax.axis_index(axis_name)
     b, s_local, h, d = q.shape
 
     q_positions = idx * s_local + jnp.arange(s_local)
-    # Accumulators must carry the inputs' varying-axes type (jax >= 0.9
-    # shard_map vma typing) or the scan carry is rejected; _compat marks the
-    # device-invariant zeros as varying over every manual axis in scope (a
-    # no-op on jax builds without vma typing).
-    from ._compat import mark_varying, varying_axes
+    # Accumulators must carry the inputs' varying-axes type (shard_map vma
+    # typing) or the scan carry is rejected: mark the device-invariant
+    # zeros as varying over every manual axis the inputs vary over.
     vma = varying_axes(q, k)
     _vary = partial(mark_varying, vma=vma)
     acc = _vary(jnp.zeros((b, s_local, h, d), jnp.float32))
@@ -87,8 +85,7 @@ def ulysses_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
     """All-to-all sequence parallelism (DeepSpeed-Ulysses style): re-shard
     (B, S/sp, H, D) -> (B, S, H/sp, D), attend locally, re-shard back.
     Requires H % sp_size == 0."""
-    from ._compat import axis_size
-    n = axis_size(axis_name)
+    n = lax.axis_size(axis_name)
     if q.shape[2] % n:
         raise ValueError(
             f"ulysses needs heads ({q.shape[2]}) divisible by sp size ({n})")
@@ -114,9 +111,8 @@ def sequence_sharded_attention(mesh: Mesh, q, k, v, *, strategy: str = "ring",
         raise ValueError(f"unknown sequence-parallel strategy {strategy!r}")
     fn = ring_attention if strategy == "ring" else ulysses_attention
     spec = P("dp", "sp", None, None)
-    from ._compat import shard_map
 
-    @shard_map(mesh=mesh, in_specs=(spec, spec, spec), out_specs=spec)
+    @jax.shard_map(mesh=mesh, in_specs=(spec, spec, spec), out_specs=spec)
     def _run(ql, kl, vl):
         return fn(ql, kl, vl, axis_name="sp", causal=causal,
                   sm_scale=sm_scale)

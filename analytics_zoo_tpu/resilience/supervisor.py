@@ -16,11 +16,8 @@ uninterrupted N-epoch fit, bit for bit). Around each segment it arms:
 
 On a hang, injected device loss, or unhandled step exception the
 supervisor: flushes the checkpoint plane (queued ≠ durable is not
-acceptable when the backend is about to be torn down), shuts the
-estimator down, optionally drops the cached JAX backend (classified
-device loss + ``ZOO_SUPERVISOR_REINIT_BACKEND=1`` — safe only when no
-other component holds live device arrays), rebuilds the estimator from
-its factory, restores the newest *committed* supervisor checkpoint
+acceptable when the estimator is about to be torn down), shuts the
+estimator down, rebuilds the estimator from its factory, restores the newest *committed* supervisor checkpoint
 (``ckpt.format.loadable_step_dirs`` candidacy — torn writes can never be
 the resume point), and resumes at the recorded epoch boundary. The
 restart budget is bounded; exhausting it raises
@@ -165,18 +162,8 @@ class TrainingSupervisor:
         return {"stats": box.get("stats") or []}
 
     # --- recovery -----------------------------------------------------------
-    @staticmethod
-    def _is_device_loss(exc: BaseException) -> bool:
-        if isinstance(exc, DispatchTimeout):
-            return True
-        msg = str(exc)
-        return any(m in msg for m in ("UNAVAILABLE", "device lost",
-                                      "DATA_LOSS", "INTERNAL"))
-
-    def _teardown(self, est, err: BaseException):
-        """Flush + shut down the failed estimator; optionally drop the
-        cached JAX backend so re-init re-probes the driver."""
-        import os
+    def _teardown(self, est):
+        """Flush + shut down the failed estimator."""
         try:
             est.flush_checkpoints(timeout=30)
         except Exception:           # noqa: BLE001 — flush is best-effort here
@@ -186,19 +173,6 @@ class TrainingSupervisor:
             est.shutdown()
         except Exception:           # noqa: BLE001
             logger.exception("supervisor: estimator shutdown failed")
-        if self._is_device_loss(err) and \
-                os.environ.get("ZOO_SUPERVISOR_REINIT_BACKEND") == "1":
-            # full backend re-init: only under classified device loss and
-            # explicit opt-in — clear_backends invalidates every live
-            # device array in the process, which is exactly right for a
-            # lost chip and exactly wrong for a shared test mesh
-            try:
-                import jax
-                jax.clear_backends()
-                logger.warning("supervisor: cleared cached JAX backends "
-                               "for re-init")
-            except Exception:       # noqa: BLE001 — best-effort
-                logger.exception("supervisor: backend re-init failed")
 
     # --- public -------------------------------------------------------------
     def fit(self, data, epochs: int = 1, batch_size: int = 32,
@@ -266,7 +240,7 @@ class TrainingSupervisor:
                     with _trace.span("supervisor.restart", kind=kind,
                                      step=int(failed_step),
                                      cause=type(err).__name__):
-                        self._teardown(est, err)
+                        self._teardown(est)
                         est = self._factory()
                         epoch = self._recover(est, err, kind, failed_step,
                                               report)

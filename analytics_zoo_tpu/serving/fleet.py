@@ -68,6 +68,25 @@ def _loads(blob: bytes):
     return pickle.loads(blob)
 
 
+# a worker that exits with an error before its first heartbeat could not
+# START (no chip left for it, a broken factory): respawning it changes
+# nothing, so after this many in a row the supervisor stops
+MAX_BOOT_FAILURES = 3
+
+
+def _apply_worker_env(env: Dict[str, str]):
+    """Apply a fleet's ``worker_env`` in the child, before the factory
+    runs. The spawn bootstrap imported this package — and with it jax,
+    which reads ``JAX_PLATFORMS`` at import — before this function could
+    run, so that one is handed to jax's config as well; what libtpu reads
+    (``TPU_VISIBLE_CHIPS`` ...) is read at backend start, after this."""
+    for k, v in env.items():
+        os.environ[k] = str(v)
+    if "JAX_PLATFORMS" in env:
+        import jax
+        jax.config.update("jax_platforms", str(env["JAX_PLATFORMS"]))
+
+
 class SleepModel:
     """Host-side stand-in for a chip-bound model: ``predict`` sleeps
     ``batch_ms`` (the GIL is released, so M worker processes on one host
@@ -202,8 +221,7 @@ def _worker_main(factory_blob: bytes, queue_spec: str, worker_id: str,
     the shared stream under this consumer id, heartbeat through the
     broker, drain gracefully on SIGTERM."""
     cfg = json.loads(cfg_json)
-    for k, v in (cfg.get("env") or {}).items():
-        os.environ[k] = str(v)
+    _apply_worker_env(cfg.get("env") or {})
     if knobs.get("ZOO_TRACE"):
         _trace.arm()
     trace_dir = cfg.get("trace_dir")
@@ -247,7 +265,11 @@ class ServingFleet:
     (respawning unexpected deaths), samples worker heartbeats into
     per-worker occupancy (busy-seconds deltas), feeds the
     :class:`Autoscaler`, and reconciles the process set to the target
-    count — retire via SIGTERM (drain), crash recovery via respawn.
+    count — retire via SIGTERM (drain), crash recovery via respawn. A
+    worker that cannot start (exits with an error before its first
+    heartbeat — e.g. every chip already belongs to another process) is
+    not respawned forever: after ``MAX_BOOT_FAILURES`` in a row the
+    supervisor stops spawning and ``metrics()["gave_up"]`` says so.
     """
 
     def __init__(self, model_factory: Callable[[], Any], queue: str,
@@ -311,6 +333,7 @@ class ServingFleet:
         self._last_stats: Dict[str, Dict] = {}
         self._prev_busy: Dict[str, tuple] = {}
         self._live_now: Dict[str, Dict] = {}
+        self._boot_failures = 0         # consecutive; see MAX_BOOT_FAILURES
         self._occupancy = 0.0
         # fleet-level obs: live/target worker gauges + lifecycle events,
         # per supervisor instance (inst label), series dropped on stop()
@@ -387,6 +410,14 @@ class ServingFleet:
                     dead_pids.append(p.pid)
                 if wid in self._retiring:
                     self._retiring.discard(wid)
+                elif (p.exitcode or 0) > 0 and wid not in self._last_stats:
+                    self._boot_failures += 1
+                    logger.error(
+                        "fleet: worker %s could not start (exitcode=%s, no "
+                        "heartbeat; its traceback is on stderr) — boot "
+                        "failure %d/%d%s", wid, p.exitcode,
+                        self._boot_failures, MAX_BOOT_FAILURES,
+                        ", giving up" if self.gave_up else "")
                 else:
                     self._events["restarted"].inc()
                     logger.warning(
@@ -410,6 +441,8 @@ class ServingFleet:
                 logger.debug("fleet: live_workers probe failed: %s", e)
                 live = {}
             self._live_now = live
+            if set(live) - set(self._last_stats):
+                self._boot_failures = 0     # a new worker did come up
             occs: List[float] = []
             for wid, stats in live.items():
                 self._last_stats[wid] = stats
@@ -451,7 +484,7 @@ class ServingFleet:
                 self._target = new
             # 4. reconcile process set to target
             active = [w for w in self._procs if w not in self._retiring]
-            while len(active) < self._target:
+            while len(active) < self._target and not self.gave_up:
                 active.append(self._spawn())
             for wid in sorted(
                     active,
@@ -461,6 +494,13 @@ class ServingFleet:
             self._g_live.set(len(live))
             self._g_target.set(self._target)
 
+    @property
+    def gave_up(self) -> bool:
+        """True while the last MAX_BOOT_FAILURES workers to die all failed
+        before their first heartbeat: the supervisor spawns no more (a
+        worker that does come up resets the count)."""
+        return self._boot_failures >= MAX_BOOT_FAILURES
+
     def scale_to(self, n: int):
         """Manual override: set the reconcile target (the next tick
         spawns/retires to it). With autoscale on, the autoscaler keeps
@@ -469,9 +509,10 @@ class ServingFleet:
             self._target = max(1, min(int(n), self.autoscaler.max_workers))
 
     def wait_live(self, n: int, timeout_s: float = 30.0) -> bool:
-        """Block until >= n workers heartbeat as live."""
+        """Block until >= n workers heartbeat as live (False at the
+        timeout, or as soon as the supervisor gave up spawning)."""
         deadline = time.time() + timeout_s
-        while time.time() < deadline:
+        while time.time() < deadline and not self.gave_up:
             try:
                 if len(self.broker.live_workers(self.worker_ttl_s)) >= n:
                     return True
@@ -493,6 +534,8 @@ class ServingFleet:
                 "spawned": ev["spawned"],
                 "restarts": ev["restarted"],
                 "retired": ev["retired"],
+                "boot_failures": self._boot_failures,
+                "gave_up": self.gave_up,
                 "scale_ups": self.autoscaler.scale_ups,
                 "scale_downs": self.autoscaler.scale_downs,
                 "records_out_total": sum(

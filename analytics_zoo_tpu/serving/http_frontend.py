@@ -372,7 +372,11 @@ def create_app(queue="memory://serving_stream", timeout_s: float = 30.0,
         return web.Response(text="model secured secret and salt succeed "
                                  "to put in app state")
 
-    app = web.Application(middlewares=[auth_middleware])
+    # aiohttp's 1 MiB default body bound refuses a single 300x300x3 image
+    # sent as a JSON list (~1-2 MB); bound a request at a full default
+    # batch (32) of them instead
+    app = web.Application(middlewares=[auth_middleware],
+                          client_max_size=64 << 20)
     app.on_cleanup.append(_drop_counter_series)
     app["model_secure"] = {}        # mutable holder, registered pre-startup
     app.router.add_get("/", index)

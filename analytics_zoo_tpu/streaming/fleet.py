@@ -49,7 +49,8 @@ from typing import Any, Callable, Dict, List, Optional
 from ..common import knobs as _knobs
 from ..obs import trace as _trace
 from ..obs.registry import REGISTRY
-from ..serving.fleet import _dumps, _loads
+from ..serving.fleet import (MAX_BOOT_FAILURES, _apply_worker_env, _dumps,
+                             _loads)
 from ..serving.queue_api import make_broker, partitioned_spec
 from ..shm import sweep_spec as _shm_sweep_spec
 from .guardrail import GuardrailEvaluator
@@ -96,8 +97,7 @@ def _consumer_main(factory_blob: bytes, queue_spec: str, partition: int,
     gracefully on SIGTERM (the commit protocol makes ANY exit point
     replay-safe — SIGKILL included, which is the chaos gate)."""
     cfg = json.loads(cfg_json)
-    for k, v in (cfg.get("env") or {}).items():
-        os.environ[k] = str(v)
+    _apply_worker_env(cfg.get("env") or {})
     if _knobs.get("ZOO_TRACE"):
         _trace.arm()
     stop_ev = threading.Event()
@@ -243,6 +243,9 @@ class StreamingFleet:
         self._lock = threading.Lock()
         self._last_stats: Dict[str, Dict] = {}
         self.restarts = 0
+        # partition -> consecutive boot failures (serving.fleet's rule: an
+        # error exit before the first heartbeat is not respawned forever)
+        self._boot_failures: Dict[int, int] = {}
 
     # --- lifecycle ----------------------------------------------------------
     def partition_root(self, partition: int) -> str:
@@ -297,6 +300,17 @@ class StreamingFleet:
                     logger.info("stream-fleet: consumer t%d completed",
                                 k)
                     continue
+                if (p.exitcode or 0) > 0 and \
+                        f"t{k}" not in self._last_stats:
+                    n = self._boot_failures[k] = \
+                        self._boot_failures.get(k, 0) + 1
+                    logger.error(
+                        "stream-fleet: consumer t%d could not start "
+                        "(exitcode=%s, no heartbeat; its traceback is on "
+                        "stderr) — boot failure %d/%d", k, p.exitcode, n,
+                        MAX_BOOT_FAILURES)
+                    if n >= MAX_BOOT_FAILURES:
+                        continue        # give this partition up
                 # a consumer CRASHED (SIGKILL, OOM, bug): respawn it onto
                 # the SAME partition — the per-partition cursor + PEL
                 # replay make the restart bit-exact
@@ -376,6 +390,7 @@ class StreamingFleet:
             "consumers": self.consumers,
             "alive": self.alive(),
             "restarts": self.restarts,
+            "boot_failures": dict(self._boot_failures),
             "windows_total": sum(
                 int(s.get("windows", 0)) for s in stats.values()),
             "records_trained_total": sum(

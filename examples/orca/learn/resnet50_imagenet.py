@@ -19,8 +19,6 @@ import argparse
 import shutil
 import tempfile
 
-import numpy as np
-
 
 def main():
     p = argparse.ArgumentParser()
@@ -69,17 +67,13 @@ def main():
             optimizer=SGD(learningrate=0.0, momentum=0.9,
                           leaningrate_schedule=sched))
 
-        first = next(pipe.epoch(shuffle=False, prefetch=False))
-        est.engine.build(tuple(np.asarray(a) for a in first.x))
-
-        for epoch in range(args.epochs):
-            losses = []
-            for batch in pipe.epoch(shuffle=True):
-                losses.append(est.engine.train_batch(batch))
-            print(f"epoch {epoch}: train_loss="
-                  f"{float(np.mean([float(l) for l in losses])):.4f} "
+        # the production path (what chip_smoke.py proves on the chip): fit
+        # drives the pipeline through the InfeedPump and its transfer lanes
+        for stats in est.fit(pipe, epochs=args.epochs, verbose=False):
+            print(f"epoch {stats['epoch']}: "
+                  f"train_loss={stats['train_loss']:.4f} "
                   f"({pipe.steps_per_epoch} steps, "
-                  f"global batch {pipe.global_bs})")
+                  f"global batch {pipe.global_bs}, {stats['time_s']} s)")
     finally:
         if tmp:
             shutil.rmtree(tmp, ignore_errors=True)

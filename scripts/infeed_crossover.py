@@ -2,7 +2,7 @@
 """Pump-vs-direct infeed crossover sweep (round-4 verdict item 7).
 
 Runs the REAL InfeedPump against a modelled device (native/infeed_sim.py)
-across host->device bandwidths from tunnel-class (10 MB/s) to PCIe/DMA
+across host->device bandwidths from a degraded link (10 MB/s) to PCIe/DMA
 class (16 GB/s) with a ResNet-50-sized batch (256 x 224 x 224 x 3 uint8 =
 38.5 MB) and a 100 ms compute step (~2560 img/s). Prints the measured
 steady-state step times and writes docs-ready JSON.

@@ -39,7 +39,7 @@ from analytics_zoo_tpu.orca.learn.estimator import TPUEstimator
 def test_f64_rule_fires_on_planted_x64_program():
     """A real jax lowering with x64 enabled leaks f64 tensors; the rule
     fires for a TPU target and stays silent for CPU (where f64 is legal)."""
-    with jax.experimental.enable_x64(True):
+    with jax.enable_x64(True):
         lowered = jax.jit(lambda x: x * 2.0).lower(
             jnp.ones((8, 8), jnp.float64))
         text = lowered.as_text()
@@ -58,7 +58,7 @@ def test_f64_rule_silent_on_clean_f32_program():
 def test_promotion_rule_fires_on_planted_f64_promotion():
     """An astype(f64) *inside* the traced program is a promotion no input
     narrowing can undo — exactly what the rule exists for."""
-    with jax.experimental.enable_x64(True):
+    with jax.enable_x64(True):
         text = jax.jit(lambda x: x.astype(jnp.float64) * 2.0).lower(
             jnp.ones((8,), jnp.float32)).as_text()
     found = HloLinter(target="tpu").lint_text(text, label="train")
@@ -66,7 +66,7 @@ def test_promotion_rule_fires_on_planted_f64_promotion():
     assert promos and promos[0].details == {"from": "f32", "to": "f64"}
     assert promos[0].severity == "error"          # f64 on a TPU target
     # narrowing converts (f64 -> f32) must NOT fire the rule
-    with jax.experimental.enable_x64(True):
+    with jax.enable_x64(True):
         narrow = jax.jit(lambda x: x.astype(jnp.float32)).lower(
             jnp.ones((8,), jnp.float64)).as_text()
     assert not [f for f in HloLinter(target="cpu").lint_text(narrow)

@@ -7,7 +7,7 @@ import numpy as np
 import pytest
 from jax.sharding import Mesh, PartitionSpec as P
 
-from analytics_zoo_tpu.parallel._compat import shard_map
+from jax import shard_map
 from analytics_zoo_tpu.ops.attention import flash_attention, mha_reference
 from analytics_zoo_tpu.parallel.ring_attention import (
     ring_attention, sequence_sharded_attention, ulysses_attention)
@@ -234,3 +234,54 @@ def test_sequence_sharded_wrapper():
     out = sequence_sharded_attention(mesh, q, k, v, strategy="ring")
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                rtol=2e-4, atol=2e-4)
+
+
+@pytest.mark.parametrize("d", [64, 128])
+def test_flash_attention_lowers_to_mosaic_for_tpu(monkeypatch, d):
+    """The path tier-1 cannot execute: with the backend decision forced to
+    the compiled kernel, cross-lowering for platform tpu runs the Pallas ->
+    Mosaic lowering on the CPU. One custom call forward; forward-with-lse,
+    dQ and dK/dV for the gradient — no interpret mode, no mha_reference."""
+    import analytics_zoo_tpu.ops.attention as attn
+    monkeypatch.setattr(attn, "_interpret", lambda: False)
+    sds = jax.ShapeDtypeStruct((1, 512, 2, d), jnp.bfloat16)
+
+    def loss(q, k, v):
+        return flash_attention(q, k, v, causal=True).astype(
+            jnp.float32).sum()
+
+    fwd = jax.export.export(
+        jax.jit(lambda q, k, v: flash_attention(q, k, v, causal=True)),
+        platforms=["tpu"])(sds, sds, sds).mlir_module()
+    grad = jax.export.export(
+        jax.jit(jax.grad(loss, argnums=(0, 1, 2))),
+        platforms=["tpu"])(sds, sds, sds).mlir_module()
+    assert fwd.count("tpu_custom_call") == 1
+    assert grad.count("tpu_custom_call") == 3
+
+
+def test_flash_attention_refuses_unknown_platforms(monkeypatch):
+    """Interpret mode is for the CPU backend only; a backend with no kernel
+    lowering is an error, not a silent interpreter run."""
+    import analytics_zoo_tpu.ops.attention as attn
+    monkeypatch.setattr(jax, "default_backend", lambda: "gpu")
+    with pytest.raises(NotImplementedError, match="gpu"):
+        attn._interpret()
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    assert attn._interpret() is False
+
+
+def test_reference_fall_through_on_tpu_is_counted(monkeypatch):
+    """A shape no kernel tile fits takes mha_reference; on a TPU that is a
+    counted, logged event (chip_smoke.py asserts the count stays zero)."""
+    import analytics_zoo_tpu.ops.attention as attn
+    q, _, _ = _qkv(s=32)
+    _, k, v = _qkv(s=16)            # causal with s_q > s_k: rows see no key
+    before = attn._REFERENCE_ON_TPU.value
+    flash_attention(q, k, v, causal=True)
+    assert attn._REFERENCE_ON_TPU.value == before       # CPU: silent
+    monkeypatch.setattr(attn, "_interpret", lambda: False)
+    out = flash_attention(q, k, v, causal=True)
+    assert attn._REFERENCE_ON_TPU.value == before + 1
+    np.testing.assert_allclose(out, mha_reference(q, k, v, causal=True),
+                               rtol=1e-6)

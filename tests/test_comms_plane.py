@@ -135,7 +135,7 @@ def test_grad_allreduce_mean_skips_absent_axes(orca_context):
     ``Mesh(devices, ("dp",))``)."""
     from jax.sharding import Mesh, PartitionSpec as P
     from analytics_zoo_tpu.parallel import collective as C
-    from analytics_zoo_tpu.parallel._compat import shard_map
+    from jax import shard_map
 
     mesh = Mesh(np.asarray(jax.devices()), ("dp",))
     x = np.arange(8, dtype=np.float32).reshape(8, 1)
@@ -785,7 +785,7 @@ def test_hier_numpy_twins_match_device_bitwise(orca_context):
     multiprocess CPU collectives."""
     from jax.sharding import Mesh, PartitionSpec as P
 
-    from analytics_zoo_tpu.parallel._compat import shard_map
+    from jax import shard_map
     from analytics_zoo_tpu.parallel.comms import (hier_allreduce_np,
                                                   hier_mean_np,
                                                   hier_reduce_scatter_np)
@@ -1189,7 +1189,7 @@ def test_native_ring_matches_twin_and_exact_reduce(orca_context):
     reduce-scatter it replaces BITWISE, with a residual of exact zero."""
     from jax.sharding import Mesh, PartitionSpec as P
 
-    from analytics_zoo_tpu.parallel._compat import shard_map
+    from jax import shard_map
     from analytics_zoo_tpu.parallel.comms import (
         native_ring_reduce_scatter_np)
 

@@ -314,3 +314,100 @@ def test_asha_rung_compiles_once_and_logs_events(orca_context, tmp_path):
     compile_events = [e for e in events if e["event"] == "compile"]
     assert all({"label", "key", "seconds"} <= set(e) for e in compile_events)
     assert runtime.summary()["compile"]["cache_hits"] >= 3
+
+
+# --- cache placement ---------------------------------------------------------
+
+def _placement(monkeypatch, backend, env_dir=None, zoo_dir=None):
+    """configure_compile_cache against a throwaway cache and a recording
+    jax.config.update (returned as the dict of updates): no process-wide
+    state is touched."""
+    import jax
+
+    from analytics_zoo_tpu.compile import cache as cache_mod
+    updates, local = {}, ExecutableCache()
+    monkeypatch.setattr(jax.config, "update", updates.__setitem__)
+    monkeypatch.setattr(jax, "default_backend", lambda: backend)
+    monkeypatch.setattr(cache_mod, "get_compile_cache", lambda: local)
+    for name, value in (("JAX_COMPILATION_CACHE_DIR", env_dir),
+                        ("ZOO_COMPILE_CACHE", zoo_dir)):
+        if value is None:
+            monkeypatch.delenv(name, raising=False)
+        else:
+            monkeypatch.setenv(name, value)
+    return updates, local
+
+
+def test_cache_placement_env_var_wins_and_is_not_set_in_code(
+        tmp_path, monkeypatch):
+    """JAX_COMPILATION_CACHE_DIR set: the executable store moves there and
+    no code path updates jax_compilation_cache_dir — JAX reads the variable
+    itself, and whoever set it owns the placement."""
+    from analytics_zoo_tpu.compile import configure_compile_cache
+    env_dir = str(tmp_path / "from-env")
+    updates, local = _placement(monkeypatch, "tpu", env_dir=env_dir,
+                            zoo_dir=str(tmp_path / "ignored"))
+    assert configure_compile_cache(str(tmp_path / "also-ignored")) == env_dir
+    assert "jax_compilation_cache_dir" not in updates
+    assert local.cache_dir == env_dir and os.path.isdir(env_dir)
+    assert updates["jax_persistent_cache_min_compile_time_secs"] == 0.0
+
+
+def test_cache_placement_defaults(tmp_path, monkeypatch):
+    """Nothing set: an accelerator gets ONE fixed git-ignored path in the
+    checkout (the path is part of JAX's key: no tempdir, pid or timestamp);
+    the CPU backend persists nothing, so tests never turn compiles into
+    disk hits. An explicit directory still works on either."""
+    from analytics_zoo_tpu.compile import (DEFAULT_CACHE_DIR,
+                                           configure_compile_cache)
+    from analytics_zoo_tpu.compile import cache as cache_mod
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    assert DEFAULT_CACHE_DIR == os.path.join(root, ".zoo_compile_cache")
+    with open(os.path.join(root, ".gitignore")) as f:
+        assert ".zoo_compile_cache/" in f.read().split()
+
+    updates, local = _placement(monkeypatch, "cpu")
+    assert configure_compile_cache() is None
+    assert not updates and local.cache_dir is None
+
+    fixed = str(tmp_path / "fixed")
+    monkeypatch.setattr(cache_mod, "DEFAULT_CACHE_DIR", fixed)
+    updates, local = _placement(monkeypatch, "tpu")
+    assert configure_compile_cache() == fixed
+    assert updates["jax_compilation_cache_dir"] == fixed
+    assert local.cache_dir == fixed
+
+    explicit = str(tmp_path / "explicit")
+    updates, local = _placement(monkeypatch, "cpu", zoo_dir=explicit)
+    assert configure_compile_cache() == explicit
+    assert updates["jax_compilation_cache_dir"] == explicit
+
+
+def test_cache_files_land_under_the_env_dir(tmp_path):
+    """End to end in a fresh process: with JAX_COMPILATION_CACHE_DIR set,
+    JAX's own entries and the exe-*.pkl store land in that directory and
+    nowhere else."""
+    import subprocess
+    import sys
+    cache = tmp_path / "cache"
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    code = (
+        "import jax, jax.numpy as jnp\n"
+        "from analytics_zoo_tpu import init_orca_context\n"
+        "from analytics_zoo_tpu.compile import get_compile_cache\n"
+        "init_orca_context('local')\n"
+        "f = get_compile_cache().wrap(lambda x: jnp.sin(x) * 2, label='t')\n"
+        "f(jnp.ones((8, 8))).block_until_ready()\n"
+        "jax.jit(lambda x: jnp.cos(x) + 1)(jnp.ones((4, 4)))"
+        ".block_until_ready()\n"
+        "assert jax.config.jax_compilation_cache_dir == "
+        f"{str(cache)!r}\n")
+    env = dict(os.environ, JAX_PLATFORMS="cpu", PYTHONPATH=root,
+               JAX_COMPILATION_CACHE_DIR=str(cache))
+    env.pop("ZOO_COMPILE_CACHE", None)
+    subprocess.run([sys.executable, "-c", code], env=env, check=True,
+                   cwd=str(tmp_path), timeout=120)
+    names = os.listdir(cache)
+    assert any(n.startswith("exe-") and n.endswith(".pkl") for n in names)
+    assert any(n.startswith("jit_") for n in names), names
+    assert os.listdir(tmp_path) == ["cache"]

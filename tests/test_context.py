@@ -72,7 +72,7 @@ def test_batch_divisor(orca_context):
 
 
 def test_collectives_shard_map(orca_context):
-    from analytics_zoo_tpu.parallel._compat import shard_map
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
     from analytics_zoo_tpu.parallel import collective as C
 

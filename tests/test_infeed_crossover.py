@@ -25,7 +25,7 @@ def test_pump_hides_transfer_at_dma_bandwidth():
     assert pumped < direct
 
 
-def test_pump_cannot_help_at_tunnel_bandwidth():
+def test_pump_cannot_help_when_transfer_bound():
     """At 10 MB/s the 4 MB batch costs ~400 ms vs a 20 ms step — both
     paths are transfer-bound; the pump's steady state is ~the transfer
     time (overlap hides compute, not transfer)."""

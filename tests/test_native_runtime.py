@@ -186,3 +186,41 @@ def test_infeed_pump_abandoned_consumer_does_not_hang(caplog):
     # if close() stopped unblocking the producer, the pump would fall back
     # to the 30s join timeout and log this leak warning
     assert "infeed producer did not stop" not in caplog.text
+
+
+def test_build_stamp_forces_rebuild_when_source_changes(tmp_path,
+                                                        monkeypatch):
+    """The library is rebuilt whenever the stamp (hash of the source + the
+    compile command) differs from the one on disk — an mtime says nothing
+    about a build directory that travelled with a copied checkout — and it
+    is not built for this host's CPU only."""
+    import os
+    import shutil
+
+    from analytics_zoo_tpu.native import runtime as rt
+
+    assert "-march=native" not in rt._CXX
+    src = tmp_path / "zoo_runtime.cc"
+    shutil.copy(rt._SRC, src)
+    build = tmp_path / "build"
+    so = build / "libzoo_runtime.so"
+    monkeypatch.setattr(rt, "_SRC", str(src))
+    monkeypatch.setattr(rt, "_BUILD_DIR", str(build))
+    monkeypatch.setattr(rt, "_SO", str(so))
+    monkeypatch.setattr(rt, "_STAMP", str(so) + ".stamp")
+
+    def load_fresh():
+        monkeypatch.setattr(rt, "_lib", None)
+        assert rt.load() is not None
+        return (so.stat().st_mtime_ns, (build / "libzoo_runtime.so.stamp"
+                                        ).read_text())
+
+    built, stamp = load_fresh()
+    assert stamp == rt._build_stamp()
+    assert load_fresh() == (built, stamp)            # same stamp: no rebuild
+    with open(src, "a") as f:
+        f.write("\n// a change that an mtime could have missed\n")
+    os.utime(src, ns=(0, 0))                         # older than the .so
+    rebuilt, stamp2 = load_fresh()
+    assert stamp2 != stamp and stamp2 == rt._build_stamp()
+    assert rebuilt != built

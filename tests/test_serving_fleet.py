@@ -393,6 +393,29 @@ def test_fleet_sigkill_reclaim_and_respawn(tmp_path):
     assert snap["records_out_total"] >= 1
 
 
+def test_fleet_gives_up_on_workers_that_cannot_start(tmp_path):
+    """A worker that exits with an error before its first heartbeat (on a
+    TPU host: every chip already belongs to another process) must not be
+    respawned forever: MAX_BOOT_FAILURES in a row and the supervisor
+    stops, says so, and wait_live stops waiting."""
+    from analytics_zoo_tpu.serving.fleet import MAX_BOOT_FAILURES
+
+    # SleepModel(k=float("no-chip")) raises in the child's factory call
+    fleet = ServingFleet(
+        functools.partial(sleep_model_factory, "no-chip"),
+        f"file://{tmp_path}/fleet", workers=3, autoscale=False,
+        poll_s=0.05).start()
+    try:
+        assert not fleet.wait_live(1, 90.0)
+        m = fleet.metrics()
+        assert m["gave_up"] and m["boot_failures"] == MAX_BOOT_FAILURES, m
+        # the three start together; at most the first two deaths respawn
+        assert 3 <= m["spawned"] <= 5, m
+        assert m["restarts"] == 0 and m["workers_live"] == 0, m
+    finally:
+        fleet.stop()
+
+
 def test_fleet_autoscales_up_and_back_down(tmp_path):
     """Occupancy-driven 1 -> 2 -> 1: saturate one worker (sleep-bound, so
     occupancy ~1.0), the control loop adds a worker after the sustain
