@@ -59,10 +59,13 @@ _MAX_DEPTH = 8
 class PipelineStats:
     """Monotonic per-stage timers/counters for the input pipeline.
 
-    Stages: ``assemble`` (host batch gather/pad), ``h2d`` (device_put),
-    ``step`` (engine dispatch, recorded by TrainEngine), ``stall`` (time
-    the consumer waited on the delivery queue). Thread-safe; shared by the
-    iterator, the pump, and the engine.
+    Stages: ``assemble`` (host batch gather/pad), ``h2d`` (device_put: on an
+    accelerator the time to enqueue, not the transfer), ``step`` (engine
+    dispatch, recorded by TrainEngine), ``stall`` (time the consumer waited
+    on the delivery queue for every batch of an epoch but the first),
+    ``first_batch`` (an epoch's entry into the pump, thread start included,
+    to its first delivered batch: the pipeline's fill, once an epoch).
+    Thread-safe; shared by the iterator, the pump, and the engine.
 
     Stages that report bytes (H2D always; assemble when the pump feeds it)
     get a ``<stage>_MBps`` rate in :meth:`snapshot`, and the snapshot carries
@@ -73,7 +76,7 @@ class PipelineStats:
     per-lane rate; aggregate wire rate is up to ``lanes ×`` that.
     """
 
-    STAGES = ("assemble", "h2d", "step", "stall")
+    STAGES = ("assemble", "h2d", "step", "stall", "first_batch")
 
     def __init__(self):
         self._lock = threading.Lock()
@@ -298,7 +301,10 @@ class InfeedPump:
     def _transfer(self, host_batch):
         """One lane's work: stage a whole batch into HBM. Runs concurrently
         on up to ``lanes`` threads; ordering is restored by the caller's
-        FIFO future window."""
+        FIFO future window. On an accelerator ``device_put`` returns at the
+        enqueue, before the bytes are on the device: the span and ``h2d_s``
+        both end there (PERF.md, PR 33, has the time to readiness and why
+        no lane waits for it)."""
         with _trace.span_under(self._trace_token, "infeed.h2d"):
             t0 = time.perf_counter()
             dev = self._device_put(host_batch)
@@ -359,6 +365,8 @@ class InfeedPump:
                     # next(); that time IS the assemble stage
                     self.stats.add("assemble", dt,
                                    nbytes=_batch_nbytes(item))
+                    _trace.record_span("infeed.assemble", t0, t0 + dt,
+                                       parent=self._trace_token)
                     if not submit_h2d(item):
                         return
             while asm_window:
@@ -405,34 +413,38 @@ class InfeedPump:
         # inside fit's epoch span; the producer + lane threads parent their
         # spans here so one trace id covers fit → assemble → h2d
         self._trace_token = _trace.token()
+        t_enter = time.perf_counter()
         q = _FlexQueue(self._depth)
         self.stats.observe_depth(q.capacity)
         err: list = []
         t = threading.Thread(target=self._producer, args=(q, err),
                              daemon=True, name="zoo-infeed-pump")
-        t.start()
-        first = True
         try:
-            while True:
-                t0 = time.perf_counter()
+            # the pump is restarted every epoch (a new thread, new pools),
+            # so its fill is paid once an epoch: a stage of its own, not a
+            # steady-state starvation signal
+            with _trace.span("infeed.first_batch"):
+                t.start()
                 item = q.get()
+            self.stats.add("first_batch", time.perf_counter() - t_enter)
+            while item is not _STOP and item is not None:
+                yield item
+                t0 = time.perf_counter()
+                with _trace.span("infeed.wait"):    # never across the yield
+                    item = q.get()
                 wait = time.perf_counter() - t0
                 if item is _STOP or item is None:
                     break
-                # the first get always waits on pipeline warmup — not a
-                # steady-state starvation signal
-                if not first:
-                    self.stats.add("stall", wait)
-                    if wait > 1e-4 and t.is_alive():
-                        # consumer starved while the producer still runs:
-                        # deepen the buffer (bounded by the memory budget)
-                        # and/or open another transfer lane
-                        self._maybe_grow(q, item)
-                first = False
-                yield item
+                self.stats.add("stall", wait)
+                if wait > 1e-4 and t.is_alive():
+                    # consumer starved while the producer still runs:
+                    # deepen the buffer (bounded by the memory budget)
+                    # and/or open another transfer lane
+                    self._maybe_grow(q, item)
         finally:
             q.close()                   # unblocks the producer's put()
-            t.join(timeout=30)
+            if t.ident is not None:     # started
+                t.join(timeout=30)
             if t.is_alive():
                 import logging
                 logging.getLogger("analytics_zoo_tpu").warning(

@@ -17,25 +17,39 @@ The serving path additionally rides the token *through the broker payload
 meta*, Dapper-style, so the device-dispatch span in the batcher thread
 chains to the HTTP request span that enqueued it.
 
+Liveness: a span is live when tracing is armed (``ZOO_TRACE=1`` at import
+time, :func:`arm`, the :func:`tracing` context manager) **or while a JAX
+profiler session is collecting in this process** (``jax.profiler
+.start_trace`` … ``stop_trace``, ``fit(profile=<dir>)``, a TensorBoard
+capture), which ``TraceAnnotation.is_enabled()`` answers. Every hook uses
+that one predicate. While a session is collecting, a live span also enters
+a ``jax.profiler.TraceAnnotation("zoo:<name>", **attrs)`` for its duration:
+the same span is then in the ``.xplane.pb`` on the profiler's clock, on its
+own thread's line, beside the device's ops. (:func:`record_span` is
+retroactive, so it reaches the ring only.)
+
 Cost discipline (same as ``resilience/faults.py``): the production hook is
-:func:`span`, whose disarmed path is one module-global flag check returning
-a shared no-op context manager — measured in ``bench.py --only obs`` and
-CI-gated below 1% of the NCF smoke step. Arm with ``ZOO_TRACE=1`` (import
-time), :func:`arm`, or the :func:`tracing` context manager. Finished spans
-land in a bounded ring (``ZOO_TRACE_RING`` spans, default 4096, oldest
-evicted) exported by ``obs/export.py`` as Chrome/Perfetto ``trace_event``
-JSON (``ZOO_TRACE_PERFETTO=<path>`` writes it at process exit).
+:func:`span`, whose off path is one module-global flag check and one
+``is_enabled()`` call returning a shared no-op context manager — measured
+in ``bench.py --only obs`` and CI-gated below 1% of the NCF smoke step.
+Finished spans land in a bounded ring (``ZOO_TRACE_RING`` spans, default
+4096, oldest evicted) exported by ``obs/export.py`` as Chrome/Perfetto
+``trace_event`` JSON (``ZOO_TRACE_PERFETTO=<path>`` writes it at process
+exit).
 """
 
 from __future__ import annotations
 
 import contextvars
+import os
+import random
 import threading
 import time
-import uuid
 from collections import deque
 from contextlib import contextmanager
 from typing import Any, Dict, List, Optional, Tuple
+
+from jax.profiler import TraceAnnotation as _Annotation
 
 from ..common import knobs
 
@@ -119,8 +133,16 @@ _ctx: contextvars.ContextVar[Optional[Tuple[str, str]]] = \
 _armed = False
 
 
+# ids come from a generator seeded once a process (and again in a forked
+# child), not from ``uuid4``: that reads ``os.urandom``, which releases the
+# GIL, and beside the infeed's assembly threads a span site then waited
+# milliseconds to get it back (PERF.md, PR 33)
+_ids = random.Random()
+os.register_at_fork(after_in_child=_ids.seed)
+
+
 def _new_id() -> str:
-    return uuid.uuid4().hex[:16]
+    return "%016x" % _ids.getrandbits(64)
 
 
 # --- arming ------------------------------------------------------------------
@@ -135,8 +157,13 @@ def disarm():
     _armed = False
 
 
+#: true between ``start_trace`` and ``stop_trace`` (host tracer level >= 1)
+_profiling = _Annotation.is_enabled
+
+
 def enabled() -> bool:
-    return _armed
+    """The one liveness predicate: armed, or a profiler session collects."""
+    return _armed or _profiling()
 
 
 @contextmanager
@@ -185,10 +212,11 @@ _NOOP = _Noop()
 
 
 class _LiveSpan:
-    """Armed context manager: stamps ids, times the body, records on exit."""
+    """Live context manager: stamps ids, times the body, records on exit;
+    under a profiler session it is a ``zoo:<name>`` annotation as well."""
 
     __slots__ = ("name", "attrs", "_parent", "trace_id", "span_id",
-                 "_t0", "_reset")
+                 "_t0", "_reset", "_ann")
 
     def __init__(self, name: str, parent: Optional[Tuple[str, str]],
                  attrs: Dict[str, Any]):
@@ -199,9 +227,13 @@ class _LiveSpan:
         self.span_id = _new_id()
         self._t0 = 0.0
         self._reset = None
+        self._ann = None
 
     def __enter__(self):
         self._reset = _ctx.set((self.trace_id, self.span_id))
+        if _profiling():
+            self._ann = _Annotation("zoo:" + self.name, **self.attrs)
+            self._ann.__enter__()
         # perf_counter, not time.time(): spans are intervals and the
         # Perfetto export renders t0 relative to the run's first span —
         # an NTP step mid-run must not produce negative durations or
@@ -212,6 +244,8 @@ class _LiveSpan:
 
     def __exit__(self, exc_type, exc, tb):
         t1 = time.perf_counter()
+        if self._ann is not None:
+            self._ann.__exit__(exc_type, exc, tb)
         if self._reset is not None:
             _ctx.reset(self._reset)
         if exc_type is not None:
@@ -224,13 +258,16 @@ class _LiveSpan:
 
     def set(self, **attrs):
         self.attrs.update(attrs)
+        if self._ann is not None:
+            self._ann.set_metadata(**attrs)
         return self
 
 
 def span(name: str, **attrs):
     """Open a span under the current context (or start a new trace at a
-    root site). Disarmed: one flag check, shared no-op back."""
-    if not _armed:
+    root site). Off: one flag check and one ``is_enabled()``, shared no-op
+    back."""
+    if not enabled():
         return _NOOP
     return _LiveSpan(name, _ctx.get(), attrs)
 
@@ -240,7 +277,7 @@ def span_under(tok: Optional[str], name: str, **attrs):
     :func:`token`, captured on the originating thread) — the cross-thread
     form of :func:`span`. A ``None`` token falls back to the local
     context (so a disarmed-at-capture pump still nests sanely)."""
-    if not _armed:
+    if not enabled():
         return _NOOP
     return _LiveSpan(name, _parse(tok) or _ctx.get(), attrs)
 
@@ -251,8 +288,9 @@ def record_span(name: str, t0: float, t1: float,
     parent token is only known after the work ran, e.g. the serving
     decode stage discovering the request's token inside the payload).
     ``t0``/``t1`` must come from ``time.perf_counter()`` — the span
-    timebase all live spans use."""
-    if not _armed:
+    timebase all live spans use. A past interval cannot be written into a
+    profiler session, so it reaches the ring only."""
+    if not enabled():
         return
     p = _parse(parent) or _ctx.get()
     t = threading.current_thread()
@@ -265,9 +303,9 @@ def record_span(name: str, t0: float, t1: float,
 
 def token() -> Optional[str]:
     """The current span context as a portable string token (``trace:span``)
-    for thread/process/payload handoff; None when disarmed or outside any
+    for thread/process/payload handoff; None when off or outside any
     span."""
-    if not _armed:
+    if not enabled():
         return None
     cur = _ctx.get()
     return f"{cur[0]}:{cur[1]}" if cur else None

@@ -485,28 +485,36 @@ class TrainEngine:
 
     # --- steps --------------------------------------------------------------
     def _train_step(self, params, extra, opt_state, step, x, y, w):
-        x, y = self._pre(x, y)
+        # stable scope names on the step's ops (free at run time; no op
+        # changes): a trace's reduction splits a step's device time into
+        # `jvp(forward)`, `transpose(jvp(forward))` (the backward; JAX wraps
+        # the scope's name itself) and `optimizer`, whatever XLA fuses
+        with jax.named_scope("prologue"):
+            x, y = self._pre(x, y)
         rng = jax.random.fold_in(jax.random.PRNGKey(self.seed), step)
 
         def loss_of(p):
-            preds, new_extra = self._apply(p, extra, x, True, rng)
-            loss = self._compute_loss(y, preds, w)
+            with jax.named_scope("forward"):
+                preds, new_extra = self._apply(p, extra, x, True, rng)
+                loss = self._compute_loss(y, preds, w)
             return loss, (preds, new_extra)
 
         (loss, (_, new_extra)), grads = jax.value_and_grad(
             loss_of, has_aux=True)(params)
-        grads = self._clip_grads(grads)
-        if self.fsdp_plan is not None and FsdpPlan.is_composite(grads):
-            # constrain bucket grads back to P(fsdp): XLA combines over
-            # the fsdp groups and each device keeps only its own shard,
-            # so the optimizer update below is shard-local (ZeRO)
-            grads = self.fsdp_plan.constrain_shards(grads)
-        updates, new_opt = self.tx.update(grads, opt_state, params)
-        new_params = optax.apply_updates(params, updates)
-        if self.fsdp_plan is not None and FsdpPlan.is_composite(new_params):
-            # pin updated params onto their resting shardings so scan
-            # carries and donated outputs keep the 1/N layout
-            new_params = self.fsdp_plan.constrain_shards(new_params)
+        with jax.named_scope("optimizer"):
+            grads = self._clip_grads(grads)
+            if self.fsdp_plan is not None and FsdpPlan.is_composite(grads):
+                # constrain bucket grads back to P(fsdp): XLA combines over
+                # the fsdp groups and each device keeps only its own shard,
+                # so the optimizer update below is shard-local (ZeRO)
+                grads = self.fsdp_plan.constrain_shards(grads)
+            updates, new_opt = self.tx.update(grads, opt_state, params)
+            new_params = optax.apply_updates(params, updates)
+            if self.fsdp_plan is not None \
+                    and FsdpPlan.is_composite(new_params):
+                # pin updated params onto their resting shardings so scan
+                # carries and donated outputs keep the 1/N layout
+                new_params = self.fsdp_plan.constrain_shards(new_params)
         return new_params, new_extra, new_opt, loss
 
     def _train_multi_step(self, params, extra, opt_state, step0, xs, ys, ws):
@@ -987,39 +995,6 @@ class TrainEngine:
         return fn.cache_key(self.params, self.extra_vars, metric_states,
                             batch.x, batch.y, batch.w)
 
-    def _record_comms_spans(self, t0: float, t1: float,
-                            parent: Optional[str], steps: int = 1):
-        """Per-bucket ``comms.rs_start`` / ``comms.rs_done`` span markers
-        on the step timeline (overlapped mode, tracing armed).
-
-        The reduce-scatters launch INSIDE one fused XLA program, so their
-        per-bucket device timing is not host-observable; what the host
-        does know is the measured dispatch window and the static plan
-        (bucket count, wire bytes, segment order). The markers place each
-        bucket's launch/completion across the window in plan order,
-        carrying the declared byte accounting as attrs — enough for the
-        Perfetto timeline to attribute which slice of the step is wire
-        time and which bucket it belongs to (``modeled: true`` says the
-        sub-step placement is derived, not sampled)."""
-        plan = self.comms
-        lo = plan.layout
-        n_b = len(lo.bucket_sizes)
-        window = (t1 - t0) / max(steps, 1)
-        per_bucket_bytes = lo.wire_bytes_per_step() / n_b
-        for s in range(min(steps, 8)):      # cap fused attribution depth
-            base = t0 + s * window
-            for k in range(n_b):
-                ts = base + window * k / n_b
-                te = base + window * (k + 1) / n_b
-                _trace.record_span("comms.rs_start", ts, ts, parent=parent,
-                                   bucket=k, step=self.step + s,
-                                   wire_bytes=int(per_bucket_bytes),
-                                   segments=plan.segplan.n_segments,
-                                   modeled=True)
-                _trace.record_span("comms.rs_done", te, te, parent=parent,
-                                   bucket=k, step=self.step + s,
-                                   modeled=True)
-
     def train_batch(self, batch: Batch) -> jnp.ndarray:
         self.ensure_jit_train()
         # resilience hooks (one global read each when disarmed): the
@@ -1028,7 +1003,6 @@ class TrainEngine:
         wd = _watchdog.active()
         token = wd.enter("engine.dispatch") if wd is not None else None
         t0 = time.perf_counter()
-        tok = None
         try:
             # obs span (one flag check disarmed): the per-step device-time
             # segment the Perfetto timeline renders, step-indexed
@@ -1042,14 +1016,10 @@ class TrainEngine:
                 else:
                     self.params, self.extra_vars, self.opt_state, loss = \
                         self._jit_train(*self.train_step_args(batch))
-                tok = _trace.token()
         finally:
             if token is not None:
                 wd.exit(token)
         t1 = time.perf_counter()
-        if (self.comms is not None and self.comms.segplan is not None
-                and _trace.enabled()):
-            self._record_comms_spans(t0, t1, tok)
         if self.pipeline_stats is not None:
             self.pipeline_stats.add("step", t1 - t0)
         self.step += 1
@@ -1075,7 +1045,6 @@ class TrainEngine:
         wd = _watchdog.active()
         token = wd.enter("engine.dispatch") if wd is not None else None
         t0 = time.perf_counter()
-        tok = None
         try:
             with _trace.span("engine.dispatch", step=self.step,
                              fused=int(batch.fused)):
@@ -1087,7 +1056,6 @@ class TrainEngine:
                 else:
                     self.params, self.extra_vars, self.opt_state, losses = \
                         self._jit_train_multi(*self.train_step_args(batch))
-                tok = _trace.token()
         finally:
             if token is not None:
                 wd.exit(token)
@@ -1095,8 +1063,6 @@ class TrainEngine:
         k = int(losses.shape[0])
         if self.comms is not None:
             self.comms_steps += k
-            if self.comms.segplan is not None and _trace.enabled():
-                self._record_comms_spans(t0, t1, tok, steps=k)
         if self.pipeline_stats is not None:
             self.pipeline_stats.add("step", t1 - t0,
                                     count=k)

@@ -123,6 +123,7 @@ class TPUEstimator:
         self.train_stats: List[Dict[str, float]] = []
         self._tb_train = None
         self._tb_val = None
+        self._profile_open = False      # fit(profile=<dir>)'s session
         # probed fuse factors per (mode, input signature): fit with
         # validation_data evaluates every epoch, and hyperparameter loops
         # re-fit — the probe answer cannot change for the same
@@ -169,10 +170,12 @@ class TPUEstimator:
     def data_pipeline_stats(self, reset: bool = False) -> Dict[str, Any]:
         """Cumulative input-pipeline stage counters: ``assemble_s`` (host
         batch gather), ``h2d_s`` (+``h2d_bytes``/``h2d_MBps``, device
-        staging), ``step_s`` (engine dispatch), ``stall_s`` (training loop
-        starved waiting on the infeed), plus the pump's prefetch ``depth``
-        history. Every future perf PR should look here first to see where
-        epoch time goes."""
+        staging: on an accelerator the time to enqueue), ``step_s`` (engine
+        dispatch), ``stall_s`` (training loop starved waiting on the
+        infeed), ``first_batch_s`` (each epoch's wait for the pump's first
+        batch, which ``stall_s`` leaves out), plus the pump's prefetch
+        ``depth`` history. Every future perf PR should look here first to see
+        where epoch time goes."""
         snap = self._pipeline_stats.snapshot()
         if self._ckpt_plane is not None:
             # checkpoint-plane counters (bytes written, dedup ratio, save
@@ -267,7 +270,15 @@ class TPUEstimator:
         ``profile`` — True collects per-step data-wait / step-execution
         timings into the epoch stats (the Ray torch runner's ``profile=True``,
         reference torch_runner.py:360); a directory path additionally wraps
-        the first epoch in a ``jax.profiler`` trace.
+        the call up to the end of its first epoch in a ``jax.profiler``
+        trace, which holds the program's spans as ``zoo:<name>`` annotations
+        beside the device's ops (docs/observability.md). The session starts
+        before ``fit.prepare``, and on a TPU host it slows every
+        host-to-device transfer (the runtime logs each chunk it re-tiles):
+        the prepare's one sample batch, put and fetched back, took ~2 s in
+        such a capture against 0.13 s outside one (256 uint8 ImageNet
+        images, v5e; PERF.md, PR 33), so read host times from
+        ``ZOO_TRACE=1`` and device times from the capture.
 
         ``max_failure_retries`` — when ``model_dir`` is set, a failing
         training step is retried from the latest checkpoint up to this many
@@ -279,6 +290,82 @@ class TPUEstimator:
         calls (the AutoML scheduler's pause/resume): with it, epoch i of a
         resumed run draws the same shuffle order as epoch i of an
         uninterrupted one, keeping segmented training bit-equivalent."""
+        if isinstance(profile, str):
+            # before the root span: a span is live, and mirrored into the
+            # trace, only if the session collects when it opens
+            jax.profiler.start_trace(profile)
+            self._profile_open = True
+        step0 = self._trainer_state.iteration
+        try:
+            # root span of the training trace (obs plane): everything the
+            # call does; prepare, epoch, dispatch, infeed-lane and
+            # ckpt-writer spans all chain under this trace id
+            with _trace.span("fit", epochs=epochs,
+                             initial_epoch=initial_epoch) as root:
+                try:
+                    return self._fit(
+                        data, epochs, batch_size, feature_cols, label_cols,
+                        validation_data, checkpoint_trigger, steps_per_epoch,
+                        shuffle, verbose, profile, max_failure_retries,
+                        initial_epoch)
+                finally:
+                    root.set(steps=self._trainer_state.iteration - step0)
+        finally:
+            self._stop_profile()
+
+    def _stop_profile(self):
+        """End ``fit(profile=<dir>)``'s profiler session, once: after the
+        call's first epoch, or when the call ends before that."""
+        if self._profile_open:
+            self._profile_open = False
+            jax.profiler.stop_trace()
+
+    def _fit(self, data, epochs, batch_size, feature_cols, label_cols,
+             validation_data, checkpoint_trigger, steps_per_epoch, shuffle,
+             verbose, profile, max_failure_retries, initial_epoch):
+        with _trace.span("fit.prepare"):
+            it, checkpoint_trigger, can_recover, retries_left, fuse = \
+                self._fit_prepare(data, batch_size, feature_cols, label_cols,
+                                  checkpoint_trigger, steps_per_epoch,
+                                  shuffle, max_failure_retries,
+                                  initial_epoch)
+        import contextlib
+
+        from .preemption import PreemptionWatcher
+
+        epoch_stats = []
+        watcher = PreemptionWatcher() if can_recover else None
+        try:
+            with (watcher if watcher is not None
+                  else contextlib.nullcontext()):
+                return self._fit_loop(it, epochs, steps_per_epoch,
+                                      batch_size, feature_cols,
+                                      label_cols, validation_data,
+                                      checkpoint_trigger, profile,
+                                      verbose, can_recover,
+                                      retries_left, epoch_stats,
+                                      watcher, fuse)
+        finally:
+            # returning from fit() means every queued checkpoint is
+            # durable — resumers (AutoML pause/resume, a supervisor
+            # restart) read the dir right after. A failed async write
+            # gets one blocking retry; past that, log-and-continue (an
+            # exception here would mask the loop's own)
+            if not self.flush_checkpoints() and self.model_dir is not None:
+                try:
+                    self.save_checkpoint(self.model_dir, blocking=True)
+                except Exception as save_err:       # noqa: BLE001
+                    logger.error(
+                        "final checkpoint could not be written (%s); the "
+                        "newest restore point predates this fit's last "
+                        "trigger", save_err)
+
+    def _fit_prepare(self, data, batch_size, feature_cols, label_cols,
+                     checkpoint_trigger, steps_per_epoch, shuffle,
+                     max_failure_retries, initial_epoch):
+        """What a ``fit`` call does before its first epoch: the iterator, one
+        sample batch for ``engine.build``, checkpoint arming, the fuse
+        factor."""
         it = learn_utils.data_to_iterator(
             data, batch_size, self.mesh, feature_cols, label_cols,
             shuffle=shuffle, config=self.config,
@@ -321,11 +408,6 @@ class TPUEstimator:
                 learn_utils.find_latest_checkpoint(self.model_dir)[0] is None:
             # guarantee a restore point exists before the first step
             self.save_checkpoint(self.model_dir)
-
-        import contextlib
-
-        from .preemption import PreemptionWatcher
-
         try:
             fuse = self._choose_fuse(it, steps_per_epoch, checkpoint_trigger)
         except (KeyboardInterrupt, SystemExit):
@@ -342,37 +424,7 @@ class TPUEstimator:
             logger.warning("fuse probe failed (%s: %s); training unfused",
                            type(e).__name__, e)
             fuse = 1
-        epoch_stats = []
-        watcher = PreemptionWatcher() if can_recover else None
-        try:
-            with (watcher if watcher is not None
-                  else contextlib.nullcontext()):
-                # root span of the training trace (obs plane): epoch,
-                # dispatch, infeed-lane and ckpt-writer spans all chain
-                # under this trace id
-                with _trace.span("fit", epochs=epochs,
-                                 initial_epoch=initial_epoch):
-                    return self._fit_loop(it, epochs, steps_per_epoch,
-                                          batch_size, feature_cols,
-                                          label_cols, validation_data,
-                                          checkpoint_trigger, profile,
-                                          verbose, can_recover,
-                                          retries_left, epoch_stats,
-                                          watcher, fuse)
-        finally:
-            # returning from fit() means every queued checkpoint is
-            # durable — resumers (AutoML pause/resume, a supervisor
-            # restart) read the dir right after. A failed async write
-            # gets one blocking retry; past that, log-and-continue (an
-            # exception here would mask the loop's own)
-            if not self.flush_checkpoints() and self.model_dir is not None:
-                try:
-                    self.save_checkpoint(self.model_dir, blocking=True)
-                except Exception as save_err:       # noqa: BLE001
-                    logger.error(
-                        "final checkpoint could not be written (%s); the "
-                        "newest restore point predates this fit's last "
-                        "trigger", save_err)
+        return it, checkpoint_trigger, can_recover, retries_left, fuse
 
     def _choose_fuse(self, it, steps_per_epoch, trigger=None) -> int:
         """Pick the scan-fusion factor for this fit. Small-model steps are
@@ -549,6 +601,10 @@ class TPUEstimator:
                     ep + 1, type(e).__name__, e, path, retries_left)
                 self._trainer_state.iteration = self.engine.step
                 continue                 # re-run the failed epoch
+            finally:
+                # fit(profile=<dir>) traces up to the end of the call's
+                # first epoch, its epoch-end sync included
+                self._stop_profile()
             if watcher is not None and watcher.triggered:
                 # preemption notice (SIGTERM on spot/preemptible TPU VMs):
                 # checkpoint IMMEDIATELY — the grace window is short, and
@@ -615,52 +671,46 @@ class TPUEstimator:
         tb_steps = []
         nsteps = steps_per_epoch or it.steps_per_epoch
         prof = {"data_s": 0.0, "step_s": 0.0} if profile else None
-        tracing = isinstance(profile, str) and ep == 0
-        if tracing:
-            jax.profiler.start_trace(profile)
         steps_done = 0
-        try:
-            batches = iter(it.epoch(fuse=fuse) if fuse > 1 else it.epoch())
-            while fuse > 1 or steps_done < nsteps:
-                if prof is not None:
-                    td = time.perf_counter()
-                batch = next(batches, None)
-                if batch is None:
-                    break
-                if prof is not None:
-                    ts = time.perf_counter()
-                    prof["data_s"] += ts - td
-                if getattr(batch, "fused", 1) > 1:
-                    loss = self.engine.train_batch_group(batch)
-                    took = batch.fused
-                else:
-                    loss = self.engine.train_batch(batch)
-                    took = 1
-                steps_done += took
-                if prof is not None:
-                    jax.block_until_ready(loss)
-                    prof["step_s"] += time.perf_counter() - ts
-                losses.append(loss)
-                self._trainer_state.iteration += took
-                if self._tb_train is not None:
-                    # keep the device array; flush with ONE device_get at
-                    # epoch end so logging never blocks async dispatch
-                    tb_steps.extend(
-                        range(self._trainer_state.iteration - took + 1,
-                              self._trainer_state.iteration + 1))
-                if checkpoint_trigger and self.model_dir:
-                    self._trainer_state.epoch_finished = False
-                    if checkpoint_trigger(self._trainer_state):
-                        self.save_checkpoint(self.model_dir)
-                if watcher is not None and watcher.triggered:
-                    break        # preemption: end the epoch at this step
-        finally:
-            if tracing:
-                jax.profiler.stop_trace()
+        batches = iter(it.epoch(fuse=fuse) if fuse > 1 else it.epoch())
+        while fuse > 1 or steps_done < nsteps:
+            if prof is not None:
+                td = time.perf_counter()
+            batch = next(batches, None)
+            if batch is None:
+                break
+            if prof is not None:
+                ts = time.perf_counter()
+                prof["data_s"] += ts - td
+            if getattr(batch, "fused", 1) > 1:
+                loss = self.engine.train_batch_group(batch)
+                took = batch.fused
+            else:
+                loss = self.engine.train_batch(batch)
+                took = 1
+            steps_done += took
+            if prof is not None:
+                jax.block_until_ready(loss)
+                prof["step_s"] += time.perf_counter() - ts
+            losses.append(loss)
+            self._trainer_state.iteration += took
+            if self._tb_train is not None:
+                # keep the device array; flush with ONE device_get at
+                # epoch end so logging never blocks async dispatch
+                tb_steps.extend(
+                    range(self._trainer_state.iteration - took + 1,
+                          self._trainer_state.iteration + 1))
+            if checkpoint_trigger and self.model_dir:
+                self._trainer_state.epoch_finished = False
+                if checkpoint_trigger(self._trainer_state):
+                    self.save_checkpoint(self.model_dir)
+            if watcher is not None and watcher.triggered:
+                break        # preemption: end the epoch at this step
         # the epoch-end sync is where a wedged device actually blocks on
         # real TPUs (dispatch is async) — bound it like the dispatches
         from ...resilience.watchdog import watched
-        host_losses = watched("engine.sync", jax.device_get, losses)
+        with _trace.span("epoch.sync"):
+            host_losses = watched("engine.sync", jax.device_get, losses)
         if host_losses:
             host_losses = np.concatenate(
                 [np.atleast_1d(np.asarray(l)) for l in host_losses])

@@ -670,38 +670,6 @@ def test_overlap_knobs_resolve_and_default_bucket(orca_context,
         CommsConfig(overlap=True, segments=-1)
 
 
-def test_overlapped_rs_spans_in_perfetto_timeline(orca_context):
-    """Per-bucket ``comms.rs_start``/``comms.rs_done`` markers land on the
-    step timeline under the dispatch span's trace and survive the
-    Perfetto export — the attribution surface the stall analysis reads."""
-    from analytics_zoo_tpu.obs import trace
-    from analytics_zoo_tpu.obs.export import perfetto_trace
-
-    with trace.tracing():
-        _, est = _fit({"grad_bucket_mb": 0.001, "comms_overlap": True},
-                      epochs=1, sharded_update=True)
-        spans = trace.spans()
-    n_b = len(est.engine.comms.layout.bucket_sizes)
-    by = {}
-    for s in spans:
-        by.setdefault(s.name, []).append(s)
-    starts, dones = by.get("comms.rs_start", []), by.get("comms.rs_done", [])
-    assert {s.attrs["bucket"] for s in starts} == set(range(n_b))
-    assert {s.attrs["bucket"] for s in dones} == set(range(n_b))
-    assert all(s.attrs["wire_bytes"] > 0 and s.attrs["modeled"]
-               for s in starts)
-    # chained into the dispatch trace, not floating as their own roots
-    disp_traces = {s.trace_id for s in by["engine.dispatch"]}
-    assert all(s.trace_id in disp_traces for s in starts + dones)
-    doc = perfetto_trace(spans)
-    names = {e.get("name") for e in doc["traceEvents"]}
-    assert {"comms.rs_start", "comms.rs_done"} <= names
-    # disarmed runs record nothing (the hook is one flag check)
-    trace.clear()
-    _fit({"grad_bucket_mb": 0.001, "comms_overlap": True}, epochs=1)
-    assert not trace.spans()
-
-
 # ---------------------------------------------------------------------------
 # PR 12: pod-scale hierarchical comms — ICI reduce-scatter x DCN exchange
 # ---------------------------------------------------------------------------

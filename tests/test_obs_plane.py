@@ -481,3 +481,248 @@ def test_trial_events_carry_per_trial_trace_ids(orca_context, tmp_path,
     # one consistent trace id per trial, distinct across trials
     assert all(len(tids) == 1 for tids in per_trial.values())
     assert len(set().union(*per_trial.values())) == 2
+
+
+# ---------------------------------------------------------------------------
+# ISSUE 33: spans live under a JAX profiler session, and where fit loses time
+# ---------------------------------------------------------------------------
+
+FIT_SPANS = ("fit", "fit.prepare", "epoch", "infeed.first_batch",
+             "infeed.wait", "infeed.assemble", "infeed.h2d",
+             "engine.dispatch", "epoch.sync")
+
+
+def _tiny_fit(**fit_kwargs):
+    from analytics_zoo_tpu.orca.learn.estimator import TPUEstimator
+    rng = np.random.RandomState(0)
+    est = TPUEstimator(_tiny_module(), loss="mse", optimizer="adam", seed=0,
+                       config={"steps_per_dispatch": 1})
+    est.fit({"x": rng.rand(128, 8).astype(np.float32),
+             "y": rng.rand(128).astype(np.float32)},
+            epochs=2, batch_size=32, verbose=False, **fit_kwargs)
+    return est
+
+
+@pytest.fixture(scope="module")
+def profiled_fit(tmp_path_factory):
+    """One tiny fit, unarmed, inside a profiler session: the ring's spans,
+    the session's trace file, and what ``span()`` returned before and after."""
+    import glob
+
+    import jax
+
+    from analytics_zoo_tpu import init_orca_context
+    from analytics_zoo_tpu.common import context as ctx_mod
+    live = ctx_mod._current
+    if live is None or live._stopped:
+        init_orca_context("cpu-sim", mesh_axes={"dp": -1})
+    trace.disarm()
+    trace.clear()
+    _tiny_fit().shutdown()              # no session, unarmed: compiles too
+    out = {"before": trace.spans(), "noop_before": trace.span("x")}
+    logdir = str(tmp_path_factory.mktemp("profile"))
+    jax.profiler.start_trace(logdir)
+    try:
+        out["enabled_inside"] = trace.enabled()
+        _tiny_fit().shutdown()
+    finally:
+        jax.profiler.stop_trace()
+    out["noop_after"] = trace.span("x")
+    out["spans"] = trace.spans()
+    (out["xplane"],) = glob.glob(
+        logdir + "/plugins/profile/*/*.xplane.pb")
+    trace.clear()
+    return out
+
+
+def _by_name(spans):
+    by = {}
+    for s in spans:
+        by.setdefault(s.name, []).append(s)
+    return by
+
+
+def test_profiler_session_makes_fit_spans_live(profiled_fit):
+    """(a) nothing armed: the ring stays empty through a fit, fills inside
+    ``start_trace`` … ``stop_trace`` under one trace id with the catalogue's
+    parent links, and is off again after the session."""
+    with trace.span("x") as noop:
+        pass
+    assert profiled_fit["before"] == []
+    assert profiled_fit["noop_before"] is noop
+    assert profiled_fit["noop_after"] is noop
+    assert profiled_fit["enabled_inside"] and not trace.enabled()
+    spans = profiled_fit["spans"]
+    by = _by_name(spans)
+    assert set(FIT_SPANS) <= set(by)
+    (fit,) = by["fit"]
+    assert fit.parent_id is None and fit.attrs["steps"] == 8
+    assert {s.trace_id for s in spans} == {fit.trace_id}
+    by_id = {s.span_id: s for s in spans}
+    parent = {"fit.prepare": "fit", "epoch": "fit",
+              "infeed.first_batch": "epoch", "infeed.wait": "epoch",
+              "infeed.assemble": "epoch", "infeed.h2d": "epoch",
+              "engine.dispatch": "epoch", "epoch.sync": "epoch"}
+    for name, above in parent.items():
+        assert all(by_id[s.parent_id].name == above for s in by[name]), name
+    for name in ("fit.prepare", "epoch", "infeed.first_batch", "infeed.wait",
+                 "engine.dispatch", "epoch.sync"):
+        assert all(s.thread == fit.thread for s in by[name]), name
+    for name in ("infeed.assemble", "infeed.h2d"):
+        assert all(s.thread != fit.thread for s in by[name]), name
+    assert len(by["epoch"]) == len(by["infeed.first_batch"]) == \
+        len(by["epoch.sync"]) == 2
+    assert len(by["engine.dispatch"]) == len(by["infeed.h2d"]) == 8
+
+
+def test_profiler_trace_holds_zoo_annotations(profiled_fit):
+    """(b) the same spans are in the session's ``.xplane.pb`` as ``zoo:``
+    annotations on a host plane, ``engine.dispatch`` inside ``fit`` in time."""
+    import jax
+    data = jax.profiler.ProfileData.from_file(profiled_fit["xplane"])
+    found = {}
+    for plane in data.planes:
+        if not plane.name.startswith("/host:"):
+            continue
+        for line in plane.lines:
+            for ev in line.events:
+                if ev.name.startswith("zoo:"):
+                    found.setdefault(ev.name, []).append(
+                        (ev.start_ns, ev.start_ns + ev.duration_ns))
+    assert {"zoo:" + n for n in FIT_SPANS} <= set(found)
+    ((fit_lo, fit_hi),) = found["zoo:fit"]
+    assert len(found["zoo:engine.dispatch"]) == 8
+    assert all(fit_lo <= lo and hi <= fit_hi
+               for lo, hi in found["zoo:engine.dispatch"])
+
+
+def test_fit_prepare_span_and_ready_batch_assembly(profiled_fit):
+    """(c) ``fit.prepare`` opens with ``fit`` and closes before the first
+    epoch; a factory of ready batches (the ImageNetPipeline contract) leaves
+    ``infeed.assemble`` spans too, from the producer thread."""
+    from analytics_zoo_tpu.native.infeed import InfeedPump
+    by = _by_name(profiled_fit["spans"])
+    (fit,), (prep,) = by["fit"], by["fit.prepare"]
+    others = [s for s in profiled_fit["spans"]
+              if s.name not in ("fit", "fit.prepare")]
+    assert fit.t0 <= prep.t0 <= prep.t1 <= min(s.t0 for s in others)
+    assert prep.t1 <= min(s.t0 for s in by["epoch"])
+
+    batches = [np.full((4, 2), i, np.float32) for i in range(5)]
+    trace.clear()
+    with trace.tracing():
+        with trace.span("epoch") as ep:
+            got = [np.asarray(b) for b in InfeedPump(lambda: iter(batches))]
+    assert [int(g[0, 0]) for g in got] == list(range(5))
+    spans = trace.drain()
+    asm = [s for s in spans if s.name == "infeed.assemble"]
+    assert len(asm) == 5
+    assert all(s.parent_id == ep.span_id and s.trace_id == ep.trace_id
+               and s.thread_name == "zoo-infeed-pump" for s in asm)
+
+
+def test_pump_counts_each_epochs_first_batch_apart_from_stalls():
+    """(d) the wait for an epoch's first batch is a stage of its own;
+    ``stall`` keeps its meaning (every later get that delivered a batch)."""
+    from analytics_zoo_tpu.native.infeed import InfeedPump
+    epochs, n = 3, 5
+    pump = InfeedPump(lambda: (np.zeros((4, 2), np.float32)
+                               for _ in range(n)))
+    for _ in range(epochs):
+        assert sum(1 for _ in pump) == n
+    snap = pump.stats.snapshot()
+    assert snap["first_batch_n"] == epochs
+    assert snap["stall_n"] == epochs * (n - 1)
+    assert snap["first_batch_s"] > 0
+    assert snap["h2d_n"] == snap["assemble_n"] == epochs * n
+
+
+def test_h2d_span_and_counter_share_the_enqueue_boundary():
+    """(e) ``device_put`` returns at the enqueue; the ``infeed.h2d`` span and
+    ``h2d_s`` both end there, live or off (one code path, no wait of a lane
+    for the device), and a put that fails surfaces at the consumer."""
+    import time
+
+    from analytics_zoo_tpu.native import infeed
+    order = []
+
+    def fake_put(host):
+        order.append(("put", time.perf_counter()))
+        return host
+
+    def transfer():
+        pump = infeed.InfeedPump(lambda: iter(()), device_put=fake_put)
+        add = pump.stats.add
+        pump.stats.add = lambda stage, *a, **k: (
+            order.append((stage, time.perf_counter())), add(stage, *a, **k))
+        pump._transfer(np.zeros((4, 2), np.float32))
+        return pump
+
+    trace.disarm()
+    trace.clear()
+    transfer()
+    assert [o[0] for o in order] == ["put", "h2d"] and trace.spans() == []
+    del order[:]
+    with trace.tracing():
+        pump = transfer()
+    assert [o[0] for o in order] == ["put", "h2d"]
+    (span,) = [s for s in trace.drain() if s.name == "infeed.h2d"]
+    assert span.t0 <= order[0][1] <= order[1][1] <= span.t1
+    assert pump.stats.snapshot()["h2d_n"] == 1
+
+    def broken_put(host):
+        raise OSError("link down")
+
+    batches = [np.zeros((4, 2), np.float32)] * 3
+    with pytest.raises(OSError, match="link down"):
+        list(infeed.InfeedPump(lambda: iter(batches), device_put=broken_put))
+
+
+def test_span_ids_never_read_os_urandom(monkeypatch):
+    """A live span site draws its ids without a system call: ``os.urandom``
+    (``uuid4``) releases the GIL, and beside CPU-bound threads the training
+    loop then waits milliseconds at every site to get it back."""
+    import os
+
+    def no_syscall(n):
+        raise AssertionError("a span id read os.urandom")
+
+    monkeypatch.setattr(os, "urandom", no_syscall)
+    trace.clear()
+    with trace.tracing():
+        for _ in range(2000):
+            with trace.span("x"):
+                pass
+        trace.record_span("y", 0.0, 1.0)
+    spans = trace.drain()
+    ids = {s.span_id for s in spans} | {s.trace_id for s in spans}
+    assert len(ids) == 2 * 2001
+    assert all(len(i) == 16 and int(i, 16) >= 0 for i in ids)
+
+
+def test_train_step_ops_carry_stable_scope_names(orca_context):
+    """(f) forward / backward / optimizer can be told apart in the lowered
+    step (and so in a device trace) whatever XLA fuses."""
+    import re
+
+    import jax
+
+    from analytics_zoo_tpu.orca.learn.estimator import TPUEstimator
+    from analytics_zoo_tpu.orca.learn.utils import Batch
+    est = TPUEstimator(_tiny_module(), loss="mse", optimizer="adam", seed=0)
+    x = np.zeros((32, 8), np.float32)
+    est.engine.build((x,))
+    batch = Batch(x=(x,), y=(np.zeros(32, np.float32),), w=None)
+    for step in (est.engine._train_step, est.engine._train_multi_step):
+        args = est.engine.train_step_args(batch)
+        if step == est.engine._train_multi_step:
+            args = args[:4] + tuple(
+                jax.tree.map(lambda a: a[None], a) for a in args[4:])
+        text = jax.jit(step).lower(*args).as_text(debug_info=True)
+        # under value_and_grad JAX names the forward ops `jvp(forward)` and
+        # their transposes, the backward, `transpose(jvp(forward))`
+        for scope in ("jvp(forward)", "transpose(jvp(forward))",
+                      "optimizer"):
+            # a scope opens a location's name, or follows the jit's
+            assert re.search('["/]' + re.escape(scope) + "/", text), scope
+    est.shutdown()
