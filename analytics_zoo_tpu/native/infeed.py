@@ -64,7 +64,9 @@ class PipelineStats:
     dispatch, recorded by TrainEngine), ``stall`` (time the consumer waited
     on the delivery queue for every batch of an epoch but the first),
     ``first_batch`` (an epoch's entry into the pump, thread start included,
-    to its first delivered batch: the pipeline's fill, once an epoch).
+    to its first delivered batch: the pipeline's fill, once an epoch),
+    ``open_ahead`` (recorded by ``TPUEstimator.fit``: an epoch opened, and
+    its first batch waited for, before the sync of the epoch before it).
     Thread-safe; shared by the iterator, the pump, and the engine.
 
     Stages that report bytes (H2D always; assemble when the pump feeds it)
@@ -76,7 +78,8 @@ class PipelineStats:
     per-lane rate; aggregate wire rate is up to ``lanes ×`` that.
     """
 
-    STAGES = ("assemble", "h2d", "step", "stall", "first_batch")
+    STAGES = ("assemble", "h2d", "step", "stall", "first_batch",
+              "open_ahead")
 
     def __init__(self):
         self._lock = threading.Lock()

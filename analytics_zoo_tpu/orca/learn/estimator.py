@@ -10,6 +10,7 @@ user code ports; the execution is a single jitted step over the mesh.
 
 from __future__ import annotations
 
+import itertools
 import logging
 import os
 import pickle
@@ -30,6 +31,15 @@ from .optimizers.optimizers_impl import convert_optimizer
 from .trigger import EveryEpoch, TrainerState, Trigger
 
 logger = logging.getLogger("analytics_zoo_tpu")
+
+
+def _close(batches):
+    """Close an epoch's iterator: a generator over an ``InfeedPump`` then
+    joins the pump's producer thread. A plain iterator has nothing to
+    close."""
+    close = getattr(batches, "close", None)
+    if close is not None:
+        close()
 
 
 class Estimator:
@@ -173,9 +183,12 @@ class TPUEstimator:
         staging: on an accelerator the time to enqueue), ``step_s`` (engine
         dispatch), ``stall_s`` (training loop starved waiting on the
         infeed), ``first_batch_s`` (each epoch's wait for the pump's first
-        batch, which ``stall_s`` leaves out), plus the pump's prefetch
-        ``depth`` history. Every future perf PR should look here first to see
-        where epoch time goes."""
+        batch, which ``stall_s`` leaves out), ``open_ahead_s`` /
+        ``open_ahead_n`` (the epochs that ``fit`` opened before the sync of
+        the epoch before them, so that this wait passed while the device
+        was still working: ``open_ahead_n / first_batch_n`` of all), plus
+        the pump's prefetch ``depth`` history. Every future perf PR should
+        look here first to see where epoch time goes."""
         snap = self._pipeline_stats.snapshot()
         if self._ckpt_plane is not None:
             # checkpoint-plane counters (bytes written, dedup ratio, save
@@ -275,9 +288,10 @@ class TPUEstimator:
         beside the device's ops (docs/observability.md). The session starts
         before ``fit.prepare``, and on a TPU host it slows every
         host-to-device transfer (the runtime logs each chunk it re-tiles):
-        the prepare's one sample batch, put and fetched back, took ~2 s in
-        such a capture against 0.13 s outside one (256 uint8 ImageNet
-        images, v5e; PERF.md, PR 33), so read host times from
+        an estimator's first call, whose prepare puts one sample batch for
+        ``engine.build``, spent ~2 s there in such a capture against 0.13 s
+        outside one (256 uint8 ImageNet images, v5e; PERF.md, PR 33; a
+        call on a built engine takes no sample), so read host times from
         ``ZOO_TRACE=1`` and device times from the capture.
 
         ``max_failure_retries`` — when ``model_dir`` is set, a failing
@@ -363,30 +377,38 @@ class TPUEstimator:
     def _fit_prepare(self, data, batch_size, feature_cols, label_cols,
                      checkpoint_trigger, steps_per_epoch, shuffle,
                      max_failure_retries, initial_epoch):
-        """What a ``fit`` call does before its first epoch: the iterator, one
-        sample batch for ``engine.build``, checkpoint arming, the fuse
-        factor."""
+        """What a ``fit`` call does before its first epoch: the iterator, on
+        an unbuilt engine one sample for ``engine.build``, checkpoint
+        arming, the fuse factor."""
         it = learn_utils.data_to_iterator(
             data, batch_size, self.mesh, feature_cols, label_cols,
             shuffle=shuffle, config=self.config,
             stats=self._pipeline_stats)
+        # BatchIterator counts shuffle epochs in `_epoch`; duck-typed
+        # pipelines (e.g. ImageNetPipeline) use `_epoch_idx`
+        counter = next((c for c in ("_epoch", "_epoch_idx")
+                        if hasattr(it, c)), None)
         if initial_epoch:
-            # BatchIterator counts shuffle epochs in `_epoch`; duck-typed
-            # pipelines (e.g. ImageNetPipeline) use `_epoch_idx`. A silent
-            # no-op here would break the pause/resume bit-equivalence the
-            # parameter exists for, so warn when neither counter exists.
-            if hasattr(it, "_epoch"):
-                it._epoch = int(initial_epoch)
-            elif hasattr(it, "_epoch_idx"):
-                it._epoch_idx = int(initial_epoch)
+            # A silent no-op here would break the pause/resume
+            # bit-equivalence the parameter exists for, so warn when
+            # neither counter exists.
+            if counter is not None:
+                setattr(it, counter, int(initial_epoch))
             else:
                 logger.warning(
                     "fit(initial_epoch=%d): iterator %s has no epoch "
                     "counter to re-align; resumed epochs will not replay "
                     "the uninterrupted run's shuffle order",
                     initial_epoch, type(it).__name__)
-        sample = next(it.epoch(shuffle=False, prefetch=False))
-        self.engine.build(tuple(np.asarray(a) for a in sample.x))
+        if self.engine.params is None:
+            self._build_engine(next(it.epoch(shuffle=False, prefetch=False)))
+        elif counter is not None:
+            # a built engine needs no sample: nothing is assembled, put or
+            # fetched while the device sits empty. The sample's epoch()
+            # consumed one shuffle seed; keep every call's epochs on the
+            # seeds they had, so that segmented (initial_epoch) and
+            # uninterrupted runs stay bit-equivalent
+            setattr(it, counter, getattr(it, counter) + 1)
         checkpoint_trigger = (Trigger.convert_trigger(checkpoint_trigger)
                               if checkpoint_trigger else None)
         if checkpoint_trigger is not None:
@@ -425,6 +447,13 @@ class TPUEstimator:
                            type(e).__name__, e)
             fuse = 1
         return it, checkpoint_trigger, can_recover, retries_left, fuse
+
+    def _build_engine(self, sample):
+        """Build an unbuilt engine from the one row of a sample batch that
+        ``engine.build`` reads, cut on the device: the batch itself is not
+        fetched back."""
+        if self.engine.params is None:
+            self.engine.build(tuple(np.asarray(a[:1]) for a in sample.x))
 
     def _choose_fuse(self, it, steps_per_epoch, trigger=None) -> int:
         """Pick the scan-fusion factor for this fit. Small-model steps are
@@ -578,161 +607,210 @@ class TPUEstimator:
                   checkpoint_trigger, profile, verbose, can_recover,
                   retries_left, epoch_stats, watcher, fuse=1):
         ep = 0
-        while ep < epochs:
-            try:
-                with _trace.span("epoch", epoch=ep):
-                    stats = self._fit_epoch(it, ep, steps_per_epoch,
-                                            checkpoint_trigger, profile,
-                                            watcher, fuse)
-            except (KeyboardInterrupt, SystemExit):
-                raise
-            except Exception as e:
-                if not can_recover or retries_left <= 0:
+        # epoch `ep`'s open iterator and first batch, where the epoch before
+        # it opened them ahead of its own sync (`_fit_epoch`)
+        ahead = None
+        try:
+            while ep < epochs:
+                opened, ahead = ahead, None
+                try:
+                    with _trace.span("epoch", epoch=ep):
+                        stats, ahead = self._fit_epoch(
+                            it, ep, steps_per_epoch, checkpoint_trigger,
+                            profile, watcher, fuse, opened,
+                            more=ep + 1 < epochs)
+                except (KeyboardInterrupt, SystemExit):
                     raise
-                retries_left -= 1
-                # load_checkpoint flushes pending async writes first and
-                # returns the path it ACTUALLY restored (logging a scanner
-                # guess here could name a different dir than the one the
-                # plane's fallback logic lands on)
-                path = self.load_checkpoint(self.model_dir)
-                logger.warning(
-                    "training failed at epoch %d (%s: %s); restored "
-                    "checkpoint %s, retrying (%d retries left)",
-                    ep + 1, type(e).__name__, e, path, retries_left)
-                self._trainer_state.iteration = self.engine.step
-                continue                 # re-run the failed epoch
-            finally:
-                # fit(profile=<dir>) traces up to the end of the call's
-                # first epoch, its epoch-end sync included
-                self._stop_profile()
-            if watcher is not None and watcher.triggered:
-                # preemption notice (SIGTERM on spot/preemptible TPU VMs):
-                # checkpoint IMMEDIATELY — the grace window is short, and
-                # validation/logging must not stand between the notice and
-                # the restore point. The epoch is partial; flag it so
-                # consumers don't read its stats as a full epoch. Pending
-                # async writes are flushed too: the host may die right
-                # after the grace window, so queued != durable is not
-                # acceptable here.
-                self.save_checkpoint(self.model_dir)
-                if not self.flush_checkpoints():
-                    # the async write failed (disk full?): one blocking
-                    # retry — a stale restore point on preemption loses a
-                    # whole trigger interval of work
-                    try:
-                        self.save_checkpoint(self.model_dir, blocking=True)
-                    except Exception as save_err:   # noqa: BLE001
-                        logger.error(
-                            "preemption checkpoint could not be written "
-                            "(%s); resume will use the previous restore "
-                            "point", save_err)
-                stats["preempted"] = True
-                stats["partial_epoch"] = True
+                except Exception as e:
+                    if not can_recover or retries_left <= 0:
+                        raise
+                    retries_left -= 1
+                    # load_checkpoint flushes pending async writes first and
+                    # returns the path it ACTUALLY restored (logging a scanner
+                    # guess here could name a different dir than the one the
+                    # plane's fallback logic lands on)
+                    path = self.load_checkpoint(self.model_dir)
+                    logger.warning(
+                        "training failed at epoch %d (%s: %s); restored "
+                        "checkpoint %s, retrying (%d retries left)",
+                        ep + 1, type(e).__name__, e, path, retries_left)
+                    self._trainer_state.iteration = self.engine.step
+                    continue                 # re-run the failed epoch
+                finally:
+                    # fit(profile=<dir>) traces up to the end of the call's
+                    # first epoch, its epoch-end sync included
+                    self._stop_profile()
+                if watcher is not None and watcher.triggered:
+                    # preemption notice (SIGTERM on spot/preemptible TPU VMs):
+                    # checkpoint IMMEDIATELY — the grace window is short, and
+                    # validation/logging must not stand between the notice and
+                    # the restore point. The epoch is partial; flag it so
+                    # consumers don't read its stats as a full epoch. Pending
+                    # async writes are flushed too: the host may die right
+                    # after the grace window, so queued != durable is not
+                    # acceptable here.
+                    self.save_checkpoint(self.model_dir)
+                    if not self.flush_checkpoints():
+                        # the async write failed (disk full?): one blocking
+                        # retry — a stale restore point on preemption loses a
+                        # whole trigger interval of work
+                        try:
+                            self.save_checkpoint(self.model_dir, blocking=True)
+                        except Exception as save_err:   # noqa: BLE001
+                            logger.error(
+                                "preemption checkpoint could not be written "
+                                "(%s); resume will use the previous restore "
+                                "point", save_err)
+                    stats["preempted"] = True
+                    stats["partial_epoch"] = True
+                    epoch_stats.append(stats)
+                    logger.warning(
+                        "stopping after a preemption notice "
+                        "(checkpointed at step %d)", self.engine.step)
+                    break
+                if validation_data is not None:
+                    val = self.evaluate(validation_data, batch_size=batch_size,
+                                        feature_cols=feature_cols,
+                                        label_cols=label_cols, verbose=False)
+                    stats.update({f"val_{k}": v for k, v in val.items()})
+                    self._trainer_state.score = val.get(
+                        next(iter(self.metrics), "loss"), val.get("loss"))
+                    if self._tb_val is not None:
+                        for k, v in val.items():
+                            if isinstance(v, (int, float)):
+                                self._tb_val.add_scalar(
+                                    k, float(v), self._trainer_state.iteration)
+                if checkpoint_trigger and self.model_dir and \
+                        checkpoint_trigger(self._trainer_state):
+                    self.save_checkpoint(self.model_dir)
+                if verbose:
+                    logger.info("epoch %d: %s", ep + 1, stats)
                 epoch_stats.append(stats)
-                logger.warning(
-                    "stopping after a preemption notice "
-                    "(checkpointed at step %d)", self.engine.step)
-                break
-            if validation_data is not None:
-                val = self.evaluate(validation_data, batch_size=batch_size,
-                                    feature_cols=feature_cols,
-                                    label_cols=label_cols, verbose=False)
-                stats.update({f"val_{k}": v for k, v in val.items()})
-                self._trainer_state.score = val.get(
-                    next(iter(self.metrics), "loss"), val.get("loss"))
-                if self._tb_val is not None:
-                    for k, v in val.items():
-                        if isinstance(v, (int, float)):
-                            self._tb_val.add_scalar(
-                                k, float(v), self._trainer_state.iteration)
-            if checkpoint_trigger and self.model_dir and \
-                    checkpoint_trigger(self._trainer_state):
-                self.save_checkpoint(self.model_dir)
-            if verbose:
-                logger.info("epoch %d: %s", ep + 1, stats)
-            epoch_stats.append(stats)
-            ep += 1
+                ep += 1
+        finally:
+            # preemption, or validation raised: that epoch never runs
+            if ahead is not None:
+                _close(ahead[0])
         self.train_stats.extend(epoch_stats)
         return epoch_stats
 
+    @staticmethod
+    def _open_epoch(it, fuse: int):
+        """An epoch's open iterator and its first batch (None: it has
+        none)."""
+        gen = iter(it.epoch(fuse=fuse) if fuse > 1 else it.epoch())
+        return gen, next(gen, None)
+
     def _fit_epoch(self, it, ep: int, steps_per_epoch: Optional[int],
-                   checkpoint_trigger, profile,
-                   watcher=None, fuse: int = 1) -> Dict[str, float]:
+                   checkpoint_trigger, profile, watcher=None, fuse: int = 1,
+                   opened=None, more: bool = False):
         """One epoch of the hot loop; raises through to fit()'s retry.
+        Returns the epoch's stats and the next epoch's ``_open_epoch``, or
+        None where it opened none.
 
         With ``fuse`` > 1 the iterator yields stacked superbatches and each
         dispatch runs ``fuse`` optimizer steps inside one jitted lax.scan
         (``TrainEngine.train_batch_group``) — numerically identical to the
         per-step loop, but host dispatch latency is amortized k-fold.
         Checkpoint triggers and preemption are checked between dispatches
-        (≤ ~0.5 s apart by construction of the auto fuse factor)."""
+        (≤ ~0.5 s apart by construction of the auto fuse factor).
+
+        ``opened`` is this epoch's ``_open_epoch`` where the epoch before
+        made it. With ``more`` (another epoch of this call follows) the next
+        epoch's is made after the last dispatch and BEFORE the epoch-end
+        sync: a pump takes an assembly and a transfer to its first batch,
+        and here they pass while the device works off its queue instead of
+        after the sync has emptied it. This epoch's iterator is closed
+        first (never two pumps alive); a call's last epoch opens nothing,
+        and an epoch that fails after opening closes what it opened."""
         t0 = time.time()
         losses = []                # device scalars (fuse=1) or (k,) arrays
         tb_steps = []
         nsteps = steps_per_epoch or it.steps_per_epoch
         prof = {"data_s": 0.0, "step_s": 0.0} if profile else None
         steps_done = 0
-        batches = iter(it.epoch(fuse=fuse) if fuse > 1 else it.epoch())
-        while fuse > 1 or steps_done < nsteps:
-            if prof is not None:
-                td = time.perf_counter()
-            batch = next(batches, None)
-            if batch is None:
-                break
-            if prof is not None:
-                ts = time.perf_counter()
-                prof["data_s"] += ts - td
-            if getattr(batch, "fused", 1) > 1:
-                loss = self.engine.train_batch_group(batch)
-                took = batch.fused
-            else:
-                loss = self.engine.train_batch(batch)
-                took = 1
-            steps_done += took
-            if prof is not None:
-                jax.block_until_ready(loss)
-                prof["step_s"] += time.perf_counter() - ts
-            losses.append(loss)
-            self._trainer_state.iteration += took
-            if self._tb_train is not None:
-                # keep the device array; flush with ONE device_get at
-                # epoch end so logging never blocks async dispatch
-                tb_steps.extend(
-                    range(self._trainer_state.iteration - took + 1,
-                          self._trainer_state.iteration + 1))
-            if checkpoint_trigger and self.model_dir:
-                self._trainer_state.epoch_finished = False
-                if checkpoint_trigger(self._trainer_state):
-                    self.save_checkpoint(self.model_dir)
-            if watcher is not None and watcher.triggered:
-                break        # preemption: end the epoch at this step
-        # the epoch-end sync is where a wedged device actually blocks on
-        # real TPUs (dispatch is async) — bound it like the dispatches
-        from ...resilience.watchdog import watched
-        with _trace.span("epoch.sync"):
-            host_losses = watched("engine.sync", jax.device_get, losses)
-        if host_losses:
-            host_losses = np.concatenate(
-                [np.atleast_1d(np.asarray(l)) for l in host_losses])
-        if self._tb_train is not None:
-            for step, lv in zip(tb_steps, host_losses):
-                self._tb_train.add_scalar("Loss", float(lv), step)
-            self._tb_train.flush()
-        mean_loss = float(np.mean(host_losses))
-        self._trainer_state.epoch += 1
-        self._trainer_state.epoch_finished = True
-        self._trainer_state.loss = mean_loss
-        dt = time.time() - t0
-        stats = {"epoch": ep + 1, "train_loss": mean_loss,
-                 "num_samples": len(it.x[0]) if hasattr(it, "x") else None,
-                 "time_s": round(dt, 3)}
+        td = time.perf_counter()
+        gen, first = opened or self._open_epoch(it, fuse)
         if prof is not None:
-            n = max(len(host_losses), 1)
-            stats["profile"] = {
-                "mean_data_s": prof["data_s"] / n,
-                "mean_step_s": prof["step_s"] / n,
-                "steps": len(host_losses)}
-        return stats
+            prof["data_s"] += time.perf_counter() - td
+        batches = itertools.chain((first,), gen)
+        try:
+            while fuse > 1 or steps_done < nsteps:
+                if prof is not None:
+                    td = time.perf_counter()
+                batch = next(batches, None)
+                if batch is None:
+                    break
+                if prof is not None:
+                    ts = time.perf_counter()
+                    prof["data_s"] += ts - td
+                if getattr(batch, "fused", 1) > 1:
+                    loss = self.engine.train_batch_group(batch)
+                    took = batch.fused
+                else:
+                    loss = self.engine.train_batch(batch)
+                    took = 1
+                steps_done += took
+                if prof is not None:
+                    jax.block_until_ready(loss)
+                    prof["step_s"] += time.perf_counter() - ts
+                losses.append(loss)
+                self._trainer_state.iteration += took
+                if self._tb_train is not None:
+                    # keep the device array; flush with ONE device_get at
+                    # epoch end so logging never blocks async dispatch
+                    tb_steps.extend(
+                        range(self._trainer_state.iteration - took + 1,
+                              self._trainer_state.iteration + 1))
+                if checkpoint_trigger and self.model_dir:
+                    self._trainer_state.epoch_finished = False
+                    if checkpoint_trigger(self._trainer_state):
+                        self.save_checkpoint(self.model_dir)
+                if watcher is not None and watcher.triggered:
+                    break        # preemption: end the epoch at this step
+        finally:
+            # joins the pump's producer, also where `steps_per_epoch` or a
+            # failing step left the epoch's iterator unfinished
+            _close(gen)
+        ahead = None
+        if more and not (watcher is not None and watcher.triggered):
+            t_open = time.perf_counter()
+            with _trace.span("epoch.open_ahead", epoch=ep + 1):
+                ahead = self._open_epoch(it, fuse)
+            self._pipeline_stats.add("open_ahead",
+                                     time.perf_counter() - t_open)
+        try:
+            # the epoch-end sync is where a wedged device actually blocks on
+            # real TPUs (dispatch is async) — bound it like the dispatches
+            from ...resilience.watchdog import watched
+            with _trace.span("epoch.sync"):
+                host_losses = watched("engine.sync", jax.device_get, losses)
+            if host_losses:
+                host_losses = np.concatenate(
+                    [np.atleast_1d(np.asarray(l)) for l in host_losses])
+            if self._tb_train is not None:
+                for step, lv in zip(tb_steps, host_losses):
+                    self._tb_train.add_scalar("Loss", float(lv), step)
+                self._tb_train.flush()
+            mean_loss = float(np.mean(host_losses))
+            self._trainer_state.epoch += 1
+            self._trainer_state.epoch_finished = True
+            self._trainer_state.loss = mean_loss
+            dt = time.time() - t0
+            stats = {"epoch": ep + 1, "train_loss": mean_loss,
+                     "num_samples": len(it.x[0]) if hasattr(it, "x") else None,
+                     "time_s": round(dt, 3)}
+            if prof is not None:
+                n = max(len(host_losses), 1)
+                stats["profile"] = {
+                    "mean_data_s": prof["data_s"] / n,
+                    "mean_step_s": prof["step_s"] / n,
+                    "steps": len(host_losses)}
+            return stats, ahead
+        except BaseException:
+            if ahead is not None:
+                _close(ahead[0])
+            raise
 
     # --- evaluate -----------------------------------------------------------
     def evaluate(self, data, batch_size: int = 32, feature_cols=None,
@@ -744,7 +822,7 @@ class TPUEstimator:
             shuffle=False, config=self.config,
             stats=self._pipeline_stats)
         sample = next(it.epoch(shuffle=False, prefetch=False))
-        self.engine.build(tuple(np.asarray(a) for a in sample.x))
+        self._build_engine(sample)
         fuse = self._choose_eval_fuse(it, sample, num_steps)
         states = self.engine.init_metric_states()
         # accumulate device scalars; ONE device_get at the end so eval keeps

@@ -396,3 +396,277 @@ def test_fit_with_validation_uses_cached_eval_fuse(orca_context):
                     validation_data={"x": x, "y": y}, verbose=False)
     assert all("val_mae" in s and np.isfinite(s["val_mae"]) for s in stats)
     assert calls["n"] <= 1          # probed once, cached for epochs 2-3
+
+
+# ---------------------------------------------------------------------------
+# ISSUE 34: fit's batch work at call and epoch boundaries. Driven through a
+# feed that forwards only epoch() and steps_per_epoch, as the benchmark's
+# RecordingFeed does: whatever fit does has to work through those two.
+# ---------------------------------------------------------------------------
+
+_FEED_ROWS, _FEED_BS, _FEED_STEPS = 256, 32, 8
+
+
+def _id_data(seed=0):
+    """Rows that carry their own index in feature 0."""
+    rng = np.random.RandomState(seed)
+    x = rng.rand(_FEED_ROWS, 4).astype(np.float32)
+    x[:, 0] = np.arange(_FEED_ROWS)
+    return {"x": x, "y": rng.rand(_FEED_ROWS).astype(np.float32)}
+
+
+def _id_iterator(mesh, seed=7):
+    from analytics_zoo_tpu.orca.learn import utils as learn_utils
+    return learn_utils.data_to_iterator(_id_data(), _FEED_BS, mesh,
+                                        shuffle=True, seed=seed)
+
+
+def _batch_ids(batch):
+    return np.asarray(batch.x[0])[..., 0].astype(int).ravel().tolist()
+
+
+class _TwoMemberFeed:
+    """``epoch()`` and ``steps_per_epoch`` of the pipeline it wraps (and
+    the ``stats`` fit shares with it), nothing else of it. Keeps every
+    ``epoch()`` call's arguments, the rows of every batch it delivered, and
+    when each call's generator opened and closed; ``on_open(k)`` runs as
+    call k opens."""
+
+    def __init__(self, pipeline, on_open=None):
+        self._pipeline = pipeline
+        self._on_open = on_open
+        self.calls, self.rows, self.events = [], [], []
+
+    @property
+    def steps_per_epoch(self):
+        return self._pipeline.steps_per_epoch
+
+    @property
+    def stats(self):
+        return self._pipeline.stats
+
+    @stats.setter
+    def stats(self, value):
+        self._pipeline.stats = value
+
+    def epoch(self, *args, prefetch=True, **kwargs):
+        k = len(self.calls)
+        self.calls.append(dict(kwargs, prefetch=prefetch))
+        fed = []
+        self.rows.append(fed)
+        self.events.append(("open", k))
+        if self._on_open is not None:
+            self._on_open(k)
+        gen = self._pipeline.epoch(*args, prefetch=prefetch, **kwargs)
+        try:
+            for batch in gen:
+                fed.append(_batch_ids(batch))
+                yield batch
+        finally:
+            gen.close()
+            self.events.append(("close", k))
+
+
+def _pump_threads():
+    import threading
+    return [t for t in threading.enumerate()
+            if t.name == "zoo-infeed-pump" and t.is_alive()]
+
+
+def _built_estimator(**kwargs):
+    from analytics_zoo_tpu.orca.learn.estimator import TPUEstimator
+    kwargs.setdefault("config", {"steps_per_dispatch": 1})
+    est = TPUEstimator(linear_model_creator({}), loss="mse", optimizer="adam",
+                       seed=0, **kwargs)
+    est.engine.build((_id_data()["x"][:1],))
+    return est
+
+
+def _fail_once(real, at):
+    """``real`` with its ``at``-th call (from 1) raising instead."""
+    seen = {"n": 0}
+
+    def flaky(*a, **kw):
+        seen["n"] += 1
+        if seen["n"] == at:
+            raise RuntimeError("injected chip failure")
+        return real(*a, **kw)
+    return flaky
+
+
+# case -> what fit is asked for, and what the feed must then have seen:
+# `epoch_calls` calls of epoch(), `open_ahead` of them before a sync, the
+# batches each call delivered
+_BOUNDARY_CASES = {
+    "plain": dict(epochs=3, epoch_calls=3, open_ahead=2,
+                  delivered=[8, 8, 8]),
+    # the pump has batches left when fit leaves the epoch: its generator is
+    # closed before the next opens
+    "short_epochs": dict(epochs=3, steps_per_epoch=2, epoch_calls=3,
+                         open_ahead=2, delivered=[2, 2, 2]),
+    # evaluate runs its own pump while the next epoch's sits filled
+    "validation": dict(epochs=3, validation=True, epoch_calls=3,
+                       open_ahead=2, delivered=[8, 8, 8]),
+    "fused": dict(epochs=3, fuse=2, epoch_calls=3, open_ahead=2,
+                  delivered=[4, 4, 4]),
+    # a step of epoch 1 raises: that epoch is run again from a fresh epoch()
+    "step_fails": dict(epochs=3, retries=True, fail_step=8 + 3,
+                       epoch_calls=4, open_ahead=2, delivered=[8, 3, 8, 8]),
+    # epoch 0's sync raises after epoch 1 was opened ahead: what was opened
+    # is closed (one batch delivered, none trained), epoch 0 runs again
+    "sync_fails": dict(epochs=2, retries=True, fail_sync=1,
+                       epoch_calls=4, open_ahead=2, delivered=[8, 1, 8, 8]),
+    # the notice comes while epoch 1 opens epoch 2 ahead: epoch 1 ends whole,
+    # epoch 2 never runs and its iterator is closed
+    "preempted": dict(epochs=5, retries=True, sigterm_at_open=2,
+                      epoch_calls=3, open_ahead=2, delivered=[8, 8, 1],
+                      stats=2),
+}
+
+
+@pytest.mark.parametrize("case", sorted(_BOUNDARY_CASES))
+def test_fit_opens_next_epoch_before_the_sync(orca_context, tmp_path,
+                                              monkeypatch, case):
+    """On a built engine fit takes no sample; it opens epoch k+1, and waits
+    for its first batch, after epoch k's last dispatch and before epoch k's
+    sync; the rows fed are those of a twin iterator walked in turn; never
+    two of its pumps alive, none left when it returns."""
+    import itertools
+    import os
+    import signal
+
+    from analytics_zoo_tpu.obs import trace
+    from analytics_zoo_tpu.resilience import watchdog
+    want = dict(_BOUNDARY_CASES[case])
+    epochs, fuse = want["epochs"], want.get("fuse", 1)
+    est = _built_estimator(
+        model_dir=str(tmp_path) if want.get("retries") else None)
+    if fuse > 1:
+        monkeypatch.setattr(est, "_choose_fuse", lambda *a, **k: fuse)
+    if "fail_step" in want:
+        monkeypatch.setattr(est.engine, "train_batch", _fail_once(
+            est.engine.train_batch, want["fail_step"]))
+    if "fail_sync" in want:
+        syncs = _fail_once(lambda: None, want["fail_sync"])
+
+        def watched(label, fn, *a, **kw):
+            if label == "engine.sync":
+                syncs()
+            return fn(*a, **kw)
+        monkeypatch.setattr(watchdog, "watched", watched)
+    on_open = None
+    if "sigterm_at_open" in want:
+        def on_open(k):
+            if k == want["sigterm_at_open"]:
+                os.kill(os.getpid(), signal.SIGTERM)
+    feed = _TwoMemberFeed(_id_iterator(est.mesh), on_open)
+    trace.clear()
+    with trace.tracing():
+        stats = est.fit(
+            feed, epochs=epochs, batch_size=_FEED_BS, verbose=False,
+            steps_per_epoch=want.get("steps_per_epoch"),
+            validation_data=_id_data(1) if want.get("validation") else None,
+            max_failure_retries=2 if want.get("retries") else None)
+        spans = trace.drain()
+    assert _pump_threads() == []
+    assert len(stats) == want.get("stats", epochs)
+    if "sigterm_at_open" in want:
+        assert stats[-1]["preempted"] is True
+    if want.get("validation"):
+        assert all(np.isfinite(s["val_loss"]) for s in stats)
+
+    # (a) no sample; (b) epoch() as often as epochs ran, never two open
+    assert [c for c in feed.calls if not c["prefetch"]] == []
+    assert len(feed.calls) == want["epoch_calls"]
+    assert all(c.get("fuse", 1) == fuse for c in feed.calls)
+    assert feed.events == [(what, k) for k in range(want["epoch_calls"])
+                           for what in ("open", "close")]
+    snap = est.data_pipeline_stats()
+    assert snap["open_ahead_n"] == want["open_ahead"]
+    assert snap["open_ahead_s"] > 0
+    if not want.get("validation"):      # evaluate's pumps count there too
+        assert snap["first_batch_n"] == want["epoch_calls"]
+
+    # the rows: a twin with the same seed, one epoch() a call, in turn
+    twin = _id_iterator(est.mesh)
+    assert [len(fed) for fed in feed.rows] == want["delivered"]
+    for fed in feed.rows:
+        walked = twin.epoch(fuse=fuse) if fuse > 1 else twin.epoch()
+        assert fed == [_batch_ids(b)
+                       for b in itertools.islice(walked, len(fed))]
+        walked.close()
+    assert all(sorted(sum(fed, [])) == list(range(_FEED_ROWS))
+               for fed in feed.rows if len(fed) * fuse == _FEED_STEPS)
+
+    # the order: an epoch's open-ahead ends before that epoch's sync starts
+    by_id = {s.span_id: s for s in spans}
+    ahead = [s for s in spans if s.name == "epoch.open_ahead"]
+    assert len(ahead) == want["open_ahead"]
+    for oa in ahead:
+        ep = by_id[oa.parent_id]
+        assert ep.name == "epoch"
+        assert oa.attrs["epoch"] == ep.attrs["epoch"] + 1
+        (sync,) = [s for s in spans if s.name == "epoch.sync"
+                   and s.parent_id == ep.span_id]
+        last = max(s.t1 for s in spans if s.name == "engine.dispatch"
+                   and s.parent_id == ep.span_id)
+        assert last <= oa.t0 <= oa.t1 <= sync.t0
+        (first,) = [s for s in spans if s.name == "infeed.first_batch"
+                    and s.parent_id == oa.span_id]
+        assert oa.t0 <= first.t0 <= first.t1 <= oa.t1
+
+
+@pytest.mark.parametrize("built", [False, True], ids=["unbuilt", "built"])
+def test_fit_prepare_samples_only_an_unbuilt_engine(orca_context, built):
+    """An unbuilt engine takes one unprefetched sample for its build; a
+    built one (every call but an estimator's first) none. A call's last
+    epoch opens nothing ahead."""
+    from analytics_zoo_tpu.orca.learn.estimator import TPUEstimator
+    est = _built_estimator() if built else TPUEstimator(
+        linear_model_creator({}), loss="mse", optimizer="adam", seed=0,
+        config={"steps_per_dispatch": 1})
+    feed = _TwoMemberFeed(_id_iterator(est.mesh))
+    est.fit(feed, epochs=1, batch_size=_FEED_BS, verbose=False)
+    samples = [c for c in feed.calls if not c["prefetch"]]
+    assert len(samples) == (0 if built else 1)
+    assert all(c["shuffle"] is False for c in samples)
+    assert len(feed.calls) == 1 + len(samples)
+    assert est.data_pipeline_stats()["open_ahead_n"] == 0
+    assert est.engine.params is not None and _pump_threads() == []
+    est.fit(feed, epochs=2, batch_size=_FEED_BS, verbose=False)
+    assert len(feed.calls) == 3 + len(samples)      # and no second sample
+    assert est.data_pipeline_stats()["open_ahead_n"] == 1
+
+
+@pytest.mark.parametrize("counter", ["_epoch", "_epoch_idx"])
+def test_fit_on_built_engine_keeps_each_calls_shuffle_seeds(orca_context,
+                                                            counter):
+    """The sample a built engine no longer takes consumed one shuffle seed a
+    call; where the iterator shows its counter, fit advances it by that one,
+    so that every call's epochs draw the seeds they drew before (and
+    ``initial_epoch`` segments stay equal to an uninterrupted run)."""
+    from analytics_zoo_tpu.orca.learn import utils as learn_utils
+
+    class _Counting:
+        """The two members, and a shuffle counter under ``counter``."""
+        steps_per_epoch = _FEED_STEPS
+
+        def __init__(self, mesh):
+            self._it = _id_iterator(mesh)
+            setattr(self, counter, 0)
+            self.seeds = []
+
+        def epoch(self, shuffle=True, prefetch=True):
+            self.seeds.append(getattr(self, counter))
+            self._it._epoch = getattr(self, counter)
+            setattr(self, counter, getattr(self, counter) + 1)
+            yield from self._it.epoch(shuffle=shuffle, prefetch=prefetch)
+
+    est = _built_estimator()
+    it = _Counting(est.mesh)
+    assert learn_utils.data_to_iterator(it, _FEED_BS, est.mesh) is it
+    est.fit(it, epochs=2, batch_size=_FEED_BS, verbose=False)
+    est.fit(it, epochs=1, batch_size=_FEED_BS, verbose=False)
+    est.fit(it, epochs=2, batch_size=_FEED_BS, verbose=False, initial_epoch=7)
+    # each call skips the seed its sample took: 0, 3, 7 are never drawn
+    assert it.seeds == [1, 2, 4, 8, 9]

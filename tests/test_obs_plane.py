@@ -489,7 +489,7 @@ def test_trial_events_carry_per_trial_trace_ids(orca_context, tmp_path,
 
 FIT_SPANS = ("fit", "fit.prepare", "epoch", "infeed.first_batch",
              "infeed.wait", "infeed.assemble", "infeed.h2d",
-             "engine.dispatch", "epoch.sync")
+             "engine.dispatch", "epoch.open_ahead", "epoch.sync")
 
 
 def _tiny_fit(**fit_kwargs):
@@ -559,20 +559,31 @@ def test_profiler_session_makes_fit_spans_live(profiled_fit):
     assert fit.parent_id is None and fit.attrs["steps"] == 8
     assert {s.trace_id for s in spans} == {fit.trace_id}
     by_id = {s.span_id: s for s in spans}
-    parent = {"fit.prepare": "fit", "epoch": "fit",
-              "infeed.first_batch": "epoch", "infeed.wait": "epoch",
-              "infeed.assemble": "epoch", "infeed.h2d": "epoch",
-              "engine.dispatch": "epoch", "epoch.sync": "epoch"}
+    parent = {"fit.prepare": "fit", "epoch": "fit", "infeed.wait": "epoch",
+              "engine.dispatch": "epoch", "epoch.open_ahead": "epoch",
+              "epoch.sync": "epoch"}
     for name, above in parent.items():
         assert all(by_id[s.parent_id].name == above for s in by[name]), name
     for name in ("fit.prepare", "epoch", "infeed.first_batch", "infeed.wait",
-                 "engine.dispatch", "epoch.sync"):
+                 "engine.dispatch", "epoch.open_ahead", "epoch.sync"):
         assert all(s.thread == fit.thread for s in by[name]), name
     for name in ("infeed.assemble", "infeed.h2d"):
         assert all(s.thread != fit.thread for s in by[name]), name
     assert len(by["epoch"]) == len(by["infeed.first_batch"]) == \
         len(by["epoch.sync"]) == 2
     assert len(by["engine.dispatch"]) == len(by["infeed.h2d"]) == 8
+    # a pump's spans hang where the pump was started: the call's first epoch
+    # starts its own, every later one (N - 1 of N) is opened ahead, before the
+    # sync of the epoch before it
+    ep0, ep1 = sorted(by["epoch"], key=lambda s: s.attrs["epoch"])
+    (ahead,) = by["epoch.open_ahead"]
+    (sync0,) = [s for s in by["epoch.sync"] if s.parent_id == ep0.span_id]
+    assert ahead.parent_id == ep0.span_id and ahead.attrs["epoch"] == 1
+    assert ahead.t1 <= sync0.t0 <= ep1.t0
+    for name, each in (("infeed.first_batch", 1), ("infeed.assemble", 4),
+                       ("infeed.h2d", 4)):
+        assert sorted(s.parent_id for s in by[name]) == sorted(
+            [ep0.span_id] * each + [ahead.span_id] * each), name
 
 
 def test_profiler_trace_holds_zoo_annotations(profiled_fit):
@@ -619,6 +630,39 @@ def test_fit_prepare_span_and_ready_batch_assembly(profiled_fit):
     assert len(asm) == 5
     assert all(s.parent_id == ep.span_id and s.trace_id == ep.trace_id
                and s.thread_name == "zoo-infeed-pump" for s in asm)
+
+
+def test_fit_on_built_engine_prepares_without_a_batch(orca_context):
+    """(c') every ``fit`` call but an estimator's first finds its engine
+    built: its ``fit.prepare`` assembles nothing and puts nothing (no span
+    of any thread starts inside it, the pipeline's counters grow by the
+    epochs' batches alone), where the first call took one sample."""
+    from analytics_zoo_tpu.orca.learn.estimator import TPUEstimator
+    rng = np.random.RandomState(0)
+    data = {"x": rng.rand(128, 8).astype(np.float32),
+            "y": rng.rand(128).astype(np.float32)}
+    est = TPUEstimator(_tiny_module(), loss="mse", optimizer="adam", seed=0,
+                       config={"steps_per_dispatch": 1})
+    grew = []
+    for _ in range(2):
+        before = est.data_pipeline_stats()
+        trace.clear()
+        with trace.tracing():
+            est.fit(data, epochs=2, batch_size=32, verbose=False)
+            spans = trace.drain()
+        after = est.data_pipeline_stats()
+        grew.append({k: after[k] - before[k] for k in
+                     ("assemble_n", "h2d_n", "first_batch_n",
+                      "open_ahead_n")})
+        (prep,) = [s for s in spans if s.name == "fit.prepare"]
+        inside = [s.name for s in spans
+                  if s is not prep and prep.t0 <= s.t0 <= prep.t1]
+        assert inside == []
+    est.shutdown()
+    assert grew[0] == {"assemble_n": 9, "h2d_n": 9, "first_batch_n": 2,
+                       "open_ahead_n": 1}
+    assert grew[1] == {"assemble_n": 8, "h2d_n": 8, "first_batch_n": 2,
+                       "open_ahead_n": 1}
 
 
 def test_pump_counts_each_epochs_first_batch_apart_from_stalls():
