@@ -39,7 +39,8 @@ LOG2_E = 1.4426950408889634      # the flash kernel softmaxes in base 2
 def mha_reference(q: jax.Array, k: jax.Array, v: jax.Array,
                   *, causal: bool = False, sm_scale: Optional[float] = None,
                   bias: Optional[jax.Array] = None) -> jax.Array:
-    """Plain materialised-scores attention. q,k,v: (B, S, H, D)."""
+    """Plain materialised-scores attention. q,k: (B, S, H, D); v: (B, S, H,
+    D) or a head size of its own."""
     if sm_scale is None:
         sm_scale = 1.0 / math.sqrt(q.shape[-1])
     logits = jnp.einsum("bqhd,bkhd->bhqk", q, k).astype(jnp.float32) * sm_scale
@@ -265,13 +266,16 @@ def _flash_forward(q, k, v, causal, sm_scale, block_q, block_k, interpret,
 
     b, s_q, h, d = q.shape
     s_k = k.shape[1]
+    # v's head size may differ from q's and k's (MLA: q.k over 192, v of
+    # 128): the p @ v product, the accumulator and the output take d_v
+    d_v = v.shape[-1]
     # (B, S, H, D) -> (B*H, S, D): each grid row owns one head's sequence.
     # q is pre-scaled into the log2 domain for the kernel's exp2 softmax
     # (see _flash_kernel); one multiply here replaces one per k-tile.
     qf = (q * jnp.asarray(sm_scale * LOG2_E, q.dtype))
     qf = jnp.moveaxis(qf, 2, 1).reshape(b * h, s_q, d)
     kf = jnp.moveaxis(k, 2, 1).reshape(b * h, s_k, d)
-    vf = jnp.moveaxis(v, 2, 1).reshape(b * h, s_k, d)
+    vf = jnp.moveaxis(v, 2, 1).reshape(b * h, s_k, d_v)
     # ones column: p @ [v | 1] yields the softmax normalizer in the last
     # output column on the MXU (free at D=64 — see _flash_kernel)
     vf = jnp.concatenate(
@@ -285,7 +289,7 @@ def _flash_forward(q, k, v, causal, sm_scale, block_q, block_k, interpret,
     grid = (b * h, num_q, num_k)
     kernel = functools.partial(
         _flash_kernel, block_q=block_q, block_k=block_k,
-        num_k_blocks=num_k, causal=causal, head_dim=d,
+        num_k_blocks=num_k, causal=causal, head_dim=d_v,
         q_offset=s_k - s_q, with_lse=with_lse)
     # Under shard_map (e.g. Ulysses sequence parallelism) the output must
     # declare which mesh axes it varies over. Use the union of the inputs'
@@ -293,8 +297,8 @@ def _flash_forward(q, k, v, causal, sm_scale, block_q, block_k, interpret,
     # call sites (e.g. cross-attention with replicated q) still compile.
     vma = varying_axes(qf, kf, vf)
     qf, kf, vf = (mark_varying(a, vma) for a in (qf, kf, vf))
-    out_shape = [jax.ShapeDtypeStruct((b * h, s_q, d), q.dtype, vma=vma)]
-    out_specs = [pl.BlockSpec((1, block_q, d),
+    out_shape = [jax.ShapeDtypeStruct((b * h, s_q, d_v), q.dtype, vma=vma)]
+    out_specs = [pl.BlockSpec((1, block_q, d_v),
                               lambda bh, qi, ki: (bh, qi, 0))]
     if with_lse:
         out_shape.append(
@@ -328,12 +332,12 @@ def _flash_forward(q, k, v, causal, sm_scale, block_q, block_k, interpret,
         in_specs=[
             pl.BlockSpec((1, block_q, d), lambda bh, qi, ki: (bh, qi, 0)),
             pl.BlockSpec((1, block_k, d), k_index),
-            pl.BlockSpec((1, block_k, d + 1), k_index),
+            pl.BlockSpec((1, block_k, d_v + 1), k_index),
         ],
         out_specs=out_specs,
         out_shape=out_shape,
         scratch_shapes=[
-            pltpu.VMEM((block_q, d + 1), jnp.float32),   # acc | l column
+            pltpu.VMEM((block_q, d_v + 1), jnp.float32),  # acc | l column
             pltpu.VMEM((block_q, 128), jnp.float32),
         ],
         # bh/q grid dims carry no state between steps — declaring them
@@ -343,7 +347,7 @@ def _flash_forward(q, k, v, causal, sm_scale, block_q, block_k, interpret,
         compiler_params=None if interpret else _mosaic_params(),
         interpret=interpret,
     )(qf, kf, vf)
-    out = jnp.moveaxis(res[0].reshape(b, h, s_q, d), 1, 2)
+    out = jnp.moveaxis(res[0].reshape(b, h, s_q, d_v), 1, 2)
     if with_lse:
         return out, res[1]
     return out
@@ -356,7 +360,7 @@ def _interpret() -> bool:
     platform = jax.default_backend()
     if platform not in ("tpu", "cpu"):
         raise NotImplementedError(
-            f"flash_attention has no kernel for platform {platform!r}")
+            f"the Pallas kernels have no lowering for platform {platform!r}")
     return platform == "cpu"
 
 
@@ -511,13 +515,14 @@ def _flash_bwd(causal, sm_scale, block_q, block_k, res, g):
     interpret = _interpret()
     b, s_q, h, d = q.shape
     s_k = k.shape[1]
+    d_v = v.shape[-1]            # g, o, dv carry v's head size; dq, dk q's
     bq, bk = _bwd_tile_sizes(s_q, s_k, block_q, block_k)
     nq, nk = s_q // bq, s_k // bk
     bh = b * h
     cd = jnp.bfloat16 if q.dtype == jnp.bfloat16 else jnp.float32
 
     def flat(a):                                 # (B,S,H,D) -> (B*H,S,D)
-        return jnp.moveaxis(a, 2, 1).reshape(bh, a.shape[1], d)
+        return jnp.moveaxis(a, 2, 1).reshape(bh, a.shape[1], a.shape[-1])
 
     q2 = flat(q * jnp.asarray(sm_scale * LOG2_E, q.dtype))
     kf, vf, gf, of = flat(k), flat(v), flat(g.astype(q.dtype)), flat(o)
@@ -540,8 +545,8 @@ def _flash_bwd(causal, sm_scale, block_q, block_k, res, g):
         in_specs=[
             pl.BlockSpec((1, bq, d), lambda bhi, qi, ki: (bhi, qi, 0)),
             pl.BlockSpec((1, bk, d), lambda bhi, qi, ki: (bhi, ki, 0)),
-            pl.BlockSpec((1, bk, d), lambda bhi, qi, ki: (bhi, ki, 0)),
-            pl.BlockSpec((1, bq, d), lambda bhi, qi, ki: (bhi, qi, 0)),
+            pl.BlockSpec((1, bk, d_v), lambda bhi, qi, ki: (bhi, ki, 0)),
+            pl.BlockSpec((1, bq, d_v), lambda bhi, qi, ki: (bhi, qi, 0)),
             pl.BlockSpec((1, bq, 1), lambda bhi, qi, ki: (bhi, qi, 0)),
             pl.BlockSpec((1, bq, 1), lambda bhi, qi, ki: (bhi, qi, 0)),
         ],
@@ -562,27 +567,27 @@ def _flash_bwd(causal, sm_scale, block_q, block_k, res, g):
         in_specs=[
             pl.BlockSpec((1, bq, d), lambda bhi, ki, qi: (bhi, qi, 0)),
             pl.BlockSpec((1, bk, d), lambda bhi, ki, qi: (bhi, ki, 0)),
-            pl.BlockSpec((1, bk, d), lambda bhi, ki, qi: (bhi, ki, 0)),
-            pl.BlockSpec((1, bq, d), lambda bhi, ki, qi: (bhi, qi, 0)),
+            pl.BlockSpec((1, bk, d_v), lambda bhi, ki, qi: (bhi, ki, 0)),
+            pl.BlockSpec((1, bq, d_v), lambda bhi, ki, qi: (bhi, qi, 0)),
             pl.BlockSpec((1, bq, 1), lambda bhi, ki, qi: (bhi, qi, 0)),
             pl.BlockSpec((1, bq, 1), lambda bhi, ki, qi: (bhi, qi, 0)),
         ],
         out_specs=[
             pl.BlockSpec((1, bk, d), lambda bhi, ki, qi: (bhi, ki, 0)),
-            pl.BlockSpec((1, bk, d), lambda bhi, ki, qi: (bhi, ki, 0)),
+            pl.BlockSpec((1, bk, d_v), lambda bhi, ki, qi: (bhi, ki, 0)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((bh, s_k, d), k.dtype, vma=vma),
-            jax.ShapeDtypeStruct((bh, s_k, d), v.dtype, vma=vma),
+            jax.ShapeDtypeStruct((bh, s_k, d_v), v.dtype, vma=vma),
         ],
         scratch_shapes=[pltpu.VMEM((bk, d), jnp.float32),
-                        pltpu.VMEM((bk, d), jnp.float32)],
+                        pltpu.VMEM((bk, d_v), jnp.float32)],
         compiler_params=None if interpret else _mosaic_params(),
         interpret=interpret,
     )(q2, kf, vf, gf, lse, D)
 
     def unflat(a, s_len):
-        return jnp.moveaxis(a.reshape(b, h, s_len, d), 1, 2)
+        return jnp.moveaxis(a.reshape(b, h, s_len, a.shape[-1]), 1, 2)
 
     return unflat(dq, s_q), unflat(dk, s_k), unflat(dv, s_k)
 
@@ -593,7 +598,9 @@ _flash_attention.defvjp(_flash_fwd, _flash_bwd)
 def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
                     causal: bool = False, sm_scale: Optional[float] = None,
                     block_q: int = 1024, block_k: int = 1024) -> jax.Array:
-    """Flash attention over (B, S, H, D). Uses the Pallas kernel when the
+    """Flash attention over (B, S, H, D); ``v`` may have a head size of its
+    own (q and k (B, S, H, d_qk), v and the output (B, S, H, d_v)), as
+    latent attention's training form has. Uses the Pallas kernel when the
     sequence tiles evenly (compiled on a TPU, interpret mode on the CPU
     backend), else the reference path — which on a TPU is logged and
     counted (``zoo_attention_reference_on_tpu_total``), never silent.

@@ -22,17 +22,31 @@ Everything is differentiable: the router trains through the gate
 scaling, experts through the dispatched tokens; the Switch load-balance
 auxiliary loss (over first-choice assignments) is returned alongside the
 output.
+
+The second half of the file is the expert layer of the DeepSeek-V3 family
+as ONE rank of an expert-parallel deployment runs it: :func:`route_noaux_tc`
+scores every token over ALL experts (sigmoid scores, selection by score plus
+a correction bias, the chosen scores renormalised and scaled) and
+:func:`held_experts_ffn` is told which contiguous range of experts it holds,
+sorts the token-choices routed to them by expert and runs grouped matrix
+products over those rows alone. No capacity, no dropped token: rows beyond
+the static buffer are worked off in further chunks. What the experts held
+elsewhere would add is not computed here; on one chip the layer runs
+without its exchange.
 """
 
 from __future__ import annotations
 
+import functools
 import math
-from typing import Any, Callable, Tuple
+from typing import Any, Callable, Dict, Tuple
 
 import jax
 import jax.numpy as jnp
 from jax import lax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+from ..ops import attention as _attention
 
 
 def stack_expert_params(per_expert) -> Any:
@@ -165,3 +179,188 @@ def moe_apply(expert_fn: Callable, expert_params: Any,
                        check_vma=False)
     y, aux = fn(expert_params, router_weights, x)
     return y, aux
+
+
+# ---------------------------------------------------------------------------
+# One rank's share of a sigmoid-routed, bias-balanced expert layer
+# ---------------------------------------------------------------------------
+
+def route_noaux_tc(x: jax.Array, router_w: jax.Array, bias: jax.Array, *,
+                   top_k: int, scaling: float = 1.0
+                   ) -> Tuple[jax.Array, jax.Array]:
+    """Auxiliary-loss-free routing (``scoring_func: sigmoid``, ``topk_method:
+    noaux_tc`` with one group): ``s = sigmoid(x W_r)`` in float32 over ALL
+    experts; the ``top_k`` experts of a token are the largest ``s + bias``;
+    their gates are ``s[chosen] / sum(s[chosen]) * scaling``. ``bias`` only
+    steers the choice and takes no gradient.
+
+    x: (N, d); router_w: (d, E); bias: (E,). Returns ``(idx (N, top_k) int32,
+    gates (N, top_k) float32)``."""
+    scores = jax.nn.sigmoid(jnp.dot(x.astype(jnp.float32),
+                                    router_w.astype(jnp.float32)))
+    _, idx = lax.top_k(scores + lax.stop_gradient(bias.astype(jnp.float32)),
+                       top_k)
+    chosen = jnp.take_along_axis(scores, idx, axis=-1)
+    gates = chosen / (jnp.sum(chosen, axis=-1, keepdims=True) + 1e-20)
+    return idx.astype(jnp.int32), gates * scaling
+
+
+def noaux_bias_update(bias: jax.Array, idx: jax.Array, rate: float
+                      ) -> jax.Array:
+    """The correction bias after a step: ``b_i += rate * sign(mean load -
+    load_i)`` with ``load_i`` the step's token-choices for expert i, so an
+    overloaded expert is chosen less and an idle one more."""
+    load = jnp.bincount(idx.reshape(-1), length=bias.shape[0]
+                        ).astype(jnp.float32)
+    return bias + rate * jnp.sign(jnp.mean(load) - load).astype(bias.dtype)
+
+
+def _fit_tile(size: int, want: int) -> int:
+    for cand in (want, 1024, 768, 512, 384, 256, 128):
+        if cand <= want and size % cand == 0:
+            return cand
+    return size
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
+def _pallas_gmm(lhs, rhs, sizes, interpret):
+    return _pallas_gmm_fwd(lhs, rhs, sizes, interpret)[0]
+
+
+def _megablox():
+    # the package's own name `gmm` is its differentiable wrapper, which
+    # takes no trailing group: the kernels' module is imported by path
+    import importlib
+    return importlib.import_module(
+        "jax.experimental.pallas.ops.tpu.megablox.gmm")
+
+
+def _gmm_tiling(m: int, k: int, n: int):
+    return (_fit_tile(m, 512), _fit_tile(k, 1024), _fit_tile(n, 1024))
+
+
+def _pallas_gmm_fwd(lhs, rhs, sizes, interpret):
+    mblx = _megablox()
+    m, k = lhs.shape
+    out = mblx.gmm(lhs, rhs, sizes, lhs.dtype,
+                   _gmm_tiling(m, k, rhs.shape[-1]),
+                   group_offset=jnp.int32(0), interpret=interpret)
+    return out, (lhs, rhs, sizes)
+
+
+def _pallas_gmm_bwd(interpret, res, g):
+    mblx = _megablox()
+    lhs, rhs, sizes = res
+    m, k = lhs.shape
+    n = rhs.shape[-1]
+    tiling = _gmm_tiling(m, k, n)
+    d_lhs = mblx.gmm(g, rhs, sizes, lhs.dtype, (tiling[0], tiling[2],
+                                                tiling[1]),
+                     group_offset=jnp.int32(0), transpose_rhs=True,
+                     interpret=interpret)
+    d_rhs = mblx.tgmm(lhs.swapaxes(0, 1), g, sizes, rhs.dtype, tiling,
+                      group_offset=jnp.int32(0),
+                      num_actual_groups=rhs.shape[0], interpret=interpret)
+    return d_lhs, d_rhs, None
+
+
+_pallas_gmm.defvjp(_pallas_gmm_fwd, _pallas_gmm_bwd)
+
+
+def grouped_matmul(lhs: jax.Array, rhs: jax.Array, sizes: jax.Array
+                   ) -> jax.Array:
+    """Rows of ``lhs`` (M, k), sorted by group, each times its group's
+    matrix of ``rhs`` (G, k, n). ``sizes`` has G + 1 entries summing to M:
+    the last counts trailing rows that belong to no group here, whose
+    output is zero. megablox's tiled Pallas kernel, which visits only the
+    tiles that hold rows: compiled on a TPU, interpreted elsewhere."""
+    return _pallas_gmm(lhs, rhs, sizes.astype(jnp.int32),
+                       _attention._interpret())
+
+
+def _round_up(x: int, to: int) -> int:
+    return -(-x // to) * to
+
+
+def held_experts_ffn(x: jax.Array, idx: jax.Array, gates: jax.Array,
+                     w_gate: jax.Array, w_up: jax.Array, w_down: jax.Array,
+                     *, first_expert: int, n_experts: int
+                     ) -> Tuple[jax.Array, Dict[str, jax.Array]]:
+    """The part of ``y_t = sum_i g_ti F_i(x_t)`` that the experts
+    ``first_expert .. first_expert + E_held - 1`` give, ``F`` a SwiGLU.
+
+    x: (N, d); idx, gates: (N, top_k) from the router over all ``n_experts``;
+    w_gate, w_up: (E_held, d, f); w_down: (E_held, f, d). The token-choices
+    routed here are sorted by expert (a stable sort: tokens ascending inside
+    an expert) and worked off in chunks of a static number of rows, each a
+    gather, three grouped products and a gate-weighted scatter-add into the
+    float32 result. The first chunk (twice the rows a balanced router sends
+    here) always runs; the further ones, up to the worst case
+    of every choice of every token landing here, run only when rows are left
+    and are rematerialised in the backward pass, so imbalance costs time and
+    never a token.
+
+    Returns ``(y (N, d) float32, counters)``: ``local_rows`` (token-choices
+    routed here), ``rows_max_over_mean`` (largest held expert's rows over
+    the held experts' mean), ``dropped_rows`` (routed here and not computed:
+    0 by construction, counted from what the chunks did)."""
+    with jax.named_scope("moe.experts"):
+        return _held_experts_ffn(x, idx, gates, w_gate, w_up, w_down,
+                                 first_expert, n_experts)
+
+
+def _held_experts_ffn(x, idx, gates, w_gate, w_up, w_down, first_expert,
+                      n_experts):
+    n, d = x.shape
+    top_k = idx.shape[1]
+    e_held = w_gate.shape[0]
+    local = idx.reshape(-1) - first_expert
+    held = (local >= 0) & (local < e_held)
+    key = jnp.where(held, local, e_held)        # elsewhere: one trailing group
+    order = jnp.argsort(key, stable=True).astype(jnp.int32)
+    sizes = jnp.bincount(key, length=e_held + 1)[:e_held].astype(jnp.int32)
+    local_rows = jnp.sum(sizes)
+    ends = jnp.cumsum(sizes)
+    starts = ends - sizes
+
+    worst = n * min(top_k, e_held)
+    share = 2 * n * top_k * e_held // n_experts
+    tile = 512 if share >= 512 else 8
+    chunk_rows = min(_round_up(max(share, 1), tile), _round_up(worst, tile))
+    n_chunks = -(-worst // chunk_rows)
+    pad = max(n_chunks * chunk_rows - n * top_k, 0)
+    tok_sorted = jnp.pad(order // top_k, (0, pad))
+    gate_sorted = jnp.pad(
+        jnp.where(held[order], gates.reshape(-1)[order], 0.0), (0, pad))
+    wg, wu, wd = (w.astype(x.dtype) for w in (w_gate, w_up, w_down))
+
+    def chunk(c, y, done):
+        lo = c * chunk_rows
+        tok = lax.dynamic_slice(tok_sorted, (lo,), (chunk_rows,))
+        g = lax.dynamic_slice(gate_sorted, (lo,), (chunk_rows,))
+        here = (jnp.clip(ends, lo, lo + chunk_rows)
+                - jnp.clip(starts, lo, lo + chunk_rows))
+        rows = jnp.sum(here)
+        sizes_c = jnp.concatenate([here, (chunk_rows - rows)[None]])
+        xs = x[tok]
+        h = (jax.nn.silu(grouped_matmul(xs, wg, sizes_c))
+             * grouped_matmul(xs, wu, sizes_c))
+        out = grouped_matmul(h, wd, sizes_c)
+        y = y.at[tok].add(out.astype(jnp.float32) * g[:, None])
+        return y, done + rows
+
+    y, done = chunk(0, jnp.zeros((n, d), jnp.float32), jnp.int32(0))
+    if n_chunks > 1:
+        def rest(carry):
+            def body(carry, c):
+                # a chunk past the last routed row has nothing to do
+                return lax.cond(c * chunk_rows < local_rows,
+                                lambda cr: jax.checkpoint(chunk)(c, *cr),
+                                lambda cr: cr, carry), None
+            return lax.scan(body, carry, jnp.arange(1, n_chunks))[0]
+        y, done = lax.cond(local_rows > chunk_rows, rest,
+                           lambda carry: carry, (y, done))
+    mean = jnp.maximum(local_rows.astype(jnp.float32) / e_held, 1e-9)
+    return y, {"local_rows": local_rows,
+               "rows_max_over_mean": jnp.max(sizes).astype(jnp.float32) / mean,
+               "dropped_rows": local_rows - done}

@@ -1,0 +1,420 @@
+"""A pre-norm decoder language model of the DeepSeek-V3 family: RMSNorm,
+interleaved RoPE, multi-head latent attention (MLA), SwiGLU, a leading dense
+block, sparse-expert blocks with a shared expert, and a multi-token-prediction
+(MTP) module that shares the embedding and the output head.
+
+Built from a config dict with the published ``config.json`` key names
+(:meth:`DecoderLM.from_config`). Trained through the estimator like any other
+module::
+
+    model = DecoderLM.from_config(cfg)
+    est = TPUEstimator(model, loss=model.loss(), optimizer=AdamWeightDecay(...))
+    est.fit({"x": ids, "y": ids}, epochs=..., batch_size=...)
+
+``ids`` are (batch, seq) token ids; the labels are the same ids, shifted on
+the device: by one for the main head, by two for the MTP head. Attention is
+causal over the whole sequence (no cross-document mask, as the family trains).
+
+Layer equations (x: (batch, seq, hidden)):
+
+* block: ``x = x + MLA(RMSNorm(x))``; ``x = x + FFN(RMSNorm(x))``;
+* MLA, training form: ``c_q = RMSNorm(x W_qa)``; ``q = c_q W_qb`` -> heads x
+  (nope | rope); ``[c_kv | k_r] = x W_kva``; ``[k_nope | v] = RMSNorm(c_kv)
+  W_kvb``; RoPE on q's rope part and on ``k_r``, which all heads share;
+  ``softmax_causal(q k^T / sqrt(d_nope + d_rope)) v`` through the flash
+  kernel, which takes v's head size beside q's and k's; ``W_o``;
+* expert layer: ``parallel/expert_parallel.py`` (sigmoid scores over all
+  experts, top-k by score + correction bias, renormalised and scaled gates;
+  the experts held here computed by grouped products; a shared expert).
+  The correction bias lives in the ``router_state`` collection and is
+  updated by the step itself (no gradient); the layer's counters in
+  ``moe_stats``. The engine carries both as it carries BatchNorm's
+  statistics;
+* MTP (depth 1): ``h' = W_eh [RMSNorm(h_t) | RMSNorm(Emb(tok_{t+1}))]``, one
+  more block, a norm, the main model's embedding and head, predicting
+  ``tok_{t+2}``.
+
+One rank of an expert-parallel deployment holds ``experts_held`` of the
+``n_routed_experts`` experts of each layer, from ``first_expert`` on: the
+router keeps its full width and the layer computes its own experts' part.
+"""
+
+from __future__ import annotations
+
+import functools
+import math
+from typing import Any, Callable, Dict, Optional
+
+import flax.linen as nn
+import jax
+import jax.numpy as jnp
+
+from analytics_zoo_tpu.obs.registry import REGISTRY as _REGISTRY
+from analytics_zoo_tpu.ops.attention import flash_attention
+from analytics_zoo_tpu.parallel.expert_parallel import (
+    held_experts_ffn, noaux_bias_update, route_noaux_tc)
+from ..engine.graph import keras_call
+
+_GAUGE_DOC = {
+    "moe_local_rows": "token-choices routed to the experts held, a step, "
+                      "summed over the expert layers (last read)",
+    "moe_rows_max_over_mean": "largest held expert's rows over the held "
+                              "experts' mean, worst layer, last step read",
+    "moe_dropped_rows": "token-choices routed here and not computed since "
+                        "the state was made: 0 by construction",
+}
+
+INIT_POSITIONS = 128
+ROUTER_STATE = "router_state"      # the correction bias: state, not parameter
+MOE_STATS = "moe_stats"            # counters the step leaves in its outputs
+
+
+class RMSNorm(nn.Module):
+    eps: float = 1e-6
+    dtype: Any = jnp.bfloat16
+
+    @nn.compact
+    def __call__(self, x):
+        w = self.param("weight", nn.initializers.ones, (x.shape[-1],))
+        x32 = x.astype(jnp.float32)
+        y = x32 * jax.lax.rsqrt(jnp.mean(jnp.square(x32), -1, keepdims=True)
+                                + self.eps)
+        return (y * w).astype(self.dtype)
+
+
+def rope_interleaved(x, theta: float):
+    """Rotary position embedding over adjacent pairs ``(x[2i], x[2i+1])`` of
+    the last axis, angle ``pos * theta**(-2i/d)``. x: (batch, seq, heads, d);
+    computed in float32."""
+    d = x.shape[-1]
+    inv = theta ** (-jnp.arange(0, d, 2, dtype=jnp.float32) / d)
+    ang = jnp.arange(x.shape[1], dtype=jnp.float32)[:, None] * inv[None, :]
+    cos, sin = jnp.cos(ang)[None, :, None, :], jnp.sin(ang)[None, :, None, :]
+    x32 = x.astype(jnp.float32)
+    even, odd = x32[..., 0::2], x32[..., 1::2]
+    out = jnp.stack([even * cos - odd * sin, even * sin + odd * cos], -1)
+    return out.reshape(x.shape).astype(x.dtype)
+
+
+def _dense(features: int, dtype, name: str, std: float = 0.02):
+    return nn.Dense(features, use_bias=False, dtype=dtype,
+                    param_dtype=jnp.float32,
+                    kernel_init=nn.initializers.normal(std), name=name)
+
+
+class MLAttention(nn.Module):
+    num_heads: int
+    q_lora_rank: int
+    kv_lora_rank: int
+    qk_nope_head_dim: int
+    qk_rope_head_dim: int
+    v_head_dim: int
+    rope_theta: float
+    eps: float
+    dtype: Any = jnp.bfloat16
+
+    @nn.compact
+    def __call__(self, x):
+        b, s, hidden = x.shape
+        h, dn, dr, dv = (self.num_heads, self.qk_nope_head_dim,
+                         self.qk_rope_head_dim, self.v_head_dim)
+        norm = functools.partial(RMSNorm, self.eps, self.dtype)
+        with jax.named_scope("attn.mla"):
+            c_q = norm(name="q_a_layernorm")(
+                _dense(self.q_lora_rank, self.dtype, "q_a_proj")(x))
+            q = _dense(h * (dn + dr), self.dtype, "q_b_proj")(c_q)
+            q = q.reshape(b, s, h, dn + dr)
+            ckv = _dense(self.kv_lora_rank + dr, self.dtype,
+                         "kv_a_proj_with_mqa")(x)
+            c_kv = norm(name="kv_a_layernorm")(ckv[..., :self.kv_lora_rank])
+            k_r = ckv[..., self.kv_lora_rank:].reshape(b, s, 1, dr)
+            kv = _dense(h * (dn + dv), self.dtype, "kv_b_proj")(c_kv)
+            kv = kv.reshape(b, s, h, dn + dv)
+            q = jnp.concatenate(
+                [q[..., :dn], rope_interleaved(q[..., dn:], self.rope_theta)],
+                axis=-1)
+            k_r = rope_interleaved(k_r, self.rope_theta)
+            k = jnp.concatenate(
+                [kv[..., :dn], jnp.broadcast_to(k_r, (b, s, h, dr))], axis=-1)
+            out = flash_attention(q, k, kv[..., dn:], causal=True,
+                                  sm_scale=1.0 / math.sqrt(dn + dr))
+            return _dense(hidden, self.dtype, "o_proj")(
+                out.reshape(b, s, h * dv))
+
+
+class SwiGLU(nn.Module):
+    width: int
+    dtype: Any = jnp.bfloat16
+
+    @nn.compact
+    def __call__(self, x):
+        gate = _dense(self.width, self.dtype, "gate_proj")(x)
+        up = _dense(self.width, self.dtype, "up_proj")(x)
+        return _dense(x.shape[-1], self.dtype, "down_proj")(
+            jax.nn.silu(gate) * up)
+
+
+class SparseExperts(nn.Module):
+    """The expert layer, as the rank that holds ``experts_held`` experts
+    from ``first_expert`` on computes it, plus the shared expert."""
+    n_routed_experts: int
+    experts_held: int
+    first_expert: int
+    num_experts_per_tok: int
+    moe_intermediate_size: int
+    n_shared_experts: int
+    routed_scaling_factor: float
+    bias_update_rate: float
+    dtype: Any = jnp.bfloat16
+
+    @nn.compact
+    def __call__(self, x):
+        b, s, hidden = x.shape
+        e, held, f = (self.n_routed_experts, self.experts_held,
+                      self.moe_intermediate_size)
+        flat = x.reshape(b * s, hidden)
+        router = self.param("gate", nn.initializers.normal(0.02), (hidden, e))
+        bias = self.variable(ROUTER_STATE, "e_score_correction_bias",
+                             jnp.zeros, (e,), jnp.float32)
+        init = nn.initializers.normal(0.02)
+        w_gate = self.param("experts_gate_proj", init, (held, hidden, f))
+        w_up = self.param("experts_up_proj", init, (held, hidden, f))
+        w_down = self.param("experts_down_proj", init, (held, f, hidden))
+        with jax.named_scope("moe.router"):
+            idx, gates = route_noaux_tc(
+                flat, router, bias.value, top_k=self.num_experts_per_tok,
+                scaling=self.routed_scaling_factor)
+        y, counters = held_experts_ffn(
+            flat, idx, gates, w_gate, w_up, w_down,
+            first_expert=self.first_expert, n_experts=e)
+        y = y.astype(self.dtype).reshape(b, s, hidden)
+        if self.n_shared_experts:
+            with jax.named_scope("moe.shared"):
+                y = y + SwiGLU(f * self.n_shared_experts, self.dtype,
+                               name="shared_experts")(x)
+        self._after_step(bias, idx, counters)
+        return y
+
+    def _after_step(self, bias, idx, counters):
+        """What a training step leaves behind: the bias moved against the
+        step's load, the layer's counters (``load``: the step's
+        token-choices for each of ALL experts). Outside training both
+        collections are read-only and nothing is written."""
+        stats = {
+            "rows_total": self.variable(MOE_STATS, "rows_total", jnp.zeros,
+                                        (), jnp.float32),
+            "steps": self.variable(MOE_STATS, "steps", jnp.zeros, (),
+                                   jnp.int32),
+            "dropped_rows": self.variable(MOE_STATS, "dropped_rows",
+                                          jnp.zeros, (), jnp.int32),
+            "rows_max_over_mean": self.variable(
+                MOE_STATS, "rows_max_over_mean", jnp.zeros, (), jnp.float32),
+            "load": self.variable(MOE_STATS, "load", jnp.zeros,
+                                  (self.n_routed_experts,), jnp.int32),
+        }
+        if self.is_initializing():
+            return
+        if self.is_mutable_collection(ROUTER_STATE):
+            with jax.named_scope("moe.router"):
+                bias.value = noaux_bias_update(bias.value, idx,
+                                               self.bias_update_rate)
+        if self.is_mutable_collection(MOE_STATS):
+            stats["rows_total"].value += \
+                counters["local_rows"].astype(jnp.float32)
+            stats["steps"].value += 1
+            stats["dropped_rows"].value += counters["dropped_rows"]
+            stats["rows_max_over_mean"].value = counters["rows_max_over_mean"]
+            stats["load"].value = jnp.bincount(
+                idx.reshape(-1), length=self.n_routed_experts
+            ).astype(jnp.int32)
+
+
+class DecoderBlock(nn.Module):
+    attention: Dict[str, Any]
+    ffn_width: int                       # the dense block's; 0 for experts
+    experts: Optional[Dict[str, Any]]
+    eps: float
+    dtype: Any = jnp.bfloat16
+
+    @nn.compact
+    def __call__(self, x):
+        norm = functools.partial(RMSNorm, self.eps, self.dtype)
+        x = x + MLAttention(eps=self.eps, dtype=self.dtype, name="self_attn",
+                            **self.attention)(norm(name="input_layernorm")(x))
+        h = norm(name="post_attention_layernorm")(x)
+        if self.experts is None:
+            return x + SwiGLU(self.ffn_width, self.dtype, name="mlp")(h)
+        return x + SparseExperts(dtype=self.dtype, name="mlp",
+                                 **self.experts)(h)
+
+
+class DecoderLM(nn.Module):
+    """ids (batch, seq) -> ``(logits, mtp_logits)``, both (batch, seq, vocab)
+    in float32. ``logits[:, t]`` scores ``tok_{t+1}``; ``mtp_logits[:, t]``
+    scores ``tok_{t+2}`` (its last position, whose input token lies beyond
+    the sequence, is fed token 0 and left out of the loss). With no MTP
+    module ``mtp_logits`` is None."""
+    vocab_size: int
+    hidden_size: int
+    num_hidden_layers: int
+    first_k_dense_replace: int
+    intermediate_size: int
+    attention: Any                       # FrozenDict of MLAttention's sizes
+    experts: Any                         # FrozenDict of SparseExperts' sizes
+    num_nextn_predict_layers: int = 0
+    rms_norm_eps: float = 1e-6
+    dtype: Any = jnp.bfloat16
+    mtp_loss_weight: float = 0.3
+
+    @classmethod
+    def from_config(cls, cfg: Dict[str, Any], **overrides) -> "DecoderLM":
+        """From a ``config.json`` of the family. Beside the published keys:
+        ``experts_held`` / ``first_expert`` (this rank's share of each
+        layer's ``n_routed_experts``; by default all of them),
+        ``bias_update_rate`` (gamma), ``mtp_loss_weight`` (lambda),
+        ``compute_dtype``."""
+        if cfg.get("scoring_func", "sigmoid") != "sigmoid" or \
+                cfg.get("topk_method", "noaux_tc") != "noaux_tc" or \
+                cfg.get("n_group", 1) != 1 or not cfg.get("norm_topk_prob",
+                                                          True):
+            raise ValueError("DecoderLM routes by sigmoid scores, noaux_tc, "
+                             "one group, renormalised gates")
+        if not cfg.get("rope_interleave", True) or cfg.get("rope_scaling"):
+            raise ValueError("DecoderLM applies interleaved RoPE with no "
+                             "scaling")
+        from flax.core import FrozenDict
+        e = int(cfg["n_routed_experts"])
+        attention = FrozenDict(
+            num_heads=int(cfg["num_attention_heads"]),
+            q_lora_rank=int(cfg["q_lora_rank"]),
+            kv_lora_rank=int(cfg["kv_lora_rank"]),
+            qk_nope_head_dim=int(cfg["qk_nope_head_dim"]),
+            qk_rope_head_dim=int(cfg["qk_rope_head_dim"]),
+            v_head_dim=int(cfg["v_head_dim"]),
+            rope_theta=float(cfg["rope_theta"]))
+        experts = FrozenDict(
+            n_routed_experts=e,
+            experts_held=int(cfg.get("experts_held", e)),
+            first_expert=int(cfg.get("first_expert", 0)),
+            num_experts_per_tok=int(cfg["num_experts_per_tok"]),
+            moe_intermediate_size=int(cfg["moe_intermediate_size"]),
+            n_shared_experts=int(cfg.get("n_shared_experts", 0)),
+            routed_scaling_factor=float(cfg.get("routed_scaling_factor", 1.0)),
+            bias_update_rate=float(cfg.get("bias_update_rate", 1e-3)))
+        kw = dict(
+            vocab_size=int(cfg["vocab_size"]),
+            hidden_size=int(cfg["hidden_size"]),
+            num_hidden_layers=int(cfg["num_hidden_layers"]),
+            first_k_dense_replace=int(cfg.get("first_k_dense_replace", 0)),
+            intermediate_size=int(cfg["intermediate_size"]),
+            attention=attention, experts=experts,
+            num_nextn_predict_layers=int(
+                cfg.get("num_nextn_predict_layers", 0)),
+            rms_norm_eps=float(cfg.get("rms_norm_eps", 1e-6)),
+            dtype=jnp.dtype(cfg.get("compute_dtype", "bfloat16")),
+            mtp_loss_weight=float(cfg.get("mtp_loss_weight", 0.3)))
+        kw.update(overrides)
+        if kw["num_nextn_predict_layers"] not in (0, 1):
+            raise ValueError("DecoderLM has an MTP module of depth 0 or 1")
+        return cls(**kw)
+
+    def loss(self) -> Callable:
+        """The loss to hand the estimator beside this module."""
+        return functools.partial(next_token_loss,
+                                 mtp_weight=self.mtp_loss_weight)
+
+    def _block(self, moe: bool, name: str):
+        return nn.remat(DecoderBlock)(
+            attention=dict(self.attention),
+            ffn_width=0 if moe else self.intermediate_size,
+            experts=dict(self.experts) if moe else None,
+            eps=self.rms_norm_eps, dtype=self.dtype, name=name)
+
+    @keras_call
+    @nn.compact
+    def __call__(self, ids, train: bool = False):
+        del train                        # no dropout; the step's state is
+        ids = ids.astype(jnp.int32)      # written where it is mutable
+        if self.is_initializing():
+            # no parameter's shape depends on the sequence's length: the
+            # engine's eager init runs on a prefix, not on 8192 positions
+            ids = ids[:, :INIT_POSITIONS]
+        norm = functools.partial(RMSNorm, self.rms_norm_eps, self.dtype)
+        embed = nn.Embed(self.vocab_size, self.hidden_size,
+                         dtype=self.dtype, param_dtype=jnp.float32,
+                         embedding_init=nn.initializers.normal(0.02),
+                         name="embed_tokens")
+        head = self.param("lm_head", nn.initializers.normal(0.02),
+                          (self.hidden_size, self.vocab_size))
+
+        def logits_of(h):
+            with jax.named_scope("lm_head"):
+                return jnp.dot(h, head.astype(self.dtype),
+                               preferred_element_type=jnp.float32)
+
+        x = embed(ids)
+        for i in range(self.num_hidden_layers):
+            x = self._block(i >= self.first_k_dense_replace,
+                            f"layers_{i}")(x)
+        logits = logits_of(norm(name="norm")(x))
+        if not self.num_nextn_predict_layers:
+            return logits, None
+        with jax.named_scope("mtp"):
+            nxt = jnp.pad(ids[:, 1:], ((0, 0), (0, 1)))
+            merged = jnp.concatenate(
+                [norm(name="mtp_hnorm")(x), norm(name="mtp_enorm")(embed(nxt))],
+                axis=-1)
+            h = _dense(self.hidden_size, self.dtype, "mtp_eh_proj")(merged)
+            h = self._block(True, "mtp_block")(h)
+            mtp_logits = logits_of(norm(name="mtp_norm")(h))
+        return logits, mtp_logits
+
+
+def _shifted_nll(logits, ids, shift: int):
+    """Per sequence, the mean over the positions that have a label of
+    ``-log softmax(logits[:, t])[ids[:, t + shift]]``."""
+    s = ids.shape[1]
+    labels = jnp.pad(ids[:, shift:], ((0, 0), (0, shift)))
+    logp = jax.nn.log_softmax(logits.astype(jnp.float32), axis=-1)
+    nll = -jnp.take_along_axis(logp, labels[..., None], axis=-1)[..., 0]
+    valid = (jnp.arange(s) < s - shift).astype(jnp.float32)
+    return jnp.sum(nll * valid, axis=-1) / (s - shift)
+
+
+def next_token_loss(ids, preds, mtp_weight: float = 0.3):
+    """``CE(main, tok_{t+1}) + mtp_weight * CE(MTP, tok_{t+2})`` for each
+    sequence (the engine takes the mean over the batch); the labels are the
+    input ids, shifted here, on the device."""
+    logits, mtp_logits = preds
+    ids = ids.astype(jnp.int32)
+    loss = _shifted_nll(logits, ids, 1)
+    if mtp_logits is not None:
+        loss = loss + mtp_weight * _shifted_nll(mtp_logits, ids, 2)
+    return loss
+
+
+def moe_counters(extra_vars: Dict[str, Any]) -> Dict[str, float]:
+    """The expert layers' counters from a step's outputs (the engine's
+    ``extra_vars``), one host fetch for all layers: ``moe_local_rows`` (the
+    token-choices routed to the experts held, a step, summed over the
+    layers), ``moe_rows_max_over_mean`` (the last step's imbalance over the
+    experts held, the worst layer), ``moe_dropped_rows`` (must read 0),
+    ``moe_steps``."""
+    stats = jax.device_get(extra_vars.get(MOE_STATS, {}))
+    layers = [v["mlp"] for v in stats.values() if "mlp" in v]
+    if not layers:
+        return {}
+    steps = max(int(l["steps"]) for l in layers)
+    rows = float(sum(float(l["rows_total"]) for l in layers))
+    out = {
+        "moe_steps": steps,
+        "moe_rows_total": rows,
+        "moe_local_rows": rows / max(steps, 1),
+        "moe_rows_max_over_mean": float(max(float(l["rows_max_over_mean"])
+                                            for l in layers)),
+        "moe_dropped_rows": int(sum(int(l["dropped_rows"]) for l in layers)),
+    }
+    for name in ("moe_local_rows", "moe_rows_max_over_mean",
+                 "moe_dropped_rows"):
+        _REGISTRY.gauge(f"zoo_{name}", _GAUGE_DOC[name]).set(out[name])
+    return out

@@ -1,0 +1,19 @@
+"""The held experts' grouped products' share of their roofline: the least
+time a chip could take for them at the rows really routed here
+(harness/work_lm.py, from the program's ``moe_local_rows``), over the device
+time per step of everything under ``moe.experts``: the sort, the gather, the
+grouped products, the scatter-add, whatever implements them."""
+
+from harness import work_lm
+
+
+def read(ctx):
+    facts, t, peaks = ctx["facts"], ctx["trace"], ctx["peaks"]
+    by = facts.get("scope_seconds")
+    if not by or t is None or peaks is None or not t.steps \
+            or by["moe.experts"] <= 0:
+        return None
+    least = work_lm.expert_min_seconds(
+        facts["model_config"], facts["moe"]["moe_local_rows"]
+        / facts["chips"], facts["dtype_bytes"], peaks)
+    return 100.0 * least / (by["moe.experts"] / t.steps)

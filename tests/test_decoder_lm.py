@@ -1,0 +1,358 @@
+"""The decoder language model (MLA, a sigmoid-routed expert layer of which
+one rank holds a share, multi-token prediction) against the benchmark's plain
+float32 reference, at small widths on the CPU; the flash kernels at a head
+size of v's own; the expert layer's share, no-drop and bias-update rules; the
+model trained through ``TPUEstimator.fit`` on arrays."""
+
+import json
+import os
+import sys
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+BENCH = os.path.join(REPO, "benchmarks")
+if BENCH not in sys.path:
+    sys.path.insert(0, BENCH)
+
+from reference import joyai_llm_flash as ref                    # noqa: E402
+from harness import spec, work_lm                               # noqa: E402
+
+from analytics_zoo_tpu.ops.attention import (                   # noqa: E402
+    flash_attention, mha_reference)
+from analytics_zoo_tpu.parallel.expert_parallel import (        # noqa: E402
+    grouped_matmul, held_experts_ffn, noaux_bias_update, route_noaux_tc)
+from analytics_zoo_tpu.pipeline.api.keras.layers.decoder_lm import (  # noqa: E402
+    DecoderLM, moe_counters, next_token_loss, rope_interleaved)
+
+CFG = dict(
+    vocab_size=96, hidden_size=32, num_attention_heads=2, q_lora_rank=24,
+    kv_lora_rank=16, qk_nope_head_dim=16, qk_rope_head_dim=8, v_head_dim=16,
+    intermediate_size=48, moe_intermediate_size=16, n_routed_experts=16,
+    experts_held=4, first_expert=4, num_experts_per_tok=4,
+    n_shared_experts=1, num_hidden_layers=3, first_k_dense_replace=1,
+    num_nextn_predict_layers=1, rms_norm_eps=1e-6, rope_theta=10000.0,
+    routed_scaling_factor=2.5, compute_dtype="float32", mtp_loss_weight=0.3,
+    bias_update_rate=1e-3,
+    init=dict(embedding_std=1.0, out_proj_scale=0.5, router_std=1.0))
+
+
+def _tree(flat):
+    out = {}
+    for name, a in flat.items():
+        node = out
+        parts = name.split("/")
+        for p in parts[:-1]:
+            node = node.setdefault(p, {})
+        node[parts[-1]] = a
+    return out
+
+
+def _flat(tree):
+    return {"/".join(str(getattr(p, "key", p)) for p in path): a
+            for path, a in jax.tree_util.tree_leaves_with_path(tree)}
+
+
+@pytest.fixture(scope="module")
+def sides():
+    """Program and reference on the same seeded weights and ids: logits of
+    both heads, the loss and every leaf's gradient."""
+    model = DecoderLM.from_config(CFG)
+    ids = np.random.RandomState(0).randint(0, 96, (2, 32)).astype(np.uint16)
+    variables = jax.jit(model.init)(jax.random.PRNGKey(0),
+                                    jnp.asarray(ids[:1]))
+    weights = ref.make_weights(CFG, 7)
+    extra = {k: v for k, v in variables.items() if k != "params"}
+
+    def loss_of(p):
+        preds, new = model.apply({"params": p, **extra}, jnp.asarray(ids),
+                                 train=True, mutable=list(extra))
+        return jnp.mean(next_token_loss(jnp.asarray(ids), preds, 0.3)), \
+            (preds, new)
+
+    (loss, (preds, new)), grads = jax.jit(jax.value_and_grad(
+        loss_of, has_aux=True))(_tree(weights))
+    ref_grad = jax.jit(jax.value_and_grad(
+        lambda p, seq: ref.sequence_loss(CFG, p, {}, seq), has_aux=True))
+    ref_forward = jax.jit(lambda p, seq: ref.forward(CFG, p, {}, seq))
+    ref_losses, ref_grads, ref_logits = [], None, []
+    for seq in ids:
+        (l, _), g = ref_grad(weights, jnp.asarray(seq))
+        ref_losses.append(float(l))
+        ref_grads = g if ref_grads is None else jax.tree.map(
+            jnp.add, ref_grads, g)
+        ref_logits.append(ref_forward(weights, jnp.asarray(seq)))
+    return dict(model=model, variables=variables, weights=weights,
+                loss=float(loss), preds=preds, new=new, grads=_flat(grads),
+                ref_loss=float(np.mean(ref_losses)),
+                ref_grads={k: v / len(ids) for k, v in ref_grads.items()},
+                ref_logits=ref_logits)
+
+
+def test_program_tree_is_the_references(sides):
+    shapes = {k: tuple(v.shape)
+              for k, v in _flat(sides["variables"]["params"]).items()}
+    assert shapes == {k: tuple(v) for k, v in ref.param_shapes(CFG).items()}
+
+
+@pytest.mark.parametrize("head", [0, 1])
+def test_logits_match_reference(sides, head):
+    for b, (main, mtp, _) in enumerate(sides["ref_logits"]):
+        want = (main, mtp)[head]
+        got = sides["preds"][head][b]
+        # the MTP head's last position is fed a pad token: defined alike
+        np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                                   rtol=2e-4, atol=2e-5)
+
+
+def test_loss_matches_reference(sides):
+    assert sides["loss"] == pytest.approx(sides["ref_loss"], rel=1e-5)
+
+
+def test_every_leafs_gradient_matches_reference(sides):
+    assert set(sides["grads"]) == set(sides["ref_grads"])
+    for name, want in sides["ref_grads"].items():
+        got = np.asarray(sides["grads"][name])
+        scale = float(jnp.abs(want).max()) + 1e-12
+        assert float(np.abs(got - np.asarray(want)).max()) <= 2e-4 * scale, \
+            name
+
+
+def test_a_training_forward_moves_bias_and_counters(sides):
+    new = sides["new"]
+    for block in ("layers_1", "layers_2", "mtp_block"):
+        bias = np.asarray(
+            new["router_state"][block]["mlp"]["e_score_correction_bias"])
+        load = np.asarray(new["moe_stats"][block]["mlp"]["load"])
+        assert load.sum() == 2 * 32 * 4
+        np.testing.assert_allclose(
+            bias, 1e-3 * np.sign(load.mean() - load), atol=1e-9)
+    counters = moe_counters(new)
+    assert counters["moe_dropped_rows"] == 0 and counters["moe_steps"] == 1
+    assert 0 < counters["moe_local_rows"] < 3 * 2 * 32 * 4
+
+
+@pytest.mark.parametrize("causal", [False, True])
+def test_flash_takes_a_head_size_of_vs_own(causal):
+    rng = np.random.RandomState(1)
+    q = jnp.asarray(rng.randn(2, 64, 2, 24), jnp.float32)
+    k = jnp.asarray(rng.randn(2, 64, 2, 24), jnp.float32)
+    v = jnp.asarray(rng.randn(2, 64, 2, 16), jnp.float32)
+
+    def flash(*a):
+        return flash_attention(*a, causal=causal, block_q=16, block_k=16)
+
+    def plain(*a):
+        return mha_reference(*a, causal=causal)
+
+    out = flash(q, k, v)
+    assert out.shape == (2, 64, 2, 16)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(plain(q, k, v)),
+                               rtol=1e-5, atol=1e-5)
+    got = jax.grad(lambda *a: jnp.sum(jnp.sin(flash(*a))), (0, 1, 2))(q, k, v)
+    want = jax.grad(lambda *a: jnp.sum(jnp.sin(plain(*a))), (0, 1, 2))(q, k, v)
+    for g, w in zip(got, want):
+        assert g.shape == w.shape
+        np.testing.assert_allclose(np.asarray(g), np.asarray(w), rtol=1e-4,
+                                   atol=1e-5)
+
+
+def test_rope_rotates_adjacent_pairs():
+    rng = np.random.RandomState(2)
+    x = rng.randn(1, 6, 2, 8).astype(np.float32)
+    got = np.asarray(rope_interleaved(jnp.asarray(x), 100.0))
+    z = x[..., 0::2] + 1j * x[..., 1::2]                 # pairs as complex
+    inv = 100.0 ** (-np.arange(0, 8, 2) / 8)
+    ang = np.arange(6)[:, None] * inv[None, :]
+    want = z * np.exp(1j * ang)[None, :, None, :]
+    np.testing.assert_allclose(got[..., 0::2], want.real, atol=1e-5)
+    np.testing.assert_allclose(got[..., 1::2], want.imag, atol=1e-5)
+    # what attention sees depends on the distance alone
+    q, k = jnp.asarray(rng.randn(1, 6, 1, 8)), jnp.asarray(rng.randn(1, 6, 1, 8))
+    same_q = jnp.broadcast_to(q[:, :1], q.shape)
+    same_k = jnp.broadcast_to(k[:, :1], k.shape)
+    dots = np.einsum("bqhd,bkhd->qk",
+                     np.asarray(rope_interleaved(same_q, 100.0)),
+                     np.asarray(rope_interleaved(same_k, 100.0)))
+    np.testing.assert_allclose(dots[1, 0], dots[4, 3], rtol=1e-4)
+    np.testing.assert_allclose(dots[0, 2], dots[3, 5], rtol=1e-4)
+    # and the reference's is the same rotation
+    np.testing.assert_allclose(
+        np.asarray(ref.rope(jnp.asarray(x[0]), 100.0)), got[0], atol=1e-6)
+
+
+def _layer_inputs(n=64, d=16, f=8, e=32, seed=3):
+    rng = np.random.RandomState(seed)
+    x = jnp.asarray(rng.randn(n, d), jnp.float32)
+    p = {"m/gate": jnp.asarray(rng.randn(d, e) * 0.3, jnp.float32),
+         "m/experts_gate_proj": jnp.asarray(rng.randn(e, d, f) * .3,
+                                            jnp.float32),
+         "m/experts_up_proj": jnp.asarray(rng.randn(e, d, f) * .3,
+                                          jnp.float32),
+         "m/experts_down_proj": jnp.asarray(rng.randn(e, f, d) * .3,
+                                            jnp.float32),
+         "m/shared_experts/gate_proj/kernel":
+             jnp.asarray(rng.randn(d, f) * .3, jnp.float32),
+         "m/shared_experts/up_proj/kernel":
+             jnp.asarray(rng.randn(d, f) * .3, jnp.float32),
+         "m/shared_experts/down_proj/kernel":
+             jnp.asarray(rng.randn(f, d) * .3, jnp.float32)}
+    cfg = dict(CFG, hidden_size=d, moe_intermediate_size=f,
+               n_routed_experts=e, experts_held=e, first_expert=0)
+    return x, p, cfg
+
+
+def test_the_sixteen_shares_add_up_to_the_uncut_layer():
+    """The routed parts that all 16 ranks give, plus the shared expert
+    counted once, are the uncut reference's expert layer."""
+    x, p, cfg = _layer_inputs()
+    bias = jnp.zeros((32,))
+    whole, _ = ref.expert_layer(cfg, p, "m", x, bias, None)
+    idx, gates = route_noaux_tc(x, p["m/gate"], bias, top_k=4, scaling=2.5)
+    total = jnp.zeros_like(x)
+    rows = 0
+    for rank in range(16):
+        lo = 2 * rank
+        y, counters = held_experts_ffn(
+            x, idx, gates, p["m/experts_gate_proj"][lo:lo + 2],
+            p["m/experts_up_proj"][lo:lo + 2],
+            p["m/experts_down_proj"][lo:lo + 2],
+            first_expert=lo, n_experts=32)
+        total = total + y
+        rows += int(counters["local_rows"])
+        assert int(counters["dropped_rows"]) == 0
+    assert rows == 64 * 4                    # every token-choice, once
+    shared = ref.swiglu(x, p["m/shared_experts/gate_proj/kernel"],
+                        p["m/shared_experts/up_proj/kernel"],
+                        p["m/shared_experts/down_proj/kernel"], None)
+    np.testing.assert_allclose(np.asarray(total + shared),
+                               np.asarray(whole), rtol=1e-4, atol=1e-5)
+
+
+@pytest.mark.parametrize("hot_share", [0.5, 1.0])
+def test_no_token_is_dropped_where_one_expert_gets_the_rows(hot_share):
+    x, p, cfg = _layer_inputs(n=64)
+    rng = np.random.RandomState(4)
+    # every token's first choice is expert 5, of the four experts held
+    # (4..7): with the second choice among the other three it gets half of
+    # the rows routed here, with the second choice elsewhere all of them,
+    # far over a balanced share either way
+    second = rng.choice([4, 6, 7], 64) if hot_share == 0.5 \
+        else rng.randint(8, 32, 64)
+    idx = np.stack([np.full(64, 5), second,
+                    rng.randint(8, 32, 64), rng.randint(8, 32, 64)], 1)
+    gates = jnp.asarray(rng.rand(64, 4), jnp.float32)
+    idx = jnp.asarray(idx, jnp.int32)
+    w = [p[f"m/experts_{n}_proj"][4:8] for n in ("gate", "up", "down")]
+    y, counters = held_experts_ffn(x, idx, gates, *w, first_expert=4,
+                                   n_experts=32)
+    assert int(counters["local_rows"]) == int(64 / hot_share)
+    assert int(counters["dropped_rows"]) == 0
+    assert float(counters["rows_max_over_mean"]) == pytest.approx(
+        4 * hot_share)
+    # the static first chunk holds 64 rows: at half, the rest went the
+    # long way
+    held_p = {k: v[4:8] if "experts_" in k else v for k, v in p.items()}
+    want = ref.experts_part(cfg, held_p, "m", x, idx, gates, None, first=4,
+                            held=4)
+    np.testing.assert_allclose(np.asarray(y), np.asarray(want), rtol=1e-4,
+                               atol=1e-5)
+    got = jax.grad(lambda x: jnp.sum(jnp.sin(held_experts_ffn(
+        x, idx, gates, *w, first_expert=4, n_experts=32)[0])))(x)
+    want_g = jax.grad(lambda x: jnp.sum(jnp.sin(ref.experts_part(
+        cfg, held_p, "m", x, idx, gates, None, first=4, held=4))))(x)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want_g),
+                               rtol=1e-3, atol=1e-5)
+
+
+def test_grouped_matmul_matches_ragged_dot():
+    rng = np.random.RandomState(5)
+    lhs = jnp.asarray(rng.randn(32, 8), jnp.float32)
+    rhs = jnp.asarray(rng.randn(3, 8, 4), jnp.float32)
+    sizes = jnp.asarray([5, 0, 9, 18], jnp.int32)     # 18 rows of no group
+
+    def oracle(l, r):
+        return jax.lax.ragged_dot(l, r, sizes[:-1])
+
+    a = grouped_matmul(lhs, rhs, sizes)
+    np.testing.assert_allclose(np.asarray(a), np.asarray(oracle(lhs, rhs)),
+                               atol=1e-5)
+    assert not np.asarray(a)[14:].any()
+    for ga, gb in zip(
+            jax.grad(lambda l, r: jnp.sum(jnp.sin(grouped_matmul(
+                l, r, sizes))), (0, 1))(lhs, rhs),
+            jax.grad(lambda l, r: jnp.sum(jnp.sin(oracle(l, r))),
+                     (0, 1))(lhs, rhs)):
+        np.testing.assert_allclose(np.asarray(ga), np.asarray(gb), atol=1e-5)
+
+
+def test_router_gates_and_bias():
+    x, p, _ = _layer_inputs()
+    bias = jnp.zeros((32,)).at[3].set(10.0)     # steers the choice only
+    idx, gates = route_noaux_tc(x, p["m/gate"], bias, top_k=4, scaling=2.5)
+    assert bool(jnp.all(jnp.any(idx == 3, axis=-1)))
+    np.testing.assert_allclose(np.asarray(gates.sum(-1)), 2.5, rtol=1e-5)
+    scores = jax.nn.sigmoid(x @ p["m/gate"])
+    chosen = jnp.take_along_axis(scores, idx, -1)
+    np.testing.assert_allclose(
+        np.asarray(gates), np.asarray(chosen / chosen.sum(-1, keepdims=True)
+                                      * 2.5), rtol=1e-5)
+    ref_idx, ref_gates = ref.route(dict(CFG, num_experts_per_tok=4),
+                                   x, p["m/gate"], bias)
+    assert np.array_equal(np.sort(np.asarray(idx)),
+                          np.sort(np.asarray(ref_idx)))
+
+
+def test_bias_update_moves_against_the_load():
+    idx = jnp.asarray([[0, 1], [0, 2], [0, 1], [0, 3]])   # loads 4,2,1,1,0,0
+    new = noaux_bias_update(jnp.zeros((6,)), idx, 0.001)
+    # mean load 8/6: experts 0 and 1 are over it, the others under
+    np.testing.assert_allclose(
+        np.asarray(new), [-.001, -.001, .001, .001, .001, .001], atol=1e-9)
+
+
+def test_trains_through_the_estimator_on_arrays():
+    from analytics_zoo_tpu import init_orca_context
+    from analytics_zoo_tpu.orca.learn.estimator import TPUEstimator
+    from analytics_zoo_tpu.orca.learn.optimizers import AdamWeightDecay
+    from analytics_zoo_tpu.parallel.mesh import create_mesh
+    ctx = init_orca_context("local")
+    mesh = create_mesh({"dp": 1}, devices=ctx.devices[:1])
+    model = DecoderLM.from_config(dict(CFG, compute_dtype="bfloat16",
+                                         num_hidden_layers=2))
+    est = TPUEstimator(model, loss=model.loss(),
+                       optimizer=AdamWeightDecay(lr=3e-3, weight_decay=0.1,
+                                                 beta_2=0.95),
+                       mesh=mesh, seed=0)
+    ids = np.random.RandomState(6).randint(0, 96, (8, 32)).astype(np.uint16)
+    stats = est.fit({"x": ids, "y": ids}, epochs=3, batch_size=4,
+                    verbose=False)
+    losses = [s["train_loss"] for s in stats]
+    assert np.all(np.isfinite(losses)) and losses[-1] < losses[0]
+    counters = moe_counters(est.engine.extra_vars)
+    assert counters["moe_steps"] == 6 and counters["moe_dropped_rows"] == 0
+    bias = jax.device_get(est.engine.extra_vars["router_state"])
+    assert np.abs(bias["layers_1"]["mlp"]["e_score_correction_bias"]).max() > 0
+    est.shutdown()
+
+
+def test_the_configurations_parameter_count_is_pinned():
+    """680.4 M parameters, 10.89 GB at 16 B a parameter: the cut of
+    ISSUE 35, counted three ways."""
+    with open(os.path.join(BENCH, "configs",
+                           "joyai_llm_flash_ep16.json")) as f:
+        cfg = json.load(f)
+    factory = spec.load_py(os.path.join(BENCH, cfg["factory"]))
+    mcfg = factory.model_config(cfg)
+    assert mcfg["n_routed_experts"] == 256 and mcfg["experts_held"] == 16
+    n = ref.param_count(mcfg)
+    assert n == 680_439_808 == work_lm.param_count(mcfg)
+    assert abs(n - 680.4e6) / 680.4e6 < 0.01
+    module = DecoderLM.from_config(mcfg)
+    shapes = jax.eval_shape(lambda: module.init(
+        jax.random.PRNGKey(0), jnp.zeros((1, 128), jnp.uint16)))
+    assert sum(int(np.prod(a.shape))
+               for a in jax.tree.leaves(shapes["params"])) == n
