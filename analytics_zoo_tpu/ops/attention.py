@@ -21,6 +21,7 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 from jax import lax
+from jax.ad_checkpoint import checkpoint_name
 
 from ..obs.registry import REGISTRY as _REGISTRY
 
@@ -34,6 +35,10 @@ _REFERENCE_ON_TPU = _REGISTRY.counter(
 
 NEG_INF = -1e30
 LOG2_E = 1.4426950408889634      # the flash kernel softmaxes in base 2
+# the forward kernel's output and logsumexp, as ``checkpoint_name`` marks
+# them in ``_flash_fwd``: a remat policy that saves these names runs the
+# forward kernel once a step, not again in the backward pass
+FLASH_RESIDUAL_NAMES = ("flash_out", "flash_lse")
 
 
 def mha_reference(q: jax.Array, k: jax.Array, v: jax.Array,
@@ -371,9 +376,20 @@ def _flash_attention(q, k, v, causal, sm_scale, block_q, block_k):
 
 
 def _flash_fwd(q, k, v, causal, sm_scale, block_q, block_k):
+    """The forward kernel, with its two results named
+    (``FLASH_RESIDUAL_NAMES``) for a caller's remat policy: the output
+    (B, S_q, H, d_v), which is both the primal result and a residual, and
+    the log2-domain logsumexp, kept as the kernel wrote it, the (B*H, S_q,
+    1) column the backward kernels read. Kept as (B*H, S_q) instead it
+    needs a relayout between sublanes and lanes on each side, which on a
+    v5e compiled to 334 MB more executable for six blocks of 2 x 8192 x 32
+    heads, a slower step and no less memory (PERF.md, PR 36). Outside a
+    remat whose policy reads the names they are identities."""
     interpret = _interpret()
     out, lse = _flash_forward(q, k, v, causal, sm_scale, block_q, block_k,
                               interpret, with_lse=True)
+    out = checkpoint_name(out, FLASH_RESIDUAL_NAMES[0])
+    lse = checkpoint_name(lse, FLASH_RESIDUAL_NAMES[1])
     return out, (q, k, v, out, lse)
 
 
@@ -604,6 +620,13 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
     sequence tiles evenly (compiled on a TPU, interpret mode on the CPU
     backend), else the reference path — which on a TPU is logged and
     counted (``zoo_attention_reference_on_tpu_total``), never silent.
+
+    Under differentiation the forward kernel's output and logsumexp are
+    named ``FLASH_RESIDUAL_NAMES`` (see ``_flash_fwd``): inside a
+    ``jax.checkpoint`` / ``nn.remat`` with
+    ``save_only_these_names(*FLASH_RESIDUAL_NAMES)`` the backward kernels
+    read the first launch's results and the forward kernel is not run
+    again; under any other policy, or none, the names do nothing.
 
     Default 1024x1024 forward tiles: round-4 sweep on a v5e chip at
     S=4096/D=64-128 measured 1024x1024 fastest of {256..2048}x{512,1024}

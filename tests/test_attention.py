@@ -8,7 +8,8 @@ import pytest
 from jax.sharding import Mesh, PartitionSpec as P
 
 from jax import shard_map
-from analytics_zoo_tpu.ops.attention import flash_attention, mha_reference
+from analytics_zoo_tpu.ops.attention import (
+    FLASH_RESIDUAL_NAMES, flash_attention, mha_reference)
 from analytics_zoo_tpu.parallel.ring_attention import (
     ring_attention, sequence_sharded_attention, ulysses_attention)
 
@@ -143,6 +144,50 @@ def test_flash_grads_match_reference():
     for a, b in zip(g_ref, g_fl):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b),
                                    rtol=2e-3, atol=2e-3)
+
+
+def equations(jaxpr):
+    """Every equation of a jaxpr and of the jaxprs in its equations'
+    parameters (remat, cond, custom_vjp); a kernel's own body is left out."""
+    for eqn in jaxpr.eqns:
+        yield eqn
+        if eqn.primitive.name != "pallas_call":
+            for sub in jax.core.jaxprs_in_params(eqn.params):
+                yield from equations(sub)
+
+
+def pallas_kernels(jaxpr):
+    """The kernel function's name of every ``pallas_call`` in a jaxpr."""
+    return [eqn.params["jaxpr"].debug_info.func_name
+            for eqn in equations(jaxpr) if eqn.primitive.name == "pallas_call"]
+
+
+@pytest.mark.parametrize("d_qk,d_v", [(48, 32), (32, 32)])
+def test_remat_policy_keeps_the_forward_kernels_results(d_qk, d_v):
+    """Under a remat that saves ``FLASH_RESIDUAL_NAMES`` the backward pass
+    reads the first launch's output and logsumexp: one forward kernel, dQ,
+    dK/dV, and the same gradients to the bit as the plain remat's, which
+    runs the forward kernel a second time."""
+    rng = np.random.RandomState(3)
+    q, k, v = (jnp.asarray(rng.randn(2, 32, 2, d), jnp.float32)
+               for d in (d_qk, d_qk, d_v))
+
+    def f(q, k, v):
+        return jnp.sum(flash_attention(q, k, v, causal=True, block_q=16,
+                                       block_k=16))
+
+    keep = jax.checkpoint_policies.save_only_these_names(
+        *FLASH_RESIDUAL_NAMES)
+    plain = jax.grad(jax.checkpoint(f), (0, 1, 2))
+    kept = jax.grad(jax.checkpoint(f, policy=keep), (0, 1, 2))
+    backward = ["_flash_bwd_dq_kernel", "_flash_bwd_dkv_kernel"]
+    assert pallas_kernels(jax.make_jaxpr(plain)(q, k, v).jaxpr) == \
+        ["_flash_kernel"] * 2 + backward
+    assert pallas_kernels(jax.make_jaxpr(kept)(q, k, v).jaxpr) == \
+        ["_flash_kernel"] + backward
+    for a, b in zip(plain(q, k, v), kept(q, k, v)):
+        assert a.shape == b.shape and bool(jnp.all(a == b))
+        assert bool(jnp.any(a != 0))
 
 
 def _sp_mesh():

@@ -27,6 +27,7 @@ from analytics_zoo_tpu.parallel.expert_parallel import (        # noqa: E402
     grouped_matmul, held_experts_ffn, noaux_bias_update, route_noaux_tc)
 from analytics_zoo_tpu.pipeline.api.keras.layers.decoder_lm import (  # noqa: E402
     DecoderLM, moe_counters, next_token_loss, rope_interleaved)
+from test_attention import equations, pallas_kernels            # noqa: E402
 
 CFG = dict(
     vocab_size=96, hidden_size=32, num_attention_heads=2, q_lora_rank=24,
@@ -86,7 +87,8 @@ def sides():
             jnp.add, ref_grads, g)
         ref_logits.append(ref_forward(weights, jnp.asarray(seq)))
     return dict(model=model, variables=variables, weights=weights,
-                loss=float(loss), preds=preds, new=new, grads=_flat(grads),
+                loss_of=loss_of, loss=float(loss), preds=preds, new=new,
+                grads=_flat(grads),
                 ref_loss=float(np.mean(ref_losses)),
                 ref_grads={k: v / len(ids) for k, v in ref_grads.items()},
                 ref_logits=ref_logits)
@@ -119,6 +121,23 @@ def test_every_leafs_gradient_matches_reference(sides):
         scale = float(jnp.abs(want).max()) + 1e-12
         assert float(np.abs(got - np.asarray(want)).max()) <= 2e-4 * scale, \
             name
+
+
+def test_each_block_runs_the_flash_forward_kernel_once(sides):
+    """The blocks are rematerialised, but their policy keeps the flash
+    kernel's output and logsumexp: the gradient's program holds three flash
+    kernels a block (forward, dQ, dK/dV), not a second forward."""
+    jaxpr = jax.make_jaxpr(jax.grad(lambda p: sides["loss_of"](p)[0]))(
+        _tree(sides["weights"])).jaxpr
+    flash = sorted(n for n in pallas_kernels(jaxpr) if "flash" in n)
+    blocks = CFG["num_hidden_layers"] + CFG["num_nextn_predict_layers"]
+    assert flash == sorted(blocks * ["_flash_kernel", "_flash_bwd_dq_kernel",
+                                     "_flash_bwd_dkv_kernel"])
+    # one policy object for all blocks: with one a block, blocks of one shape
+    # stop sharing a lowered function (3.5x the functions at the cell's size)
+    policies = [eqn.params["policy"] for eqn in equations(jaxpr)
+                if eqn.primitive.name == "remat2" and eqn.params["policy"]]
+    assert len(policies) == blocks and len(set(map(id, policies))) == 1
 
 
 def test_a_training_forward_moves_bias_and_counters(sides):
