@@ -6,11 +6,11 @@ Four parts (ISSUE 9):
   compile plane (every ``ExecutableCache`` lowering is linted before it
   compiles): f64 reaching a TPU program, 64-bit dtype promotion, large
   undonated inputs in donating programs, host callbacks inside train
-  steps, and collective launch/byte counts measured from the module and
-  cross-checked against the comms plane's declared accounting.
-* :mod:`.golden` — program-contract snapshots (collective launches, wire
-  bytes/step, donation set, executable count) for the bench train steps,
-  committed under ``tests/goldens/`` and diffed in CI.
+  steps, and per-mesh-axis collective launch/byte counts measured from
+  the module and cross-checked against the engine's declared accounting.
+* :mod:`.golden` — program-contract snapshots (collective launches by
+  mesh axis, gather bytes, donation set, executable count) for the train
+  step, committed under ``tests/goldens/`` and diffed in CI.
 * :mod:`.races` — a runtime race detector: traced-lock instrumentation
   building a lock-order graph (inversion = deadlock risk) plus watched
   shared objects whose attributes are written from >=2 threads without
@@ -20,10 +20,11 @@ Four parts (ISSUE 9):
   without daemon/name, mutable default args), run as a CI gate.
 """
 
-from .hlo_lint import (HloLinter, HloLintError, LintFinding, declare_comms,
-                       lint_report, on_lowering, parse_collectives)
+from .hlo_lint import (HloLinter, HloLintError, LintFinding,
+                       declare_accounting, lint_report, on_lowering,
+                       parse_collectives)
 from .races import RaceDetector, get_detector
 
 __all__ = ["HloLinter", "HloLintError", "LintFinding", "RaceDetector",
-           "declare_comms", "get_detector", "lint_report", "on_lowering",
+           "declare_accounting", "get_detector", "lint_report", "on_lowering",
            "parse_collectives"]

@@ -1,51 +1,29 @@
-"""Golden program contracts for the bench train steps.
+"""Golden program contracts for the train step.
 
-A *program contract* pins what a train step's lowered StableHLO actually
-does on the wire — collective launches by kind, reduce-scatter wire
-bytes/step, the donation set, and how many distinct train executables the
-bench legs compile — next to what the comms plane *declares* through
-``data_pipeline_stats()["comms"]``. The contracts are committed under
-``tests/goldens/`` and diffed in CI, so a comms/compile regression (a
+A *program contract* pins what a train step's program actually does on the
+wire — collective launches by kind and by mesh axis, the fsdp gathers'
+bytes, the donation set, and how many distinct train executables the legs
+compile — next to what the engine *declares* for its layout. The contracts
+are committed under ``tests/goldens/`` and diffed in CI, so a regression (a
 bucketing change that doubles launches, a donation that silently stops
 happening, an ``extra_key`` change that collapses two layouts onto one
-executable) fails the gate with a readable delta instead of surfacing as
-a bench slowdown five PRs later.
+executable) fails the gate with a readable delta instead of surfacing as a
+slowdown five PRs later.
 
-Five legs mirror ``bench.py bench_comms`` on the 8-device simulated mesh:
+Three legs on the 8-device simulated mesh:
 
-* ``baseline``          — comms plane off (the pre-plane GSPMD step)
-* ``flat``              — plane on, flat per-leaf-psum reference wire
-* ``bucketed_sharded``  — 4 MiB buckets + ZeRO-1 sharded update
-* ``bucketed_bf16``     — 4 MiB buckets, bf16 collective wire
-* ``overlapped``        — multi-bucket overlapped backward–comms pipeline
-  (PR 11): per-bucket reduce-scatters assembled from their own leaf
-  slices + ZeRO-1. Its contract additionally pins
-  ``overlapped_wire_matches_bucketed`` — the total reduce-scatter wire
-  bytes must stay byte-for-byte what the bucketed leg moves (the padded
-  total is invariant to the bucket split), so overlap can never trade
-  launch position for extra bytes unnoticed.
-* ``hierarchical``      — two-level ICI×DCN wire (PR 12): multi-bucket
-  ZeRO-1 over a simulated 2-host × 4-chip factorization of the dp axis.
-  Its contract pins the **per-axis** split (collectives classified by
-  replica-group shape) and ``dcn_wire_bytes`` — the number the
-  hierarchy exists to shrink — so a regression that moves gradient
-  bytes back onto the cross-host links fails even with totals unchanged.
-
-A second golden file, ``tests/goldens/multihost_contracts.json``, pins
-the hierarchical step's contract on the REAL two-process
-``jax.distributed`` topology (2 processes × 4 virtual devices — the
-same (dcn=2, ici=4) factorization, but probed from process locality
-instead of forced): cross-host launch counts and DCN wire bytes,
-checked by ``tests/test_multihost.py`` through the two-process harness.
-The lowered program depends only on the (n_dev, dcn, ici) factorization
-and shapes — not on which process hosts which chip — so
-``--update-multihost`` regenerates it on the single-process simulated
-mesh and the harness verifies the real topology lowers to exactly it.
+* ``baseline``          — the default step on a dp mesh: replicated state,
+  no explicit collective in the lowered module (GSPMD adds the gradient
+  all-reduce when it partitions), donation ``(0, 2)``.
+* ``sharding_fsdp``     — ``SpecLayout`` on ``fsdp=8``, measured on the
+  COMPILED program (the collectives exist only after the partitioner).
+* ``sharding_fsdp_tp``  — ``SpecLayout`` on ``fsdp=4 x tp=2`` with one
+  Megatron column/row pair: pins the tp all-reduce beside the gathers.
 
 Regenerate after an *intentional* program change::
 
     JAX_PLATFORMS=cpu XLA_FLAGS=--xla_force_host_platform_device_count=8 \\
-        python -m analytics_zoo_tpu.analysis.golden --update --update-multihost
+        python -m analytics_zoo_tpu.analysis.golden --update
 
 ``--check`` (the CI gate) exits 1 on drift and prints one line per
 changed field.
@@ -58,48 +36,19 @@ import json
 import os
 from typing import Any, Dict, List, Optional, Tuple
 
-from .hlo_lint import (HloLinter, collective_counts, collectives_by_axis,
-                       collectives_by_mesh_axes, parse_collectives)
+from .hlo_lint import (HloLinter, collective_counts,
+                       collectives_by_mesh_axes, declared_accounting,
+                       parse_collectives)
 
-__all__ = ["capture_contracts", "capture_multihost_contract", "check",
-           "check_multihost", "diff_contracts", "golden_path",
-           "load_goldens", "multihost_golden_path", "save_goldens"]
+__all__ = ["capture_contracts", "check", "diff_contracts", "golden_path",
+           "load_goldens", "save_goldens"]
 
 GOLDEN_FILE = "program_contracts.json"
-MULTIHOST_GOLDEN_FILE = "multihost_contracts.json"
 
-# contract legs: name -> (estimator config, estimator kwargs)
-# overlapped uses SMALL buckets on purpose: a multi-bucket layout is the
-# shape the pipeline exists for (one bucket = nothing to overlap), and for
-# the f32 wire the padded total — hence wire bytes — is invariant to the
-# bucket split, which the overlapped_wire_matches_bucketed field pins.
-_LEGS = [
-    ("baseline", {}, {}),
-    ("flat", {"comms_plane": True}, {}),
-    ("bucketed_sharded", {"grad_bucket_mb": 4.0}, {"sharded_update": True}),
-    ("bucketed_bf16", {"grad_bucket_mb": 4.0, "allreduce_dtype": "bf16"},
-     {}),
-    ("overlapped", {"grad_bucket_mb": 0.001, "comms_overlap": True},
-     {"sharded_update": True}),
-    ("hierarchical", {"grad_bucket_mb": 0.001, "comms_hierarchy": True,
-                      "comms_dcn_axis": 2},
-     {"sharded_update": True}),
-    # the native int8 ring (PR 16): the DCN leg's reduce-scatter becomes
-    # collective_permute hops that really carry int8 payload + packed
-    # scales — hop count and wire bytes are pinned BYTE-EXACT (the lint
-    # rule runs with no simulated-wire exemption for this leg)
-    ("native_int8", {"grad_bucket_mb": 0.001, "comms_hierarchy": True,
-                     "comms_dcn_axis": 2, "allreduce_dtype": "int8",
-                     "allreduce_block": 32, "comms_native_int8": True},
-     {"sharded_update": True}),
-]
-
-
-# sharding-plane legs (PR 17): OWN mesh per leg (the comms legs run the
-# ctx's pure-dp mesh; fsdp/tp need the factored one) and the contract is
-# measured on COMPILED HLO — the sharding plane's collectives exist only
-# after the SPMD partitioner runs, so a lowering-only capture would pin
-# an empty program.
+# sharding legs: each on its OWN mesh (the baseline runs the ctx's dp mesh;
+# fsdp/tp need the factored one) and measured on COMPILED HLO — their
+# collectives exist only after the SPMD partitioner runs, so a lowering-only
+# capture would pin an empty program.
 _SHARDING_LEGS = [
     ("sharding_fsdp", {"dp": 1, "fsdp": -1}),
     ("sharding_fsdp_tp", {"dp": 1, "fsdp": -1, "tp": 2}),
@@ -114,18 +63,12 @@ def golden_path(root: Optional[str] = None) -> str:
     return os.path.join(root, GOLDEN_FILE)
 
 
-def multihost_golden_path(root: Optional[str] = None) -> str:
-    return os.path.join(os.path.dirname(golden_path(root)),
-                        MULTIHOST_GOLDEN_FILE)
-
-
 def _bench_model():
     import flax.linen as nn
 
     class BenchMLP(nn.Module):
-        """Same shape family as the tier-1 comms snapshot: several small
-        Dense leaves so the flat wire pays per-leaf collectives — exactly
-        what bucketing amortizes, exactly where a regression shows."""
+        """Several small Dense leaves: what one fsdp bucket gathers in a
+        single launch, and where a per-leaf regression shows."""
 
         @nn.compact
         def __call__(self, x):
@@ -163,17 +106,26 @@ def _bench_data():
             "y": rng.rand(256).astype("float32")}
 
 
-def capture_contracts() -> Dict[str, Any]:
-    """Lower every bench leg's train step and measure its contract.
-    Requires the 8-device simulated mesh (tests/conftest.py provides it;
-    the CLI sets XLA_FLAGS itself). Lowering-only — nothing is compiled,
-    so capture is fast and deterministic."""
+def _first_batch(est, data):
     import numpy as np
 
+    from ..orca.learn.utils import data_to_iterator
+    it = data_to_iterator(dict(data), 32, est.mesh, None, None,
+                          shuffle=False, config=est.config)
+    b0 = next(it.epoch(shuffle=False, prefetch=False))
+    est.engine.build(tuple(np.asarray(a) for a in b0.x))
+    return b0
+
+
+def capture_contracts() -> Dict[str, Any]:
+    """Lower (and, for the sharding legs, compile) every leg's train step
+    and measure its contract. Requires the 8-device simulated mesh
+    (tests/conftest.py provides it; the CLI sets XLA_FLAGS itself)."""
     from ..common.context import get_context
     from ..compile.cache import ExecutableCache
     from ..orca.learn.estimator import TPUEstimator
-    from ..orca.learn.utils import data_to_iterator
+    from ..parallel.mesh import create_mesh
+    from ..parallel.sharding import SpecLayout
 
     ctx = get_context()
     dp = int(ctx.mesh.shape.get("dp", 1)) if ctx.mesh is not None else 1
@@ -191,76 +143,25 @@ def capture_contracts() -> Dict[str, Any]:
     train_keys: List[str] = []
     linter = HloLinter()
 
-    for name, cfg, kwargs in _LEGS:
-        est = TPUEstimator(_bench_model(), loss="mse", optimizer="adam",
-                           seed=0, compile_cache=cache,
-                           config={"steps_per_dispatch": 1, **cfg},
-                           **kwargs)
-        it = data_to_iterator(dict(data), 32, est.mesh, None, None,
-                              shuffle=False, config=est.config)
-        b0 = next(it.epoch(shuffle=False, prefetch=False))
-        est.engine.build(tuple(np.asarray(a) for a in b0.x))
-        fn = est.engine.ensure_jit_train()
-        args = est.engine.train_step_args(b0)
-        if hasattr(fn, "cache_key"):
-            # one lower+render serves both the executable key and the
-            # contract text (lowered_text reuses cache_key's lowering)
-            key = fn.cache_key(*args)
-            text = fn.lowered_text(*args)
-        else:
-            key, text = None, None
-        if text is None:
-            text = fn.lower(*args).as_text()
-        if key:
-            train_keys.append(key)
-
-        ops = parse_collectives(text)
-        counts = collective_counts(ops)
-        rs_bytes = sum(op.operand_bytes for op in ops
-                       if op.kind == "reduce_scatter")
-        cp_bytes = sum(op.operand_bytes for op in ops
-                       if op.kind == "collective_permute")
-
-        donation = (fn._donate if hasattr(fn, "_donate")
-                    else ((0, 2, 3) if est.engine.comms_resid is not None
-                          else (0, 2)))
-        declared = est.engine.comms_snapshot()
-        entry: Dict[str, Any] = {
-            "collectives": counts,
-            "rs_wire_bytes": int(rs_bytes),
-            "cp_wire_bytes": int(cp_bytes),
-            "donation": sorted(int(i) for i in donation),
-        }
-        if declared is not None:
-            keep = ("buckets", "collectives_per_step", "wire_bytes_per_step",
-                    "grad_leaves", "sharded_update", "wire_dtype",
-                    "grad_bytes_f32", "overlap", "segments", "hierarchy",
-                    "native_int8", "native_hops")
-            entry["declared"] = {k: declared[k] for k in keep
-                                 if k in declared}
-            hier = declared.get("hierarchy") or {}
-            if hier.get("active"):
-                # per-axis contract: the launch/byte split between the
-                # fast (ICI) and expensive (DCN) links, classified by
-                # replica-group shape
-                ax = collectives_by_axis(ops, int(hier["ici_axis"]),
-                                         int(hier["dcn_axis"]))
-                entry["by_axis"] = {k: ax[k]
-                                    for k in ("ici", "dcn", "global")}
-                entry["ici_wire_bytes"] = int(ax["ici_wire_bytes"])
-                entry["dcn_wire_bytes"] = int(ax["dcn_wire_bytes"])
-            # the accounting rule run right here: measured bytes/launches
-            # vs declared — a contract is only golden when they agree
-            findings = linter.lint_text(text, label=f"golden:{name}",
-                                        declared=declared)
-            entry["accounting_verified"] = not findings
-            entry["accounting_findings"] = [str(f) for f in findings]
-        contracts[name] = entry
-
-    # --- sharding-plane legs (fsdp / fsdp×tp on their own meshes) ----------
-    from ..parallel.mesh import create_mesh
-    from ..parallel.sharding import SpecLayout
-    from .hlo_lint import declared_comms
+    est = TPUEstimator(_bench_model(), loss="mse", optimizer="adam", seed=0,
+                       compile_cache=cache,
+                       config={"steps_per_dispatch": 1})
+    b0 = _first_batch(est, data)
+    fn = est.engine.ensure_jit_train()
+    args = est.engine.train_step_args(b0)
+    train_keys.append(fn.cache_key(*args))
+    # lowered_text reuses cache_key's lowering
+    ops = parse_collectives(fn.lowered_text(*args))
+    # the lowered default step holds no explicit collective: the two byte
+    # sums pin that at zero
+    contracts["baseline"] = {
+        "collectives": collective_counts(ops),
+        "rs_wire_bytes": sum(op.operand_bytes for op in ops
+                             if op.kind == "reduce_scatter"),
+        "cp_wire_bytes": sum(op.operand_bytes for op in ops
+                             if op.kind == "collective_permute"),
+        "donation": sorted(int(i) for i in fn._donate),
+    }
 
     for name, axes in _SHARDING_LEGS:
         mesh = create_mesh(axes)
@@ -269,21 +170,16 @@ def capture_contracts() -> Dict[str, Any]:
                            mesh=mesh, compile_cache=cache,
                            config={"steps_per_dispatch": 1},
                            sharding=SpecLayout())
-        it = data_to_iterator(dict(data), 32, est.mesh, None, None,
-                              shuffle=False, config=est.config)
-        b0 = next(it.epoch(shuffle=False, prefetch=False))
-        est.engine.build(tuple(np.asarray(a) for a in b0.x))
+        b0 = _first_batch(est, data)
         fn = est.engine.ensure_jit_train()
         args = est.engine.train_step_args(b0)
-        key = fn.cache_key(*args) if hasattr(fn, "cache_key") else None
-        if key:
-            train_keys.append(key)
+        train_keys.append(fn.cache_key(*args))
         # compiled HLO: the gathers/grad combines appear only post-partition
         text = fn.lower(*args).compile().as_text()
         ops = parse_collectives(text)
         axis_sizes = {a: int(s) for a, s in mesh.shape.items() if s > 1}
         ax = collectives_by_mesh_axes(ops, axis_sizes)
-        declared = declared_comms(est.engine._sharding_key())
+        declared = declared_accounting(est.engine._sharding_key())
         plan = est.engine.fsdp_plan
         entry = {
             "mesh_axes": axis_sizes,
@@ -309,143 +205,13 @@ def capture_contracts() -> Dict[str, Any]:
 
     # the tp leg's reason to exist, pinned: the row-parallel matmul really
     # combines partials over the tp groups
-    if "sharding_fsdp_tp" in contracts:
-        tp_ops = contracts["sharding_fsdp_tp"]["tp_collectives"]
-        contracts["tp_all_reduce_present"] = (
-            tp_ops.get("all_reduce", 0) >= 1)
+    tp_ops = contracts["sharding_fsdp_tp"]["tp_collectives"]
+    contracts["tp_all_reduce_present"] = tp_ops.get("all_reduce", 0) >= 1
 
     # every leg must map to its own executable: a regression in the
-    # comms fingerprint / extra_key salting collapses this number
-    contracts["distinct_train_executables"] = (
-        len(set(train_keys)) if train_keys else None)
-    # the overlapped pipeline's wire contract: launching per-bucket out of
-    # leaf-sliced segments must move EXACTLY the bytes the bucketed leg
-    # moves — drift here means overlap changed the wire, not the schedule
-    if "overlapped" in contracts and "bucketed_sharded" in contracts:
-        contracts["overlapped_wire_matches_bucketed"] = (
-            contracts["overlapped"]["rs_wire_bytes"]
-            == contracts["bucketed_sharded"]["rs_wire_bytes"])
-    # the hierarchy's reason to exist, pinned: the cross-host leg moves at
-    # most 1/host_count of what the flat dp wire would push through DCN
-    # (for the same layout the flat wire's bytes are the ICI leg's f32
-    # bytes — padded_total × 4)
-    if "hierarchical" in contracts:
-        entry = contracts["hierarchical"]
-        dcn = int(entry["declared"]["hierarchy"]["dcn_axis"])
-        contracts["hierarchical_dcn_shrink_ok"] = (
-            entry["dcn_wire_bytes"] * dcn <= entry["ici_wire_bytes"])
-    # the native ring's acceptance, pinned: the measured permute bytes on
-    # the DCN leg EQUAL the declared packed wire cost (byte-exact — the
-    # simulated-wire exemption must never be what makes this leg pass)
-    if "native_int8" in contracts:
-        entry = contracts["native_int8"]
-        contracts["native_int8_byte_exact"] = (
-            entry["accounting_verified"]
-            and entry["dcn_wire_bytes"] == int(
-                entry["declared"]["hierarchy"]["dcn_wire_bytes_per_step"]))
+    # sharding fingerprint / extra_key salting collapses this number
+    contracts["distinct_train_executables"] = len(set(train_keys))
     return contracts
-
-
-# ---------------------------------------------------------------------------
-# multihost contract — the hierarchical step on a real (or real-shaped)
-# cross-process mesh
-# ---------------------------------------------------------------------------
-def capture_multihost_contract(mesh=None, dcn: int = 0) -> Dict[str, Any]:
-    """Lower the hierarchical train step over ``mesh`` and measure its
-    per-axis program contract — cross-host launch counts and DCN wire
-    bytes.
-
-    Called two ways, which must agree field-for-field:
-
-    * from the two-process harness (``tests/test_multihost.py``) with the
-      real ``jax.distributed`` global mesh and ``dcn=0`` — the (dcn, ici)
-      factorization is then PROBED from process locality
-      (``mesh.dp_topology``), so the test covers the probe end-to-end;
-    * from ``--update-multihost`` / the single-process suite with the
-      8-device simulated mesh and ``dcn=2`` forced — the lowered program
-      depends only on the factorization and shapes, not on process
-      placement, so this regenerates exactly what the harness measures.
-
-    Lowering-only AND placement-free: the engine state is built as
-    ``ShapeDtypeStruct`` pytrees (module shapes from a host-side init,
-    optimizer shapes via ``eval_shape``), so nothing is device_put,
-    compiled or executed — which is what lets the two-process golden
-    check run even on jaxlib builds without multiprocess CPU collectives
-    (where even ``device_put`` to a cross-process sharding trips a
-    consistency psum, and the *execution* leg must skip).
-    """
-    import jax
-    import jax.numpy as jnp
-    import numpy as np
-    import optax
-
-    from ..orca.learn.engine import TrainEngine
-    from ..orca.learn.utils import Batch
-    from ..parallel import comms as comms_lib
-
-    if mesh is None:
-        from ..common.context import get_context
-        mesh = get_context().mesh
-    cfg = comms_lib.CommsConfig(bucket_mb=0.001, hierarchy=True,
-                                dcn_size=int(dcn))
-    eng = TrainEngine(_bench_model(), optax.adam(1e-3),
-                      lambda y, p: (p - y) ** 2, {}, mesh, seed=0,
-                      compile_cache=False, comms=cfg)
-    data = _bench_data()
-    n_dev = int(np.prod(list(mesh.shape.values())))
-    x, y = data["x"][:4 * n_dev], data["y"][:4 * n_dev]
-
-    # abstract twin of eng.build(): same init, same layout, no placement
-    sds = lambda l: jax.ShapeDtypeStruct(  # noqa: E731
-        np.shape(l), np.asarray(l).dtype)
-    variables = dict(eng._init_vars(jax.random.PRNGKey(eng.seed),
-                                    (jnp.asarray(x[:1]),)))
-    params = variables.pop("params", {})
-    eng._build_comms(params)
-    eng.params = jax.tree.map(sds, params)
-    eng.extra_vars = jax.tree.map(sds, variables)
-    eng.opt_state = jax.eval_shape(eng.tx.init, eng.params)
-    eng.step = 0
-
-    from jax.sharding import NamedSharding, PartitionSpec as P
-    sh_x = NamedSharding(mesh, P(("dp",), *([None] * (x.ndim - 1))))
-    sh_y = NamedSharding(mesh, P(("dp",)))
-    batch = Batch(x=(jax.ShapeDtypeStruct(x.shape, x.dtype,
-                                          sharding=sh_x),),
-                  y=(jax.ShapeDtypeStruct(y.shape, y.dtype,
-                                          sharding=sh_y),),
-                  w=None)
-
-    fn = eng.ensure_jit_train()
-    args = list(eng.train_step_args(batch))
-    args[4] = jax.ShapeDtypeStruct((), np.dtype("int32"))   # step counter
-    text = fn.lower(*args).as_text()
-    ops = parse_collectives(text)
-    lo = eng.comms.layout
-    ax = collectives_by_axis(ops, lo.ici, lo.dcn)
-    declared = eng.comms_snapshot()
-    findings = HloLinter().lint_text(text, label="golden:multihost",
-                                     declared=declared)
-    return {
-        "n_dev": lo.n_dev, "dcn_axis": lo.dcn, "ici_axis": lo.ici,
-        "buckets": len(lo.bucket_sizes),
-        "collectives": collective_counts(ops),
-        "by_axis": {k: ax[k] for k in ("ici", "dcn", "global")},
-        "ici_wire_bytes": int(ax["ici_wire_bytes"]),
-        "dcn_wire_bytes": int(ax["dcn_wire_bytes"]),
-        "declared_dcn_wire_bytes": int(
-            declared["hierarchy"]["dcn_wire_bytes_per_step"]),
-        "accounting_verified": not findings,
-    }
-
-
-def check_multihost(measured: Dict[str, Any],
-                    path: Optional[str] = None) -> Tuple[bool, List[str]]:
-    """Diff a measured multihost contract against the committed golden."""
-    with open(path or multihost_golden_path(), encoding="utf-8") as f:
-        golden = json.load(f)
-    delta = diff_contracts(golden, measured)
-    return (not delta, delta)
 
 
 # ---------------------------------------------------------------------------
@@ -517,40 +283,22 @@ def _init_mesh():
 
 def main(argv=None) -> int:
     ap = argparse.ArgumentParser(
-        description="Golden program-contract snapshots for the bench "
-                    "train steps")
+        description="Golden program-contract snapshots for the train "
+                    "step")
     ap.add_argument("--update", action="store_true",
                     help="regenerate tests/goldens/ from the current tree")
     ap.add_argument("--check", action="store_true",
                     help="diff current tree vs committed goldens; exit 1 "
                          "on drift")
-    ap.add_argument("--update-multihost", action="store_true",
-                    help="regenerate the multihost contract (captured on "
-                         "the simulated (dcn=2, ici=4) mesh; verified "
-                         "against the real 2-process topology by "
-                         "tests/test_multihost.py)")
     ap.add_argument("--path", default=None, help="golden file override")
     args = ap.parse_args(argv)
     _init_mesh()
-    if args.update or args.update_multihost:
-        if args.update:
-            contracts = capture_contracts()
-            path = save_goldens(contracts, args.path)
-            print(f"wrote {path}")
-            for name, _, _ in _LEGS:
-                entry = contracts[name]
-                print(f"  {name}: collectives={entry['collectives']} "
-                      f"rs_wire_bytes={entry['rs_wire_bytes']} "
-                      f"donation={entry['donation']}")
-        if args.update_multihost:
-            contract = capture_multihost_contract(dcn=2)
-            mh_path = multihost_golden_path()
-            with open(mh_path, "w", encoding="utf-8") as f:
-                json.dump(contract, f, indent=2, sort_keys=True)
-                f.write("\n")
-            print(f"wrote {mh_path}")
-            print(f"  multihost: by_axis={contract['by_axis']} "
-                  f"dcn_wire_bytes={contract['dcn_wire_bytes']}")
+    if args.update:
+        contracts = capture_contracts()
+        path = save_goldens(contracts, args.path)
+        print(f"wrote {path}")
+        for name in ["baseline"] + [n for n, _ in _SHARDING_LEGS]:
+            print(f"  {name}: collectives={contracts[name]['collectives']}")
         return 0
     ok, delta = check(args.path)
     if ok:

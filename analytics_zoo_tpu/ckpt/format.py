@@ -263,10 +263,12 @@ def read_manifest(ckpt_dir: str) -> Dict:
 def manifest_meta(ckpt_dir: str) -> Dict:
     """The caller-supplied ``meta`` dict a checkpoint's manifest carries —
     provenance readable WITHOUT loading any blob. The estimator records the
-    writing run's comms plane here (``meta["comms"]``: sharded_update,
-    wire_dtype, bucket layout signature), the training supervisor its epoch
-    boundary — a reader can tell how a checkpoint was produced before
-    deciding to adopt it."""
+    writing run's layout here (``meta["sharding"]``: the SpecLayout
+    fingerprint and the fsdp bucket layout's signature), the training
+    supervisor its epoch boundary — a reader can tell how a checkpoint was
+    produced before deciding to adopt it. Checkpoints written before the
+    explicit dp wire was retired may carry ``meta["comms"]``; nothing reads
+    it."""
     return read_manifest(ckpt_dir).get("meta", {}) or {}
 
 

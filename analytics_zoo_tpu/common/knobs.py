@@ -61,50 +61,8 @@ _KNOBS = [
     _k("ZOO_COMPILE_CACHE_DISABLE", "bool", False, "compile",
        "Disable the shared executable cache entirely (every consumer "
        "degrades to private jax.jit)."),
-    # --- comms plane --------------------------------------------------------
-    _k("ZOO_COMMS_PLANE", "bool", None, "comms",
-       "Enter the comms plane with the flat per-leaf-psum reference wire "
-       "(buckets/sharding off)."),
-    _k("ZOO_GRAD_BUCKET_MB", "float", 0.0, "comms",
-       "Target gradient bucket size for the reduce-scatter wire; 0 keeps "
-       "the flat per-leaf wire."),
-    _k("ZOO_SHARDED_UPDATE", "bool", False, "comms",
-       "ZeRO-1: shard the optimizer update over the dp axis (each replica "
-       "updates padded/N elements, then all-gathers params)."),
-    _k("ZOO_ALLREDUCE_DTYPE", "str", "f32", "comms",
-       "Gradient wire dtype: f32 | bf16 (real bf16 collective) | int8 "
-       "(block-scaled; simulated wire by default, a real ppermute ring "
-       "with ZOO_COMMS_NATIVE_INT8=1)."),
-    _k("ZOO_ALLREDUCE_BLOCK", "int", 256, "comms",
-       "Elements per int8 quantization scale block."),
-    _k("ZOO_COMMS_OVERLAP", "bool", False, "comms",
-       "Overlapped backward-comms pipeline: assemble each gradient bucket "
-       "from its own leaf slices so its reduce-scatter launches as soon "
-       "as those grads exist, hiding wire time behind backward compute."),
-    _k("ZOO_COMMS_SEGMENTS", "int", 0, "comms",
-       "Dependency-island override for the overlapped pipeline: 0 = one "
-       "segment per bucket (max overlap), 1 = classic post-backward wire, "
-       "N = buckets coalesced into N contiguous groups."),
-    _k("ZOO_COMMS_HIERARCHY", "bool", False, "comms",
-       "Two-level ICI x DCN gradient wire: reduce-scatter inside each "
-       "host group, exchange only the already-reduced 1/ici chunks "
-       "across hosts — DCN moves 1/ici of the flat wire's bytes."),
-    _k("ZOO_COMMS_DCN_AXIS", "int", 0, "comms",
-       "Host-group count for the hierarchical wire: 0 = probe process "
-       "locality (mesh.dp_topology), N = force an N-host factorization "
-       "of the dp axis (the simulated mesh's stand-in for a pod)."),
-    _k("ZOO_COMMS_QUANTIZE_DCN", "bool", True, "comms",
-       "With the hierarchical wire and a non-f32 allreduce dtype, "
-       "quantize only the cross-host (DCN) leg — the ICI leg reduces "
-       "exact f32. 0 = quantize the whole wire as the classic path does."),
-    _k("ZOO_COMMS_NATIVE_INT8", "bool", False, "comms",
-       "Native int8 collectives: replace the simulated int8 wire "
-       "(dequantize, then f32 reduce) with a shard_map ppermute ring "
-       "reduce-scatter whose hops really move int8 payloads + f32 block "
-       "scales — the full dp axis on the classic bucketed wire, each DCN "
-       "group on the hierarchical wire (ICI stays exact f32). Requires "
-       "ZOO_ALLREDUCE_DTYPE=int8."),
-    _k("ZOO_EMBED_GRAD_MODE", "str", "auto", "comms",
+    # --- embeddings ---------------------------------------------------------
+    _k("ZOO_EMBED_GRAD_MODE", "str", "auto", "embedding",
        "Embedding gradient exchange: auto | dense | sparse."),
     # --- sharding plane -----------------------------------------------------
     _k("ZOO_MESH_AXES", "str", None, "sharding",

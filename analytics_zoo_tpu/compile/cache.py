@@ -119,7 +119,7 @@ class CachedFunction:
         self.label = label
         self._uid = next(_uid_counter)
         self._donate = tuple(donate_argnums)
-        # caller-supplied structural salt (e.g. the engine's comms bucket
+        # caller-supplied structural salt (e.g. the engine's fsdp bucket
         # layout): identity the lowered text alone might not capture
         self._extra_key = extra_key
         self._local: Dict = {}       # sig -> executable (per-callsite fast path)

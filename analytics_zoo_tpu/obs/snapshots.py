@@ -3,13 +3,13 @@
 
 Each function runs a tiny CPU workload through the real production path of
 one plane and prints a single ``NAME=<json>`` line (``TRANSFER_PLANE=``,
-``CKPT_PLANE=``, ``COMMS_PLANE=``, ``SHARDING_PLANE=``, ``RESILIENCE=``,
+``CKPT_PLANE=``, ``SHARDING_PLANE=``, ``RESILIENCE=``,
 ``SHM=``, ``ANALYSIS=``, ``OBS=``). These used to live as five bespoke ``python - <<EOF`` heredocs
 inside run_tier1.sh; the script now loops over
 ``python -m analytics_zoo_tpu.obs snapshot <plane>`` so the
 snapshot logic is importable, testable and shared with the CLI.
 
-One process per plane (the comms/analysis snapshots need the 8-device
+One process per plane (the sharding/analysis snapshots need the 8-device
 simulated mesh, which must be configured before the JAX backend first
 initializes — :func:`_ensure_sim_devices` appends the XLA flag when the
 caller has not)."""
@@ -36,7 +36,7 @@ def _ensure_sim_devices(n: int = 8):
     not) — the CLI entry satisfies that."""
     # strip-then-append (same as bench.py's child env): an ambient
     # =2 left over from other tests must not shrink the documented
-    # 8-dev mesh the comms/analysis snapshots assume
+    # 8-dev mesh the sharding/analysis snapshots assume
     flags = [f for f in os.environ.get("XLA_FLAGS", "").split()
              if "xla_force_host_platform_device_count" not in f]
     flags.append(f"--xla_force_host_platform_device_count={n}")
@@ -103,113 +103,6 @@ def snapshot_ckpt() -> int:
     keys = ("saves", "stall_s", "hidden_s", "write_s", "stall_frac",
             "dedup_ratio", "bytes_written", "bytes_deduped")
     return _emit("CKPT_PLANE", {k: snap[k] for k in keys if k in snap})
-
-
-def snapshot_comms() -> int:
-    """Bucketed reduce-scatter + ZeRO-1 sharded update + the overlapped
-    backward–comms pipeline + the hierarchical two-level wire on the
-    8-device simulated mesh — buckets, wire bytes/step, collective
-    launches, bit-identity to flat psum, overlap stall attribution
-    (wall-time delta vs the post-backward wire, wire-byte parity), the
-    ICI×DCN split (dp factored as 2 simulated hosts × 4 chips; DCN
-    wire bytes are the hierarchy's point), and the native int8 ring's
-    hop count and packed DCN bytes (PR 16)."""
-    _ensure_sim_devices()
-    import time
-
-    import flax.linen as nn
-    import numpy as np
-
-    from .. import init_orca_context
-    from ..orca.learn.estimator import TPUEstimator
-
-    init_orca_context("cpu-sim", mesh_axes={"dp": -1})
-
-    class M(nn.Module):
-        @nn.compact
-        def __call__(self, x):
-            x = nn.relu(nn.Dense(32)(x))
-            x = nn.relu(nn.Dense(16)(x))
-            return nn.Dense(1)(x)[:, 0]
-
-    rng = np.random.RandomState(0)
-    data = {"x": rng.rand(256, 8).astype(np.float32),
-            "y": rng.rand(256).astype(np.float32)}
-
-    def run_cfg(cfg, timed=False, **kw):
-        est = TPUEstimator(M(), loss="mse", optimizer="adam", seed=0,
-                           config={"steps_per_dispatch": 1, **cfg}, **kw)
-        stats = est.fit(dict(data), epochs=1, batch_size=32, verbose=False)
-        dt = None
-        if timed:
-            # epoch 1 above paid the JIT compile; the timed window is a
-            # warm second epoch, so the stall attribution compares
-            # steady-state steps, not compile-time deltas
-            t0 = time.perf_counter()
-            est.fit(dict(data), epochs=1, batch_size=32, verbose=False,
-                    initial_epoch=1)
-            dt = time.perf_counter() - t0
-        return [s["train_loss"] for s in stats], est, dt
-
-    lf, _, _ = run_cfg({"comms_plane": True})
-    # stall-attribution pair: the SAME multi-bucket ZeRO-1 layout with
-    # the wire behind the whole-backward barrier vs fired per-bucket
-    # inside the backward's dependence graph — only the schedule differs
-    lb, est, dt_base = run_cfg({"grad_bucket_mb": 0.001}, timed=True,
-                               sharded_update=True)
-    lo, est_o, dt_overlap = run_cfg(
-        {"grad_bucket_mb": 0.001, "comms_overlap": True}, timed=True,
-        sharded_update=True)
-    # hierarchical pair: the same layout on the two-level wire (2
-    # simulated hosts x 4 chips); bit-identity holds WITHIN the
-    # two-level family (vs its overlapped variant) — vs the flat wire it
-    # differs at reduction-association level (parallel/comms.py)
-    lh, est_h, _ = run_cfg({"grad_bucket_mb": 0.001,
-                            "comms_hierarchy": True, "comms_dcn_axis": 2},
-                           sharded_update=True)
-    lho, _, _ = run_cfg({"grad_bucket_mb": 0.001, "comms_hierarchy": True,
-                         "comms_dcn_axis": 2, "comms_overlap": True},
-                        sharded_update=True)
-    # native int8 ring (PR 16): the DCN leg as a real collective-permute
-    # ring over block-scaled int8 payloads (quantize-where-expensive)
-    _, est_n, _ = run_cfg({"grad_bucket_mb": 0.001,
-                           "comms_hierarchy": True, "comms_dcn_axis": 2,
-                           "allreduce_dtype": "int8",
-                           "allreduce_block": 64,
-                           "comms_native_int8": True},
-                          sharded_update=True)
-    snap = est.data_pipeline_stats()["comms"]
-    osnap = est_o.data_pipeline_stats()["comms"]
-    hsnap = est_h.data_pipeline_stats()["comms"]
-    keys = ("buckets", "collectives_per_step", "wire_bytes_per_step",
-            "grad_leaves", "sharded_update", "wire_dtype",
-            "opt_shard_elems")
-    out = {k: snap[k] for k in keys if k in snap}
-    out["bit_identical_to_flat"] = lf == lb
-    out["overlap"] = {
-        "buckets": osnap.get("buckets"),
-        "segments": osnap.get("segments"),
-        "bit_identical": lo == lb,
-        "wire_bytes_unchanged": (osnap.get("wire_bytes_per_step")
-                                 == snap.get("wire_bytes_per_step")),
-        "stall_hidden_s": round(max(0.0, dt_base - dt_overlap), 3)}
-    hh = hsnap.get("hierarchy", {})
-    out["hierarchy"] = {
-        "ici_axis": hh.get("ici_axis"),
-        "dcn_axis": hh.get("dcn_axis"),
-        "dcn_wire_bytes": hh.get("dcn_wire_bytes_per_step"),
-        "ici_wire_bytes": hh.get("ici_wire_bytes_per_step"),
-        "bit_identical": lh == lho}
-    nsnap = est_n.data_pipeline_stats()["comms"]
-    nh = nsnap.get("hierarchy", {})
-    out["native_int8"] = {
-        "active": nsnap.get("native_int8"),
-        "hops": nsnap.get("native_hops"),
-        "dcn_wire_bytes": nh.get("dcn_wire_bytes_per_step"),
-        "dcn_vs_exact_shrink": round(
-            hh.get("dcn_wire_bytes_per_step", 0)
-            / max(nh.get("dcn_wire_bytes_per_step", 1), 1), 2)}
-    return _emit("COMMS_PLANE", out)
 
 
 def snapshot_sharding() -> int:
@@ -513,8 +406,7 @@ def snapshot_shm() -> int:
 
 def snapshot_analysis() -> int:
     """Repo lint findings, golden program-contract drift, and the HLO
-    linter's hook report from a bucketed comms fit on the simulated
-    mesh."""
+    linter's hook report from a fit on the simulated mesh."""
     _ensure_sim_devices()
     import flax.linen as nn
     import numpy as np
@@ -537,9 +429,7 @@ def snapshot_analysis() -> int:
 
     rng = np.random.RandomState(0)
     est = TPUEstimator(M(), loss="mse", optimizer="adam", seed=0,
-                       sharded_update=True,
-                       config={"steps_per_dispatch": 1,
-                               "grad_bucket_mb": 4.0})
+                       config={"steps_per_dispatch": 1})
     est.fit({"x": rng.rand(128, 8).astype(np.float32),
              "y": rng.rand(128).astype(np.float32)},
             epochs=1, batch_size=32, verbose=False)
@@ -549,8 +439,7 @@ def snapshot_analysis() -> int:
         "repolint_findings": len(repo_findings),
         "golden_drift": len(golden_delta),
         "hlo_programs_linted": hlo["programs_linted"],
-        "hlo_findings": hlo["by_rule"],
-        "comms_accounting_verified": hlo["comms_verified"]})
+        "hlo_findings": hlo["by_rule"]})
 
 
 def snapshot_obs() -> int:
@@ -800,7 +689,7 @@ def _snapshot_streaming_fleet() -> Dict:
 
 
 PLANES = {"transfer": snapshot_transfer, "ckpt": snapshot_ckpt,
-          "comms": snapshot_comms, "sharding": snapshot_sharding,
+          "sharding": snapshot_sharding,
           "resilience": snapshot_resilience,
           "serving": snapshot_serving, "fleet": snapshot_fleet,
           "streaming": snapshot_streaming, "shm": snapshot_shm,

@@ -27,11 +27,9 @@ import jax.numpy as jnp
 import numpy as np
 import optax
 from flax.core import FrozenDict
-from jax import lax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from ...obs import trace as _trace
-from ...parallel import comms as comms_lib
 from ...parallel.sharding import FsdpPlan, SpecLayout
 from ...resilience import faults as _faults
 from ...resilience import watchdog as _watchdog
@@ -71,7 +69,7 @@ class TrainEngine:
                  loss_fn: Optional[Callable], metrics: Dict[str, Metric],
                  mesh: Mesh, seed: int = 0,
                  fsdp_params: bool = False, compile_cache=None,
-                 prologue=None, comms=None,
+                 prologue=None,
                  sharding: Optional[SpecLayout] = None):
         from ...compile import resolve_cache
         # every jitted step goes through the process-wide compile plane
@@ -91,36 +89,12 @@ class TrainEngine:
         # into the first layer — see orca/learn/prologue.py
         self.prologue = prologue
         self.fsdp_params = fsdp_params and mesh.shape.get("fsdp", 1) > 1
-        # comms plane (parallel/comms.py): when active, the train step is
-        # rebuilt as an explicit shard_map over the dp axis — bucketed
-        # gradient reduce-scatter, optional ZeRO-1 sharded weight update,
-        # optional quantized wire. Inactive (the default) leaves the
-        # GSPMD step below byte-for-byte untouched.
-        self.comms_cfg = comms if (comms is not None
-                                   and getattr(comms, "active", False)) \
-            else None
-        self.comms: Optional[comms_lib.CommsPlan] = None
-        self.comms_resid = None          # EF residual, (dp, padded) sharded
-        self.comms_steps = 0
-        if self.comms_cfg is not None and self.fsdp_params:
-            raise ValueError(
-                "comms plane (sharded_update/grad buckets/quantized wire) "
-                "and fsdp_params are mutually exclusive — the plane owns "
-                "the gradient collectives, fsdp hands them to GSPMD")
         # sharding plane (parallel/sharding.py): SpecLayout-driven fsdp×tp
         # over the multi-axis mesh — params live as a bucketed flat vector
         # P("fsdp") plus tp-sharded held leaves, assembled (gathered) inside
         # every jitted step. GSPMD owns all its collectives.
-        self.sharding = sharding if (sharding is not None
-                                     and getattr(sharding, "active", True)) \
-            else None
+        self.sharding = sharding
         self.fsdp_plan: Optional[FsdpPlan] = None
-        if self.sharding is not None and self.comms_cfg is not None:
-            raise ValueError(
-                "sharding plane (SpecLayout fsdp×tp) and comms plane are "
-                "mutually exclusive — the comms plane's explicit shard_map "
-                "wire assumes replicated params on a pure-dp mesh; the "
-                "sharding plane hands every collective to GSPMD")
         if self.sharding is not None and self.fsdp_params:
             raise ValueError(
                 "sharding=SpecLayout supersedes fsdp_params (the legacy "
@@ -204,11 +178,7 @@ class TrainEngine:
         self.params = jax.device_put(params, self._param_sharding(params))
         self.extra_vars = jax.device_put(
             variables, jax.tree.map(lambda _: self._repl, variables))
-        if self.comms_cfg is not None:
-            self._build_comms(self.params)
-        if self.comms is not None and self.comms.cfg.sharded_update:
-            self.opt_state = self._init_sharded_opt(self.params)
-        elif self.fsdp_plan is not None:
+        if self.fsdp_plan is not None:
             self.opt_state = self._init_sharded_tree_opt()
         else:
             opt_state = self.tx.init(self.params)
@@ -237,87 +207,12 @@ class TrainEngine:
     def _init_sharded_tree_opt(self):
         """Optimizer state over the composite params, jitted with sharded
         out_shardings so no device ever materializes a full moment vector
-        (same rationale as :meth:`_init_sharded_opt` — the model may be
-        bigger than one chip)."""
+        (the model may be bigger than one chip: a plain ``tx.init`` would
+        build them whole on device 0 before any resharding ran)."""
         template = jax.eval_shape(self.tx.init, self.params)
         return jax.jit(self.tx.init,
                        out_shardings=self._opt_sharding(template))(
             self.params)
-
-    # --- comms plane (parallel/comms.py) ------------------------------------
-    def _build_comms(self, params):
-        """Bind the comms config to this param tree's bucket layout. The
-        plane owns the dp collectives, so the mesh must be pure-dp and the
-        params replicated (no TP specs)."""
-        from ...parallel.mesh import nontrivial_axes
-        offending = [a for a in nontrivial_axes(self.mesh)
-                     if a != self.comms_cfg.axis]
-        if offending:
-            raise ValueError(
-                "comms plane requires a pure data-parallel mesh; axes "
-                f"{offending} have size > 1 (mesh {dict(self.mesh.shape)}) "
-                "— multi-axis meshes belong to the sharding plane "
-                "(sharding=SpecLayout), not the explicit dp wire")
-        if self._tp_specs is not None:
-            raise ValueError("comms plane does not support tensor-parallel "
-                             "partitioned params")
-        n = self.mesh.shape.get(self.comms_cfg.axis, 1)
-        ici, dcn = n, 1
-        if self.comms_cfg.hierarchy:
-            # two-level wire: factor the dp axis into (dcn, ici) from
-            # process locality; ZOO_COMMS_DCN_AXIS imposes the simulated
-            # split on a single-process mesh. A (1, n) factorization
-            # collapses the plan onto the classic single-level wire.
-            from ...parallel.mesh import dp_topology
-            dcn, ici = dp_topology(
-                self.mesh, self.comms_cfg.axis,
-                dcn_override=self.comms_cfg.dcn_size or None)
-        layout = comms_lib.build_layout(params, n, self.comms_cfg,
-                                        ici=ici, dcn=dcn)
-        self.comms = comms_lib.CommsPlan(self.comms_cfg, layout)
-        if self.comms_cfg.quantized and self.comms_resid is None:
-            self.comms_resid = self._zero_resid()
-
-    def _zero_resid(self):
-        # created ON device, sharded — a host np.zeros would pay
-        # n_dev x param-size of pointless H2D at every build/restore.
-        # resid_elems: flat domain classically, the post-ICI chunk domain
-        # when only the DCN leg quantizes
-        lo = self.comms.layout
-        return jax.jit(
-            lambda: jnp.zeros((lo.n_dev, lo.resid_elems), jnp.float32),
-            out_shardings=NamedSharding(self.mesh, P(self.comms.axis)))()
-
-    def _init_sharded_opt(self, params):
-        """ZeRO-1 optimizer state: ``tx.init`` over the scattered-order
-        flat param vector, moment leaves laid out ``P(dp)`` so each
-        replica materializes exactly its 1/N shard.
-
-        The init runs jitted with sharded out_shardings over a sharded
-        input, so no device ever holds a FULL moment vector — the whole
-        point of ZeRO-1 is models whose unsharded Adam state does not
-        fit one chip, and a plain ``tx.init`` would OOM device 0 at
-        build before the resharding ``device_put`` ran."""
-        lo = self.comms.layout
-        host = jax.device_get(params)
-        # device-major scattered order: row k is the chunk device k OWNS
-        # after the (possibly two-level) reduce-scatter, so P(dp) places
-        # each replica's own moments (σ-permuted under hierarchy,
-        # identical to chunk-major on the flat wire)
-        flat = lo.to_device_scattered_np(lo.flatten_np(host))
-        flat_dev = jax.device_put(
-            flat, NamedSharding(self.mesh, P(self.comms.axis)))
-        state_shape = jax.eval_shape(
-            self.tx.init, jax.ShapeDtypeStruct(flat.shape, flat.dtype))
-        return jax.jit(
-            self.tx.init,
-            out_shardings=self._comms_opt_sharding(state_shape))(flat_dev)
-
-    def _comms_opt_sharding(self, opt_state):
-        moment = NamedSharding(self.mesh, P(self.comms.axis))
-        return jax.tree.map(
-            lambda l: moment if self.comms._is_moment(l) else self._repl,
-            opt_state)
 
     def _init_vars(self, rng, small_x):
         kwargs = {}
@@ -536,276 +431,6 @@ class TrainEngine:
             body, (params, extra, opt_state, step0), (xs, ys, ws))
         return params, extra, opt_state, losses
 
-    # --- comms-plane steps (explicit shard_map over dp) ---------------------
-    def _compute_loss_psum(self, y, preds, w, n_local: int):
-        """Per-replica view of :meth:`_compute_loss`: local partial sums,
-        combined with ``psum`` so every replica holds the global loss.
-
-        The downstream pmean / reduce-scatter-then-divide-by-N gradient
-        combine depends on the legacy ``check_vma=False`` AD rule where
-        **psum transposes to psum**: the ``1/n_global`` cotangent is
-        psummed back to every replica, so reverse-AD already returns each
-        replica's LOCAL-MEAN gradient and averaging over replicas yields
-        the exact global mean (verified bit-level in the tests). Under
-        vma-typed semantics (``check_vma=True``, psum transposing to
-        pbroadcast) grads would instead be ``1/n_global`` partials and
-        the same combine would under-scale gradients by the dp degree —
-        revisit this scaling before migrating."""
-        axis = self.comms.axis
-        if self.loss_fn is None:
-            per_ex = preds
-        else:
-            y0 = y[0] if (isinstance(y, tuple) and len(y) == 1) else y
-            per_ex = self.loss_fn(y0, preds)
-        per_ex = per_ex.reshape(per_ex.shape[0], -1).mean(-1)
-        if w is None:
-            n_global = n_local * self.comms.layout.n_dev
-            return lax.psum(jnp.sum(per_ex), axis) / n_global
-        num = lax.psum(jnp.sum(per_ex * w), axis)
-        den = lax.psum(jnp.sum(w), axis)
-        return num / jnp.maximum(den, 1e-8)
-
-    def _comms_clip_scale(self, shards):
-        """Norm-clip scale from the reduce-scattered gradient shards —
-        the SAME arithmetic for the sharded and unsharded update paths, so
-        turning ``sharded_update`` on cannot move the clip threshold by an
-        ulp. ``shards`` hold per-bucket SUMS; the mean-grad norm divides
-        by the axis size once at the end."""
-        if self._clip_norm is None:
-            return None
-        axis = self.comms.axis
-        part = sum(jnp.sum(s * s) for s in shards)
-        gnorm = jnp.sqrt(lax.psum(part, axis)) / self.comms.layout.n_dev
-        return jnp.minimum(1.0, self._clip_norm / jnp.maximum(gnorm, 1e-12))
-
-    def _comms_const_clip(self, g):
-        if self._clip_min is not None or self._clip_max is not None:
-            return jnp.clip(g, self._clip_min, self._clip_max)
-        return g
-
-    def _comms_body(self, params, extra, opt_state, resid, step, x, y, w):
-        """One replica's slice of the comms-plane train step. Runs inside
-        ``shard_map``: ``x``/``y``/``w`` are the local batch, ``opt_state``
-        moment leaves and ``resid`` are this replica's shard, everything
-        else is replicated."""
-        from ...parallel import collective as C
-        plan = self.comms
-        axis = plan.axis
-        x, y = self._pre(x, y)
-        # fold the replica index into the step rng so stochastic layers
-        # (dropout) draw independent local masks
-        rng = jax.random.fold_in(
-            jax.random.fold_in(jax.random.PRNGKey(self.seed), step),
-            C.axis_index(axis))
-        n_local = x[0].shape[0]
-
-        def loss_of(p):
-            preds, new_extra = self._apply(p, extra, x, True, rng)
-            loss = self._compute_loss_psum(y, preds, w, n_local)
-            return loss, (preds, new_extra)
-
-        (loss, (_, new_extra)), grads = jax.value_and_grad(
-            loss_of, has_aux=True)(params)
-
-        if plan.cfg.effective_bucket_mb > 0:
-            new_params, new_opt, new_resid = self._comms_bucketed_update(
-                plan, params, opt_state, resid, grads)
-        else:
-            # flat-psum reference wire: one pmean per leaf, classic update
-            mean_grads = plan.reduce_leafwise_mean(grads)
-            mean_grads = self._clip_grads(mean_grads)
-            updates, new_opt = self.tx.update(mean_grads, opt_state, params)
-            new_params = optax.apply_updates(params, updates)
-            new_resid = resid
-        if new_extra:
-            # batch stats are computed on the local batch — average them
-            # like the data they summarize
-            new_extra = jax.tree.map(lambda v: lax.pmean(v, axis), new_extra)
-        return new_params, new_extra, new_opt, new_resid, loss
-
-    def _comms_bucketed_update(self, plan, params, opt_state, resid, grads):
-        """Bucketed reduce-scatter (+ quantized wire + error feedback),
-        then either the ZeRO-1 sharded update + param all-gather, or the
-        classic replicated update off the all-gathered mean grads.
-
-        Overlapped mode (``plan.segplan``) assembles each bucket straight
-        from its own leaf slices instead of slicing one whole-tree flat
-        vector: same elements, same order, bit-identical — but bucket k's
-        reduce-scatter then depends only on the leaves composing it, so
-        the collective is schedulable as soon as reverse AD produced
-        those gradients, while later segments' backward keeps computing
-        (the Horovod tensor-fusion pipeline, in the XLA dependence
-        graph). The whole-tree ``flatten`` below is the barrier overlap
-        removes."""
-        from ...parallel import collective as C
-        lo = plan.layout
-        n = lo.n_dev
-        # the flat-domain EF residual is added at assembly (classic wire,
-        # and the hierarchical classic-quantize variant); the DCN-only
-        # variant's residual lives on the post-ICI chunk domain and is
-        # folded in inside plan.hier_reduce instead
-        # native classic wire: the ring folds the residual in per chunk
-        # slot itself, so the flat-domain pre-add below must not run
-        native_classic = plan.cfg.native_int8 and not plan.hierarchical
-        flat_resid = (resid is not None
-                      and lo.resid_elems == lo.padded_total
-                      and not native_classic)
-        if plan.segplan is not None:
-            bucket_vals = plan.segplan.bucket_values(grads)
-            if flat_resid:
-                # per-bucket residual add keeps each bucket's dependence
-                # cone its own (resid is a step input, not a barrier)
-                bucket_vals = [b + r for b, r in zip(
-                    bucket_vals, lo.buckets(resid[0]))]
-        else:
-            flat = lo.flatten(grads)
-            if flat_resid:
-                # error feedback: add back what last step's quantized wire
-                # dropped, and carry forward what this step's drops
-                flat = flat + resid[0]
-            bucket_vals = lo.buckets(flat)
-        if plan.hierarchical:
-            return self._comms_hier_exchange_update(
-                plan, params, opt_state, resid, bucket_vals)
-        if native_classic:
-            shards, new_resid_row = plan.native_reduce_scatter_bucket_list(
-                bucket_vals, resid[0] if resid is not None else None)
-            new_resid = (new_resid_row[None] if new_resid_row is not None
-                         else resid)
-        else:
-            shards, wires = plan.reduce_scatter_bucket_list(bucket_vals)
-            if resid is not None:
-                # elementwise subtract commutes with the bucket split, so
-                # the per-bucket form is bit-identical to
-                # (flat - concat(wires))
-                new_resid = jnp.concatenate(
-                    [b - w for b, w in zip(bucket_vals, wires)])[None]
-            else:
-                new_resid = resid
-        scale = self._comms_clip_scale(shards)
-        if plan.cfg.sharded_update:
-            gshard = jnp.concatenate(shards) / n
-            if scale is not None:
-                gshard = gshard * scale
-            gshard = self._comms_const_clip(gshard)
-            i = C.axis_index(plan.axis)
-            pshard = plan.shard_of(plan.layout.flatten(params), i)
-            updates, new_opt = self.tx.update(gshard, opt_state, pshard)
-            new_pshard = optax.apply_updates(pshard, updates)
-            new_flat = plan.unscatter(C.all_gather(new_pshard, plan.axis))
-            new_params = plan.layout.unflatten(new_flat)
-        else:
-            mean_flat = plan.gather_buckets(shards) / n
-            if scale is not None:
-                mean_flat = mean_flat * scale
-            mean_flat = self._comms_const_clip(mean_flat)
-            mean_grads = plan.layout.unflatten(mean_flat)
-            updates, new_opt = self.tx.update(mean_grads, opt_state, params)
-            new_params = optax.apply_updates(params, updates)
-        return new_params, new_opt, new_resid
-
-    def _comms_hier_exchange_update(self, plan, params, opt_state, resid,
-                                    bucket_vals):
-        """Two-level ICI×DCN exchange + update (the pod-scale wire,
-        parallel/comms.py): reduce-scatter each assembled bucket inside
-        the host group over ICI, exchange only the already-reduced
-        ``1/ici`` chunks across hosts over DCN (reduce-scatter under
-        ZeRO-1, allreduce otherwise), then gather back over the cheap
-        links. Composes with the overlapped assembly (``bucket_vals``
-        may come from the segment plan — each bucket's ICI launch keeps
-        its own dependence cone) and the quantized wire (DCN leg only by
-        default). Bit-identical to the classic wire legs *within* the
-        two-level family; differs from the flat wire at reduction-
-        association level (documented in parallel/comms.py)."""
-        from ...parallel import collective as C
-        lo = plan.layout
-        n = lo.n_dev
-        chunk_resid = resid is not None and lo.resid_elems != lo.padded_total
-        out, new_chunk_resid, flat_wires = plan.hier_reduce(
-            bucket_vals, resid[0] if chunk_resid else None)
-        if resid is None:
-            new_resid = resid
-        elif chunk_resid:
-            new_resid = new_chunk_resid[None]
-        else:
-            # classic-wire variant (quantize_dcn off): flat-domain EF,
-            # exactly the classic path's bookkeeping
-            new_resid = jnp.concatenate(
-                [b - w for b, w in zip(bucket_vals, flat_wires)])[None]
-        i = C.axis_index(plan.axis)
-        if plan.cfg.sharded_update:
-            # `out` holds this replica's unique (bucket/n) global shards
-            # — chunk σ(i) of each bucket, which is exactly what
-            # plan.shard_of slices for the params
-            scale = self._comms_clip_scale(out)
-            gshard = jnp.concatenate(out) / n
-            if scale is not None:
-                gshard = gshard * scale
-            gshard = self._comms_const_clip(gshard)
-            pshard = plan.shard_of(lo.flatten(params), i)
-            updates, new_opt = self.tx.update(gshard, opt_state, pshard)
-            new_pshard = optax.apply_updates(pshard, updates)
-            new_flat = plan.unscatter(plan.hier_gather_params(new_pshard))
-            new_params = lo.unflatten(new_flat)
-        else:
-            # `out` holds full global chunks (replicated across the host
-            # group); the clip norm reduces over each replica's UNIQUE
-            # sub-chunk so both update modes compute the identical scale
-            uniq = plan.hier_unique_shards(out, i)
-            scale = self._comms_clip_scale(uniq)
-            mean_flat = plan.hier_gather_buckets(out) / n
-            if scale is not None:
-                mean_flat = mean_flat * scale
-            mean_flat = self._comms_const_clip(mean_flat)
-            mean_grads = lo.unflatten(mean_flat)
-            updates, new_opt = self.tx.update(mean_grads, opt_state, params)
-            new_params = optax.apply_updates(params, updates)
-        return new_params, new_opt, new_resid
-
-    def _comms_specs(self, opt_state, resid, x, y, w):
-        """(in_specs, out_specs) pytrees for the shard_map'd comms step."""
-        axis = self.comms.axis
-        rep = lambda tree: jax.tree.map(lambda _: P(), tree)  # noqa: E731
-        dat = lambda tree: jax.tree.map(lambda _: P(axis), tree)  # noqa: E731
-        if self.comms.cfg.sharded_update:
-            opt_specs = jax.tree.map(
-                lambda l: P(axis) if self.comms._is_moment(l) else P(),
-                opt_state)
-        else:
-            # tree-form state is replicated — never shape-sniff it (a
-            # single 1-D param of exactly padded_total elements would
-            # make its tree-form moments look like flat moment vectors)
-            opt_specs = rep(opt_state)
-        resid_specs = jax.tree.map(lambda _: P(axis), resid)
-        in_specs = (rep(self.params), rep(self.extra_vars), opt_specs,
-                    resid_specs, P(), dat(x), dat(y), dat(w))
-        out_specs = (rep(self.params), rep(self.extra_vars), opt_specs,
-                     resid_specs, P())
-        return in_specs, out_specs
-
-    def _comms_train_step(self, params, extra, opt_state, resid, step,
-                          x, y, w):
-        in_specs, out_specs = self._comms_specs(opt_state, resid, x, y, w)
-        return jax.shard_map(self._comms_body, mesh=self.mesh,
-                         in_specs=in_specs, out_specs=out_specs,
-                         check_vma=False)(params, extra, opt_state, resid,
-                                          step, x, y, w)
-
-    def _comms_train_multi_step(self, params, extra, opt_state, resid,
-                                step0, xs, ys, ws):
-        """k fused comms-plane steps in one dispatch (scan over the
-        shard_map'd step) — same contract as :meth:`_train_multi_step`."""
-        def body(carry, inp):
-            params, extra, opt_state, resid, step = carry
-            x, y, w = inp
-            new_p, new_e, new_o, new_r, loss = self._comms_train_step(
-                params, extra, opt_state, resid, step, x, y, w)
-            return (new_p, new_e, new_o, new_r, step + 1), loss
-
-        (params, extra, opt_state, resid, _), losses = jax.lax.scan(
-            body, (params, extra, opt_state, resid, step0), (xs, ys, ws))
-        return params, extra, opt_state, resid, losses
-
     def _eval_step(self, params, extra, metric_states, x, y, w):
         x, y = self._pre(x, y)
         preds, _ = self._apply(params, extra, x, False)
@@ -870,18 +495,6 @@ class TrainEngine:
                                        donate_argnums=donate_argnums,
                                        extra_key=extra_key)
 
-    def _comms_key(self) -> Optional[str]:
-        """Comms fingerprint for the compile plane's structural key: the
-        bucket layout (boundaries, wire dtype, shard mapping) is part of
-        the train step's identity, so two engines whose layouts differ
-        must never share an executable."""
-        if self.comms_cfg is None:
-            return None
-        key = self.comms_cfg.fingerprint()
-        if self.comms is not None:
-            key += ":" + self.comms.layout.signature()
-        return key
-
     def _sharding_key(self) -> Optional[str]:
         """Sharding-plane fingerprint for the compile plane's structural
         key: the SpecLayout rules + the fsdp bucket layout are part of
@@ -903,7 +516,7 @@ class TrainEngine:
         if self.fsdp_plan is None:
             return
         try:
-            from ...analysis.hlo_lint import declare_comms
+            from ...analysis.hlo_lint import declare_accounting
         except ImportError:
             return
         summary = self.fsdp_plan.summary()
@@ -927,50 +540,21 @@ class TrainEngine:
                     self._tp_specs, is_leaf=_is_spec_leaf))
         summary["tp"] = {"axis": tp_axis, "axis_size": int(tp_size),
                          "sharded_leaves": int(tp_leaves)}
-        declare_comms(self._sharding_key(), summary)
-
-    def _comms_donate(self):
-        # params + opt state always; the EF residual only when it exists
-        # (donating an empty pytree arg is pointless noise)
-        return (0, 2, 3) if self.comms_resid is not None else (0, 2)
-
-    def _declare_comms_accounting(self):
-        """Hand the comms plane's declared per-step accounting to the
-        analysis plane under the same fingerprint the train executables
-        are salted with — the HLO linter then cross-checks every lowered
-        train program against it (measured launches/bytes == declared, or
-        a ``comms-accounting`` lint finding)."""
-        try:
-            from ...analysis.hlo_lint import declare_comms
-        except ImportError:
-            return
-        declare_comms(self._comms_key(), self.comms.summary())
+        declare_accounting(self._sharding_key(), summary)
 
     def ensure_jit_train(self):
         """Build (or return) the jitted single-step executable — the one
         place its jit options live, shared by train_batch and the
         estimator's fuse probe."""
         if self._jit_train is None:
-            if self.comms is not None:
-                self._declare_comms_accounting()
-                self._jit_train = self._wrap(
-                    "train", self._comms_train_step,
-                    donate_argnums=self._comms_donate(),
-                    extra_key=self._comms_key())
-            else:
-                self._declare_sharding_accounting()
-                self._jit_train = self._wrap("train", self._train_step,
-                                             donate_argnums=(0, 2),
-                                             extra_key=self._sharding_key())
+            self._declare_sharding_accounting()
+            self._jit_train = self._wrap("train", self._train_step,
+                                         donate_argnums=(0, 2),
+                                         extra_key=self._sharding_key())
         return self._jit_train
 
     def train_step_args(self, batch: Batch) -> Tuple:
-        """The positional args the jitted train step takes for ``batch`` —
-        comms engines carry the EF residual between opt state and step."""
-        if self.comms is not None:
-            return (self.params, self.extra_vars, self.opt_state,
-                    self.comms_resid, jnp.asarray(self.step),
-                    batch.x, batch.y, batch.w)
+        """The positional args the jitted train step takes for ``batch``."""
         return (self.params, self.extra_vars, self.opt_state,
                 jnp.asarray(self.step), batch.x, batch.y, batch.w)
 
@@ -1008,14 +592,8 @@ class TrainEngine:
             # segment the Perfetto timeline renders, step-indexed
             with _trace.span("engine.dispatch", step=self.step):
                 _faults.fire("engine.dispatch")
-                if self.comms is not None:
-                    (self.params, self.extra_vars, self.opt_state,
-                     self.comms_resid, loss) = self._jit_train(
-                        *self.train_step_args(batch))
-                    self.comms_steps += 1
-                else:
-                    self.params, self.extra_vars, self.opt_state, loss = \
-                        self._jit_train(*self.train_step_args(batch))
+                self.params, self.extra_vars, self.opt_state, loss = \
+                    self._jit_train(*self.train_step_args(batch))
         finally:
             if token is not None:
                 wd.exit(token)
@@ -1030,18 +608,11 @@ class TrainEngine:
         arrays — every x/y leaf is ``(k, local_batch, ...)`` and w (if any) is
         ``(k, local_batch)``. Returns the per-step losses ``(k,)``."""
         if self._jit_train_multi is None:
-            if self.comms is not None:
-                self._declare_comms_accounting()
-                self._jit_train_multi = self._wrap(
-                    "train_multi", self._comms_train_multi_step,
-                    donate_argnums=self._comms_donate(),
-                    extra_key=self._comms_key())
-            else:
-                self._declare_sharding_accounting()
-                self._jit_train_multi = self._wrap(
-                    "train_multi", self._train_multi_step,
-                    donate_argnums=(0, 2),
-                    extra_key=self._sharding_key())
+            self._declare_sharding_accounting()
+            self._jit_train_multi = self._wrap(
+                "train_multi", self._train_multi_step,
+                donate_argnums=(0, 2),
+                extra_key=self._sharding_key())
         wd = _watchdog.active()
         token = wd.enter("engine.dispatch") if wd is not None else None
         t0 = time.perf_counter()
@@ -1049,20 +620,13 @@ class TrainEngine:
             with _trace.span("engine.dispatch", step=self.step,
                              fused=int(batch.fused)):
                 _faults.fire("engine.dispatch")
-                if self.comms is not None:
-                    (self.params, self.extra_vars, self.opt_state,
-                     self.comms_resid, losses) = self._jit_train_multi(
-                        *self.train_step_args(batch))
-                else:
-                    self.params, self.extra_vars, self.opt_state, losses = \
-                        self._jit_train_multi(*self.train_step_args(batch))
+                self.params, self.extra_vars, self.opt_state, losses = \
+                    self._jit_train_multi(*self.train_step_args(batch))
         finally:
             if token is not None:
                 wd.exit(token)
         t1 = time.perf_counter()
         k = int(losses.shape[0])
-        if self.comms is not None:
-            self.comms_steps += k
         if self.pipeline_stats is not None:
             self.pipeline_stats.add("step", t1 - t0,
                                     count=k)
@@ -1116,38 +680,10 @@ class TrainEngine:
         should gate on model size where that matters."""
         cp = lambda t: jax.tree.map(jnp.copy, t)  # noqa: E731
         return (cp(self.params), cp(self.extra_vars), cp(self.opt_state),
-                self.step, cp(self.comms_resid), self.comms_steps)
+                self.step)
 
     def restore_snapshot(self, snap):
-        (self.params, self.extra_vars, self.opt_state, self.step,
-         self.comms_resid, self.comms_steps) = snap
-
-    # --- comms telemetry ----------------------------------------------------
-    def comms_snapshot(self) -> Optional[Dict[str, Any]]:
-        """Static per-step comms accounting (buckets, collective launches,
-        wire bytes) plus cumulative step/byte counters; None when the
-        plane is off."""
-        if self.comms is None:
-            return None
-        snap = self.comms.summary()
-        snap["steps"] = self.comms_steps
-        snap["wire_bytes_total"] = (snap["wire_bytes_per_step"]
-                                    * self.comms_steps)
-        return snap
-
-    def comms_manifest_meta(self) -> Optional[Dict[str, Any]]:
-        """What a checkpoint manifest records about the comms plane that
-        wrote it — enough for a reader to know the opt state was produced
-        by a sharded run (it is stored in canonical tree form regardless)
-        and which layout the EF residual belongs to."""
-        if self.comms is None:
-            return None
-        cfg, lo = self.comms.cfg, self.comms.layout
-        return {"sharded_update": cfg.sharded_update,
-                "wire_dtype": cfg.wire_dtype,
-                "bucket_mb": cfg.effective_bucket_mb,
-                "buckets": len(lo.bucket_sizes),
-                "layout_sig": lo.signature()}
+        self.params, self.extra_vars, self.opt_state, self.step = snap
 
     # --- sharding telemetry -------------------------------------------------
     def per_device_state_bytes(self) -> int:
@@ -1204,24 +740,16 @@ class TrainEngine:
                  # this checkpoint re-shards TP params instead of
                  # replicating them
                  "tp_specs": self._tp_specs}
-        if self.comms is not None and self.comms.cfg.sharded_update:
-            # checkpoints always carry the CANONICAL tree-form optimizer
-            # state: a sharded checkpoint restores into an unsharded run
-            # and vice versa without either knowing about the other.
-            # Padding slots hold zeros, so the conversion is lossless.
-            state["opt_state"] = self.comms.opt_flat_to_tree(
-                state["opt_state"])
         if self.fsdp_plan is not None:
-            # same contract for the sharding plane: params and moments go
-            # out in canonical tree form, so fsdp-sharded ↔ replicated
-            # restores are bit-exact in both directions
+            # checkpoints always carry the CANONICAL tree form of params
+            # and moments: an fsdp-sharded checkpoint restores into a
+            # replicated run and vice versa without either knowing about
+            # the other. Padding slots hold zeros, so the conversion is
+            # lossless.
             state["params"] = self.fsdp_plan.composite_to_tree(
                 state["params"])
             state["opt_state"] = self.fsdp_plan.state_to_tree(
                 state["opt_state"])
-        if self.comms_resid is not None:
-            state["comms_resid"] = jax.device_get(self.comms_resid)
-            state["comms_layout_sig"] = self.comms.layout.signature()
         return state
 
     def set_state(self, state: Dict[str, Any]):
@@ -1246,56 +774,12 @@ class TrainEngine:
         self.extra_vars = jax.device_put(
             state["extra_vars"], jax.tree.map(lambda _: self._repl,
                                               state["extra_vars"]))
-        if self.comms_cfg is not None and self.comms is None:
-            # restoring into a never-built engine (load before fit)
-            self._build_comms(self.params)
         opt_state = state["opt_state"]
-        if self.comms is not None and self.comms.cfg.sharded_update:
-            # State dicts carry CANONICAL tree-form optimizer state (see
-            # get_state); only an explicit marker says otherwise. Never
-            # shape-sniff: a single 1-D param of exactly padded_total
-            # elements makes tree-form moments indistinguishable from
-            # scattered-order flat vectors.
-            if state.get("opt_state_form") != "flat":
-                # structure/shape template only — eval_shape allocates
-                # nothing (an eager tx.init here would materialize full
-                # unsharded moments on one device, the OOM _init_sharded_opt
-                # exists to avoid)
-                template = jax.eval_shape(
-                    self.tx.init,
-                    jax.ShapeDtypeStruct(
-                        (self.comms.layout.padded_total,), jnp.float32))
-                opt_state = self.comms.opt_tree_to_flat(opt_state, template)
-            self.opt_state = jax.device_put(
-                opt_state, self._comms_opt_sharding(opt_state))
-        elif self.fsdp_plan is not None:
+        if self.fsdp_plan is not None:
             # canonical tree-form moments -> composite. eval_shape only
             # (structure template); nothing full-size materializes.
             template = jax.eval_shape(self.tx.init, self.params)
             opt_state = self.fsdp_plan.tree_to_state(opt_state, template)
-            self.opt_state = jax.device_put(
-                opt_state, self._opt_sharding(opt_state))
-        else:
-            self.opt_state = jax.device_put(
-                opt_state, self._opt_sharding(opt_state))
-        self._restore_resid(state)
+        self.opt_state = jax.device_put(opt_state,
+                                        self._opt_sharding(opt_state))
         self.step = int(state["step"])
-
-    def _restore_resid(self, state: Dict[str, Any]):
-        """The EF residual only transfers between runs with the same
-        bucket layout; otherwise it restarts at zero (safe — the residual
-        is an accumulated correction, not model state)."""
-        if self.comms is None or not self.comms.cfg.quantized:
-            self.comms_resid = None
-            return
-        saved = state.get("comms_resid")
-        lo = self.comms.layout
-        if (saved is not None
-                and state.get("comms_layout_sig") == lo.signature()
-                and tuple(np.asarray(saved).shape) == (lo.n_dev,
-                                                       lo.resid_elems)):
-            self.comms_resid = jax.device_put(
-                np.asarray(saved),
-                NamedSharding(self.mesh, P(self.comms.axis)))
-        else:
-            self.comms_resid = self._zero_resid()

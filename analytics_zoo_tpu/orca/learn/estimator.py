@@ -85,7 +85,7 @@ class TPUEstimator:
                  model_dir: Optional[str] = None,
                  config: Optional[dict] = None, seed: int = 0, mesh=None,
                  fsdp: bool = False, compile_cache=None, prologue=None,
-                 sharded_update: Optional[bool] = None, sharding=None):
+                 sharding=None):
         self.ctx = get_context()
         self.mesh = mesh if mesh is not None else self.ctx.mesh
         self.module = module
@@ -103,15 +103,6 @@ class TPUEstimator:
         # step so the wire carries narrow source dtypes (uint8/int32)
         if prologue is None:
             prologue = self.config.get("prologue", None)
-        # comms plane (parallel/comms.py): bucketed gradient reduce-scatter
-        # + ZeRO-1 sharded weight update + quantized wire. Knobs:
-        # ``sharded_update`` arg / config key / ZOO_SHARDED_UPDATE,
-        # config ``grad_bucket_mb`` / ZOO_GRAD_BUCKET_MB,
-        # config ``allreduce_dtype`` / ZOO_ALLREDUCE_DTYPE (f32|bf16|int8).
-        # All-default means OFF: the engine's step stays the pre-plane
-        # GSPMD program, bit for bit.
-        from ...parallel.comms import CommsConfig
-        comms = CommsConfig.resolve(self.config, sharded_update)
         # sharding plane (parallel/sharding.py): SpecLayout-driven fsdp×tp
         # param sharding over the multi-axis mesh — models bigger than one
         # chip. Knobs: ``sharding`` arg (SpecLayout | True | False) /
@@ -122,8 +113,7 @@ class TPUEstimator:
         self.engine = TrainEngine(module, tx, self.loss_fn, self.metrics,
                                   self.mesh, seed=seed, fsdp_params=fsdp,
                                   compile_cache=compile_cache,
-                                  prologue=prologue, comms=comms,
-                                  sharding=spec_layout)
+                                  prologue=prologue, sharding=spec_layout)
         # one stats object spans iterator assembly, the pump's H2D stage and
         # the engine's dispatches — the estimator is where they all meet
         from ...native.infeed import PipelineStats
@@ -199,12 +189,6 @@ class TPUEstimator:
             # (estimated) compile seconds saved, cumulative for the cache
             # this engine compiles through (shared process-wide by default)
             snap["compile"] = self.engine.compile_cache.stats.snapshot()
-        comms = self.engine.comms_snapshot()
-        if comms is not None:
-            # comms-plane accounting (static per-step wire bytes/collective
-            # counts + cumulative steps) — absent when the plane is off so
-            # existing consumers see no new key
-            snap["comms"] = comms
         shard = self.engine.sharding_snapshot()
         if shard is not None:
             # sharding-plane accounting (mesh axes, fsdp buckets/gather
@@ -1011,16 +995,11 @@ class TPUEstimator:
         rides the manifest (the training supervisor records its epoch
         boundary there)."""
         plane = self._ckpt(model_dir)
-        comms_meta = self.engine.comms_manifest_meta()
-        if comms_meta is not None:
-            # record the writing run's comms plane in the manifest (the
-            # opt state itself is stored in canonical tree form, so the
-            # meta is provenance, not a format switch)
-            meta = {**(meta or {}), "comms": comms_meta}
         shard_meta = self.engine.sharding_manifest_meta()
         if shard_meta is not None:
-            # same provenance record for the sharding plane — params and
-            # moments are stored in canonical tree form regardless
+            # record the writing run's layout in the manifest: provenance,
+            # not a format switch — params and moments are stored in
+            # canonical tree form regardless
             meta = {**(meta or {}), "sharding": shard_meta}
         path = plane.save(self.engine.get_state(), self.engine.step,
                           score=self._trainer_state.score,

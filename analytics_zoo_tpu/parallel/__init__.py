@@ -1,8 +1,7 @@
-from .comms import BucketLayout, CommsConfig, CommsPlan
 from .mesh import (batch_divisor, create_mesh, data_sharding,
-                   mesh_axis_size, mesh_topology, nontrivial_axes,
-                   parse_mesh_axes, pure_dp, replicated, resolve_axis_sizes)
-from .sharding import FsdpPlan, SpecLayout
+                   mesh_axis_size, parse_mesh_axes, replicated,
+                   resolve_axis_sizes)
+from .sharding import BucketLayout, FsdpPlan, SpecLayout
 from .expert_parallel import (expert_sharding, moe_apply,
                               stack_expert_params)
 from .pipeline_parallel import (pipeline_apply, stack_stage_params,
@@ -11,9 +10,9 @@ from .tensor_parallel import (TPDense, TPMLP, TPSelfAttention,
                               TPTransformerBlock)
 
 __all__ = ["create_mesh", "data_sharding", "replicated", "resolve_axis_sizes",
-           "mesh_axis_size", "batch_divisor", "pure_dp", "nontrivial_axes",
-           "parse_mesh_axes", "mesh_topology", "BucketLayout",
-           "CommsConfig", "CommsPlan", "SpecLayout", "FsdpPlan",
+           "mesh_axis_size", "batch_divisor", "parse_mesh_axes",
+           "BucketLayout",
+           "SpecLayout", "FsdpPlan",
            "TPDense", "TPMLP", "TPSelfAttention", "TPTransformerBlock",
            "pipeline_apply", "stack_stage_params", "stage_sharding",
            "moe_apply", "stack_expert_params", "expert_sharding"]

@@ -18,10 +18,9 @@ jitted over the full mesh so tp/sp/fsdp can be enabled by config alone.
 from __future__ import annotations
 
 import math
-from typing import Any, Dict, List, Optional, Sequence, Tuple
+from typing import Dict, List, Optional, Sequence, Tuple
 
 import jax
-import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 AXIS_ORDER = ("dp", "fsdp", "tp", "sp")
@@ -102,23 +101,6 @@ def mesh_axis_size(mesh: Mesh, name: str) -> int:
     return mesh.shape.get(name, 1)
 
 
-def nontrivial_axes(mesh: Mesh, exclude: Tuple[str, ...] = ()
-                    ) -> Tuple[str, ...]:
-    """Mesh axes with size > 1, in mesh order — the axis-aware form the
-    pure-dp guards check against (an error can then NAME the offending
-    axes instead of just failing a boolean)."""
-    return tuple(name for name, size in mesh.shape.items()
-                 if size > 1 and name not in exclude)
-
-
-def pure_dp(mesh: Mesh, axis: str = "dp") -> bool:
-    """True when ``axis`` is the only non-trivial mesh axis — the regime
-    the comms plane (parallel/comms.py) owns: params replicated, batch
-    split over ``axis``, every collective explicit. Multi-axis (fsdp/tp)
-    meshes belong to the sharding plane (parallel/sharding.py)."""
-    return not nontrivial_axes(mesh, exclude=(axis,))
-
-
 def parse_mesh_axes(spec: str) -> Dict[str, int]:
     """Parse a ``ZOO_MESH_AXES`` string — ``"dp=2,fsdp=2,tp=2"`` (one axis
     may be ``-1`` to absorb the remaining devices) — into the axes dict
@@ -146,71 +128,7 @@ def parse_mesh_axes(spec: str) -> Dict[str, int]:
     return axes
 
 
-def mesh_topology(mesh: Mesh) -> Dict[str, Any]:
-    """Factor the mesh into its named axes plus the two-level (dcn, ici)
-    split of the data axis — the one dict snapshots/benches record about
-    device topology (extends ``dp_topology``, which factors only the dp
-    axis, to the multi-axis meshes the sharding plane runs on)."""
-    dcn, ici = dp_topology(mesh)
-    return {"axes": {name: int(size) for name, size in mesh.shape.items()},
-            "nontrivial": list(nontrivial_axes(mesh)),
-            "n_devices": int(np.prod(list(mesh.shape.values()))),
-            "dp_dcn": dcn, "dp_ici": ici}
-
-
 def batch_divisor(mesh: Mesh) -> int:
     """Global batch must be a multiple of this (the TPU analogue of the
     reference's node_num*core_num rule, pyzoo/zoo/tfpark/tf_dataset.py:135-149)."""
     return mesh_axis_size(mesh, "dp") * mesh_axis_size(mesh, "fsdp")
-
-
-def dp_topology(mesh: Mesh, axis: str = "dp",
-                dcn_override: Optional[int] = None) -> Tuple[int, int]:
-    """Factor the data-parallel axis into ``(dcn, ici)`` sub-axes — the
-    two-level wire the hierarchical comms plane reduces over
-    (parallel/comms.py): fast intra-host links (ICI) inside each group of
-    ``ici`` consecutive devices, slow cross-host links (DCN) between the
-    ``dcn`` groups.
-
-    The factorization comes from device process locality: when the
-    devices along ``axis`` are *process-contiguous* (every process
-    contributes one equal-sized consecutive block — what
-    ``mesh_utils``/multihost init produce for a pure-dp mesh), ``dcn`` is
-    the process count and ``ici`` the per-process device count. A
-    single-process mesh (the 8-device simulated CPU slice) has no real
-    host boundary, so it factors ``(1, n)`` unless ``dcn_override``
-    (``ZOO_COMMS_DCN_AXIS`` / config ``comms_dcn_axis``) imposes a
-    simulated split — the knob the tier-1 mesh uses to stand in for a
-    2-host pod.
-
-    An interleaved device order (process boundaries not contiguous along
-    ``axis``) cannot host the two-level wire — a "host group" would span
-    DCN — so it deliberately degrades to ``(1, n)`` rather than build
-    groups that are hierarchical in name only.
-    """
-    n = mesh_axis_size(mesh, axis)
-    if dcn_override is not None and int(dcn_override) > 0:
-        dcn = int(dcn_override)
-        if n % dcn != 0:
-            raise ValueError(
-                f"comms_dcn_axis={dcn} does not divide the {axis} axis "
-                f"size {n}")
-        return dcn, n // dcn
-    if axis not in mesh.shape:
-        return 1, n
-    # devices laid out along `axis`, everything else collapsed: for the
-    # pure-dp meshes the comms plane owns, this is just the flat order
-    axes = list(mesh.axis_names)
-    dev = np.moveaxis(mesh.devices, axes.index(axis), 0)
-    dev = dev.reshape(n, -1)
-    procs = [getattr(d, "process_index", 0) for d in dev[:, 0]]
-    nproc = len(set(procs))
-    if nproc <= 1 or n % nproc != 0:
-        return 1, n
-    ici = n // nproc
-    blocks = [procs[h * ici:(h + 1) * ici] for h in range(nproc)]
-    contiguous = (all(len(set(b)) == 1 for b in blocks)
-                  and len({b[0] for b in blocks}) == nproc)
-    if not contiguous:
-        return 1, n
-    return nproc, ici

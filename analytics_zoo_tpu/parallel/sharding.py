@@ -7,30 +7,27 @@ Two pieces:
   PartitionSpecs over the named mesh axes (Megatron-style tp columns/rows,
   fsdp×tp embedding tables), plus the batch-axis convention. It is the ONE
   object the estimator, engine, serving (``InferenceModel``) and tests agree
-  on, the way ``CommsConfig`` is for the dp wire. Modules that declare their
-  own specs via ``nn.with_partitioning`` (parallel/tensor_parallel.py) win;
-  SpecLayout rules fill the rest.
+  on. Modules that declare their own specs via ``nn.with_partitioning``
+  (parallel/tensor_parallel.py) win; SpecLayout rules fill the rest.
 
-* :class:`FsdpPlan` — parameter sharding over the ``fsdp`` axis riding the
-  comms plane's :class:`~analytics_zoo_tpu.parallel.comms.BucketLayout`
-  machinery: params whose spec is trivial live as a padded flat f32 vector
-  split into buckets, each bucket stored ``P("fsdp")`` (1/N per device).
+* :class:`FsdpPlan` — parameter sharding over the ``fsdp`` axis through a
+  :class:`BucketLayout`: params whose spec is trivial live as a padded flat
+  f32 vector split into buckets, each bucket stored ``P("fsdp")`` (1/N per
+  device).
   Inside the jitted step every bucket passes through
   ``with_sharding_constraint(bucket, P())`` — GSPMD emits exactly ONE
   all-gather per bucket (operand = the 1/N shard), the forward consumes the
   gathered params and drops them, and the gradient constraint back to
   ``P("fsdp")`` makes XLA combine grads over the fsdp groups (grouped
-  all-reduce / reduce-scatter + slice, backend's choice). This is the param
-  extension of ZeRO-1 weight-update sharding (arXiv:2004.13336): PR 8
-  sharded the *optimizer moments* over the flat vector; the same flat-vector
-  layout now holds the *parameters* too, so per-device param+moment bytes
-  scale as 1/fsdp and the largest trainable model is the mesh's HBM, not one
-  chip's.
+  all-reduce / reduce-scatter + slice, backend's choice). This is
+  weight-update sharding (arXiv:2004.13336) with the parameters sharded
+  too: the optimizer moments inherit the flat vector's structure, so
+  per-device param+moment bytes scale as 1/fsdp and the largest trainable
+  model is the mesh's HBM, not one chip's.
 
 Why buckets and not per-leaf sharding: one all-gather per parameter leaf is
 a launch-bound wire (hundreds of small collectives); per-bucket gathers are
-few, large, and individually schedulable against the forward's compute —
-the mirror image of PR 11's per-bucket reduce-scatter in the backward.
+few, large, and individually schedulable against the forward's compute.
 
 The composite param pytree
 --------------------------
@@ -45,8 +42,7 @@ It is a plain pytree, so every existing code path — ``lax.scan`` multi-step,
 buffer donation, ``snapshot()`` — works unchanged; only ``_apply`` assembles
 the full tree (gather), and checkpoints always store the CANONICAL tree form
 (:meth:`FsdpPlan.composite_to_tree`), so fsdp-sharded ↔ replicated restores
-are bit-exact in both directions — the same contract the comms plane's
-sharded optimizer state keeps (PR 8/12).
+are bit-exact in both directions.
 """
 
 from __future__ import annotations
@@ -60,8 +56,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-
-from .comms import BucketLayout
 
 # canonical rules: embedding tables shard rows over fsdp and columns over tp
 # (the friesian/NCF pod-scale recommender layout — one table bigger than any
@@ -115,14 +109,12 @@ class SpecLayout:
     # leaves smaller than 2*axis_size never shard (a shard under one
     # element per device is padding, not parallelism)
 
-    active = True
-
     # -- resolution ----------------------------------------------------------
     @classmethod
     def resolve(cls, config: Dict[str, Any], arg=None
                 ) -> Optional["SpecLayout"]:
         """One resolution path for the estimator/serving kwarg + config +
-        env knobs (mirrors ``CommsConfig.resolve``):
+        env knobs:
 
         * ``arg`` a SpecLayout → use it; ``arg False`` → plane off.
         * ``arg True`` / config ``sharding: true`` / ``ZOO_SHARDING_PLANE=1``
@@ -251,15 +243,106 @@ class SpecLayout:
                 f"bucket_mb={float(self.bucket_mb)}:{h.hexdigest()[:16]}")
 
 
+@dataclasses.dataclass
+class BucketLayout:
+    """Static placement of a float32 pytree inside a zero-padded flat vector
+    cut into buckets.
+
+    Leaf order is ``jax.tree_util.tree_flatten`` order — deterministic for
+    a given tree structure (dict keys sort), and the SAME order every
+    flatten/unflatten call uses, so assembly/disassembly round-trips
+    bit-exactly.
+
+    Every bucket is a whole multiple of the axis size ``n_dev`` (the last
+    one padded up with zeros): a bucket stored ``P(axis)`` then puts exactly
+    1/N of it on each device, and its all-gather tiles evenly — a bucket
+    that did not divide would have to be replicated or re-padded inside
+    every step. Padding slots hold zeros and receive zero gradients, so
+    they stay zero through any elementwise optimizer.
+    """
+
+    treedef: Any
+    shapes: Tuple[Tuple[int, ...], ...]
+    sizes: Tuple[int, ...]
+    n_dev: int
+    bucket_sizes: Tuple[int, ...]
+    total: int
+    padded_total: int
+    shard_size: int
+
+    @staticmethod
+    def build(tree, n_dev: int, bucket_mb: float) -> "BucketLayout":
+        """``bucket_mb`` is the target bucket size in MiB; 0 means one
+        bucket over the whole vector."""
+        leaves, treedef = jax.tree_util.tree_flatten(tree)
+        if not leaves:
+            raise ValueError("bucket layout: empty parameter tree")
+
+        # metadata only — leaf .dtype/.shape, never np.asarray (which
+        # would D2H-copy every on-device param just to read its header)
+        def _dtype(l):
+            dt = getattr(l, "dtype", None)
+            return np.dtype(dt) if dt is not None else np.result_type(l)
+        for l in leaves:
+            # the flat vector is f32: a bf16/f16 leaf would silently
+            # change precision through it and an integer leaf would be
+            # rounded, so neither round-trips losslessly
+            if _dtype(l) != np.dtype(np.float32):
+                raise ValueError(
+                    f"bucket layout: leaf of dtype {_dtype(l)} cannot ride "
+                    "the f32 flat vector (lossless round-trips are "
+                    "f32-only)")
+        shapes = tuple(tuple(int(d) for d in np.shape(l)) for l in leaves)
+        sizes = tuple(int(np.prod(s)) if s else 1 for s in shapes)
+        total = sum(sizes)
+        n_dev = int(n_dev)
+        if bucket_mb > 0:
+            target = max(int(bucket_mb * (1 << 20)) // 4, n_dev)
+            b = (target // n_dev) * n_dev
+            n_full = total // b
+            rem = total - n_full * b
+            bucket_sizes = [b] * n_full
+            if rem or not bucket_sizes:
+                bucket_sizes.append(-(-rem // n_dev) * n_dev or n_dev)
+        else:
+            bucket_sizes = [-(-total // n_dev) * n_dev]
+        padded_total = sum(bucket_sizes)
+        return BucketLayout(
+            treedef=treedef, shapes=shapes, sizes=sizes,
+            n_dev=n_dev, bucket_sizes=tuple(bucket_sizes), total=total,
+            padded_total=padded_total, shard_size=padded_total // n_dev)
+
+    def signature(self) -> str:
+        """Content hash of everything that changes a step's program."""
+        h = hashlib.sha256(repr((
+            self.shapes, self.n_dev, self.bucket_sizes)).encode())
+        return h.hexdigest()[:16]
+
+    def flatten_np(self, tree) -> np.ndarray:
+        """Pytree -> padded flat f32 vector on the host (bit-exact per
+        element)."""
+        flat = np.concatenate([np.asarray(l).reshape(-1)
+                               for l in jax.tree_util.tree_leaves(tree)])
+        return np.pad(flat, (0, self.padded_total - self.total))
+
+    def unflatten(self, flat):
+        """Padded flat vector -> pytree (inverse of :meth:`flatten_np`):
+        slices and reshapes only, so it serves a numpy vector on the host
+        and a traced one inside a jitted step alike."""
+        out, off = [], 0
+        for shape, size in zip(self.shapes, self.sizes):
+            out.append(flat[off:off + size].reshape(shape))
+            off += size
+        return jax.tree_util.tree_unflatten(self.treedef, out)
+
+
 class FsdpPlan:
     """Bucketed fsdp parameter sharding bound to one param tree.
 
     Built once per engine from the param tree + merged spec tree: every f32
     leaf with a trivial spec and >= 2*fsdp elements *rides* the flat vector
-    (:class:`BucketLayout` over the fsdp axis — the same padding/bucketing
-    arithmetic the dp comms plane uses, so flatten/unflatten round-trips
-    are bit-exact by the already-tested contract); everything else is
-    *held* aside with its own (tp/explicit) sharding.
+    (:class:`BucketLayout` over the fsdp axis); everything else is *held*
+    aside with its own (tp/explicit) sharding.
     """
 
     FLAT_KEY = "__fsdp_flat__"
@@ -365,7 +448,7 @@ class FsdpPlan:
         other's state without either knowing about the other."""
         flat = np.concatenate([np.asarray(comp[self.FLAT_KEY][k]).reshape(-1)
                                for k in self.bucket_keys])
-        ridden = jax.tree_util.tree_leaves(self.layout.unflatten_np(flat))
+        ridden = jax.tree_util.tree_leaves(self.layout.unflatten(flat))
         held = [np.asarray(comp[self.HELD_KEY][k]) for k in self.held_keys]
         return self._join(ridden, held)
 
@@ -413,8 +496,7 @@ class FsdpPlan:
         """Optimizer state over composite params (moment nodes ARE
         composites — optax inherits the param structure) -> canonical
         tree form for checkpoints. Padding slots hold zeros (zero grads
-        keep zero moments), so the conversion is lossless — same argument
-        as the comms plane's ``opt_flat_to_tree``."""
+        keep zero moments), so the conversion is lossless."""
         return jax.tree.map(
             lambda node: (self.composite_to_tree(node)
                           if self.is_composite(node) else node),

@@ -2280,323 +2280,6 @@ def bench_infeed(smoke: bool) -> dict:
             "batch": batch, "n": n, "image_side": side}
 
 
-def _comms_child(smoke: bool) -> dict:
-    """Runs inside the 8-device simulated CPU mesh subprocess: flat-psum
-    vs bucketed reduce-scatter vs quantized wire through the production
-    estimator, reporting collective launches (counted in the lowered
-    StableHLO), grad wire bytes/step, and bit-identity."""
-    import re
-
-    import flax.linen as nn
-    import jax
-
-    from analytics_zoo_tpu import init_orca_context
-    from analytics_zoo_tpu.orca.learn.estimator import TPUEstimator
-    from analytics_zoo_tpu.orca.learn.utils import data_to_iterator
-
-    init_orca_context("cpu-sim", mesh_axes={"dp": -1})
-    width = 32 if smoke else 64
-    depth = 6 if smoke else 8
-    n = 512 if smoke else 2048
-    epochs = 2 if smoke else 3
-
-    class DeepMLP(nn.Module):
-        # many small leaves on purpose: the flat wire pays one collective
-        # per leaf, which is exactly what bucketing amortizes
-        @nn.compact
-        def __call__(self, x):
-            for _ in range(depth):
-                x = nn.relu(nn.Dense(width)(x))
-            return nn.Dense(1)(x)[:, 0]
-
-    rng = np.random.RandomState(0)
-    data = {"x": rng.rand(n, 16).astype(np.float32),
-            "y": rng.rand(n).astype(np.float32)}
-
-    from analytics_zoo_tpu.analysis.hlo_lint import (HloLinter,
-                                                     collective_counts,
-                                                     collectives_by_axis,
-                                                     parse_collectives)
-
-    def run(cfg, **kw):
-        est = TPUEstimator(DeepMLP(), loss="mse", optimizer="adam", seed=0,
-                           config={"steps_per_dispatch": 1, **cfg}, **kw)
-        it = data_to_iterator(dict(data), 64, est.mesh, None, None,
-                              shuffle=False, config=est.config)
-        b0 = next(it.epoch(shuffle=False, prefetch=False))
-        est.engine.build(tuple(np.asarray(a) for a in b0.x))
-        fn = est.engine.ensure_jit_train()
-        text = fn.lower(*est.engine.train_step_args(b0)).as_text()
-        collectives = len(re.findall(
-            r"stablehlo\.(?:all_reduce|reduce_scatter|all_gather|"
-            r"collective_permute)", text))
-        by_kind = collective_counts(parse_collectives(text))
-        declared = est.engine.comms_snapshot()
-        # the hlo_lint accounting rule, run right here: measured launches
-        # and reduce-scatter wire bytes vs what the plane declares
-        # (per-axis under the hierarchical wire)
-        accounting_ok = not HloLinter().lint_text(
-            text, label="bench:train", declared=declared)
-        by_axis = None
-        lo = est.engine.comms.layout if est.engine.comms else None
-        if lo is not None and lo.hierarchical:
-            by_axis = collectives_by_axis(parse_collectives(text),
-                                          lo.ici, lo.dcn)
-        # warm the executable with one rolled-back step so the timed fit
-        # measures steady-state step rate, not each leg's JIT compile
-        # (the snapshot copies survive the step's buffer donation)
-        snap = est.engine.snapshot()
-        fn(*est.engine.train_step_args(b0))
-        est.engine.restore_snapshot(snap)
-        t0 = time.perf_counter()
-        stats = est.fit(dict(data), epochs=epochs, batch_size=64,
-                        verbose=False)
-        dt = time.perf_counter() - t0
-        snap = est.data_pipeline_stats().get("comms", {})
-        weights = np.concatenate(
-            [np.asarray(l).ravel() for l in
-             jax.tree_util.tree_leaves(est.engine.params)])
-        return {"losses": [s["train_loss"] for s in stats],
-                "weights": weights, "collectives": collectives,
-                "by_kind": by_kind, "by_axis": by_axis,
-                "accounting_verified": accounting_ok,
-                "fit_s": dt,
-                "steps_per_s": round(snap.get("steps", 0) / max(dt, 1e-9),
-                                     1),
-                "comms": snap}
-
-    flat = run({"comms_plane": True})
-    bucketed = run({"grad_bucket_mb": 4.0})
-    sharded = run({"grad_bucket_mb": 4.0}, sharded_update=True)
-    bf16 = run({"grad_bucket_mb": 4.0, "allreduce_dtype": "bf16"})
-    # overlapped leg (PR 11): multi-bucket layout (small buckets — one
-    # bucket has nothing to overlap) + ZeRO-1, per-bucket reduce-scatters
-    # assembled from their own leaf slices inside the backward's
-    # dependence graph. For the f32 wire the padded total is invariant to
-    # the bucket split, so wire bytes must match the 4 MiB bucketed leg
-    # byte for byte. ``sharded_small`` is the stall-attribution baseline:
-    # the SAME small-bucket layout with overlap off, so the wall-time
-    # delta isolates the schedule change (comparing against the 1-bucket
-    # sharded leg would measure layout overhead, not overlap).
-    sharded_small = run({"grad_bucket_mb": 0.016}, sharded_update=True)
-    overlapped = run({"grad_bucket_mb": 0.016, "comms_overlap": True},
-                     sharded_update=True)
-    # hierarchical leg (PR 12): the SAME multi-bucket ZeRO-1 layout on
-    # the two-level ICI x DCN wire, dp factored as 2 simulated hosts x 4
-    # chips. Per-axis launches/bytes come from the replica-group shapes
-    # in the lowered program; the DCN byte gate is the hierarchy's whole
-    # point (cross-host bytes <= flat wire bytes / host_count). The
-    # bit-identity family holds WITHIN the two-level wire (vs the
-    # overlapped-hierarchical leg below); vs the flat wire it differs at
-    # reduction-association level (documented in parallel/comms.py), so
-    # hier_vs_flat_drift is reported, not gated to zero.
-    hier = run({"grad_bucket_mb": 0.016, "comms_hierarchy": True,
-                "comms_dcn_axis": 2}, sharded_update=True)
-    hier_overlap = run({"grad_bucket_mb": 0.016, "comms_hierarchy": True,
-                        "comms_dcn_axis": 2, "comms_overlap": True},
-                       sharded_update=True)
-    # native int8 legs (PR 16): the SAME two-level wire with the DCN leg
-    # as a real collective-permute ring over block-scaled int8 payloads.
-    # The byte baseline is the bf16 hierarchical wire — the honest
-    # comparison (against f32 the ring would win 2x for free); the gate
-    # is measured DCN-leg operand bytes in the lowered program, not a
-    # model.
-    hier_bf16 = run({"grad_bucket_mb": 0.016, "comms_hierarchy": True,
-                     "comms_dcn_axis": 2, "allreduce_dtype": "bf16"},
-                    sharded_update=True)
-    hier_native = run({"grad_bucket_mb": 0.016, "comms_hierarchy": True,
-                       "comms_dcn_axis": 2, "allreduce_dtype": "int8",
-                       "allreduce_block": 64, "comms_native_int8": True},
-                      sharded_update=True)
-
-    reduction = flat["collectives"] / max(bucketed["collectives"], 1)
-    wire = bf16["comms"]
-    wire_reduction = wire["grad_bytes_f32"] / wire["wire_bytes_per_step"]
-    drift = float(np.abs(np.asarray(bf16["losses"])
-                         - np.asarray(bucketed["losses"])).max())
-    # stall-hidden seconds: the wall time the overlapped schedule gave
-    # back vs the SAME layout behind the whole-backward barrier. On the
-    # sequential CPU-sim mesh this hovers near 0 — the overlap headroom
-    # only exists where collectives run async.
-    stall_hidden = max(0.0, sharded_small["fit_s"] - overlapped["fit_s"])
-    out = {
-        "metric": "comms_collective_launch_reduction",
-        "value": round(reduction, 2), "unit": "x",
-        # no reference baseline (the reference allreduced per parameter
-        # block through the Spark block manager) — the reduction IS the
-        # vs-baseline signal
-        "vs_baseline": round(reduction, 2),
-        "bit_identical": bool(
-            flat["losses"] == bucketed["losses"]
-            and (flat["weights"] == bucketed["weights"]).all()),
-        "sharded_bit_identical": bool(
-            sharded["losses"] == bucketed["losses"]
-            and (sharded["weights"] == bucketed["weights"]).all()),
-        "collectives_per_step_flat": flat["collectives"],
-        "collectives_per_step_bucketed": bucketed["collectives"],
-        "grad_bytes_per_step_f32": wire["grad_bytes_f32"],
-        "wire_bytes_per_step_bf16": wire["wire_bytes_per_step"],
-        "wire_byte_reduction_bf16": round(wire_reduction, 2),
-        "bf16_loss_drift": drift,
-        "buckets": bucketed["comms"].get("buckets"),
-        "opt_shard_elems": sharded["comms"].get("opt_shard_elems"),
-        "opt_full_elems": sharded["comms"].get("opt_full_elems"),
-        "steps_per_s": {"flat": flat["steps_per_s"],
-                        "bucketed": bucketed["steps_per_s"],
-                        "sharded": sharded["steps_per_s"],
-                        "bf16": bf16["steps_per_s"],
-                        "sharded_small": sharded_small["steps_per_s"],
-                        "overlapped": overlapped["steps_per_s"]},
-        "grad_leaves": flat["comms"].get("grad_leaves"),
-        # overlapped leg (PR 11): bit-identity, per-bucket launch counts,
-        # byte-for-byte wire parity with the bucketed leg, verified
-        # accounting, and the steps/s gate vs the sharded legs (10%
-        # tolerance: the CPU-sim mesh runs collectives synchronously, so
-        # the comparison bounds regression noise, it cannot show the
-        # async win — the structural fields are the portable truth)
-        "overlapped_bit_identical": bool(
-            overlapped["losses"] == bucketed["losses"]
-            and (overlapped["weights"] == bucketed["weights"]).all()),
-        "overlapped_buckets": overlapped["comms"].get("buckets"),
-        "overlapped_segments": overlapped["comms"].get("segments"),
-        "overlapped_rs_launches": overlapped["by_kind"].get(
-            "reduce_scatter", 0),
-        "overlapped_wire_bytes_unchanged": bool(
-            overlapped["comms"].get("wire_bytes_per_step")
-            == bucketed["comms"].get("wire_bytes_per_step")),
-        "overlapped_accounting_verified": overlapped["accounting_verified"],
-        "overlapped_ge_sharded": bool(
-            overlapped["steps_per_s"] >= 0.9 * sharded["steps_per_s"]),
-        "overlapped_ge_same_layout": bool(
-            overlapped["steps_per_s"]
-            >= 0.9 * sharded_small["steps_per_s"]),
-        "stall_hidden_s": round(stall_hidden, 3),
-        "dp": 8, "model_depth": depth, "model_width": width,
-    }
-    hsnap = hier["comms"].get("hierarchy", {})
-    hax = hier["by_axis"] or {}
-    out.update({
-        # hierarchical leg (PR 12)
-        "hierarchical_bit_identical": bool(
-            hier["losses"] == hier_overlap["losses"]
-            and (hier["weights"] == hier_overlap["weights"]).all()),
-        "hierarchical_accounting_verified": hier["accounting_verified"],
-        "hierarchical_overlap_accounting_verified":
-            hier_overlap["accounting_verified"],
-        "hierarchical_ici_axis": hsnap.get("ici_axis"),
-        "hierarchical_dcn_axis": hsnap.get("dcn_axis"),
-        "hierarchical_buckets": hier["comms"].get("buckets"),
-        "hierarchical_rs_ici_launches": hax.get("ici", {}).get(
-            "reduce_scatter", 0),
-        "hierarchical_rs_dcn_launches": hax.get("dcn", {}).get(
-            "reduce_scatter", 0),
-        "hierarchical_ici_wire_bytes": hax.get("ici_wire_bytes"),
-        "hierarchical_dcn_wire_bytes": hax.get("dcn_wire_bytes"),
-        # the gate: cross-host bytes at most flat-wire bytes / host count
-        # (the flat dp wire for this layout moves the ICI leg's f32
-        # bytes, padded_total x 4)
-        "hierarchical_dcn_shrink_ok": bool(
-            hax.get("dcn_wire_bytes", 1 << 60) * hsnap.get("dcn_axis", 2)
-            <= hax.get("ici_wire_bytes", 0)),
-        "hier_vs_flat_drift": float(np.abs(
-            hier["weights"] - sharded_small["weights"]).max()),
-        "hierarchical_ge_sharded": bool(
-            hier["steps_per_s"] >= 0.9 * sharded_small["steps_per_s"]),
-    })
-    out["steps_per_s"]["hierarchical"] = hier["steps_per_s"]
-    out["steps_per_s"]["hierarchical_overlap"] = hier_overlap["steps_per_s"]
-    nsnap = hier_native["comms"]
-    nhier = nsnap.get("hierarchy", {})
-    nax = hier_native["by_axis"] or {}
-    bax = hier_bf16["by_axis"] or {}
-    native_dcn = nax.get("dcn_wire_bytes", 0)
-    bf16_dcn = bax.get("dcn_wire_bytes", 0)
-    out.update({
-        # native int8 ring (PR 16): byte-exact accounting (the linter has
-        # no simulated-wire exemption for this leg), measured DCN bytes vs
-        # the bf16 wire on the identical layout, and the EF drift vs the
-        # exact-f32 hierarchical leg
-        "native_int8_accounting_verified":
-            hier_native["accounting_verified"],
-        "native_int8_hops": nsnap.get("native_hops"),
-        "native_int8_cp_dcn_launches": nax.get("dcn", {}).get(
-            "collective_permute", 0),
-        "native_int8_rs_dcn_launches": nax.get("dcn", {}).get(
-            "reduce_scatter", 0),
-        "native_int8_dcn_wire_bytes": native_dcn,
-        "bf16_dcn_wire_bytes": bf16_dcn,
-        "native_dcn_byte_reduction_bf16": round(
-            bf16_dcn / max(native_dcn, 1), 2),
-        "native_int8_byte_exact": bool(
-            native_dcn == nhier.get("dcn_wire_bytes_per_step")),
-        "native_vs_hier_drift": float(np.abs(
-            hier_native["weights"] - hier["weights"]).max()),
-    })
-    out["steps_per_s"]["hier_bf16"] = hier_bf16["steps_per_s"]
-    out["steps_per_s"]["hier_native_int8"] = hier_native["steps_per_s"]
-    return out
-
-
-def bench_comms(smoke: bool) -> dict:
-    """Comms-plane microbench (PR 8 + PR 11): flat per-leaf psum vs
-    bucketed reduce-scatter+all-gather vs the quantized bf16 wire, the
-    ZeRO-1 sharded update, and the overlapped backward–comms pipeline,
-    on a SIMULATED 8-device CPU mesh.
-
-    The bench process may own a real TPU (or a 1-device CPU backend), and
-    the device count is fixed at jax import — so the mesh runs in a
-    subprocess with ``xla_force_host_platform_device_count=8``. Every leg
-    pays one rolled-back warmup step so the timed window is steady-state.
-    CI gates on: bucketed bit-identical to flat psum, >=2x fewer
-    collective launches, >=1.9x fewer grad wire bytes with bf16, sharded
-    update bit-identical, the overlapped leg bit-identical with
-    per-bucket launch counts, byte-for-byte wire parity and verified
-    hlo_lint accounting, and the hierarchical leg (PR 12: two-level
-    ICI x DCN wire on a simulated 2-host x 4-chip factorization)
-    bit-identical within its family with per-axis accounting verified
-    and DCN wire bytes <= flat wire bytes / host_count, and the native
-    int8 ring (PR 16: the DCN leg as a real collective-permute ring over
-    block-scaled int8 payloads) with BYTE-EXACT accounting, >=1.9x fewer
-    measured DCN bytes than the bf16 wire on the identical layout, and
-    bounded error-feedback drift
-    (.github/workflows/tier1.yml). ``stall_hidden_s`` and
-    ``overlapped_ge_sharded`` report the steps/s gate vs the sharded
-    leg (soft on the sequential CPU-sim mesh, where async overlap cannot
-    exist; the structural contract is the portable truth).
-    """
-    import re
-    import subprocess
-
-    env = dict(os.environ)
-    env["JAX_PLATFORMS"] = "cpu"
-    # the child configures each leg explicitly — ambient comms knobs would
-    # contaminate the flat baseline (ZOO_GRAD_BUCKET_MB=4 in the caller's
-    # shell must not turn the "flat" leg into a bucketed one)
-    for knob in ("ZOO_GRAD_BUCKET_MB", "ZOO_SHARDED_UPDATE",
-                 "ZOO_ALLREDUCE_DTYPE", "ZOO_ALLREDUCE_BLOCK",
-                 "ZOO_COMMS_PLANE", "ZOO_COMMS_OVERLAP",
-                 "ZOO_COMMS_SEGMENTS", "ZOO_COMMS_HIERARCHY",
-                 "ZOO_COMMS_DCN_AXIS", "ZOO_COMMS_QUANTIZE_DCN",
-                 "ZOO_COMMS_NATIVE_INT8"):
-        env.pop(knob, None)
-    # force the count — an ambient =4 from the caller's shell would run the
-    # mesh at dp=4 while the output and the tier1 gate assume dp=8
-    flags = re.sub(r"--xla_force_host_platform_device_count=\d+", "",
-                   env.get("XLA_FLAGS", ""))
-    env["XLA_FLAGS"] = (
-        flags + " --xla_force_host_platform_device_count=8").strip()
-    proc = subprocess.run(
-        [sys.executable, os.path.abspath(__file__), "--_comms_child",
-         "1" if smoke else "0"],
-        env=env, capture_output=True, text=True, timeout=900)
-    lines = [l for l in proc.stdout.splitlines() if l.strip()]
-    if proc.returncode != 0 or not lines:
-        raise RuntimeError(
-            f"comms child failed (rc={proc.returncode}): "
-            f"{proc.stderr.strip()[-2000:]}")
-    return json.loads(lines[-1])
-
-
 def _sharding_child(smoke: bool) -> dict:
     """Runs inside the 8-device simulated CPU mesh subprocess: the sharding
     plane (PR 17) through the production estimator. Two legs:
@@ -2625,7 +2308,7 @@ def _sharding_child(smoke: bool) -> dict:
     from analytics_zoo_tpu.analysis.hlo_lint import (HloLinter,
                                                      collective_counts,
                                                      collectives_by_mesh_axes,
-                                                     declared_comms,
+                                                     declared_accounting,
                                                      parse_collectives)
     from analytics_zoo_tpu.orca.learn.estimator import TPUEstimator
     from analytics_zoo_tpu.orca.learn.utils import data_to_iterator
@@ -2680,7 +2363,7 @@ def _sharding_child(smoke: bool) -> dict:
         axes = {a: int(s) for a, s in est.engine.mesh.shape.items()
                 if int(s) > 1}
         bya = collectives_by_mesh_axes(parse_collectives(text), axes)
-        declared = (declared_comms(est.engine._sharding_key())
+        declared = (declared_accounting(est.engine._sharding_key())
                     if sharding is not False else None)
         accounting_ok = (not HloLinter().lint_text(
             text, label="bench:train", declared=declared)
@@ -2796,8 +2479,8 @@ def _sharding_child(smoke: bool) -> dict:
 def bench_sharding(smoke: bool) -> dict:
     """Sharding-plane microbench (PR 17): fsdp×tp SpecLayout through the
     production estimator + InferenceModel on a SIMULATED 8-device CPU
-    mesh (subprocess, like bench_comms — the bench process's device count
-    is fixed at jax import).
+    mesh (subprocess — the bench process's device count is fixed at jax
+    import).
 
     CI gates on: sharded training and serving bit-identical to the
     replicated layout on the SAME mesh (SGD — elementwise-safe math),
@@ -2813,13 +2496,10 @@ def bench_sharding(smoke: bool) -> dict:
 
     env = dict(os.environ)
     env["JAX_PLATFORMS"] = "cpu"
-    # each leg configures its plane explicitly — ambient sharding/comms
-    # knobs would contaminate the replicated baseline
+    # each leg configures its plane explicitly — ambient sharding knobs
+    # would contaminate the replicated baseline
     for knob in ("ZOO_SHARDING_PLANE", "ZOO_FSDP_BUCKET_MB",
-                 "ZOO_MESH_AXES", "ZOO_GRAD_BUCKET_MB",
-                 "ZOO_SHARDED_UPDATE", "ZOO_ALLREDUCE_DTYPE",
-                 "ZOO_COMMS_PLANE", "ZOO_COMMS_OVERLAP",
-                 "ZOO_COMMS_HIERARCHY", "ZOO_COMMS_DCN_AXIS"):
+                 "ZOO_MESH_AXES"):
         env.pop(knob, None)
     flags = re.sub(r"--xla_force_host_platform_device_count=\d+", "",
                    env.get("XLA_FLAGS", ""))
@@ -3215,20 +2895,13 @@ DEVICE_LEGS = ("resnet50", "ncf", "fraud_mlp", "autots", "serving_od",
 # explicit JAX_PLATFORMS=cpu while the parent stays off JAX: they run FIRST
 # (a parent that has touched JAX holds the chip) and are labeled without
 # asking JAX for its devices
-HOST_ONLY_LEGS = ("serving_fleet", "shm", "comms", "sharding")
+HOST_ONLY_LEGS = ("serving_fleet", "shm", "sharding")
 _HOST_ONLY_LABEL = {"platform": "cpu", "device_kind": "cpu",
                     "device_count": 0,
                     "children_platform": _CHILD_ENV["JAX_PLATFORMS"]}
 
 
 def main():
-    if "--_comms_child" in sys.argv:
-        # bench_comms' simulated-mesh subprocess: no other workloads — one
-        # JSON line on stdout
-        pos = sys.argv.index("--_comms_child") + 1
-        smoke = pos < len(sys.argv) and sys.argv[pos] == "1"
-        print(json.dumps(_comms_child(smoke)))
-        return
     if "--_sharding_child" in sys.argv:
         # bench_sharding's simulated-mesh subprocess — one JSON line
         pos = sys.argv.index("--_sharding_child") + 1
@@ -3253,7 +2926,7 @@ def main():
     # no context is created here: the first leg that needs JAX makes one
     # (get_context), so the HOST_ONLY legs run with the parent off JAX
     benches = {"serving_fleet": bench_serving_fleet, "shm": bench_shm,
-               "comms": bench_comms, "sharding": bench_sharding,
+               "sharding": bench_sharding,
                "streaming_fleet": bench_streaming_fleet,
                "resnet50": bench_resnet50, "ncf": bench_ncf,
                "fraud_mlp": bench_fraud_mlp, "autots": bench_autots_trials,
@@ -3319,7 +2992,6 @@ def main():
                       ("compile_plane", "compile_warm_start"),
                       ("infeed", "infeed_wire_reduction"),
                       ("ckpt", "ckpt_async_hiding"),
-                      ("comms", "comms_collective_reduction"),
                       ("sharding", "sharding_model_over_chip"),
                       ("obs", "obs_disarmed_overhead"),
                       ("streaming", "streaming_records_per_s"),

@@ -28,18 +28,18 @@ echo "DOTS_FAILED=$(printf '%s\n' "$fails" | grep -c . )"
 if [ -n "$fails" ]; then
     printf 'DOTS_FAILED_ID=%s\n' $fails
 fi
-# per-plane snapshot lines (TRANSFER_PLANE= / CKPT_PLANE= / COMMS_PLANE= /
+# per-plane snapshot lines (TRANSFER_PLANE= / CKPT_PLANE= /
 # SHARDING_PLANE= / RESILIENCE= / SERVING_PLANE= / FLEET= / STREAMING= /
 # SHM= / ANALYSIS= / OBS=): tiny CPU workloads through each plane's
 # production path, all through the ONE zoo-metrics snapshot codepath
 # (analytics_zoo_tpu/obs/snapshots.py — previously five bespoke heredocs
-# here). One process per plane: the comms/analysis snapshots configure the
+# here). One process per plane: the sharding/analysis snapshots configure the
 # 8-device simulated mesh themselves, which must happen before the JAX
 # backend first initializes. The streaming snapshot carries the PR-19
 # fleet block ("fleet": consumers/windows_total/freshness_p99_ratio/
 # guard_rejected/rejected_never_adopted — a 2-consumer sharded run plus
 # one guardrail-rejected poisoned commit). Never affects the exit code.
-for plane in transfer ckpt comms sharding resilience serving fleet streaming shm analysis obs; do
+for plane in transfer ckpt sharding resilience serving fleet streaming shm analysis obs; do
     env JAX_PLATFORMS=cpu \
         python -m analytics_zoo_tpu.obs snapshot "$plane" \
         2>/dev/null | grep -aE '^[A-Z_]+=' || true

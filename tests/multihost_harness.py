@@ -1,9 +1,8 @@
 """Shared two-process ``jax.distributed`` test harness.
 
-``test_multihost.py`` grew this scaffolding inline (worker script
-materialization, coordinator port allocation, subprocess fan-out, timeout
-kill + output surfacing, the no-CPU-collectives skip); the multihost
-golden-contract test needs the identical machinery, so it lives here once.
+The scaffolding ``test_multihost.py`` runs its workers through: worker
+script materialization, coordinator port allocation, subprocess fan-out,
+timeout kill + output surfacing, the no-CPU-collectives skip.
 
 The coordinator port comes from :func:`free_port` — bind an ephemeral
 socket, read the number, close it. That is inherently racy: another

@@ -7,8 +7,9 @@ lock-order inversion under two threads, an unregistered knob read, ...)
 and by staying silent on the clean tree — the acceptance criteria of
 ISSUE 9. The golden program-contract gate is shown to fail on an injected
 collective-count regression, and the committed goldens carry
-``accounting_verified: true`` for every comms leg (measured lowered-program
-launches/bytes == ``data_pipeline_stats()["comms"]`` declared accounting).
+``accounting_verified: true`` for every sharded leg (launches and bytes
+measured per mesh axis in the compiled program == the accounting the engine
+declares for its layout).
 """
 
 import json
@@ -129,6 +130,16 @@ def test_parse_collectives_reads_region_and_inline_signatures():
     assert ag.operand_bytes == 105 * 4 and ag.result_bytes == 840 * 4
 
 
+def _declared_fsdp(axes, shard_bytes, buckets=1, tp_leaves=0):
+    """What the engine declares for a SpecLayout (FsdpPlan.summary() plus
+    its tp leaves), cut to the fields the accounting rule reads."""
+    return {"plane": "sharding",
+            "fsdp": {"axis": "fsdp", "axes": dict(axes), "buckets": buckets,
+                     "gather_shard_bytes_per_sweep": shard_bytes},
+            "tp": {"axis": "tp", "axis_size": axes.get("tp", 1),
+                   "sharded_leaves": tp_leaves}}
+
+
 _ASYNC_MODULE = textwrap.dedent("""\
     module @jit_step_async {
       func.func public @main(%arg0: tensor<840xf32>) -> tensor<840xf32> {
@@ -164,10 +175,17 @@ def test_parse_collectives_counts_async_start_done_pairs_once():
            "%rsd = f32[105] reduce-scatter-done(%rs)\n")
     assert [op.kind for op in parse_collectives(hlo)] == ["reduce_scatter"]
     # and the accounting rule accepts an async pair as the declared bucket
-    declared = {"buckets": 1, "sharded_update": True, "wire_dtype": "f32",
-                "wire_bytes_per_step": 840 * 4}
+    hlo = ("%ags = (f32[104], f32[832]) all-gather-start(f32[104]{0} %p), "
+           "replica_groups=[1,8]<=[8], dimensions={0}\n"
+           "%agd = f32[832] all-gather-done(%ags)\n"
+           "%ar = f32[832] all-reduce(f32[832] %g), "
+           "replica_groups=[1,8]<=[8], to_apply=%add\n")
+    assert parse_collectives(hlo)[0].operand_bytes == 416
     assert HloLinter(target="cpu").lint_text(
-        _ASYNC_MODULE, label="train", declared=declared) == []
+        hlo, label="train", declared=_declared_fsdp({"fsdp": 8}, 416)) == []
+    # the start's type is the tuple (operand, result): bare operands or not
+    bare = hlo.replace("f32[104]{0} %p", "%p")
+    assert parse_collectives(bare)[0].operand_bytes == 416
 
 
 _PERMUTE_MODULE = textwrap.dedent("""\
@@ -185,22 +203,22 @@ def test_parse_collectives_recognizes_permute_and_all_to_all():
     """PR 16: a ppermute-based wire must be visible to the accounting
     gate. stablehlo sync, async start/done, and hyphenated HLO-text forms
     all count with dtype-true (int8, not x4) bytes, and a permute's
-    source->target pairs classify it onto a leg the way replica_groups
-    classify a reduce-scatter."""
-    from analytics_zoo_tpu.analysis.hlo_lint import collectives_by_axis
+    source->target pairs classify it onto a mesh axis the way
+    replica_groups classify a reduce-scatter."""
+    from analytics_zoo_tpu.analysis.hlo_lint import collectives_by_mesh_axes
     ops = parse_collectives(_PERMUTE_MODULE)
     assert sorted(op.kind for op in ops) == ["all_to_all",
                                              "collective_permute"]
     cp = next(op for op in ops if op.kind == "collective_permute")
     assert cp.operand_bytes == 288            # int8: one byte per element
-    # 4 disjoint 2-cycles == the (ici=4, dcn=2) DCN-leg group shape
+    # 4 disjoint 2-cycles == the group shape of the tp axis on fsdp=4 x tp=2
     assert cp.group_shape == (4, 2)
     a2a = next(op for op in ops if op.kind == "all_to_all")
     assert a2a.operand_bytes == 288 and a2a.group_shape == (1, 8)
-    by = collectives_by_axis(ops, ici=4, dcn=2)
-    assert by["dcn"]["collective_permute"] == 1
-    assert by["dcn_wire_bytes"] == 288        # the a2a is global, not DCN
-    assert by["global"]["all_to_all"] == 1
+    by = collectives_by_mesh_axes(ops, {"fsdp": 4, "tp": 2})
+    assert by["by_axis"]["tp"] == {"collective_permute": 1}
+    assert by["axis_bytes"]["tp"] == {"collective_permute": 288}
+    assert by["global"] == {"all_to_all": 1}      # over all 8, no one axis
     # async start/done pair = ONE launch (what the latency-hiding
     # scheduler emits when the ring hop overlaps compute)
     async_txt = (
@@ -226,20 +244,47 @@ def test_parse_collectives_recognizes_permute_and_all_to_all():
     assert ops[1].operand_bytes == 96 and ops[1].group_shape == (1, 2)
 
 
+# a compiled fsdp=4 x tp=2 train step in miniature: one bucket's gather over
+# the fsdp groups (2 groups of 4), the gradient combine over the same
+# groups, the row-parallel matmul's combine over the tp groups (4 of 2)
+_SHARDED_HLO = (
+    "%ag = f32[608]{0} all-gather(f32[152]{0} %p), channel_id=1, "
+    "replica_groups=[2,4]<=[4,2]T(1,0), dimensions={0}\n"
+    "%ar = f32[608]{0} all-reduce(f32[608]{0} %g), channel_id=2, "
+    "replica_groups=[2,4]<=[4,2]T(1,0), to_apply=%add\n"
+    "%tp = f32[4,32]{1,0} all-reduce(f32[4,32]{1,0} %h), channel_id=3, "
+    "replica_groups=[4,2]<=[8], to_apply=%add\n")
+
+
 def test_comms_accounting_rule_verifies_and_catches_drift():
-    declared = {"buckets": 1, "sharded_update": True, "wire_dtype": "f32",
-                "wire_bytes_per_step": 840 * 4}
+    declared = _declared_fsdp({"fsdp": 4, "tp": 2}, 152 * 4, tp_leaves=3)
     linter = HloLinter(target="cpu")
-    assert linter.lint_text(_SYNTH_MODULE, label="train",
+    assert linter.lint_text(_SHARDED_HLO, label="train",
                             declared=declared) == []
-    # an injected byte regression (declared != lowered) must fail
-    bad = dict(declared, wire_bytes_per_step=840 * 4 * 2)
-    found = linter.lint_text(_SYNTH_MODULE, label="train", declared=bad)
+    # an XLA build that prints operands bare reads the same bytes: a
+    # gather's operand is its result over the group size
+    bare = _SHARDED_HLO.replace("f32[152]{0} %p", "%p")
+    assert parse_collectives(bare)[0].operand_bytes == 152 * 4
+    assert linter.lint_text(bare, label="train", declared=declared) == []
+    # an injected byte regression (declared != compiled) must fail
+    bad = _declared_fsdp({"fsdp": 4, "tp": 2}, 152 * 4 * 2, tp_leaves=3)
+    found = linter.lint_text(_SHARDED_HLO, label="train", declared=bad)
     assert [f.rule for f in found] == ["comms-accounting"]
-    # an injected launch regression (extra declared bucket) must fail
-    bad = dict(declared, buckets=2)
-    found = linter.lint_text(_SYNTH_MODULE, label="train", declared=bad)
-    assert any("reduce-scatter" in f.message for f in found)
+    assert "B/step" in found[0].message
+    # an injected launch regression (a second declared bucket) must fail
+    bad = _declared_fsdp({"fsdp": 4, "tp": 2}, 152 * 4, buckets=2,
+                         tp_leaves=3)
+    found = linter.lint_text(_SHARDED_HLO, label="train", declared=bad)
+    assert any("all-gathers" in f.message for f in found)
+    # a train step that lost its gradient combine, or its tp combine
+    no_grad = "\n".join(l for l in _SHARDED_HLO.splitlines()
+                        if not l.startswith("%ar"))
+    found = linter.lint_text(no_grad, label="train", declared=declared)
+    assert any("combines no gradients" in f.message for f in found)
+    no_tp = "\n".join(l for l in _SHARDED_HLO.splitlines()
+                      if not l.startswith("%tp"))
+    found = linter.lint_text(no_tp, label="train", declared=declared)
+    assert any("tp leg launches no collectives" in f.message for f in found)
 
 
 # ---------------------------------------------------------------------------
@@ -296,9 +341,16 @@ def test_on_lowering_warn_collects_and_off_disables(monkeypatch):
 
 
 def test_hook_verifies_comms_accounting_on_real_fit(orca_context):
-    """End-to-end acceptance: a bucketed+sharded fit routes its train
-    lowering through ExecutableCache -> on_lowering, which cross-checks
-    the lowered collectives against the engine's declared accounting."""
+    """End to end: a SpecLayout fit routes its train lowering through
+    ExecutableCache -> on_lowering and registers what it declares under
+    its sharding key. The layout's collectives exist only once the
+    partitioner has run, so the hook's module holds none and passes; the
+    compiled program, linted the way the hook lints (recording), checks
+    against that declaration."""
+    from analytics_zoo_tpu.analysis.hlo_lint import declared_accounting
+    from analytics_zoo_tpu.orca.learn.utils import data_to_iterator
+    from analytics_zoo_tpu.parallel.mesh import create_mesh
+    from analytics_zoo_tpu.parallel.sharding import SpecLayout
     reset_report()
 
     class M(nn.Module):
@@ -308,24 +360,33 @@ def test_hook_verifies_comms_accounting_on_real_fit(orca_context):
             return nn.Dense(1)(x)[:, 0]
 
     rng = np.random.RandomState(0)
+    data = {"x": rng.rand(128, 8).astype(np.float32),
+            "y": rng.rand(128).astype(np.float32)}
     est = TPUEstimator(M(), loss="mse", optimizer="adam", seed=0,
-                       sharded_update=True,
-                       config={"steps_per_dispatch": 1,
-                               "grad_bucket_mb": 4.0})
-    est.fit({"x": rng.rand(128, 8).astype(np.float32),
-             "y": rng.rand(128).astype(np.float32)},
-            epochs=1, batch_size=32, verbose=False)
-    rep = lint_report(reset=True)
+                       mesh=create_mesh({"dp": 1, "fsdp": -1}),
+                       config={"steps_per_dispatch": 1},
+                       sharding=SpecLayout())
+    est.fit(dict(data), epochs=1, batch_size=32, verbose=False)
+    rep = lint_report()
     assert rep["programs_linted"] >= 1
-    assert rep["comms_verified"] >= 1
     assert rep["findings"] == []
+    declared = declared_accounting(est.engine._sharding_key())
+    assert declared is not None and declared["fsdp"]["buckets"] == 1
+    it = data_to_iterator(dict(data), 32, est.mesh, None, None,
+                          shuffle=False, config=est.config)
+    b0 = next(it.epoch(shuffle=False, prefetch=False))
+    fn = est.engine.ensure_jit_train()
+    text = fn.lower(*est.engine.train_step_args(b0)).compile().as_text()
+    assert HloLinter(record_verified=True).lint_text(
+        text, label="train", declared=declared) == []
+    assert lint_report(reset=True)["comms_verified"] == 1
 
 
 # ---------------------------------------------------------------------------
 # golden program contracts
 # ---------------------------------------------------------------------------
 def test_golden_contracts_match_committed_goldens(orca_context):
-    """The CI gate itself: fresh capture over all four bench legs equals
+    """The CI gate itself: fresh capture over the three legs equals
     the committed tests/goldens/program_contracts.json."""
     ok, delta = golden_mod.check()
     assert ok, "golden program contracts drifted:\n" + "\n".join(delta)
@@ -333,91 +394,44 @@ def test_golden_contracts_match_committed_goldens(orca_context):
 
 def test_committed_goldens_carry_verified_accounting():
     contracts = golden_mod.load_goldens()
-    legs = [name for name, _, _ in golden_mod._LEGS if name != "baseline"]
-    assert legs
-    for name in legs:
+    # the sharded legs verify per-mesh-axis accounting at capture time:
+    # the compiled gathers move exactly what the plan declares
+    for name, _ in golden_mod._SHARDING_LEGS:
         entry = contracts[name]
         assert entry["accounting_verified"] is True, (name, entry)
-        assert entry["declared"]["wire_bytes_per_step"] > 0
-    # the sharding-plane legs (PR 17) verify per-mesh-axis accounting at
-    # capture time too
-    for name, _ in golden_mod._SHARDING_LEGS:
-        assert contracts[name]["accounting_verified"] is True, name
+        assert entry["fsdp_gather_bytes"] == \
+            entry["gather_shard_bytes_per_sweep"] > 0
+    # the default step declares nothing and launches nothing explicit
+    assert contracts["baseline"]["collectives"] == {}
+    assert contracts["baseline"]["donation"] == [0, 2]
     # every leg lowers to its own executable (extra_key salting intact)
     assert contracts["distinct_train_executables"] == \
-        len(golden_mod._LEGS) + len(golden_mod._SHARDING_LEGS)
+        1 + len(golden_mod._SHARDING_LEGS)
 
 
 def test_golden_gate_fails_on_injected_collective_regression():
     contracts = golden_mod.load_goldens()
     tampered = json.loads(json.dumps(contracts))      # deep copy
-    tampered["flat"]["collectives"]["all_reduce"] += 2
-    tampered["bucketed_sharded"]["rs_wire_bytes"] *= 2
-    # an overlapped launch-count regression (a segment merge collapsing
-    # per-bucket reduce-scatters into one) must fail field-level too
-    tampered["overlapped"]["collectives"]["reduce_scatter"] = 1
-    tampered["overlapped_wire_matches_bucketed"] = False
-    # PR 16: the native int8 leg's hop count and wire bytes are pinned —
-    # a lost ring hop or a widened payload must fail field-level
-    tampered["native_int8"]["collectives"]["collective_permute"] -= 1
-    tampered["native_int8"]["cp_wire_bytes"] += 4
-    tampered["native_int8"]["declared"]["native_hops"] += 1
-    tampered["native_int8_byte_exact"] = False
+    # a per-leaf regression: one gather a parameter leaf, not one a bucket
+    tampered["sharding_fsdp"]["collectives"]["all_gather"] += 5
+    tampered["sharding_fsdp"]["fsdp_gather_bytes"] *= 8
+    # the tp leg's combine lost, a donation that stopped happening, two
+    # layouts collapsed onto one executable
+    del tampered["sharding_fsdp_tp"]["tp_collectives"]["all_reduce"]
+    tampered["tp_all_reduce_present"] = False
+    tampered["baseline"]["donation"] = [0]
+    tampered["distinct_train_executables"] -= 1
     ok, delta = golden_mod.check(measured=tampered)
     assert not ok
     joined = "\n".join(delta)
-    assert "flat.collectives.all_reduce" in joined
-    assert "bucketed_sharded.rs_wire_bytes" in joined
-    assert "overlapped.collectives.reduce_scatter" in joined
-    assert "overlapped_wire_matches_bucketed" in joined
-    assert "native_int8.collectives.collective_permute" in joined
-    assert "native_int8.cp_wire_bytes" in joined
-    assert "native_int8.declared.native_hops" in joined
-    assert "native_int8_byte_exact" in joined
+    assert "sharding_fsdp.collectives.all_gather" in joined
+    assert "sharding_fsdp.fsdp_gather_bytes" in joined
+    assert "sharding_fsdp_tp.tp_collectives.all_reduce" in joined
+    assert "tp_all_reduce_present" in joined
+    assert "baseline.donation" in joined
+    assert "distinct_train_executables" in joined
     # the delta is field-level and readable: golden -> measured
     assert any("->" in line for line in delta)
-
-
-def test_overlapped_golden_leg_contract():
-    """The committed overlapped contract: one reduce-scatter launch per
-    bucket (a real multi-bucket pipeline), total wire bytes byte-for-byte
-    the bucketed leg's, verified accounting, own executable."""
-    contracts = golden_mod.load_goldens()
-    leg = contracts["overlapped"]
-    assert leg["declared"]["overlap"] is True
-    assert leg["declared"]["buckets"] >= 2
-    assert leg["declared"]["segments"] == leg["declared"]["buckets"]
-    assert leg["collectives"]["reduce_scatter"] == leg["declared"]["buckets"]
-    assert leg["collectives"]["all_gather"] == 1      # ZeRO-1 param gather
-    assert leg["rs_wire_bytes"] == \
-        contracts["bucketed_sharded"]["rs_wire_bytes"]
-    assert contracts["overlapped_wire_matches_bucketed"] is True
-    assert leg["accounting_verified"] is True
-
-
-def test_native_int8_golden_leg_contract():
-    """PR 16: the committed native-int8 contract. The DCN leg is a pure
-    collective-permute ring — (dcn-1) hops per bucket, NO reduce-scatter
-    or all-reduce — and the measured permute bytes equal the declared
-    packed payload+scale cost exactly: no simulated-wire exemption left."""
-    contracts = golden_mod.load_goldens()
-    leg = contracts["native_int8"]
-    d = leg["declared"]
-    assert d["native_int8"] is True and d["wire_dtype"] == "int8"
-    hier = d["hierarchy"]
-    assert hier["quantize_dcn"] is True
-    assert d["native_hops"] == d["buckets"] * (hier["dcn_axis"] - 1)
-    assert leg["by_axis"]["dcn"]["collective_permute"] == d["native_hops"]
-    assert "reduce_scatter" not in leg["by_axis"]["dcn"]
-    assert "all_reduce" not in leg["by_axis"]["dcn"]
-    # byte-exact: measured permute operands == declared DCN wire cost
-    assert leg["cp_wire_bytes"] == hier["dcn_wire_bytes_per_step"]
-    assert leg["dcn_wire_bytes"] == leg["cp_wire_bytes"]
-    assert contracts["native_int8_byte_exact"] is True
-    assert leg["accounting_verified"] is True
-    # the int8 hops genuinely shrink the DCN leg: well under the f32
-    # reduce-scatter bytes the ICI leg moves for the same gradients
-    assert leg["cp_wire_bytes"] * 3 < hier["ici_wire_bytes_per_step"]
 
 
 # ---------------------------------------------------------------------------
@@ -614,7 +628,7 @@ def test_repolint_registered_knob_read_is_legal(tmp_path):
     path = tmp_path / "clean.py"
     path.write_text('import os\n'
                     'a = os.environ.get("ZOO_H2D_LANES")\n'
-                    'b = os.getenv("ZOO_COMMS_PLANE")\n'
+                    'b = os.getenv("ZOO_SHARDING_PLANE")\n'
                     'c = "ZOO_FAULTS" in os.environ\n'
                     'd = os.environ["ZOO_COMPILE_CACHE"]\n')
     assert repolint.lint_file(str(path)) == []
@@ -641,14 +655,14 @@ def test_zoo_lint_cli_exit_codes(tmp_path, capsys):
 # knob registry
 # ---------------------------------------------------------------------------
 def test_knobs_typed_get_and_defaults(monkeypatch):
-    monkeypatch.delenv("ZOO_GRAD_BUCKET_MB", raising=False)
-    assert knobs.get("ZOO_GRAD_BUCKET_MB") == 0.0
-    monkeypatch.setenv("ZOO_GRAD_BUCKET_MB", "2.5")
-    assert knobs.get("ZOO_GRAD_BUCKET_MB") == 2.5
-    monkeypatch.setenv("ZOO_SHARDED_UPDATE", "0")
-    assert knobs.get("ZOO_SHARDED_UPDATE") is False
-    monkeypatch.setenv("ZOO_SHARDED_UPDATE", "1")
-    assert knobs.get("ZOO_SHARDED_UPDATE") is True
+    monkeypatch.delenv("ZOO_SERVING_SLACK_MS", raising=False)
+    assert knobs.get("ZOO_SERVING_SLACK_MS") == 5.0
+    monkeypatch.setenv("ZOO_SERVING_SLACK_MS", "2.5")
+    assert knobs.get("ZOO_SERVING_SLACK_MS") == 2.5
+    monkeypatch.setenv("ZOO_COMPILE_CACHE_DISABLE", "0")
+    assert knobs.get("ZOO_COMPILE_CACHE_DISABLE") is False
+    monkeypatch.setenv("ZOO_COMPILE_CACHE_DISABLE", "1")
+    assert knobs.get("ZOO_COMPILE_CACHE_DISABLE") is True
     monkeypatch.setenv("ZOO_H2D_LANES", "")      # empty == unset
     assert knobs.get("ZOO_H2D_LANES") == 2
     assert knobs.get("ZOO_H2D_LANES", default=7) == 7
@@ -670,15 +684,15 @@ def test_knobs_markdown_table_covers_registry():
 
 
 # ---------------------------------------------------------------------------
-# PR 12: per-axis accounting + hierarchical / multihost goldens
+# per-mesh-axis classification
 # ---------------------------------------------------------------------------
 def test_parse_collectives_group_shapes():
     """Replica-group shapes come out of both attribute formats — the
     stablehlo dense tensor and the HLO-text brace form — and classify
-    ICI vs DCN vs global legs."""
+    collectives onto the fsdp axis, the tp axis, or neither."""
     import textwrap
 
-    from analytics_zoo_tpu.analysis.hlo_lint import collectives_by_axis
+    from analytics_zoo_tpu.analysis.hlo_lint import collectives_by_mesh_axes
 
     mod = textwrap.dedent("""\
         module @jit_step {
@@ -706,124 +720,19 @@ def test_parse_collectives_group_shapes():
     ops = parse_collectives(mod)
     assert [op.group_shape for op in ops] == [(2, 4), (4, 2), (2, 4),
                                               (1, 8)]
-    ax = collectives_by_axis(ops, 4, 2)
-    assert ax["ici"] == {"reduce_scatter": 1, "all_gather": 1}
-    assert ax["dcn"] == {"all_reduce": 1}
+    ax = collectives_by_mesh_axes(ops, {"fsdp": 4, "tp": 2})
+    assert ax["by_axis"]["fsdp"] == {"reduce_scatter": 1, "all_gather": 1}
+    assert ax["by_axis"]["tp"] == {"all_reduce": 1}
     assert ax["global"] == {"all_reduce": 1}
-    assert ax["ici_wire_bytes"] == 64 * 4
-    assert ax["dcn_wire_bytes"] == 16 * 4
+    assert ax["axis_bytes"]["fsdp"] == {"reduce_scatter": 64 * 4,
+                                        "all_gather": 16 * 4}
+    assert ax["axis_bytes"]["tp"] == {"all_reduce": 16 * 4}
+    assert not ax["ambiguous"]
+    # two axes of one size cannot be told apart by group shape
+    assert collectives_by_mesh_axes(ops, {"fsdp": 2, "tp": 2})["ambiguous"]
     # HLO-text brace form (post-compile text, async start op)
     hlo = ('%rs = f32[16] reduce-scatter-start(f32[64] %p), '
            'replica_groups={{0,1,2,3},{4,5,6,7}}, dimensions={0}, '
            'to_apply=%add : (tensor<64xf32>) -> tensor<16xf32>')
     ops2 = parse_collectives(hlo)
     assert len(ops2) == 1 and ops2[0].group_shape == (2, 4)
-
-
-def test_hierarchical_golden_leg_contract():
-    """The committed hierarchical contract: per-axis launch counts (one
-    ICI reduce-scatter + one DCN reduce-scatter per bucket under ZeRO-1,
-    the two-stage param all-gather) and the DCN shrink pin."""
-    contracts = golden_mod.load_goldens()
-    entry = contracts["hierarchical"]
-    hier = entry["declared"]["hierarchy"]
-    assert (hier["ici_axis"], hier["dcn_axis"]) == (4, 2)
-    buckets = entry["declared"]["buckets"]
-    assert buckets >= 2
-    assert entry["by_axis"]["ici"]["reduce_scatter"] == buckets
-    assert entry["by_axis"]["dcn"]["reduce_scatter"] == buckets
-    assert entry["by_axis"]["ici"]["all_gather"] == 1
-    assert entry["by_axis"]["dcn"]["all_gather"] == 1
-    assert entry["accounting_verified"] is True
-    assert entry["dcn_wire_bytes"] * 4 == entry["ici_wire_bytes"]
-    assert contracts["hierarchical_dcn_shrink_ok"] is True
-
-
-def test_golden_gate_fails_on_dcn_byte_regression():
-    """Moving gradient bytes onto the cross-host links must fail the
-    gate even when total launches/bytes stay plausible."""
-    contracts = golden_mod.load_goldens()
-    tampered = json.loads(json.dumps(contracts))      # deep copy
-    tampered["hierarchical"]["dcn_wire_bytes"] *= 4
-    tampered["hierarchical"]["by_axis"]["dcn"]["reduce_scatter"] += 1
-    ok, delta = golden_mod.check(measured=tampered)
-    assert not ok
-    joined = "\n".join(delta)
-    assert "hierarchical.dcn_wire_bytes" in joined
-    assert "hierarchical.by_axis.dcn.reduce_scatter" in joined
-
-
-def test_multihost_golden_matches_simulated_capture(orca_context):
-    """The committed multihost contract regenerates exactly on the
-    single-process simulated mesh (the program depends only on the
-    (n_dev, dcn, ici) factorization) — so the contract is enforced
-    everywhere, and the two-process harness additionally proves the
-    real topology lowers to the same program."""
-    measured = golden_mod.capture_multihost_contract(dcn=2)
-    ok, delta = golden_mod.check_multihost(measured)
-    assert ok, "multihost contract drifted:\n" + "\n".join(delta)
-    assert measured["accounting_verified"] is True
-    assert measured["dcn_wire_bytes"] == measured["declared_dcn_wire_bytes"]
-
-
-def test_accounting_hier_ici_eq_dcn_checks_kinds_and_bytes():
-    """ici == dcn meshes: group shapes coincide, but collective kinds and
-    combined wire bytes are still verified — a byte regression on the
-    grouped legs cannot pass as 'ambiguous'."""
-    import textwrap
-
-    mod = textwrap.dedent("""\
-        module @jit_step {
-          func.func public @main(%arg0: tensor<64xf32>) -> tensor<64xf32> {
-            %0 = "stablehlo.reduce_scatter"(%arg0) <{replica_groups = dense<[[0, 1], [2, 3]]> : tensor<2x2xi64>, scatter_dimension = 0 : i64}> ({
-            ^bb0(%a: tensor<f32>, %b: tensor<f32>):
-              %s = stablehlo.add %a, %b : tensor<f32>
-              stablehlo.return %s : tensor<f32>
-            }) : (tensor<64xf32>) -> tensor<32xf32>
-            %1 = "stablehlo.all_reduce"(%0) <{replica_groups = dense<[[0, 2], [1, 3]]> : tensor<2x2xi64>}> ({
-            ^bb0(%a: tensor<f32>, %b: tensor<f32>):
-              %s = stablehlo.add %a, %b : tensor<f32>
-              stablehlo.return %s : tensor<f32>
-            }) : (tensor<32xf32>) -> tensor<32xf32>
-            %2 = "stablehlo.all_gather"(%1) <{all_gather_dim = 0 : i64, replica_groups = dense<[[0, 1], [2, 3]]> : tensor<2x2xi64>}> : (tensor<32xf32>) -> tensor<64xf32>
-            %3 = "stablehlo.all_reduce"(%2) <{replica_groups = dense<[[0, 1, 2, 3]]> : tensor<1x4xi64>}> ({
-            ^bb0(%a: tensor<f32>, %b: tensor<f32>):
-              %s = stablehlo.add %a, %b : tensor<f32>
-              stablehlo.return %s : tensor<f32>
-            }) : (tensor<64xf32>) -> tensor<64xf32>
-            return %3 : tensor<64xf32>
-          }
-        }
-        """)
-    declared = {"buckets": 1, "sharded_update": False, "wire_dtype": "f32",
-                "grad_leaves": 3, "collectives_per_step": 3,
-                "wire_bytes_per_step": 64 * 4 + 32 * 4,
-                "hierarchy": {"active": True, "ici_axis": 2, "dcn_axis": 2,
-                              "quantize_dcn": True,
-                              "ici_wire_bytes_per_step": 64 * 4,
-                              "dcn_wire_bytes_per_step": 32 * 4}}
-    linter = HloLinter()
-    assert not linter.lint_text(mod, label="train", declared=declared)
-    # combined grouped bytes drift -> caught even without a per-leg split
-    bad = json.loads(json.dumps(declared))
-    bad["hierarchy"]["dcn_wire_bytes_per_step"] += 64
-    found = linter.lint_text(mod, label="train", declared=bad)
-    assert found and any("ici==dcn" in f.message for f in found)
-    # a lost param all-gather is caught by kind
-    bad2 = mod.replace("all_gather", "all_gather_DISABLED")
-    found2 = linter.lint_text(bad2, label="train", declared=declared)
-    assert found2 and any("all-gather" in f.message for f in found2)
-
-
-def test_hier_capture_on_ici_eq_dcn_mesh_verifies(orca_context):
-    """The placement-free multihost capture on a 4-device (2-host x
-    2-chip) submesh — the ici==dcn case end-to-end through the real
-    lowered program."""
-    import jax as _jax
-
-    from analytics_zoo_tpu.parallel.mesh import create_mesh
-
-    mesh = create_mesh({"dp": -1}, devices=_jax.devices()[:4])
-    contract = golden_mod.capture_multihost_contract(mesh, dcn=2)
-    assert (contract["ici_axis"], contract["dcn_axis"]) == (2, 2)
-    assert contract["accounting_verified"] is True, contract

@@ -9,18 +9,10 @@ scripts/launch_multihost.sh on localhost:
 * ``test_two_process_multihost`` — global-array assembly + one jitted
   TrainEngine step whose gradients reduce across the process boundary
   (skips on jaxlib builds without multiprocess CPU collectives).
-* ``test_multihost_golden_contract`` — the hierarchical comms plane's
-  program contract on the real 2-process topology: the ``(dcn, ici)``
-  factorization probed from process locality, cross-host launch counts
-  and DCN wire bytes diffed against ``tests/goldens/
-  multihost_contracts.json``. Lowering-only, so it runs even where the
-  execution test must skip.
 
 The worker-subprocess scaffolding (port allocation + bind-race retry,
 timeout kill, output surfacing) lives in ``tests/multihost_harness.py``.
 """
-
-import json
 
 import pytest
 
@@ -63,22 +55,6 @@ assert np.isfinite(loss)
 print("WORKER_OK %d %.5f" % (pid, loss))
 stop_orca_context()
 '''
-
-# golden worker: 4 virtual devices per process -> the (dcn=2, ici=4)
-# factorization the committed contract pins, PROBED from process
-# locality (dcn=0). Lowering only — no cross-process execution.
-_GOLDEN_WORKER = WORKER_PREAMBLE + r'''
-assert ctx.num_devices == 8
-
-from analytics_zoo_tpu.analysis.golden import capture_multihost_contract
-import json
-contract = capture_multihost_contract(ctx.mesh, dcn=0)
-if pid == 0:
-    print("MH_CONTRACT " + json.dumps(contract))
-print("WORKER_OK %d" % pid)
-stop_orca_context()
-'''
-
 
 # a lost free_port() race, in miniature: the first round's "coordinator"
 # reports the bind failure and dies, the retry round (fresh port) succeeds
@@ -124,33 +100,3 @@ def test_two_process_multihost(tmp_path):
         losses.append(float(out.split(f"WORKER_OK {i}")[1].split()[0]))
     # SPMD: both controllers must compute the identical global loss
     assert losses[0] == losses[1], losses
-
-
-def test_multihost_golden_contract(tmp_path):
-    """The first committed MULTIHOST program contract: two real
-    processes build the global 8-device mesh, the topology probe factors
-    dp into (dcn=2, ici=4) from process locality, and the hierarchical
-    train step's lowered per-axis launch counts + DCN wire bytes must
-    match tests/goldens/multihost_contracts.json field for field."""
-    from analytics_zoo_tpu.analysis.golden import check_multihost
-
-    run = run_workers(_GOLDEN_WORKER, tmp_path, devices_per_proc=4)
-    if run.timed_out:
-        pytest.fail("multihost golden worker timed out; captured "
-                    "output:\n" + run.tail())
-    if run.no_collectives and not run.ok:
-        # lowering needs no cross-process execution, so only an init-time
-        # failure on a collectives-free jaxlib justifies skipping
-        pytest.skip(NO_COLLECTIVES_SKIP)
-    for i, (rc, out) in enumerate(zip(run.returncodes, run.outs)):
-        assert rc == 0, f"proc{i} failed:\n{out[-3000:]}"
-        assert f"WORKER_OK {i}" in out, out[-2000:]
-    line = [l for l in run.outs[0].splitlines()
-            if l.startswith("MH_CONTRACT ")]
-    assert line, run.outs[0][-2000:]
-    measured = json.loads(line[0][len("MH_CONTRACT "):])
-    assert measured["dcn_axis"] == 2 and measured["ici_axis"] == 4, (
-        "topology probe did not factor the 2-process mesh", measured)
-    ok, delta = check_multihost(measured)
-    assert ok, ("multihost golden contract drifted "
-                "(golden -> measured):\n  " + "\n  ".join(delta))

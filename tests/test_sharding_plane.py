@@ -4,19 +4,20 @@ mesh — fsdp bucketed param gathers + tp layers through one layout object
 
 Numerics contract under test, on the 8-device f32 CPU mesh:
 
-* FsdpPlan composite ↔ canonical tree conversions round-trip bit-exactly
-  (they ride BucketLayout's already-tested padding arithmetic);
-* sharded (fsdp×tp) training == replicated training on the SAME mesh, bit
-  for bit under SGD — the gathers and the output-dim splits preserve
-  elementwise order. adam is allclose-only: XLA fuses its sqrt/div chain
-  program-dependently (~1 ulp), while the GRADS stay bit-identical (the
-  SGD leg proves it);
+* BucketLayout's flatten/unflatten and FsdpPlan's composite ↔ canonical
+  tree conversions round-trip bit-exactly (host-side data movement, no
+  arithmetic);
+* sharded (fsdp×tp) training agrees with replicated training on the SAME
+  mesh to rounding — two XLA programs, so never `==`; each tolerance is
+  ten times the gap measured here (tests/test_train_layouts.py holds every
+  layout to one device the same way);
 * checkpoints store canonical tree form, so fsdp-sharded ↔ replicated
-  restores are bit-exact in BOTH directions (the PR 8/12 contract);
-* serving through a sharded InferenceModel predicts bit-identically to
-  the replicated layout while each device holds ~1/fsdp of the weights;
+  restores are bit-exact in BOTH directions;
+* serving through a sharded InferenceModel predicts what the replicated
+  layout predicts to a few ulps while each device holds ~1/fsdp of the
+  weights;
 * the compiled train program's per-axis collectives match the engine's
-  declared accounting (hlo_lint's sharding rule).
+  declared accounting (hlo_lint's accounting rule).
 """
 
 import numpy as np
@@ -28,7 +29,8 @@ from jax.sharding import PartitionSpec as P
 
 from analytics_zoo_tpu.orca.learn.estimator import TPUEstimator
 from analytics_zoo_tpu.parallel.mesh import create_mesh, parse_mesh_axes
-from analytics_zoo_tpu.parallel.sharding import FsdpPlan, SpecLayout
+from analytics_zoo_tpu.parallel.sharding import (BucketLayout, FsdpPlan,
+                                                 SpecLayout)
 from analytics_zoo_tpu.parallel.tensor_parallel import TPMLP
 
 
@@ -84,6 +86,93 @@ def _tree_equal(a, b):
         np.asarray(x).shape == np.asarray(y).shape
         and (np.asarray(x) == np.asarray(y)).all()
         for x, y in zip(la, lb))
+
+
+def _assert_within_ulps(got, want, ulps):
+    """|got - want| in float32 ulps at the largest |want|. Counting each
+    element's own ulps would read the same absolute rounding as thousands
+    of ulps wherever a prediction lands near zero."""
+    got, want = np.asarray(got), np.asarray(want)
+    assert got.shape == want.shape
+    one = np.spacing(np.float32(np.abs(want).max()))
+    assert np.abs(got - want).max() <= ulps * one
+
+
+# two programs over one set of weights: sharded serving splits output
+# features over fsdp, and XLA vectorises the narrower matmuls otherwise.
+# Measured here: 1.5 ulps on these tests' own inputs, 4.1 over 180 probes
+# of 20 random initialisations; ten times that, rounded up
+SERVING_ULPS = 64
+
+
+# --- BucketLayout: the flat vector under the fsdp buckets --------------------
+def _random_tree(seed=0):
+    rng = np.random.RandomState(seed)
+    return {"a": {"kernel": rng.randn(7, 5).astype(np.float32),
+                  "bias": rng.randn(5).astype(np.float32)},
+            "b": [rng.randn(3, 3, 2).astype(np.float32),
+                  rng.randn(1).astype(np.float32)],
+            "c": rng.randn(131).astype(np.float32)}
+
+
+def test_bucket_round_trip_bit_exact(orca_context):
+    tree = _random_tree()
+    lo = BucketLayout.build(tree, 8, 0.0005)     # tiny buckets -> several
+    assert len(lo.bucket_sizes) > 1
+    assert all(b % 8 == 0 for b in lo.bucket_sizes)
+    assert lo.padded_total == sum(lo.bucket_sizes) == 8 * lo.shard_size
+    assert lo.total == sum(lo.sizes) <= lo.padded_total
+
+    flat = lo.flatten_np(tree)
+    assert flat.shape == (lo.padded_total,)
+    assert (flat[lo.total:] == 0).all()
+    # on the host and traced (what a jitted step runs), unflatten inverts
+    # the flatten exactly
+    for back in (lo.unflatten(flat), jax.jit(lo.unflatten)(flat)):
+        for a, b in zip(jax.tree_util.tree_leaves(tree),
+                        jax.tree_util.tree_leaves(back)):
+            assert a.dtype == np.asarray(b).dtype and a.shape == b.shape
+            assert (np.asarray(a) == np.asarray(b)).all()
+
+
+def test_layout_deterministic(orca_context):
+    tree = _random_tree()
+    assert BucketLayout.build(tree, 8, 0.0005).signature() == \
+        BucketLayout.build(tree, 8, 0.0005).signature()
+    # another bucket size, or another axis size, is another layout
+    assert BucketLayout.build(tree, 8, 0.001).signature() != \
+        BucketLayout.build(tree, 8, 0.0005).signature()
+    assert BucketLayout.build(tree, 4, 0.0005).signature() != \
+        BucketLayout.build(tree, 8, 0.0005).signature()
+
+
+def test_non_f32_leaf_rejected(orca_context):
+    # the flat vector is f32: ints would round, narrow floats would change
+    # precision through it, so neither round-trips and both are refused
+    for bad in (np.ones(4, np.int32), np.ones(4, np.float16)):
+        with pytest.raises(ValueError, match="f32"):
+            BucketLayout.build({"w": bad}, 8, 0.0)
+
+
+def test_grad_allreduce_mean_skips_absent_axes(orca_context):
+    """Regression: the default ``axes=("dp", "fsdp")`` used to raise inside
+    any mesh that does not bind an ``fsdp`` axis (e.g. a user's 1-D
+    ``Mesh(devices, ("dp",))``)."""
+    from jax import shard_map
+    from jax.sharding import Mesh
+
+    from analytics_zoo_tpu.parallel import collective as C
+
+    mesh = Mesh(np.asarray(jax.devices()), ("dp",))
+    x = np.arange(8, dtype=np.float32).reshape(8, 1)
+    out = jax.jit(shard_map(lambda v: C.grad_allreduce_mean(v),
+                            mesh=mesh, in_specs=P("dp"),
+                            out_specs=P("dp")))(x)
+    np.testing.assert_array_equal(np.asarray(out), np.full((8, 1), 3.5))
+    # but NO bound axis at all still fails loudly — a silent no-op would
+    # let replicas diverge
+    with pytest.raises(NameError, match="none of the axes"):
+        jax.jit(lambda v: C.grad_allreduce_mean(v))(x)
 
 
 # --- SpecLayout resolution + rules ------------------------------------------
@@ -187,23 +276,23 @@ def test_fsdp_plan_none_when_nothing_rides(orca_context):
                           create_mesh({"dp": 1, "fsdp": -1})) is None
 
 
-# --- training bit-identity ---------------------------------------------------
-def test_sharded_train_bit_identical_sgd(orca_context):
-    """fsdp×tp vs replicated on the SAME mesh, SGD: losses and canonical
-    params bit for bit."""
+# --- sharded against replicated on one mesh ----------------------------------
+def test_sharded_train_matches_replicated_sgd(orca_context):
+    """fsdp×tp vs replicated on the SAME mesh, SGD, 12 steps. The gathers
+    and the output-dim splits keep each element's sum in order, so the two
+    programs came out equal to the bit where this was measured (gap 0);
+    the bound is the floor tests/test_train_layouts.py uses."""
     mesh = create_mesh({"dp": 1, "fsdp": 4, "tp": 2})
     ls, es = _fit(mesh, TPNet(), SpecLayout())
     lr, er = _fit(mesh, TPNet(), False)
     assert es.engine.fsdp_plan is not None
-    assert ls == lr
-    ws, wr = _canon_params(es), _canon_params(er)
-    assert ws.shape == wr.shape and (ws == wr).all()
+    np.testing.assert_allclose(ls, lr, rtol=0, atol=1e-6)
+    np.testing.assert_allclose(_canon_params(es), _canon_params(er),
+                               rtol=0, atol=1e-6)
 
 
 def test_sharded_train_adam_allclose(orca_context):
-    """adam's compound sqrt/div fuses program-dependently (~1 ulp); the
-    contract there is tight allclose, with losses still bit-equal at
-    these step counts."""
+    """adam's compound sqrt/div fuses program-dependently (~1 ulp)."""
     mesh = create_mesh({"dp": 1, "fsdp": 4, "tp": 2})
     ls, es = _fit(mesh, MLP(), SpecLayout(), optimizer="adam")
     lr, er = _fit(mesh, MLP(), False, optimizer="adam")
@@ -261,7 +350,7 @@ def test_ckpt_manifest_records_sharding(orca_context, tmp_path):
 
 
 # --- serving -----------------------------------------------------------------
-def test_serving_sharded_bit_identical(orca_context):
+def test_serving_sharded_matches_replicated(orca_context):
     from analytics_zoo_tpu.pipeline.inference.inference_model import \
         InferenceModel
     mesh = create_mesh({"dp": 1, "fsdp": 4, "tp": 2})
@@ -272,8 +361,7 @@ def test_serving_sharded_bit_identical(orca_context):
         m, variables)
     rep = InferenceModel(mesh=mesh).load_jax(m, variables)
     xq = np.random.RandomState(1).randn(13, 16).astype(np.float32)
-    ps, pr = shd.predict(xq), rep.predict(xq)
-    assert (np.asarray(ps) == np.asarray(pr)).all()
+    _assert_within_ulps(shd.predict(xq), rep.predict(xq), SERVING_ULPS)
 
     def dev_bytes(model):
         return sum(int(leaf.addressable_shards[0].data.nbytes)
@@ -299,8 +387,7 @@ def test_serving_hot_swap_keeps_layout(orca_context):
                                  "extra_vars": {}}}, 7)
     rep = InferenceModel(mesh=mesh).load_jax(m, v2)
     xq = np.random.RandomState(2).randn(9, 16).astype(np.float32)
-    assert (np.asarray(im.predict(xq))
-            == np.asarray(rep.predict(xq))).all()
+    _assert_within_ulps(im.predict(xq), rep.predict(xq), SERVING_ULPS)
     shards = {str(l.sharding.spec) for l in
               jax.tree_util.tree_leaves(im._variables)}
     assert any("fsdp" in s for s in shards)
@@ -329,7 +416,7 @@ def test_compiled_accounting_verified(orca_context):
     exist post-SPMD-partitioner): fsdp gathers in whole sweeps with
     declared bytes, grad combine present, tp collective present."""
     from analytics_zoo_tpu.analysis.hlo_lint import (
-        HloLinter, collectives_by_mesh_axes, declared_comms,
+        HloLinter, collectives_by_mesh_axes, declared_accounting,
         parse_collectives)
     mesh = create_mesh({"dp": 1, "fsdp": 4, "tp": 2})
     est = TPUEstimator(TPNet(), loss="mse", optimizer="sgd", seed=0,
@@ -342,7 +429,7 @@ def test_compiled_accounting_verified(orca_context):
     est.engine.build(tuple(np.asarray(a) for a in b0.x))
     fn = est.engine.ensure_jit_train()
     text = fn.lower(*est.engine.train_step_args(b0)).compile().as_text()
-    declared = declared_comms(est.engine._sharding_key())
+    declared = declared_accounting(est.engine._sharding_key())
     assert declared is not None and declared["plane"] == "sharding"
     assert HloLinter().lint_text(text, label="t:train",
                                  declared=declared) == []
