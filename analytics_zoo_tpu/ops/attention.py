@@ -33,12 +33,31 @@ _REFERENCE_ON_TPU = _REGISTRY.counter(
     "flash_attention call sites traced on a TPU that fell through to "
     "mha_reference (sequence not tileable, or causal with s_q > s_k)")
 
+# which backward a run compiled: one fused launch a call site, or the dQ and
+# dK/dV pair (a dQ accumulator past ``_FUSED_BWD_DQ_BYTES``)
+_BACKWARD_PATHS = _REGISTRY.counter(
+    "zoo_attention_backward_total",
+    "flash_attention backward call sites traced, by the kernels they "
+    "launch: fused (dQ beside dK/dV, one launch) or two_kernel",
+    labelnames=("path",))
+_BACKWARD_FUSED = _BACKWARD_PATHS.labels(path="fused")
+_BACKWARD_TWO_KERNEL = _BACKWARD_PATHS.labels(path="two_kernel")
+
 NEG_INF = -1e30
 LOG2_E = 1.4426950408889634      # the flash kernel softmaxes in base 2
 # the forward kernel's output and logsumexp, as ``checkpoint_name`` marks
 # them in ``_flash_fwd``: a remat policy that saves these names runs the
 # forward kernel once a step, not again in the backward pass
 FLASH_RESIDUAL_NAMES = ("flash_out", "flash_lse")
+MIB = 2 ** 20
+# Mosaic's default scoped VMEM: what every flash kernel's tiles fit today
+_SCOPED_VMEM_BYTES = 16 * MIB
+# The fused backward holds one (batch, head)'s whole dQ in VMEM: a float32
+# accumulator and the double-buffered output block, lanes padded to 128.
+# Up to this many bytes of them (three eighths of a v5e's 128 MiB; the
+# token cell's 8192 x 192 in bf16 takes 16 MiB) dQ rides the dK/dV launch;
+# longer sequences keep the dQ kernel of their own.
+_FUSED_BWD_DQ_BYTES = 48 * MIB
 
 
 def mha_reference(q: jax.Array, k: jax.Array, v: jax.Array,
@@ -107,7 +126,7 @@ def blockwise_attention(q, k, v, *, causal: bool = False,
     block_k=512), not fully linear. For truly linear-in-S training memory
     shard the sequence instead (parallel/ring_attention.py). Historical
     note: this was the flash backward through round 3; round 4 replaced it
-    with dedicated Pallas dQ/dKV kernels (``_flash_bwd``) whose tiles stay
+    with dedicated Pallas backward kernels (``_flash_bwd``) whose tiles stay
     in VMEM — blockwise_attention remains as the ring-attention building
     block and a host-portable exact-attention fallback."""
     if sm_scale is None:
@@ -152,25 +171,33 @@ def blockwise_attention(q, k, v, *, causal: bool = False,
 
 def _flash_kernel(q_ref, k_ref, v_ref, o_ref, *rest, block_q,
                   block_k, num_k_blocks, causal, head_dim, q_offset=0,
-                  with_lse=False):
+                  with_lse=False, ones_column=True):
     """Grid = (batch*heads, num_q_blocks, num_k_blocks); the k dim is innermost
     so (acc, m) scratch carries the online softmax across k iterations.
     With ``with_lse`` the kernel also emits the log2-domain logsumexp
     (m + log2 l) per q row, which the Pallas backward consumes.
 
-    ``v_ref`` arrives AUGMENTED with a trailing ones column
-    (_flash_forward), so the p @ v matmul computes the softmax normalizer
-    l = sum(p) in its last output column for free: at D=64 the matmul's N
-    dim uses half the MXU lanes anyway, and the separate sum(p) reduction
-    was one of the (block_q, block_k) VPU passes this VPU-bound kernel is
-    made of. acc's last column carries l (the rescale correction applies
-    to it identically)."""
+    The softmax normaliser l = sum(p) comes one of two ways, by v's head
+    size (``_flash_forward`` decides):
+
+    - ``ones_column``: ``v_ref`` arrives AUGMENTED with a trailing ones
+      column, so the p @ v product leaves l in its last output column and
+      acc's last column carries it (the rescale correction applies to it
+      identically). Free where d_v is no multiple of 128 (the BERT and
+      Transformer layers' 64): the product's N dim pads to the next 128
+      lanes either way, and the row reduction it replaces is a (block_q,
+      block_k) VPU/XLU pass.
+    - otherwise (d_v a multiple of 128: MLA's 128) the column would cost the
+      product a whole MXU pass of its own (N 129 pads to 256), so v goes in
+      as it is and l is a running float32 row sum in scratch beside m."""
     import jax.experimental.pallas as pl  # local import keeps module cpu-safe
 
     if with_lse:
-        lse_ref, acc_ref, m_ref = rest
-    else:
+        lse_ref, *rest = rest
+    if ones_column:
         acc_ref, m_ref = rest
+    else:
+        acc_ref, m_ref, l_ref = rest
     q_idx = pl.program_id(1)
     k_idx = pl.program_id(2)
 
@@ -178,6 +205,8 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, *rest, block_q,
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
         m_ref[...] = jnp.full_like(m_ref, NEG_INF)
+        if not ones_column:
+            l_ref[...] = jnp.zeros_like(l_ref)
 
     q_start = q_idx * block_q
     k_start = k_idx * block_k
@@ -187,8 +216,7 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, *rest, block_q,
         # rate) with f32 accumulation; softmax state is always f32.
         # q arrives PRE-SCALED by sm_scale*log2(e) (_flash_forward), so the
         # scores are already in the log2 domain: one fewer (block_q,
-        # block_k) multiply per tile, and exp2 instead of exp — at D=64
-        # the kernel is VPU-bound on exactly these elementwise passes.
+        # block_k) multiply per tile, and exp2 instead of exp.
         q = q_ref[0]                                     # (block_q, D)
         k = k_ref[0]                                     # (block_k, D)
         s = jax.lax.dot_general(
@@ -208,7 +236,11 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, *rest, block_q,
         p = jnp.exp2(s - m_new)                          # (block_q, block_k)
         correction = jnp.exp2(m_prev - m_new)            # (block_q, 1)
         m_ref[...] = jnp.broadcast_to(m_new, m_ref.shape)
-        v = v_ref[0]                                     # (block_k, D+1)
+        if not ones_column:
+            l_ref[...] = jnp.broadcast_to(
+                l_ref[:, :1] * correction +
+                jnp.sum(p, axis=-1, keepdims=True), l_ref.shape)
+        v = v_ref[0]                          # (block_k, D_v [+ 1 of ones])
         acc_ref[...] = (acc_ref[...] * correction +
                         jnp.dot(p.astype(v.dtype), v,
                                 preferred_element_type=jnp.float32))
@@ -217,7 +249,7 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, *rest, block_q,
         # Three tile classes: fully masked (skip), diagonal (mask), and
         # interior (q_pos >= k_pos everywhere — no mask work: the two
         # iotas + compare + select are (block_q, block_k) VPU passes that
-        # would otherwise run on every tile of a VPU-bound kernel).
+        # would otherwise run on every tile).
         active = q_offset + q_start + block_q - 1 >= k_start
         diagonal = q_offset + q_start < k_start + block_k - 1
         pl.when(active & diagonal)(lambda: _compute(True))
@@ -228,7 +260,8 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, *rest, block_q,
     @pl.when(k_idx == num_k_blocks - 1)
     def _finalize():
         acc = acc_ref[...]
-        l = jnp.maximum(acc[:, head_dim:head_dim + 1], 1e-30)
+        l = acc[:, head_dim:head_dim + 1] if ones_column else l_ref[:, :1]
+        l = jnp.maximum(l, 1e-30)
         o_ref[0] = (acc[:, :head_dim] / l).astype(o_ref.dtype)
         if with_lse:
             # p_ij = exp2(s2_ij - L2_i) with L2 = m + log2 l (log2 domain)
@@ -237,12 +270,13 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, *rest, block_q,
 
 @functools.lru_cache(maxsize=1)
 def _mosaic_params():
-    """Grid dimension semantics for all three flash kernels: dims 0/1
-    (batch*heads and the non-carry sequence dim) are parallel, the
+    """Grid dimension semantics of the forward, dQ and dK/dV kernels: dims
+    0/1 (batch*heads and the non-carry sequence dim) are parallel, the
     innermost dim carries online-softmax / accumulator state and must stay
     ordered. Parallel dims let Mosaic overlap the next tile's DMA with the
     current tile's compute instead of treating the whole grid as one
-    sequential loop."""
+    sequential loop. (The fused backward carries dQ across both sequence
+    dims and states its own.)"""
     from jax.experimental.pallas import tpu as pltpu
     return pltpu.CompilerParams(
         dimension_semantics=("parallel", "parallel", "arbitrary"))
@@ -281,10 +315,14 @@ def _flash_forward(q, k, v, causal, sm_scale, block_q, block_k, interpret,
     qf = jnp.moveaxis(qf, 2, 1).reshape(b * h, s_q, d)
     kf = jnp.moveaxis(k, 2, 1).reshape(b * h, s_k, d)
     vf = jnp.moveaxis(v, 2, 1).reshape(b * h, s_k, d_v)
-    # ones column: p @ [v | 1] yields the softmax normalizer in the last
-    # output column on the MXU (free at D=64 — see _flash_kernel)
-    vf = jnp.concatenate(
-        [vf, jnp.ones((b * h, s_k, 1), vf.dtype)], axis=-1)
+    # ones column: p @ [v | 1] yields the softmax normaliser in the last
+    # output column on the MXU, where that column is free: not at a d_v
+    # that already fills whole 128-lane tiles (see _flash_kernel)
+    ones_column = d_v % 128 != 0
+    if ones_column:
+        vf = jnp.concatenate(
+            [vf, jnp.ones((b * h, s_k, 1), vf.dtype)], axis=-1)
+    d_acc = vf.shape[-1]
 
     block_q = min(block_q, s_q)
     block_k = min(block_k, s_k)
@@ -295,7 +333,7 @@ def _flash_forward(q, k, v, causal, sm_scale, block_q, block_k, interpret,
     kernel = functools.partial(
         _flash_kernel, block_q=block_q, block_k=block_k,
         num_k_blocks=num_k, causal=causal, head_dim=d_v,
-        q_offset=s_k - s_q, with_lse=with_lse)
+        q_offset=s_k - s_q, with_lse=with_lse, ones_column=ones_column)
     # Under shard_map (e.g. Ulysses sequence parallelism) the output must
     # declare which mesh axes it varies over. Use the union of the inputs'
     # varying sets and lift any less-varying input up to it so mixed-vma
@@ -337,14 +375,15 @@ def _flash_forward(q, k, v, causal, sm_scale, block_q, block_k, interpret,
         in_specs=[
             pl.BlockSpec((1, block_q, d), lambda bh, qi, ki: (bh, qi, 0)),
             pl.BlockSpec((1, block_k, d), k_index),
-            pl.BlockSpec((1, block_k, d_v + 1), k_index),
+            pl.BlockSpec((1, block_k, d_acc), k_index),
         ],
         out_specs=out_specs,
         out_shape=out_shape,
         scratch_shapes=[
-            pltpu.VMEM((block_q, d_v + 1), jnp.float32),  # acc | l column
-            pltpu.VMEM((block_q, 128), jnp.float32),
-        ],
+            pltpu.VMEM((block_q, d_acc), jnp.float32),    # acc [| l column]
+            pltpu.VMEM((block_q, 128), jnp.float32),      # m
+        ] + ([] if ones_column else
+             [pltpu.VMEM((block_q, 128), jnp.float32)]),  # l
         # bh/q grid dims carry no state between steps — declaring them
         # parallel lets Mosaic double-buffer the next tile's DMA behind
         # this tile's compute; only the k dim (online-softmax carry) is
@@ -418,7 +457,9 @@ def _flash_bwd_dq_kernel(q2_ref, k_ref, v_ref, g_ref, L_ref, D_ref, dq_ref,
     accumulates across k iterations in VMEM scratch — no (S, S) tensor
     ever reaches HBM (the round-3 pure-JAX backward streamed every P/dS
     tile through HBM between the dot_generals, which bounded fwd+bwd at
-    ~1.4x materialized; tiles resident in VMEM are the FA-2 design)."""
+    ~1.4x materialized; tiles resident in VMEM are the FA-2 design). Runs
+    only where a (batch, head)'s whole dQ is past the fused kernel's VMEM
+    budget (``_flash_bwd``)."""
     import jax.experimental.pallas as pl
 
     q_idx = pl.program_id(1)
@@ -453,11 +494,15 @@ def _flash_bwd_dq_kernel(q2_ref, k_ref, v_ref, g_ref, L_ref, D_ref, dq_ref,
 
 def _flash_bwd_dkv_kernel(q2_ref, k_ref, v_ref, g_ref, L_ref, D_ref,
                           dk_ref, dv_ref, dk_acc, dv_acc, *, block_q,
-                          block_k, num_q_blocks, causal, q_offset, cd):
+                          block_k, num_q_blocks, causal, q_offset, cd,
+                          dq=None):
     """dK/dV pass: grid (batch*heads, num_k, num_q), q innermost; both
     accumulators live in VMEM scratch. dv += P^T g and dk += dS^T q2 are
     expressed as dot_generals contracting the q (sublane) dim. q2 is the
-    log2-prescaled q, so dk carries a 1/log2(e) correction at finalize."""
+    log2-prescaled q, so dk carries a 1/log2(e) correction at finalize.
+
+    ``dq`` is the fused kernel's (see ``_flash_bwd_fused_kernel``): given,
+    each tile's dS also goes into dQ."""
     import jax.experimental.pallas as pl
 
     k_idx = pl.program_id(1)
@@ -470,6 +515,14 @@ def _flash_bwd_dkv_kernel(q2_ref, k_ref, v_ref, g_ref, L_ref, D_ref,
 
     q_start = q_idx * block_q
     k_start = k_idx * block_k
+    if dq is not None:
+        dq_ref, dq_acc, sm_scale, num_k_blocks = dq
+        rows = pl.ds(pl.multiple_of(q_start, block_q), block_q)
+
+        @pl.when(k_idx == 0)
+        def _init_dq():
+            dq_acc[rows, :] = jnp.zeros((block_q, dq_acc.shape[-1]),
+                                        dq_acc.dtype)
 
     def _compute(masked):
         g = g_ref[0]
@@ -482,6 +535,9 @@ def _flash_bwd_dkv_kernel(q2_ref, k_ref, v_ref, g_ref, L_ref, D_ref,
         dk_acc[...] += jax.lax.dot_general(
             ds, q2_ref[0], (((0,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
+        if dq is not None:
+            dq_acc[rows, :] += jnp.dot(ds, k_ref[0],
+                                       preferred_element_type=jnp.float32)
 
     if causal:
         active = q_offset + q_start + block_q - 1 >= k_start
@@ -495,6 +551,41 @@ def _flash_bwd_dkv_kernel(q2_ref, k_ref, v_ref, g_ref, L_ref, D_ref,
     def _finalize():
         dk_ref[0] = (dk_acc[...] * (1.0 / LOG2_E)).astype(dk_ref.dtype)
         dv_ref[0] = dv_acc[...].astype(dv_ref.dtype)
+
+    if dq is not None:
+        # a q tile's rows are whole once the last k block has passed them,
+        # masked tiles too: written a tile at a time, never the whole
+        # sequence in one statement
+        @pl.when(k_idx == num_k_blocks - 1)
+        def _finalize_dq():
+            dq_ref[0, rows, :] = (dq_acc[rows, :] * sm_scale).astype(
+                dq_ref.dtype)
+
+
+def _flash_bwd_fused_kernel(q2_ref, k_ref, v_ref, g_ref, L_ref, D_ref,
+                            dq_ref, dk_ref, dv_ref, dq_acc, dk_acc, dv_acc,
+                            *, sm_scale, num_k_blocks, **tiles):
+    """The whole backward in one launch: the dK/dV pass (same grid, q
+    innermost) whose every tile also adds dS . k into a float32 accumulator
+    for ALL of this (batch, head)'s queries, (s_q, d) in VMEM scratch, at
+    the tile's rows. Each (block_q, block_k) score tile, its exp2 and dP are
+    rebuilt once a step, not once in each of two kernels. ``dq_ref`` is the
+    whole (s_q, d) output block, indexed by batch*heads alone; for a fixed
+    q tile the contributions arrive in ascending k order, as in
+    ``_flash_bwd_dq_kernel``: the three gradients equal the pair's to the
+    bit."""
+    _flash_bwd_dkv_kernel(q2_ref, k_ref, v_ref, g_ref, L_ref, D_ref, dk_ref,
+                          dv_ref, dk_acc, dv_acc,
+                          dq=(dq_ref, dq_acc, sm_scale, num_k_blocks),
+                          **tiles)
+
+
+def _fused_bwd_dq_bytes(s_q: int, d: int, dtype) -> int:
+    """VMEM the fused backward takes for one (batch, head)'s dQ: the float32
+    accumulator and the output block, which the pipeline buffers twice;
+    lanes pad to 128."""
+    lanes = -(-d // 128) * 128
+    return s_q * lanes * (4 + 2 * jnp.dtype(dtype).itemsize)
 
 
 def _bwd_tile_sizes(s_q: int, s_k: int, block_q: int, block_k: int):
@@ -517,13 +608,20 @@ def _bwd_tile_sizes(s_q: int, s_k: int, block_q: int, block_k: int):
 
 
 def _flash_bwd(causal, sm_scale, block_q, block_k, res, g):
-    """FlashAttention-2-style Pallas backward: a dQ kernel (k innermost)
-    and a dK/dV kernel (q innermost), both consuming the forward's
+    """FlashAttention-2-style Pallas backward consuming the forward's
     log2-domain logsumexp. Every (block_q, block_k) P/dS tile lives and
     dies in VMEM — the previous pure-JAX backward streamed each of its
     ~6 (b, h, S, S)-shaped intermediates through HBM between dot_generals
     (~13 GB per step at S=4096), which bounded fwd+bwd at ~1.4x
-    materialized attention on a v5e chip."""
+    materialized attention on a v5e chip.
+
+    One launch where a (batch, head)'s whole dQ fits VMEM beside the tiles
+    (``_fused_bwd_dq_bytes`` within ``_FUSED_BWD_DQ_BYTES``: any sequence
+    the cells or the layers send): ``_flash_bwd_fused_kernel``, the dK/dV
+    pass accumulating dQ too. Past that, a dQ kernel (k innermost) and the
+    dK/dV kernel (q innermost), each rebuilding the score tiles. The shape
+    decides; both give the same bits and count themselves in
+    ``zoo_attention_backward_total``."""
     import jax.experimental.pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
@@ -548,59 +646,102 @@ def _flash_bwd(causal, sm_scale, block_q, block_k, res, g):
                 axis=-1, keepdims=True)
 
     vma = varying_axes(q2, kf, vf, gf, lse, D)
-    q2, kf, vf, gf, lse, D = (mark_varying(a, vma)
-                              for a in (q2, kf, vf, gf, lse, D))
+    operands = tuple(mark_varying(a, vma) for a in (q2, kf, vf, gf, lse, D))
+    tiles = dict(block_q=bq, block_k=bk, causal=causal, q_offset=s_k - s_q,
+                 cd=cd)
+    # both kernels walk (bh, nk, nq), q innermost
+    if causal:
+        # the q tiles wholly above a k block's diagonal are skipped
+        # (pl.when): they name the first active tile again, so the block
+        # index does not change and nothing is fetched for them, as
+        # _flash_forward's k_index does for its masked tail. Here it
+        # pays: a skipped tile's q2 and g are (bq, d + d_v) of DMA with no
+        # compute to hide behind (the fused launch 37.3 -> 29.4 ms, the
+        # dK/dV launch 30.7 -> 22.4, at 2 x 32 heads x 8192 x 192/128 on
+        # a v5e; PERF.md, PR 38)
+        def q_index(bhi, ki, qi):
+            first = jnp.maximum((ki * bk - (s_k - s_q)) // bq, 0)
+            return (bhi, jnp.maximum(qi, first), 0)
+    else:
+        def q_index(bhi, ki, qi):
+            return (bhi, qi, 0)
+    by_q = [pl.BlockSpec((1, bq, w), q_index)
+            for w in (d, d_v, 1, 1)]                     # q2, g, lse, D
+    by_k = [pl.BlockSpec((1, bk, w), lambda bhi, ki, qi: (bhi, ki, 0))
+            for w in (d, d_v)]                           # k | dk, v | dv
+    dkv_in_specs = [by_q[0], *by_k, *by_q[1:]]
+    dq_shape = jax.ShapeDtypeStruct((bh, s_q, d), q.dtype, vma=vma)
+    dkv_shapes = [jax.ShapeDtypeStruct((bh, s_k, d), k.dtype, vma=vma),
+                  jax.ShapeDtypeStruct((bh, s_k, d_v), v.dtype, vma=vma)]
+    dkv_scratch = [pltpu.VMEM((bk, d), jnp.float32),
+                   pltpu.VMEM((bk, d_v), jnp.float32)]
 
-    # --- dQ: grid (bh, nq, nk), k innermost --------------------------------
-    dq_kernel = functools.partial(
-        _flash_bwd_dq_kernel, sm_scale=sm_scale, block_q=bq, block_k=bk,
-        num_k_blocks=nk, causal=causal, q_offset=s_k - s_q, cd=cd)
-    dq = pl.pallas_call(
-        dq_kernel,
-        grid=(bh, nq, nk),
-        in_specs=[
-            pl.BlockSpec((1, bq, d), lambda bhi, qi, ki: (bhi, qi, 0)),
-            pl.BlockSpec((1, bk, d), lambda bhi, qi, ki: (bhi, ki, 0)),
-            pl.BlockSpec((1, bk, d_v), lambda bhi, qi, ki: (bhi, ki, 0)),
-            pl.BlockSpec((1, bq, d_v), lambda bhi, qi, ki: (bhi, qi, 0)),
-            pl.BlockSpec((1, bq, 1), lambda bhi, qi, ki: (bhi, qi, 0)),
-            pl.BlockSpec((1, bq, 1), lambda bhi, qi, ki: (bhi, qi, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, bq, d), lambda bhi, qi, ki: (bhi, qi, 0)),
-        out_shape=jax.ShapeDtypeStruct((bh, s_q, d), q.dtype, vma=vma),
-        scratch_shapes=[pltpu.VMEM((bq, d), jnp.float32)],
-        compiler_params=None if interpret else _mosaic_params(),
-        interpret=interpret,
-    )(q2, kf, vf, gf, lse, D)
-
-    # --- dK/dV: grid (bh, nk, nq), q innermost -----------------------------
-    dkv_kernel = functools.partial(
-        _flash_bwd_dkv_kernel, block_q=bq, block_k=bk, num_q_blocks=nq,
-        causal=causal, q_offset=s_k - s_q, cd=cd)
-    dk, dv = pl.pallas_call(
-        dkv_kernel,
-        grid=(bh, nk, nq),
-        in_specs=[
-            pl.BlockSpec((1, bq, d), lambda bhi, ki, qi: (bhi, qi, 0)),
-            pl.BlockSpec((1, bk, d), lambda bhi, ki, qi: (bhi, ki, 0)),
-            pl.BlockSpec((1, bk, d_v), lambda bhi, ki, qi: (bhi, ki, 0)),
-            pl.BlockSpec((1, bq, d_v), lambda bhi, ki, qi: (bhi, qi, 0)),
-            pl.BlockSpec((1, bq, 1), lambda bhi, ki, qi: (bhi, qi, 0)),
-            pl.BlockSpec((1, bq, 1), lambda bhi, ki, qi: (bhi, qi, 0)),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, bk, d), lambda bhi, ki, qi: (bhi, ki, 0)),
-            pl.BlockSpec((1, bk, d_v), lambda bhi, ki, qi: (bhi, ki, 0)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((bh, s_k, d), k.dtype, vma=vma),
-            jax.ShapeDtypeStruct((bh, s_k, d_v), v.dtype, vma=vma),
-        ],
-        scratch_shapes=[pltpu.VMEM((bk, d), jnp.float32),
-                        pltpu.VMEM((bk, d_v), jnp.float32)],
-        compiler_params=None if interpret else _mosaic_params(),
-        interpret=interpret,
-    )(q2, kf, vf, gf, lse, D)
+    dq_bytes = _fused_bwd_dq_bytes(s_q, d, q.dtype)
+    if dq_bytes <= _FUSED_BWD_DQ_BYTES:
+        _BACKWARD_FUSED.inc()
+        dq, dk, dv = pl.pallas_call(
+            functools.partial(
+                _flash_bwd_fused_kernel, sm_scale=sm_scale, num_q_blocks=nq,
+                num_k_blocks=nk, **tiles),
+            grid=(bh, nk, nq),
+            in_specs=dkv_in_specs,
+            out_specs=[pl.BlockSpec((1, s_q, d),
+                                    lambda bhi, ki, qi: (bhi, 0, 0)),
+                       *by_k],
+            out_shape=[dq_shape, *dkv_shapes],
+            scratch_shapes=[pltpu.VMEM((s_q, d), jnp.float32),
+                            *dkv_scratch],
+            # dQ is carried across both sequence dims: only batch*heads is
+            # parallel, and the scoped limit grows by what dQ takes
+            compiler_params=None if interpret else pltpu.CompilerParams(
+                dimension_semantics=("parallel", "arbitrary", "arbitrary"),
+                vmem_limit_bytes=_SCOPED_VMEM_BYTES + dq_bytes),
+            interpret=interpret,
+        )(*operands)
+    else:
+        _BACKWARD_TWO_KERNEL.inc()
+        # --- dQ: grid (bh, nq, nk), k innermost ----------------------------
+        if causal:
+            # likewise the k blocks past a q tile's diagonal name the last
+            # active one again (clamped at 0 as in _flash_forward)
+            def k_index(bhi, qi, ki):
+                last = jnp.maximum((s_k - s_q + (qi + 1) * bq - 1) // bk, 0)
+                return (bhi, jnp.minimum(ki, last), 0)
+        else:
+            def k_index(bhi, qi, ki):
+                return (bhi, ki, 0)
+        dq = pl.pallas_call(
+            functools.partial(
+                _flash_bwd_dq_kernel, sm_scale=sm_scale, num_k_blocks=nk,
+                **tiles),
+            grid=(bh, nq, nk),
+            in_specs=[
+                pl.BlockSpec((1, bq, d), lambda bhi, qi, ki: (bhi, qi, 0)),
+                pl.BlockSpec((1, bk, d), k_index),
+                pl.BlockSpec((1, bk, d_v), k_index),
+                pl.BlockSpec((1, bq, d_v), lambda bhi, qi, ki: (bhi, qi, 0)),
+                pl.BlockSpec((1, bq, 1), lambda bhi, qi, ki: (bhi, qi, 0)),
+                pl.BlockSpec((1, bq, 1), lambda bhi, qi, ki: (bhi, qi, 0)),
+            ],
+            out_specs=pl.BlockSpec((1, bq, d),
+                                   lambda bhi, qi, ki: (bhi, qi, 0)),
+            out_shape=dq_shape,
+            scratch_shapes=[pltpu.VMEM((bq, d), jnp.float32)],
+            compiler_params=None if interpret else _mosaic_params(),
+            interpret=interpret,
+        )(*operands)
+        # --- dK/dV: grid (bh, nk, nq), q innermost -------------------------
+        dk, dv = pl.pallas_call(
+            functools.partial(_flash_bwd_dkv_kernel, num_q_blocks=nq,
+                              **tiles),
+            grid=(bh, nk, nq),
+            in_specs=dkv_in_specs,
+            out_specs=by_k,
+            out_shape=dkv_shapes,
+            scratch_shapes=dkv_scratch,
+            compiler_params=None if interpret else _mosaic_params(),
+            interpret=interpret,
+        )(*operands)
 
     def unflat(a, s_len):
         return jnp.moveaxis(a.reshape(b, h, s_len, a.shape[-1]), 1, 2)
@@ -632,8 +773,12 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
     S=4096/D=64-128 measured 1024x1024 fastest of {256..2048}x{512,1024}
     (bigger tiles amortize the per-tile softmax state and keep the MXU
     fed; 2048-wide tiles spill VMEM and regress). The backward caps its
-    tiles at 512 internally — its VMEM working set is ~4 score tiles.
-    fit_block below shrinks tiles for short/odd sequences."""
+    tiles at 512 internally (``_bwd_tile_sizes``: its working set is ~4
+    score tiles) and is one launch, dQ accumulated beside dK/dV, wherever
+    a (batch, head)'s whole dQ fits VMEM beside them; past
+    ``_FUSED_BWD_DQ_BYTES`` it is a dQ launch and a dK/dV launch on the
+    same tiles (``_flash_bwd``). fit_block below shrinks tiles for
+    short/odd sequences."""
     if sm_scale is None:
         sm_scale = 1.0 / math.sqrt(q.shape[-1])
     s_q, s_k = q.shape[1], k.shape[1]

@@ -376,12 +376,13 @@ def stage_kernel(*, seq: int = 4096, head_dims=(64, 128), batch: int = 1,
             n_fwd = fwd.lower(qb, kb, vb).as_text().count("tpu_custom_call")
             n_grad = grad.lower(qb, kb, vb).as_text().count("tpu_custom_call")
             if expect_custom_calls:
-                # 1 forward; forward-with-lse + dQ + dK/dV for the gradient:
-                # neither interpret mode nor mha_reference was taken
+                # 1 forward; forward-with-lse + the fused backward for the
+                # gradient (a whole dQ fits VMEM at these sizes): neither
+                # interpret mode nor mha_reference was taken
                 check(n_fwd == 1, f"D={d} causal={causal}: {n_fwd} Mosaic "
                                   "custom calls in the forward, want 1")
-                check(n_grad == 3, f"D={d} causal={causal}: {n_grad} Mosaic "
-                                   "custom calls in the gradient, want 3")
+                check(n_grad == 2, f"D={d} causal={causal}: {n_grad} Mosaic "
+                                   "custom calls in the gradient, want 2")
             out = fwd(qb, kb, vb)
             grads = grad(qb, kb, vb)
             with jax.default_matmul_precision("highest"):

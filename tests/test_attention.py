@@ -165,9 +165,9 @@ def pallas_kernels(jaxpr):
 @pytest.mark.parametrize("d_qk,d_v", [(48, 32), (32, 32)])
 def test_remat_policy_keeps_the_forward_kernels_results(d_qk, d_v):
     """Under a remat that saves ``FLASH_RESIDUAL_NAMES`` the backward pass
-    reads the first launch's output and logsumexp: one forward kernel, dQ,
-    dK/dV, and the same gradients to the bit as the plain remat's, which
-    runs the forward kernel a second time."""
+    reads the first launch's output and logsumexp: one forward kernel, the
+    fused backward kernel, and the same gradients to the bit as the plain
+    remat's, which runs the forward kernel a second time."""
     rng = np.random.RandomState(3)
     q, k, v = (jnp.asarray(rng.randn(2, 32, 2, d), jnp.float32)
                for d in (d_qk, d_qk, d_v))
@@ -180,7 +180,7 @@ def test_remat_policy_keeps_the_forward_kernels_results(d_qk, d_v):
         *FLASH_RESIDUAL_NAMES)
     plain = jax.grad(jax.checkpoint(f), (0, 1, 2))
     kept = jax.grad(jax.checkpoint(f, policy=keep), (0, 1, 2))
-    backward = ["_flash_bwd_dq_kernel", "_flash_bwd_dkv_kernel"]
+    backward = ["_flash_bwd_fused_kernel"]
     assert pallas_kernels(jax.make_jaxpr(plain)(q, k, v).jaxpr) == \
         ["_flash_kernel"] * 2 + backward
     assert pallas_kernels(jax.make_jaxpr(kept)(q, k, v).jaxpr) == \
@@ -188,6 +188,121 @@ def test_remat_policy_keeps_the_forward_kernels_results(d_qk, d_v):
     for a, b in zip(plain(q, k, v), kept(q, k, v)):
         assert a.shape == b.shape and bool(jnp.all(a == b))
         assert bool(jnp.any(a != 0))
+
+
+def _flash_grads(q, k, v, w, causal, block=16):
+    def loss(q, k, v):
+        return jnp.sum(flash_attention(q, k, v, causal=causal, block_q=block,
+                                       block_k=block) * w)
+    return jax.grad(loss, argnums=(0, 1, 2))(q, k, v)
+
+
+def _mla_qkv(d_qk, d_v, s_q, s_k=64, seed=5):
+    rng = np.random.RandomState(seed)
+    mk = lambda s, d: jnp.asarray(rng.randn(2, s, 2, d), jnp.float32) * 0.5
+    return mk(s_q, d_qk), mk(s_k, d_qk), mk(s_k, d_v), mk(s_q, d_v)
+
+
+@pytest.mark.parametrize("causal,s_q", [(False, 64), (True, 64), (True, 32)])
+@pytest.mark.parametrize("d_qk,d_v", [(64, 64), (192, 128)])
+def test_fused_backward_equals_the_two_kernel_backward(monkeypatch, d_qk,
+                                                       d_v, causal, s_q):
+    """One launch (dQ accumulated beside dK/dV) and the dQ + dK/dV pair give
+    the same float32 gradients to the bit: for a fixed q tile the dS . k
+    contributions arrive in ascending k order either way. Non-causal,
+    causal, and the causal decode shape s_q < s_k; equal heads and MLA's.
+    The counter says which backward each trace took."""
+    import analytics_zoo_tpu.ops.attention as attn
+    q, k, v, w = _mla_qkv(d_qk, d_v, s_q)
+    fused_n, pair_n = (attn._BACKWARD_FUSED.value,
+                       attn._BACKWARD_TWO_KERNEL.value)
+    fused = _flash_grads(q, k, v, w, causal)
+    assert (attn._BACKWARD_FUSED.value,
+            attn._BACKWARD_TWO_KERNEL.value) == (fused_n + 1, pair_n)
+    monkeypatch.setattr(attn, "_FUSED_BWD_DQ_BYTES", 0)
+    pair = _flash_grads(q, k, v, w, causal)
+    assert (attn._BACKWARD_FUSED.value,
+            attn._BACKWARD_TWO_KERNEL.value) == (fused_n + 1, pair_n + 1)
+    for a, b in zip(fused, pair):
+        assert a.shape == b.shape and bool(jnp.all(a == b))
+        assert bool(jnp.any(a != 0))
+
+
+def test_a_dq_past_the_vmem_budget_takes_the_two_kernel_backward():
+    """The shape decides the path: a (batch, head)'s dQ accumulator and
+    output block are counted in bytes from s_q, d and the dtype, and past
+    the module's budget the dQ kernel and the dK/dV kernel run, with
+    mha_reference's gradients. The budget admits the token cell's 8192 x
+    192 in bf16 three times over, and no sequence a 16 GB chip can train
+    at four times that."""
+    import analytics_zoo_tpu.ops.attention as attn
+    assert attn._fused_bwd_dq_bytes(8192, 192, jnp.bfloat16) == 16 * attn.MIB
+    assert attn._fused_bwd_dq_bytes(8192, 64, jnp.float32) == 12 * attn.MIB
+    assert attn._fused_bwd_dq_bytes(8192, 192, jnp.bfloat16) * 3 \
+        <= attn._FUSED_BWD_DQ_BYTES \
+        < attn._fused_bwd_dq_bytes(32768, 192, jnp.bfloat16)
+    # the smallest float32 sequence past the budget at d = 8: 128 lanes
+    s_q = attn._FUSED_BWD_DQ_BYTES // (128 * 12) + 512
+    assert attn._fused_bwd_dq_bytes(s_q, 8, jnp.float32) \
+        > attn._FUSED_BWD_DQ_BYTES
+    rng = np.random.RandomState(7)
+    q = jnp.asarray(rng.randn(1, s_q, 1, 8), jnp.float32) * 0.5
+    k, v = (jnp.asarray(rng.randn(1, 16, 1, 8), jnp.float32) * 0.5
+            for _ in range(2))
+    w = jnp.asarray(rng.randn(1, s_q, 1, 8), jnp.float32)
+
+    def loss(fn, **kw):
+        return lambda q, k, v: jnp.sum(fn(q, k, v, **kw) * w)
+
+    flash = jax.grad(loss(flash_attention, block_q=512, block_k=16),
+                     argnums=(0, 1, 2))
+    kernels = pallas_kernels(jax.make_jaxpr(flash)(q, k, v).jaxpr)
+    assert kernels == ["_flash_kernel", "_flash_bwd_dq_kernel",
+                       "_flash_bwd_dkv_kernel"]
+    g_ref = jax.grad(loss(mha_reference), argnums=(0, 1, 2))(q, k, v)
+    for a, b in zip(flash(q, k, v), g_ref):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                   rtol=2e-3, atol=2e-3)
+
+
+def _forward_call(q, k, v, **kw):
+    """The forward kernel's ``pallas_call`` equation under differentiation
+    (the launch that also writes the logsumexp)."""
+    jaxpr = jax.make_jaxpr(lambda q, k, v: jax.vjp(
+        lambda *a: flash_attention(*a, **kw), q, k, v)[0])(q, k, v).jaxpr
+    (eqn,) = [e for e in equations(jaxpr)
+              if e.primitive.name == "pallas_call"]
+    return eqn
+
+
+@pytest.mark.parametrize("causal", [False, True])
+@pytest.mark.parametrize("d_qk,d_v,v_lanes", [(192, 128, 128), (128, 128, 128),
+                                              (64, 64, 65), (48, 32, 33)])
+def test_forward_normaliser_follows_vs_head_size(d_qk, d_v, v_lanes, causal):
+    """Where d_v fills whole 128-lane tiles the kernel takes v as it is and
+    keeps the normaliser as a running float32 row sum in a third scratch;
+    elsewhere (the layers' 64) v carries the ones column, which is free
+    there. Either way the output and the base-2 logsumexp are
+    mha_reference's."""
+    from analytics_zoo_tpu.ops.attention import _flash_fwd
+    q, k, v, _ = _mla_qkv(d_qk, d_v, 64)
+    eqn = _forward_call(q, k, v, causal=causal, block_q=16, block_k=16)
+    assert eqn.invars[2].aval.shape[-1] == v_lanes
+    # the kernel's refs: q, k, v; output, logsumexp; then the scratch
+    n_scratch = len(eqn.params["jaxpr"].invars) - 3 - 2
+    assert n_scratch == (2 if v_lanes > d_v else 3)
+    sm_scale = d_qk ** -0.5
+    out, (_, _, _, _, lse) = _flash_fwd(q, k, v, causal, sm_scale, 16, 16)
+    np.testing.assert_allclose(
+        np.asarray(out), np.asarray(mha_reference(q, k, v, causal=causal)),
+        rtol=2e-5, atol=2e-6)
+    logits = jnp.einsum("bqhd,bkhd->bhqk", q, k) * sm_scale
+    if causal:
+        logits = jnp.where(jnp.tril(jnp.ones((64, 64), bool)), logits,
+                           -jnp.inf)
+    want = jax.nn.logsumexp(logits, axis=-1) / np.log(2.0)   # (B, H, S)
+    np.testing.assert_allclose(np.asarray(lse).reshape(want.shape),
+                               np.asarray(want), rtol=2e-6, atol=2e-6)
 
 
 def _sp_mesh():
@@ -281,15 +396,23 @@ def test_sequence_sharded_wrapper():
                                rtol=2e-4, atol=2e-4)
 
 
-@pytest.mark.parametrize("d", [64, 128])
-def test_flash_attention_lowers_to_mosaic_for_tpu(monkeypatch, d):
+@pytest.mark.parametrize("d_qk,d_v,dq_budget,backward_calls", [
+    (64, 64, None, 1), (128, 128, None, 1), (192, 128, None, 1),
+    (192, 128, 0, 2)])
+def test_flash_attention_lowers_to_mosaic_for_tpu(monkeypatch, d_qk, d_v,
+                                                  dq_budget, backward_calls):
     """The path tier-1 cannot execute: with the backend decision forced to
     the compiled kernel, cross-lowering for platform tpu runs the Pallas ->
-    Mosaic lowering on the CPU. One custom call forward; forward-with-lse,
-    dQ and dK/dV for the gradient — no interpret mode, no mha_reference."""
+    Mosaic lowering on the CPU. One custom call forward; for the gradient
+    forward-with-lse and the fused backward (2 a call site), or
+    forward-with-lse, dQ and dK/dV (3) where dQ is past the budget — no
+    interpret mode, no mha_reference."""
     import analytics_zoo_tpu.ops.attention as attn
     monkeypatch.setattr(attn, "_interpret", lambda: False)
-    sds = jax.ShapeDtypeStruct((1, 512, 2, d), jnp.bfloat16)
+    if dq_budget is not None:
+        monkeypatch.setattr(attn, "_FUSED_BWD_DQ_BYTES", dq_budget)
+    qk = jax.ShapeDtypeStruct((1, 512, 2, d_qk), jnp.bfloat16)
+    v = jax.ShapeDtypeStruct((1, 512, 2, d_v), jnp.bfloat16)
 
     def loss(q, k, v):
         return flash_attention(q, k, v, causal=True).astype(
@@ -297,12 +420,12 @@ def test_flash_attention_lowers_to_mosaic_for_tpu(monkeypatch, d):
 
     fwd = jax.export.export(
         jax.jit(lambda q, k, v: flash_attention(q, k, v, causal=True)),
-        platforms=["tpu"])(sds, sds, sds).mlir_module()
+        platforms=["tpu"])(qk, qk, v).mlir_module()
     grad = jax.export.export(
         jax.jit(jax.grad(loss, argnums=(0, 1, 2))),
-        platforms=["tpu"])(sds, sds, sds).mlir_module()
+        platforms=["tpu"])(qk, qk, v).mlir_module()
     assert fwd.count("tpu_custom_call") == 1
-    assert grad.count("tpu_custom_call") == 3
+    assert grad.count("tpu_custom_call") == 1 + backward_calls
 
 
 def test_flash_attention_refuses_unknown_platforms(monkeypatch):

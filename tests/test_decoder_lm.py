@@ -125,14 +125,15 @@ def test_every_leafs_gradient_matches_reference(sides):
 
 def test_each_block_runs_the_flash_forward_kernel_once(sides):
     """The blocks are rematerialised, but their policy keeps the flash
-    kernel's output and logsumexp: the gradient's program holds three flash
-    kernels a block (forward, dQ, dK/dV), not a second forward."""
+    kernel's output and logsumexp: the gradient's program holds two flash
+    kernels a block (forward and the fused backward), not a second forward
+    and not a dQ launch beside the dK/dV one."""
     jaxpr = jax.make_jaxpr(jax.grad(lambda p: sides["loss_of"](p)[0]))(
         _tree(sides["weights"])).jaxpr
     flash = sorted(n for n in pallas_kernels(jaxpr) if "flash" in n)
     blocks = CFG["num_hidden_layers"] + CFG["num_nextn_predict_layers"]
-    assert flash == sorted(blocks * ["_flash_kernel", "_flash_bwd_dq_kernel",
-                                     "_flash_bwd_dkv_kernel"])
+    assert flash == sorted(blocks * ["_flash_kernel",
+                                     "_flash_bwd_fused_kernel"])
     # one policy object for all blocks: with one a block, blocks of one shape
     # stop sharing a lowered function (3.5x the functions at the cell's size)
     policies = [eqn.params["policy"] for eqn in equations(jaxpr)
