@@ -20,6 +20,7 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax import lax
 from jax.ad_checkpoint import checkpoint_name
 
@@ -43,6 +44,16 @@ _BACKWARD_PATHS = _REGISTRY.counter(
 _BACKWARD_FUSED = _BACKWARD_PATHS.labels(path="fused")
 _BACKWARD_TWO_KERNEL = _BACKWARD_PATHS.labels(path="two_kernel")
 
+# what a windowed call site's grids compute against what its mask needs
+_WINDOW_TILES = _REGISTRY.counter(
+    "zoo_attention_window_tiles_total",
+    "(q, k) tiles of windowed flash_attention call sites traced, forward and "
+    "backward kernels, each at its own tile size: visited (tiles the grids "
+    "compute) and needed (tiles that hold an entry inside the window)",
+    labelnames=("kind",))
+_TILES_VISITED = _WINDOW_TILES.labels(kind="visited")
+_TILES_NEEDED = _WINDOW_TILES.labels(kind="needed")
+
 NEG_INF = -1e30
 LOG2_E = 1.4426950408889634      # the flash kernel softmaxes in base 2
 # the forward kernel's output and logsumexp, as ``checkpoint_name`` marks
@@ -62,17 +73,27 @@ _FUSED_BWD_DQ_BYTES = 48 * MIB
 
 def mha_reference(q: jax.Array, k: jax.Array, v: jax.Array,
                   *, causal: bool = False, sm_scale: Optional[float] = None,
-                  bias: Optional[jax.Array] = None) -> jax.Array:
+                  bias: Optional[jax.Array] = None,
+                  window: Optional[int] = None) -> jax.Array:
     """Plain materialised-scores attention. q,k: (B, S, H, D); v: (B, S, H,
-    D) or a head size of its own."""
+    D) or a head size of its own. k and v may have fewer heads than q
+    (grouped queries: each run of H / H_kv query heads shares one, repeated
+    here); ``window`` keeps, of a causal mask, the keys ``0 <= t - j <
+    window`` of query position t."""
     if sm_scale is None:
         sm_scale = 1.0 / math.sqrt(q.shape[-1])
+    group = q.shape[2] // k.shape[2]
+    if group > 1:
+        k, v = jnp.repeat(k, group, axis=2), jnp.repeat(v, group, axis=2)
     logits = jnp.einsum("bqhd,bkhd->bhqk", q, k).astype(jnp.float32) * sm_scale
     if bias is not None:
         logits = logits + bias
     if causal:
         s_q, s_k = logits.shape[-2], logits.shape[-1]
         mask = jnp.tril(jnp.ones((s_q, s_k), dtype=bool), k=s_k - s_q)
+        if window is not None:
+            mask &= ~jnp.tril(jnp.ones((s_q, s_k), dtype=bool),
+                              k=s_k - s_q - window)
         logits = jnp.where(mask, logits, NEG_INF)
     probs = jax.nn.softmax(logits, axis=-1)
     return jnp.einsum("bhqk,bkhd->bqhd", probs.astype(v.dtype), v)
@@ -169,13 +190,92 @@ def blockwise_attention(q, k, v, *, causal: bool = False,
 # Pallas TPU flash-attention kernel
 # ---------------------------------------------------------------------------
 
+def _visible(q_pos, k_pos, window):
+    """The causal mask, and inside it the window's ``t - j < window``."""
+    keep = q_pos >= k_pos
+    if window is not None:
+        keep &= q_pos - k_pos < window
+    return keep
+
+
+def _tile_class(q_pos0, k_start, block_q, block_k, window):
+    """``(active, masked)`` of a causal (block_q, block_k) tile whose first
+    row is query position ``q_pos0`` and whose first column is key
+    ``k_start``: active unless wholly above the diagonal; masked where the
+    diagonal or, with a window, its far edge crosses the tile (the tile's
+    last row sees its first key no more). Interior tiles do no mask work.
+    Tiles wholly beyond a window's far edge are never walked (``_k_band``,
+    ``_q_band``)."""
+    active = q_pos0 + block_q - 1 >= k_start
+    masked = q_pos0 < k_start + block_k - 1
+    if window is not None:
+        masked |= q_pos0 + block_q - 1 - k_start >= window
+    return active, masked
+
+
+def _k_band(qi, block_q, block_k, q_offset, window, xp=jnp):
+    """First and last k block that hold a key q tile ``qi`` sees through
+    its window; every block between them holds one too. Traced values, or
+    numpy's with ``xp=numpy``."""
+    first = xp.maximum(q_offset + qi * block_q - (window - 1), 0) // block_k
+    last = xp.maximum((q_offset + (qi + 1) * block_q - 1) // block_k, 0)
+    return first, last
+
+
+def _k_start(qi, step, block_q, block_k, q_offset, window):
+    """First key of the k block a k-innermost kernel works on at ``step`` of
+    q tile ``qi``: every block in turn, or with a window the band's."""
+    if window is not None:
+        step = step + _k_band(qi, block_q, block_k, q_offset, window)[0]
+    return step * block_k
+
+
+def _q_band(ki, block_q, block_k, q_offset, window, num_q, xp=jnp):
+    """First and last q tile that hold a query which sees a key of k block
+    ``ki`` through its window."""
+    first = xp.maximum((ki * block_k - q_offset) // block_q, 0)
+    last = xp.minimum(
+        ((ki + 1) * block_k - 1 + window - 1 - q_offset) // block_q,
+        num_q - 1)
+    return first, last
+
+
+def _window_tiles(s_q, s_k, block_q, block_k, window):
+    """Static counts of a windowed call's tile grid: ``k_steps`` / ``q_steps``
+    (the widest band: the grids' innermost extent by k and by q),
+    ``by_k`` / ``by_q`` (tiles a head's k-innermost / q-innermost grid
+    computes) and ``needed`` (tiles that hold an entry inside the window,
+    counted from the mask itself)."""
+    off, nq, nk = s_k - s_q, s_q // block_q, s_k // block_k
+    qi, ki = np.arange(nq), np.arange(nk)
+    k_first, k_last = _k_band(qi, block_q, block_k, off, window, np)
+    q_first, q_last = _q_band(ki, block_q, block_k, off, window, nq, np)
+    by_k = k_last - k_first + 1
+    by_q = np.maximum(q_last - q_first + 1, 0)
+    q0 = off + qi[:, None] * block_q         # a tile's first query position
+    k0 = ki[None, :] * block_k
+    needed = (q0 + block_q - 1 >= k0) & (q0 - (k0 + block_k - 1) < window)
+    return {"k_steps": int(by_k.max()), "q_steps": int(by_q.max()),
+            "by_k": int(by_k.sum()), "by_q": int(by_q.sum()),
+            "needed": int(needed.sum())}
+
+
 def _flash_kernel(q_ref, k_ref, v_ref, o_ref, *rest, block_q,
                   block_k, num_k_blocks, causal, head_dim, q_offset=0,
-                  with_lse=False, ones_column=True):
+                  with_lse=False, ones_column=True, window=None):
     """Grid = (batch*heads, num_q_blocks, num_k_blocks); the k dim is innermost
     so (acc, m) scratch carries the online softmax across k iterations.
     With ``with_lse`` the kernel also emits the log2-domain logsumexp
     (m + log2 l) per q row, which the Pallas backward consumes.
+
+    With a ``window`` the k dim walks a q tile's band alone:
+    ``num_k_blocks`` is the widest band's blocks, step j is the block
+    ``_k_band(...)[0] + j``, and the steps past the diagonal's block are
+    the shorter bands' padding (no compute; the index map names the
+    diagonal's block again, so nothing is fetched). A row whose first
+    blocks are all outside its window carries m = NEG_INF and p = 1
+    through them; its first block with a visible key rescales that to
+    exactly 0.
 
     The softmax normaliser l = sum(p) comes one of two ways, by v's head
     size (``_flash_forward`` decides):
@@ -209,7 +309,7 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, *rest, block_q,
             l_ref[...] = jnp.zeros_like(l_ref)
 
     q_start = q_idx * block_q
-    k_start = k_idx * block_k
+    k_start = _k_start(q_idx, k_idx, block_q, block_k, q_offset, window)
 
     def _compute(masked):
         # matmuls keep the input dtype (bf16 inputs hit the MXU at full
@@ -230,7 +330,7 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, *rest, block_q,
             q_pos = (q_offset + q_start +
                      lax.broadcasted_iota(jnp.int32, s.shape, 0))
             k_pos = k_start + lax.broadcasted_iota(jnp.int32, s.shape, 1)
-            s = jnp.where(q_pos >= k_pos, s, NEG_INF)
+            s = jnp.where(_visible(q_pos, k_pos, window), s, NEG_INF)
         m_prev = m_ref[:, :1]                            # (block_q, 1)
         m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
         p = jnp.exp2(s - m_new)                          # (block_q, block_k)
@@ -250,8 +350,8 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, *rest, block_q,
         # interior (q_pos >= k_pos everywhere — no mask work: the two
         # iotas + compare + select are (block_q, block_k) VPU passes that
         # would otherwise run on every tile).
-        active = q_offset + q_start + block_q - 1 >= k_start
-        diagonal = q_offset + q_start < k_start + block_k - 1
+        active, diagonal = _tile_class(q_offset + q_start, k_start, block_q,
+                                       block_k, window)
         pl.when(active & diagonal)(lambda: _compute(True))
         pl.when(active & jnp.logical_not(diagonal))(lambda: _compute(False))
     else:
@@ -298,13 +398,22 @@ def mark_varying(x, vma):
     return lax.pcast(x, missing, to="varying") if missing else x
 
 
+def _kv_row(group):
+    """Index-map form of "the k/v row a q row reads": q rows are (batch,
+    head) pairs flattened, k/v rows (batch, kv head) pairs, and a run of
+    ``group`` query heads shares one kv head, so the row is ``bh // group``
+    and no copy of k or v is made for the group."""
+    return (lambda bh: bh) if group == 1 else (lambda bh: bh // group)
+
+
 def _flash_forward(q, k, v, causal, sm_scale, block_q, block_k, interpret,
-                   with_lse=False):
+                   with_lse=False, window=None):
     import jax.experimental.pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
     b, s_q, h, d = q.shape
-    s_k = k.shape[1]
+    s_k, h_kv = k.shape[1], k.shape[2]
+    kv_row = _kv_row(h // h_kv)
     # v's head size may differ from q's and k's (MLA: q.k over 192, v of
     # 128): the p @ v product, the accumulator and the output take d_v
     d_v = v.shape[-1]
@@ -313,27 +422,34 @@ def _flash_forward(q, k, v, causal, sm_scale, block_q, block_k, interpret,
     # (see _flash_kernel); one multiply here replaces one per k-tile.
     qf = (q * jnp.asarray(sm_scale * LOG2_E, q.dtype))
     qf = jnp.moveaxis(qf, 2, 1).reshape(b * h, s_q, d)
-    kf = jnp.moveaxis(k, 2, 1).reshape(b * h, s_k, d)
-    vf = jnp.moveaxis(v, 2, 1).reshape(b * h, s_k, d_v)
+    kf = jnp.moveaxis(k, 2, 1).reshape(b * h_kv, s_k, d)
+    vf = jnp.moveaxis(v, 2, 1).reshape(b * h_kv, s_k, d_v)
     # ones column: p @ [v | 1] yields the softmax normaliser in the last
     # output column on the MXU, where that column is free: not at a d_v
     # that already fills whole 128-lane tiles (see _flash_kernel)
     ones_column = d_v % 128 != 0
     if ones_column:
         vf = jnp.concatenate(
-            [vf, jnp.ones((b * h, s_k, 1), vf.dtype)], axis=-1)
+            [vf, jnp.ones((b * h_kv, s_k, 1), vf.dtype)], axis=-1)
     d_acc = vf.shape[-1]
 
     block_q = min(block_q, s_q)
     block_k = min(block_k, s_k)
     num_q = s_q // block_q
     num_k = s_k // block_k
+    if window is not None:
+        # the k dim walks each q tile's band: its widest band's blocks
+        tiles = _window_tiles(s_q, s_k, block_q, block_k, window)
+        num_k = tiles["k_steps"]
+        _TILES_VISITED.inc(b * h * tiles["by_k"])
+        _TILES_NEEDED.inc(b * h * tiles["needed"])
 
     grid = (b * h, num_q, num_k)
     kernel = functools.partial(
         _flash_kernel, block_q=block_q, block_k=block_k,
         num_k_blocks=num_k, causal=causal, head_dim=d_v,
-        q_offset=s_k - s_q, with_lse=with_lse, ones_column=ones_column)
+        q_offset=s_k - s_q, with_lse=with_lse, ones_column=ones_column,
+        window=window)
     # Under shard_map (e.g. Ulysses sequence parallelism) the output must
     # declare which mesh axes it varies over. Use the union of the inputs'
     # varying sets and lift any less-varying input up to it so mixed-vma
@@ -364,10 +480,12 @@ def _flash_forward(q, k, v, causal, sm_scale, block_q, block_k, interpret,
             # active block and issue a negative-index k/v DMA
             last = jnp.maximum((q_off + (qi + 1) * block_q - 1) // block_k,
                                0)
-            return (bh, jnp.minimum(ki, last), 0)
+            if window is not None:
+                ki = ki + _k_band(qi, block_q, block_k, q_off, window)[0]
+            return (kv_row(bh), jnp.minimum(ki, last), 0)
     else:
         def k_index(bh, qi, ki):
-            return (bh, ki, 0)
+            return (kv_row(bh), ki, 0)
 
     res = pl.pallas_call(
         kernel,
@@ -408,13 +526,13 @@ def _interpret() -> bool:
     return platform == "cpu"
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))
-def _flash_attention(q, k, v, causal, sm_scale, block_q, block_k):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7))
+def _flash_attention(q, k, v, causal, sm_scale, block_q, block_k, window):
     return _flash_forward(q, k, v, causal, sm_scale, block_q, block_k,
-                          _interpret())
+                          _interpret(), window=window)
 
 
-def _flash_fwd(q, k, v, causal, sm_scale, block_q, block_k):
+def _flash_fwd(q, k, v, causal, sm_scale, block_q, block_k, window=None):
     """The forward kernel, with its two results named
     (``FLASH_RESIDUAL_NAMES``) for a caller's remat policy: the output
     (B, S_q, H, d_v), which is both the primal result and a residual, and
@@ -426,13 +544,14 @@ def _flash_fwd(q, k, v, causal, sm_scale, block_q, block_k):
     remat whose policy reads the names they are identities."""
     interpret = _interpret()
     out, lse = _flash_forward(q, k, v, causal, sm_scale, block_q, block_k,
-                              interpret, with_lse=True)
+                              interpret, with_lse=True, window=window)
     out = checkpoint_name(out, FLASH_RESIDUAL_NAMES[0])
     lse = checkpoint_name(lse, FLASH_RESIDUAL_NAMES[1])
     return out, (q, k, v, out, lse)
 
 
-def _bwd_tile(masked, q2, k, v, g, L, D, q_offset, q_start, k_start, cd):
+def _bwd_tile(masked, q2, k, v, g, L, D, q_offset, q_start, k_start, cd,
+              window=None):
     """Shared (block_q, block_k) backward tile: rebuild P from (q2, k, L),
     then ds = P*(dP - D). All matmuls keep the input dtype (bf16 rides the
     MXU) with f32 accumulation; returns (p, ds) in compute dtype ``cd``."""
@@ -442,7 +561,7 @@ def _bwd_tile(masked, q2, k, v, g, L, D, q_offset, q_start, k_start, cd):
         q_pos = (q_offset + q_start +
                  lax.broadcasted_iota(jnp.int32, s2.shape, 0))
         k_pos = k_start + lax.broadcasted_iota(jnp.int32, s2.shape, 1)
-        s2 = jnp.where(q_pos >= k_pos, s2, NEG_INF)
+        s2 = jnp.where(_visible(q_pos, k_pos, window), s2, NEG_INF)
     p = jnp.exp2(s2 - L)                             # true softmax probs
     dp = jax.lax.dot_general(g, v, (((1,), (1,)), ((), ())),
                              preferred_element_type=jnp.float32)
@@ -452,14 +571,15 @@ def _bwd_tile(masked, q2, k, v, g, L, D, q_offset, q_start, k_start, cd):
 
 def _flash_bwd_dq_kernel(q2_ref, k_ref, v_ref, g_ref, L_ref, D_ref, dq_ref,
                          acc_ref, *, sm_scale, block_q, block_k,
-                         num_k_blocks, causal, q_offset, cd):
+                         num_k_blocks, causal, q_offset, cd, window=None):
     """dQ pass: grid (batch*heads, num_q, num_k), k innermost; the dq tile
     accumulates across k iterations in VMEM scratch — no (S, S) tensor
     ever reaches HBM (the round-3 pure-JAX backward streamed every P/dS
     tile through HBM between the dot_generals, which bounded fwd+bwd at
     ~1.4x materialized; tiles resident in VMEM are the FA-2 design). Runs
-    only where a (batch, head)'s whole dQ is past the fused kernel's VMEM
-    budget (``_flash_bwd``)."""
+    only where a kv head's whole dQ is past the fused kernel's VMEM
+    budget (``_flash_bwd``). With a ``window`` the k dim walks the q
+    tile's band, as in ``_flash_kernel``."""
     import jax.experimental.pallas as pl
 
     q_idx = pl.program_id(1)
@@ -470,18 +590,18 @@ def _flash_bwd_dq_kernel(q2_ref, k_ref, v_ref, g_ref, L_ref, D_ref, dq_ref,
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
     q_start = q_idx * block_q
-    k_start = k_idx * block_k
+    k_start = _k_start(q_idx, k_idx, block_q, block_k, q_offset, window)
 
     def _compute(masked):
         _, ds = _bwd_tile(masked, q2_ref[0], k_ref[0], v_ref[0],
                           g_ref[0], L_ref[0], D_ref[0], q_offset, q_start,
-                          k_start, cd)
+                          k_start, cd, window)
         acc_ref[...] += jnp.dot(ds, k_ref[0],
                                 preferred_element_type=jnp.float32)
 
     if causal:
-        active = q_offset + q_start + block_q - 1 >= k_start
-        diagonal = q_offset + q_start < k_start + block_k - 1
+        active, diagonal = _tile_class(q_offset + q_start, k_start, block_q,
+                                       block_k, window)
         pl.when(active & diagonal)(lambda: _compute(True))
         pl.when(active & jnp.logical_not(diagonal))(lambda: _compute(False))
     else:
@@ -495,40 +615,69 @@ def _flash_bwd_dq_kernel(q2_ref, k_ref, v_ref, g_ref, L_ref, D_ref, dq_ref,
 def _flash_bwd_dkv_kernel(q2_ref, k_ref, v_ref, g_ref, L_ref, D_ref,
                           dk_ref, dv_ref, dk_acc, dv_acc, *, block_q,
                           block_k, num_q_blocks, causal, q_offset, cd,
-                          dq=None):
-    """dK/dV pass: grid (batch*heads, num_k, num_q), q innermost; both
-    accumulators live in VMEM scratch. dv += P^T g and dk += dS^T q2 are
-    expressed as dot_generals contracting the q (sublane) dim. q2 is the
-    log2-prescaled q, so dk carries a 1/log2(e) correction at finalize.
+                          dq=None, window=None, group=1, seq_q_blocks=None):
+    """dK/dV pass: grid (batch*kv heads, num_k, group * num_q), the
+    innermost dim walking the q tiles of each of the ``group`` query heads
+    that share this kv head in turn, so dK and dV are summed over the
+    group where they are made; both accumulators live in VMEM scratch.
+    dv += P^T g and dk += dS^T q2 are expressed as dot_generals contracting
+    the q (sublane) dim. q2 is the log2-prescaled q, so dk carries a
+    1/log2(e) correction at finalize. With a ``window``, ``num_q_blocks``
+    is the widest band's tiles and step j of a head is the tile
+    ``_q_band(...)[0] + j``, the steps past the band's last tile padding;
+    ``seq_q_blocks`` is then the sequence's tiles.
 
     ``dq`` is the fused kernel's (see ``_flash_bwd_fused_kernel``): given,
     each tile's dS also goes into dQ."""
     import jax.experimental.pallas as pl
 
     k_idx = pl.program_id(1)
-    q_idx = pl.program_id(2)
+    step = pl.program_id(2)
+    if group == 1:
+        head, q_idx = 0, step
+    else:
+        head, q_idx = step // num_q_blocks, step % num_q_blocks
 
-    @pl.when(q_idx == 0)
+    @pl.when(step == 0)
     def _init():
         dk_acc[...] = jnp.zeros_like(dk_acc)
         dv_acc[...] = jnp.zeros_like(dv_acc)
 
-    q_start = q_idx * block_q
     k_start = k_idx * block_k
+    if window is not None:
+        first, last = _q_band(k_idx, block_q, block_k, q_offset, window,
+                              seq_q_blocks)
+        q_idx = first + q_idx
+    q_start = q_idx * block_q
     if dq is not None:
         dq_ref, dq_acc, sm_scale, num_k_blocks = dq
         rows = pl.ds(pl.multiple_of(q_start, block_q), block_q)
+        # this step's query head's rows of the tile, in the accumulator
+        # (one head: (s_q, d)) and in the output block (group, s_q, d)
+        acc_at = (rows, slice(None)) if group == 1 else \
+            (head, rows, slice(None))
+        out_at = (head, rows, slice(None))
 
-        @pl.when(k_idx == 0)
-        def _init_dq():
-            dq_acc[rows, :] = jnp.zeros((block_q, dq_acc.shape[-1]),
-                                        dq_acc.dtype)
+        def _write_dq():
+            dq_ref[out_at] = (dq_acc[acc_at] * sm_scale).astype(dq_ref.dtype)
+
+        if window is None:
+            @pl.when(k_idx == 0)
+            def _init_dq():
+                dq_acc[acc_at] = jnp.zeros((block_q, dq_acc.shape[-1]),
+                                           dq_acc.dtype)
+        else:
+            # a band's walk does not pass every q tile under the first k
+            # block: the whole accumulator is cleared as a kv head begins
+            @pl.when((k_idx == 0) & (step == 0))
+            def _init_dq():
+                dq_acc[...] = jnp.zeros_like(dq_acc)
 
     def _compute(masked):
         g = g_ref[0]
         p, ds = _bwd_tile(masked, q2_ref[0], k_ref[0], v_ref[0],
                           g, L_ref[0], D_ref[0], q_offset, q_start,
-                          k_start, cd)
+                          k_start, cd, window)
         dv_acc[...] += jax.lax.dot_general(
             p, g, (((0,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
@@ -536,30 +685,34 @@ def _flash_bwd_dkv_kernel(q2_ref, k_ref, v_ref, g_ref, L_ref, D_ref,
             ds, q2_ref[0], (((0,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
         if dq is not None:
-            dq_acc[rows, :] += jnp.dot(ds, k_ref[0],
-                                       preferred_element_type=jnp.float32)
+            dq_acc[acc_at] += jnp.dot(ds, k_ref[0],
+                                      preferred_element_type=jnp.float32)
 
     if causal:
-        active = q_offset + q_start + block_q - 1 >= k_start
-        diagonal = q_offset + q_start < k_start + block_k - 1
+        active, diagonal = _tile_class(q_offset + q_start, k_start, block_q,
+                                       block_k, window)
+        if window is not None:
+            active = q_idx <= last       # past it: the band's padding steps
         pl.when(active & diagonal)(lambda: _compute(True))
         pl.when(active & jnp.logical_not(diagonal))(lambda: _compute(False))
+        if window is not None and dq is not None:
+            # a q tile's rows are whole once its diagonal's k block, the
+            # last of its band, has passed them
+            pl.when(active & (k_idx == _k_band(
+                q_idx, block_q, block_k, q_offset, window)[1]))(_write_dq)
     else:
         _compute(False)
 
-    @pl.when(q_idx == num_q_blocks - 1)
+    @pl.when(step == group * num_q_blocks - 1)
     def _finalize():
         dk_ref[0] = (dk_acc[...] * (1.0 / LOG2_E)).astype(dk_ref.dtype)
         dv_ref[0] = dv_acc[...].astype(dv_ref.dtype)
 
-    if dq is not None:
+    if dq is not None and window is None:
         # a q tile's rows are whole once the last k block has passed them,
         # masked tiles too: written a tile at a time, never the whole
         # sequence in one statement
-        @pl.when(k_idx == num_k_blocks - 1)
-        def _finalize_dq():
-            dq_ref[0, rows, :] = (dq_acc[rows, :] * sm_scale).astype(
-                dq_ref.dtype)
+        pl.when(k_idx == num_k_blocks - 1)(_write_dq)
 
 
 def _flash_bwd_fused_kernel(q2_ref, k_ref, v_ref, g_ref, L_ref, D_ref,
@@ -567,13 +720,13 @@ def _flash_bwd_fused_kernel(q2_ref, k_ref, v_ref, g_ref, L_ref, D_ref,
                             *, sm_scale, num_k_blocks, **tiles):
     """The whole backward in one launch: the dK/dV pass (same grid, q
     innermost) whose every tile also adds dS . k into a float32 accumulator
-    for ALL of this (batch, head)'s queries, (s_q, d) in VMEM scratch, at
-    the tile's rows. Each (block_q, block_k) score tile, its exp2 and dP are
-    rebuilt once a step, not once in each of two kernels. ``dq_ref`` is the
-    whole (s_q, d) output block, indexed by batch*heads alone; for a fixed
-    q tile the contributions arrive in ascending k order, as in
-    ``_flash_bwd_dq_kernel``: the three gradients equal the pair's to the
-    bit."""
+    for ALL the queries that share this (batch, kv head), (group, s_q, d)
+    in VMEM scratch, at the tile's rows. Each (block_q, block_k) score
+    tile, its exp2 and dP are rebuilt once a step, not once in each of two
+    kernels. ``dq_ref`` is the whole (group, s_q, d) output block, indexed
+    by batch*kv heads alone; for a fixed q tile the contributions arrive in
+    ascending k order, as in ``_flash_bwd_dq_kernel``: the three gradients
+    equal the pair's to the bit."""
     _flash_bwd_dkv_kernel(q2_ref, k_ref, v_ref, g_ref, L_ref, D_ref, dk_ref,
                           dv_ref, dk_acc, dv_acc,
                           dq=(dq_ref, dq_acc, sm_scale, num_k_blocks),
@@ -581,9 +734,10 @@ def _flash_bwd_fused_kernel(q2_ref, k_ref, v_ref, g_ref, L_ref, D_ref,
 
 
 def _fused_bwd_dq_bytes(s_q: int, d: int, dtype) -> int:
-    """VMEM the fused backward takes for one (batch, head)'s dQ: the float32
-    accumulator and the output block, which the pipeline buffers twice;
-    lanes pad to 128."""
+    """VMEM the fused backward takes for ``s_q`` rows of dQ (a kv head's:
+    the sequence times its group of query heads): the float32 accumulator
+    and the output block, which the pipeline buffers twice; lanes pad to
+    128."""
     lanes = -(-d // 128) * 128
     return s_q * lanes * (4 + 2 * jnp.dtype(dtype).itemsize)
 
@@ -607,7 +761,7 @@ def _bwd_tile_sizes(s_q: int, s_k: int, block_q: int, block_k: int):
     return bq, bk
 
 
-def _flash_bwd(causal, sm_scale, block_q, block_k, res, g):
+def _flash_bwd(causal, sm_scale, block_q, block_k, window, res, g):
     """FlashAttention-2-style Pallas backward consuming the forward's
     log2-domain logsumexp. Every (block_q, block_k) P/dS tile lives and
     dies in VMEM — the previous pure-JAX backward streamed each of its
@@ -615,28 +769,39 @@ def _flash_bwd(causal, sm_scale, block_q, block_k, res, g):
     (~13 GB per step at S=4096), which bounded fwd+bwd at ~1.4x
     materialized attention on a v5e chip.
 
-    One launch where a (batch, head)'s whole dQ fits VMEM beside the tiles
-    (``_fused_bwd_dq_bytes`` within ``_FUSED_BWD_DQ_BYTES``: any sequence
-    the cells or the layers send): ``_flash_bwd_fused_kernel``, the dK/dV
-    pass accumulating dQ too. Past that, a dQ kernel (k innermost) and the
-    dK/dV kernel (q innermost), each rebuilding the score tiles. The shape
-    decides; both give the same bits and count themselves in
-    ``zoo_attention_backward_total``."""
+    One launch where the whole dQ of a (batch, kv head)'s queries fits
+    VMEM beside the tiles (``_fused_bwd_dq_bytes`` of the sequence times
+    the group, within ``_FUSED_BWD_DQ_BYTES``): ``_flash_bwd_fused_kernel``,
+    the dK/dV pass accumulating dQ too. Past that (8 query heads a kv head
+    at 16384 positions of 128 are 128 MiB), a dQ kernel (k innermost) and
+    the dK/dV kernel (q innermost), each rebuilding the score tiles. The
+    shape decides; both give the same bits and count themselves in
+    ``zoo_attention_backward_total``. k and v keep their own head count
+    throughout, and with a ``window`` every grid walks bands
+    (``_window_tiles``)."""
     import jax.experimental.pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
     q, k, v, o, lse = res
     interpret = _interpret()
     b, s_q, h, d = q.shape
-    s_k = k.shape[1]
+    s_k, h_kv = k.shape[1], k.shape[2]
+    group = h // h_kv
+    kv_row = _kv_row(group)
     d_v = v.shape[-1]            # g, o, dv carry v's head size; dq, dk q's
     bq, bk = _bwd_tile_sizes(s_q, s_k, block_q, block_k)
     nq, nk = s_q // bq, s_k // bk
-    bh = b * h
+    bh, bh_kv = b * h, b * h_kv
     cd = jnp.bfloat16 if q.dtype == jnp.bfloat16 else jnp.float32
+    # innermost extents: every tile, or with a window the widest band's
+    nq_in, nk_in, tiles_w = nq, nk, None
+    if window is not None:
+        tiles_w = _window_tiles(s_q, s_k, bq, bk, window)
+        nq_in, nk_in = tiles_w["q_steps"], tiles_w["k_steps"]
 
     def flat(a):                                 # (B,S,H,D) -> (B*H,S,D)
-        return jnp.moveaxis(a, 2, 1).reshape(bh, a.shape[1], a.shape[-1])
+        return jnp.moveaxis(a, 2, 1).reshape(b * a.shape[2], a.shape[1],
+                                             a.shape[-1])
 
     q2 = flat(q * jnp.asarray(sm_scale * LOG2_E, q.dtype))
     kf, vf, gf, of = flat(k), flat(v), flat(g.astype(q.dtype)), flat(o)
@@ -647,50 +812,66 @@ def _flash_bwd(causal, sm_scale, block_q, block_k, res, g):
 
     vma = varying_axes(q2, kf, vf, gf, lse, D)
     operands = tuple(mark_varying(a, vma) for a in (q2, kf, vf, gf, lse, D))
-    tiles = dict(block_q=bq, block_k=bk, causal=causal, q_offset=s_k - s_q,
-                 cd=cd)
-    # both kernels walk (bh, nk, nq), q innermost
-    if causal:
-        # the q tiles wholly above a k block's diagonal are skipped
-        # (pl.when): they name the first active tile again, so the block
-        # index does not change and nothing is fetched for them, as
-        # _flash_forward's k_index does for its masked tail. Here it
-        # pays: a skipped tile's q2 and g are (bq, d + d_v) of DMA with no
-        # compute to hide behind (the fused launch 37.3 -> 29.4 ms, the
-        # dK/dV launch 30.7 -> 22.4, at 2 x 32 heads x 8192 x 192/128 on
-        # a v5e; PERF.md, PR 38)
-        def q_index(bhi, ki, qi):
-            first = jnp.maximum((ki * bk - (s_k - s_q)) // bq, 0)
-            return (bhi, jnp.maximum(qi, first), 0)
-    else:
-        def q_index(bhi, ki, qi):
-            return (bhi, qi, 0)
+    off = s_k - s_q
+    tiles = dict(block_q=bq, block_k=bk, causal=causal, q_offset=off,
+                 cd=cd, window=window)
+    dkv_tiles = dict(tiles, num_q_blocks=nq_in, group=group,
+                     seq_q_blocks=nq)
+
+    # both kernels walk (bh_kv, nk, group * nq_in), q innermost
+    def q_index(bhi, ki, st):
+        if group == 1:
+            row, qi = bhi, st
+        else:
+            row, qi = bhi * group + st // nq_in, st % nq_in
+        if window is not None:
+            first, last = _q_band(ki, bq, bk, off, window, nq)
+            return (row, jnp.minimum(first + qi, last), 0)
+        if causal:
+            # the q tiles wholly above a k block's diagonal are skipped
+            # (pl.when): they name the first active tile again, so the
+            # block index does not change and nothing is fetched for them,
+            # as _flash_forward's k_index does for its masked tail. Here
+            # it pays: a skipped tile's q2 and g are (bq, d + d_v) of DMA
+            # with no compute to hide behind (the fused launch 37.3 ->
+            # 29.4 ms, the dK/dV launch 30.7 -> 22.4, at 2 x 32 heads x
+            # 8192 x 192/128 on a v5e; PERF.md, PR 38)
+            first = jnp.maximum((ki * bk - off) // bq, 0)
+            return (row, jnp.maximum(qi, first), 0)
+        return (row, qi, 0)
     by_q = [pl.BlockSpec((1, bq, w), q_index)
             for w in (d, d_v, 1, 1)]                     # q2, g, lse, D
     by_k = [pl.BlockSpec((1, bk, w), lambda bhi, ki, qi: (bhi, ki, 0))
             for w in (d, d_v)]                           # k | dk, v | dv
     dkv_in_specs = [by_q[0], *by_k, *by_q[1:]]
     dq_shape = jax.ShapeDtypeStruct((bh, s_q, d), q.dtype, vma=vma)
-    dkv_shapes = [jax.ShapeDtypeStruct((bh, s_k, d), k.dtype, vma=vma),
-                  jax.ShapeDtypeStruct((bh, s_k, d_v), v.dtype, vma=vma)]
+    dkv_shapes = [jax.ShapeDtypeStruct((bh_kv, s_k, d), k.dtype, vma=vma),
+                  jax.ShapeDtypeStruct((bh_kv, s_k, d_v), v.dtype, vma=vma)]
     dkv_scratch = [pltpu.VMEM((bk, d), jnp.float32),
                    pltpu.VMEM((bk, d_v), jnp.float32)]
 
-    dq_bytes = _fused_bwd_dq_bytes(s_q, d, q.dtype)
-    if dq_bytes <= _FUSED_BWD_DQ_BYTES:
+    dq_bytes = _fused_bwd_dq_bytes(group * s_q, d, q.dtype)
+    fused = dq_bytes <= _FUSED_BWD_DQ_BYTES
+    if tiles_w is not None:
+        passes = 1 if fused else 2
+        _TILES_NEEDED.inc(bh * tiles_w["needed"] * passes)
+        _TILES_VISITED.inc(bh * (tiles_w["by_q"] + (
+            0 if fused else tiles_w["by_k"])))
+    if fused:
         _BACKWARD_FUSED.inc()
         dq, dk, dv = pl.pallas_call(
             functools.partial(
-                _flash_bwd_fused_kernel, sm_scale=sm_scale, num_q_blocks=nq,
-                num_k_blocks=nk, **tiles),
-            grid=(bh, nk, nq),
+                _flash_bwd_fused_kernel, sm_scale=sm_scale,
+                num_k_blocks=nk, **dkv_tiles),
+            grid=(bh_kv, nk, group * nq_in),
             in_specs=dkv_in_specs,
-            out_specs=[pl.BlockSpec((1, s_q, d),
+            out_specs=[pl.BlockSpec((group, s_q, d),
                                     lambda bhi, ki, qi: (bhi, 0, 0)),
                        *by_k],
             out_shape=[dq_shape, *dkv_shapes],
-            scratch_shapes=[pltpu.VMEM((s_q, d), jnp.float32),
-                            *dkv_scratch],
+            scratch_shapes=[pltpu.VMEM(
+                (s_q, d) if group == 1 else (group, s_q, d), jnp.float32),
+                *dkv_scratch],
             # dQ is carried across both sequence dims: only batch*heads is
             # parallel, and the scoped limit grows by what dQ takes
             compiler_params=None if interpret else pltpu.CompilerParams(
@@ -700,21 +881,23 @@ def _flash_bwd(causal, sm_scale, block_q, block_k, res, g):
         )(*operands)
     else:
         _BACKWARD_TWO_KERNEL.inc()
-        # --- dQ: grid (bh, nq, nk), k innermost ----------------------------
+        # --- dQ: grid (bh, nq, nk_in), k innermost -------------------------
         if causal:
             # likewise the k blocks past a q tile's diagonal name the last
             # active one again (clamped at 0 as in _flash_forward)
             def k_index(bhi, qi, ki):
-                last = jnp.maximum((s_k - s_q + (qi + 1) * bq - 1) // bk, 0)
-                return (bhi, jnp.minimum(ki, last), 0)
+                last = jnp.maximum((off + (qi + 1) * bq - 1) // bk, 0)
+                if window is not None:
+                    ki = ki + _k_band(qi, bq, bk, off, window)[0]
+                return (kv_row(bhi), jnp.minimum(ki, last), 0)
         else:
             def k_index(bhi, qi, ki):
-                return (bhi, ki, 0)
+                return (kv_row(bhi), ki, 0)
         dq = pl.pallas_call(
             functools.partial(
-                _flash_bwd_dq_kernel, sm_scale=sm_scale, num_k_blocks=nk,
+                _flash_bwd_dq_kernel, sm_scale=sm_scale, num_k_blocks=nk_in,
                 **tiles),
-            grid=(bh, nq, nk),
+            grid=(bh, nq, nk_in),
             in_specs=[
                 pl.BlockSpec((1, bq, d), lambda bhi, qi, ki: (bhi, qi, 0)),
                 pl.BlockSpec((1, bk, d), k_index),
@@ -730,11 +913,10 @@ def _flash_bwd(causal, sm_scale, block_q, block_k, res, g):
             compiler_params=None if interpret else _mosaic_params(),
             interpret=interpret,
         )(*operands)
-        # --- dK/dV: grid (bh, nk, nq), q innermost -------------------------
+        # --- dK/dV: grid (bh_kv, nk, group * nq_in), q innermost -----------
         dk, dv = pl.pallas_call(
-            functools.partial(_flash_bwd_dkv_kernel, num_q_blocks=nq,
-                              **tiles),
-            grid=(bh, nk, nq),
+            functools.partial(_flash_bwd_dkv_kernel, **dkv_tiles),
+            grid=(bh_kv, nk, group * nq_in),
             in_specs=dkv_in_specs,
             out_specs=by_k,
             out_shape=dkv_shapes,
@@ -744,7 +926,7 @@ def _flash_bwd(causal, sm_scale, block_q, block_k, res, g):
         )(*operands)
 
     def unflat(a, s_len):
-        return jnp.moveaxis(a.reshape(b, h, s_len, a.shape[-1]), 1, 2)
+        return jnp.moveaxis(a.reshape(b, -1, s_len, a.shape[-1]), 1, 2)
 
     return unflat(dq, s_q), unflat(dk, s_k), unflat(dv, s_k)
 
@@ -754,10 +936,18 @@ _flash_attention.defvjp(_flash_fwd, _flash_bwd)
 
 def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
                     causal: bool = False, sm_scale: Optional[float] = None,
-                    block_q: int = 1024, block_k: int = 1024) -> jax.Array:
+                    block_q: int = 1024, block_k: int = 1024,
+                    window: Optional[int] = None) -> jax.Array:
     """Flash attention over (B, S, H, D); ``v`` may have a head size of its
     own (q and k (B, S, H, d_qk), v and the output (B, S, H, d_v)), as
-    latent attention's training form has. Uses the Pallas kernel when the
+    latent attention's training form has. k and v may have fewer heads
+    than q (grouped queries: each run of H / H_kv query heads reads one kv
+    head, through the kernels' index maps, with no copy of k or v for the
+    group; dK and dV are summed over it inside the kernel). ``window``
+    (causal only) keeps the keys ``0 <= t - j < window`` of query position
+    t: the kernels' grids walk each tile's band and nothing outside it is
+    fetched or computed; ``zoo_attention_window_tiles_total`` counts the
+    tiles. Uses the Pallas kernel when the
     sequence tiles evenly (compiled on a TPU, interpret mode on the CPU
     backend), else the reference path — which on a TPU is logged and
     counted (``zoo_attention_reference_on_tpu_total``), never silent.
@@ -775,13 +965,22 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
     fed; 2048-wide tiles spill VMEM and regress). The backward caps its
     tiles at 512 internally (``_bwd_tile_sizes``: its working set is ~4
     score tiles) and is one launch, dQ accumulated beside dK/dV, wherever
-    a (batch, head)'s whole dQ fits VMEM beside them; past
+    the whole dQ of a kv head's queries fits VMEM beside them; past
     ``_FUSED_BWD_DQ_BYTES`` it is a dQ launch and a dK/dV launch on the
     same tiles (``_flash_bwd``). fit_block below shrinks tiles for
     short/odd sequences."""
     if sm_scale is None:
         sm_scale = 1.0 / math.sqrt(q.shape[-1])
     s_q, s_k = q.shape[1], k.shape[1]
+    if q.shape[2] % k.shape[2] or k.shape[2] != v.shape[2]:
+        raise ValueError(f"{q.shape[2]} query heads cannot share "
+                         f"{k.shape[2]} key and {v.shape[2]} value heads")
+    if window is not None:
+        if not causal or window < 1:
+            raise ValueError("a window is a causal mask's: causal=True and "
+                             "window >= 1")
+        if window >= s_k:
+            window = None                # every causal key is inside it
 
     def fit_block(s, want):
         # largest tile <= want that divides the sequence, so raising the
@@ -805,12 +1004,14 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
                 "flash_attention: no kernel tile fits q%s k%s causal=%s; "
                 "materializing O(S^2) scores through mha_reference on the "
                 "TPU", tuple(q.shape), tuple(k.shape), causal)
-        return mha_reference(q, k, v, causal=causal, sm_scale=sm_scale)
+        return mha_reference(q, k, v, causal=causal, sm_scale=sm_scale,
+                             window=window)
     if interpret and varying_axes(q, k, v):
         # Interpret-mode pallas under shard_map: the HLO interpreter's
         # grid dynamic_slice rejects varying operands with invariant
         # indices for some (non-causal) shapes. The compiled kernel
         # handles vma (the union logic in _flash_forward); the CPU backend
         # uses the reference math.
-        return mha_reference(q, k, v, causal=causal, sm_scale=sm_scale)
-    return _flash_attention(q, k, v, causal, sm_scale, bq, bk)
+        return mha_reference(q, k, v, causal=causal, sm_scale=sm_scale,
+                             window=window)
+    return _flash_attention(q, k, v, causal, sm_scale, bq, bk, window)

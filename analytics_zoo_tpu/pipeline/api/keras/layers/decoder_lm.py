@@ -1,11 +1,17 @@
-"""A pre-norm decoder language model of the DeepSeek-V3 family: RMSNorm,
-interleaved RoPE, multi-head latent attention (MLA), SwiGLU, a leading dense
-block, sparse-expert blocks with a shared expert, and a multi-token-prediction
-(MTP) module that shares the embedding and the output head.
+"""A decoder language model of two published families, chosen by the keys of
+the ``config.json`` it is built from (:meth:`DecoderLM.from_config`):
 
-Built from a config dict with the published ``config.json`` key names
-(:meth:`DecoderLM.from_config`). Trained through the estimator like any other
-module::
+* the DeepSeek-V3 family: pre-norm, RMSNorm, interleaved RoPE, multi-head
+  latent attention (MLA), SwiGLU, leading dense blocks, sparse-expert blocks
+  with a shared expert, and a multi-token-prediction (MTP) module that shares
+  the embedding and the output head;
+* the ``afmoe`` family (a ``layer_types`` key): sandwich norms, gated
+  grouped-query attention with q/k norms whose layers are, by
+  ``layer_types``, sliding-window (rotate-half RoPE, a causal window) or
+  global (causal, no position encoding), the embedding scaled by
+  ``sqrt(hidden)``, the same expert layer, no MTP module.
+
+Trained through the estimator like any other module::
 
     model = DecoderLM.from_config(cfg)
     est = TPUEstimator(model, loss=model.loss(), optimizer=AdamWeightDecay(...))
@@ -32,7 +38,13 @@ Layer equations (x: (batch, seq, hidden)):
   statistics;
 * MTP (depth 1): ``h' = W_eh [RMSNorm(h_t) | RMSNorm(Emb(tok_{t+1}))]``, one
   more block, a norm, the main model's embedding and head, predicting
-  ``tok_{t+2}``.
+  ``tok_{t+2}``;
+* ``afmoe``: ``x = Emb(ids) sqrt(hidden)``; block ``x = x + N(Attn(N(x)))``;
+  ``x = x + N(FFN(N(x)))`` (four norms a block); ``q = x W_q``, ``k = x
+  W_k``, ``v = x W_v``, ``g = x W_g``; RMSNorm over each head's width on q
+  and k; on sliding layers RoPE and ``window``; each run of ``heads /
+  kv_heads`` query heads reads one key/value head, inside the flash kernel;
+  ``out = (softmax(q k^T / sqrt(d)) v * sigmoid(g)) W_o``.
 
 One rank of an expert-parallel deployment holds ``experts_held`` of the
 ``n_routed_experts`` experts of each layer, from ``first_expert`` on: the
@@ -83,18 +95,35 @@ class RMSNorm(nn.Module):
         return (y * w).astype(self.dtype)
 
 
+def _rope_angles(x, theta: float):
+    """cos and sin of ``pos * theta**(-2i/d)``, (1, seq, 1, d/2), float32."""
+    d = x.shape[-1]
+    inv = theta ** (-jnp.arange(0, d, 2, dtype=jnp.float32) / d)
+    ang = jnp.arange(x.shape[1], dtype=jnp.float32)[:, None] * inv[None, :]
+    return jnp.cos(ang)[None, :, None, :], jnp.sin(ang)[None, :, None, :]
+
+
 def rope_interleaved(x, theta: float):
     """Rotary position embedding over adjacent pairs ``(x[2i], x[2i+1])`` of
     the last axis, angle ``pos * theta**(-2i/d)``. x: (batch, seq, heads, d);
     computed in float32."""
-    d = x.shape[-1]
-    inv = theta ** (-jnp.arange(0, d, 2, dtype=jnp.float32) / d)
-    ang = jnp.arange(x.shape[1], dtype=jnp.float32)[:, None] * inv[None, :]
-    cos, sin = jnp.cos(ang)[None, :, None, :], jnp.sin(ang)[None, :, None, :]
+    cos, sin = _rope_angles(x, theta)
     x32 = x.astype(jnp.float32)
     even, odd = x32[..., 0::2], x32[..., 1::2]
     out = jnp.stack([even * cos - odd * sin, even * sin + odd * cos], -1)
     return out.reshape(x.shape).astype(x.dtype)
+
+
+def rope_half(x, theta: float):
+    """Rotary position embedding over the pairs ``(x[i], x[i + d/2])`` of the
+    last axis (rotate-half), angle ``pos * theta**(-2i/d)``. x: (batch, seq,
+    heads, d); computed in float32."""
+    d = x.shape[-1]
+    cos, sin = _rope_angles(x, theta)
+    x32 = x.astype(jnp.float32)
+    lo, hi = x32[..., :d // 2], x32[..., d // 2:]
+    return jnp.concatenate([lo * cos - hi * sin, hi * cos + lo * sin],
+                           -1).astype(x.dtype)
 
 
 def _dense(features: int, dtype, name: str, std: float = 0.02):
@@ -141,6 +170,44 @@ class MLAttention(nn.Module):
                                   sm_scale=1.0 / math.sqrt(dn + dr))
             return _dense(hidden, self.dtype, "o_proj")(
                 out.reshape(b, s, h * dv))
+
+
+class GQAttention(nn.Module):
+    """Gated grouped-query attention with q/k norms. ``window`` makes the
+    layer a sliding one (RoPE, the causal window); without it the layer is
+    global: causal, no position encoding. k and v go to the flash kernel
+    with their own ``num_kv_heads``."""
+    num_heads: int
+    num_kv_heads: int
+    head_dim: int
+    rope_theta: float
+    eps: float
+    window: Optional[int] = None
+    dtype: Any = jnp.bfloat16
+
+    @nn.compact
+    def __call__(self, x):
+        b, s, hidden = x.shape
+        h, hk, d = self.num_heads, self.num_kv_heads, self.head_dim
+        norm = functools.partial(RMSNorm, self.eps, self.dtype)
+        with jax.named_scope("attn.gqa"):
+            q = _dense(h * d, self.dtype, "q_proj")(x).reshape(b, s, h, d)
+            k = _dense(hk * d, self.dtype, "k_proj")(x).reshape(b, s, hk, d)
+            v = _dense(hk * d, self.dtype, "v_proj")(x).reshape(b, s, hk, d)
+            gate = _dense(h * d, self.dtype, "gate_proj")(x)
+            q, k = norm(name="q_norm")(q), norm(name="k_norm")(k)
+            if self.window is not None:
+                q, k = (rope_half(t, self.rope_theta) for t in (q, k))
+            with jax.named_scope("attn.global" if self.window is None
+                                 else "attn.window"):
+                out = flash_attention(q, k, v, causal=True,
+                                      window=self.window,
+                                      sm_scale=1.0 / math.sqrt(d))
+            out = out.reshape(b, s, h * d) * jax.nn.sigmoid(gate)
+            return _dense(hidden, self.dtype, "o_proj")(out)
+
+
+_ATTENTION = {"mla": MLAttention, "gqa": GQAttention}
 
 
 class SwiGLU(nn.Module):
@@ -237,22 +304,34 @@ _KEEP_FLASH_RESULTS = jax.checkpoint_policies.save_only_these_names(
 
 
 class DecoderBlock(nn.Module):
-    attention: Dict[str, Any]
+    attention: Dict[str, Any]            # its "kind" picks the module
     ffn_width: int                       # the dense block's; 0 for experts
     experts: Optional[Dict[str, Any]]
     eps: float
+    sandwich_norms: bool = False         # a norm after each sublayer too
     dtype: Any = jnp.bfloat16
 
     @nn.compact
     def __call__(self, x):
         norm = functools.partial(RMSNorm, self.eps, self.dtype)
-        x = x + MLAttention(eps=self.eps, dtype=self.dtype, name="self_attn",
-                            **self.attention)(norm(name="input_layernorm")(x))
-        h = norm(name="post_attention_layernorm")(x)
+        sizes = dict(self.attention)
+        attend = _ATTENTION[sizes.pop("kind", "mla")](
+            eps=self.eps, dtype=self.dtype, name="self_attn", **sizes)
+        a = attend(norm(name="input_layernorm")(x))
+        if self.sandwich_norms:
+            x = x + norm(name="post_attention_layernorm")(a)
+            h = norm(name="pre_mlp_layernorm")(x)
+        else:
+            x = x + a
+            h = norm(name="post_attention_layernorm")(x)
         if self.experts is None:
-            return x + SwiGLU(self.ffn_width, self.dtype, name="mlp")(h)
-        return x + SparseExperts(dtype=self.dtype, name="mlp",
-                                 **self.experts)(h)
+            y = SwiGLU(self.ffn_width, self.dtype, name="mlp")(h)
+        else:
+            y = SparseExperts(dtype=self.dtype, name="mlp",
+                              **self.experts)(h)
+        if self.sandwich_norms:
+            y = norm(name="post_mlp_layernorm")(y)
+        return x + y
 
 
 class DecoderLM(nn.Module):
@@ -266,12 +345,17 @@ class DecoderLM(nn.Module):
     num_hidden_layers: int
     first_k_dense_replace: int
     intermediate_size: int
-    attention: Any                       # FrozenDict of MLAttention's sizes
+    attention: Any                       # FrozenDict of the attention's sizes
     experts: Any                         # FrozenDict of SparseExperts' sizes
     num_nextn_predict_layers: int = 0
     rms_norm_eps: float = 1e-6
     dtype: Any = jnp.bfloat16
     mtp_loss_weight: float = 0.3
+    # the afmoe family's: each layer's window (None: a global layer), a norm
+    # after each sublayer, the embedding's scale
+    layer_windows: Optional[tuple] = None
+    sandwich_norms: bool = False
+    embed_scale: float = 1.0
 
     @classmethod
     def from_config(cls, cfg: Dict[str, Any], **overrides) -> "DecoderLM":
@@ -280,6 +364,8 @@ class DecoderLM(nn.Module):
         layer's ``n_routed_experts``; by default all of them),
         ``bias_update_rate`` (gamma), ``mtp_loss_weight`` (lambda),
         ``compute_dtype``."""
+        if "layer_types" in cfg:
+            return cls(**dict(_afmoe_fields(cfg), **overrides))
         if cfg.get("scoring_func", "sigmoid") != "sigmoid" or \
                 cfg.get("topk_method", "noaux_tc") != "noaux_tc" or \
                 cfg.get("n_group", 1) != 1 or not cfg.get("norm_topk_prob",
@@ -330,7 +416,7 @@ class DecoderLM(nn.Module):
         return functools.partial(next_token_loss,
                                  mtp_weight=self.mtp_loss_weight)
 
-    def _block(self, moe: bool, name: str):
+    def _block(self, moe: bool, name: str, layer: Optional[int] = None):
         """A rematerialised block that keeps the flash forward kernel's two
         results across the step: the attention output, B*S*H*d_v values of
         the compute dtype, and the logsumexp, a (B*H, S, 1) column of floats
@@ -341,11 +427,15 @@ class DecoderLM(nn.Module):
         block is rebuilt in the backward pass as before: norms,
         projections, RoPE (so q, k and v), the expert layer, the dense
         FFN."""
+        attention = dict(self.attention)
+        if self.layer_windows is not None:
+            attention["window"] = self.layer_windows[layer]
         return nn.remat(DecoderBlock, policy=_KEEP_FLASH_RESULTS)(
-            attention=dict(self.attention),
+            attention=attention,
             ffn_width=0 if moe else self.intermediate_size,
             experts=dict(self.experts) if moe else None,
-            eps=self.rms_norm_eps, dtype=self.dtype, name=name)
+            eps=self.rms_norm_eps, sandwich_norms=self.sandwich_norms,
+            dtype=self.dtype, name=name)
 
     @keras_call
     @nn.compact
@@ -370,9 +460,11 @@ class DecoderLM(nn.Module):
                                preferred_element_type=jnp.float32)
 
         x = embed(ids)
+        if self.embed_scale != 1.0:
+            x = x * jnp.asarray(self.embed_scale, x.dtype)
         for i in range(self.num_hidden_layers):
             x = self._block(i >= self.first_k_dense_replace,
-                            f"layers_{i}")(x)
+                            f"layers_{i}", i)(x)
         logits = logits_of(norm(name="norm")(x))
         if not self.num_nextn_predict_layers:
             return logits, None
@@ -385,6 +477,58 @@ class DecoderLM(nn.Module):
             h = self._block(True, "mtp_block")(h)
             mtp_logits = logits_of(norm(name="mtp_norm")(h))
         return logits, mtp_logits
+
+
+def _afmoe_fields(cfg: Dict[str, Any]) -> Dict[str, Any]:
+    """``DecoderLM``'s fields from the ``afmoe`` family's ``config.json``
+    keys (``num_dense_layers``, ``num_experts``, ``num_shared_experts``,
+    ``layer_types``, ``sliding_window``, ``num_key_value_heads``,
+    ``head_dim``, ``route_scale``, ``load_balance_coeff``, ``mup_enabled``,
+    ...) plus this repo's ``experts_held`` / ``first_expert`` /
+    ``compute_dtype``. ``layer_types`` names each layer's attention; the
+    first ``num_hidden_layers`` entries are used."""
+    if cfg.get("score_func", "sigmoid") != "sigmoid" or \
+            not cfg.get("route_norm", True) or cfg.get("n_group", 1) != 1:
+        raise ValueError("DecoderLM routes by sigmoid scores, one group, "
+                         "renormalised gates")
+    if cfg.get("rope_scaling") or cfg.get("tie_word_embeddings"):
+        raise ValueError("DecoderLM applies RoPE with no scaling and keeps "
+                         "an untied head")
+    from flax.core import FrozenDict
+    layers = int(cfg["num_hidden_layers"])
+    kinds = tuple(cfg["layer_types"])[:layers]
+    if len(kinds) != layers or \
+            set(kinds) - {"sliding_attention", "full_attention"}:
+        raise ValueError(f"layer_types names {layers} layers as "
+                         f"sliding_attention or full_attention, got {kinds}")
+    hidden, e = int(cfg["hidden_size"]), int(cfg["num_experts"])
+    heads = int(cfg["num_attention_heads"])
+    return dict(
+        vocab_size=int(cfg["vocab_size"]), hidden_size=hidden,
+        num_hidden_layers=layers,
+        first_k_dense_replace=int(cfg.get("num_dense_layers", 0)),
+        intermediate_size=int(cfg["intermediate_size"]),
+        attention=FrozenDict(
+            kind="gqa", num_heads=heads,
+            num_kv_heads=int(cfg.get("num_key_value_heads", heads)),
+            head_dim=int(cfg.get("head_dim", hidden // heads)),
+            rope_theta=float(cfg["rope_theta"])),
+        experts=FrozenDict(
+            n_routed_experts=e,
+            experts_held=int(cfg.get("experts_held", e)),
+            first_expert=int(cfg.get("first_expert", 0)),
+            num_experts_per_tok=int(cfg["num_experts_per_tok"]),
+            moe_intermediate_size=int(cfg["moe_intermediate_size"]),
+            n_shared_experts=int(cfg.get("num_shared_experts", 0)),
+            routed_scaling_factor=float(cfg.get("route_scale", 1.0)),
+            bias_update_rate=float(cfg.get("load_balance_coeff", 1e-3))),
+        layer_windows=tuple(int(cfg["sliding_window"])
+                            if kind == "sliding_attention" else None
+                            for kind in kinds),
+        sandwich_norms=True,
+        embed_scale=math.sqrt(hidden) if cfg.get("mup_enabled") else 1.0,
+        rms_norm_eps=float(cfg.get("rms_norm_eps", 1e-5)),
+        dtype=jnp.dtype(cfg.get("compute_dtype", "bfloat16")))
 
 
 def _shifted_nll(logits, ids, shift: int):

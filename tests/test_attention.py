@@ -453,3 +453,166 @@ def test_reference_fall_through_on_tpu_is_counted(monkeypatch):
     assert attn._REFERENCE_ON_TPU.value == before + 1
     np.testing.assert_allclose(out, mha_reference(q, k, v, causal=True),
                                rtol=1e-6)
+
+
+# --- grouped queries and a causal window --------------------------------------
+
+def _gqa_qkv(s_q, s_k, h, h_kv, d=16, d_v=16, seed=11):
+    rng = np.random.RandomState(seed)
+    mk = lambda s, hh, dd: jnp.asarray(rng.randn(1, s, hh, dd),   # noqa: E731
+                                       jnp.float32) * 0.5
+    return mk(s_q, h, d), mk(s_k, h_kv, d), mk(s_k, h_kv, d_v), \
+        mk(s_q, h, d_v)
+
+
+@pytest.mark.parametrize("s_q,s_k,h,h_kv,window,block", [
+    (64, 64, 4, 2, None, 16),       # grouped alone
+    (64, 64, 8, 1, None, 16),       # one kv head for all
+    (64, 64, 2, 2, 24, 16),         # a window alone, no multiple of the tile
+    (128, 128, 8, 2, 40, 16),       # both; q tiles wholly outside the window
+    (128, 128, 4, 2, 33, 32),       # the far edge inside a tile's first row
+    (32, 64, 4, 2, 24, 16),         # the decode shape, s_q < s_k
+    (64, 64, 4, 1, 17, 8),          # more bands than the window has tiles
+])
+def test_grouped_and_windowed_flash_matches_reference(monkeypatch, s_q, s_k,
+                                                      h, h_kv, window, block):
+    """k and v with fewer heads than q and a causal window, forward and
+    gradients against ``mha_reference`` given the same mask (which repeats k
+    and v and writes the mask out); dK and dV come back at the kv heads'
+    count, summed over each group inside the kernel; the fused backward and
+    the dQ + dK/dV pair agree to the bit."""
+    import analytics_zoo_tpu.ops.attention as attn
+    q, k, v, w = _gqa_qkv(s_q, s_k, h, h_kv)
+
+    def loss(fn, **kw):
+        return lambda q, k, v: jnp.sum(
+            fn(q, k, v, causal=True, window=window, **kw) * w)
+
+    tiles = dict(block_q=block, block_k=block)
+    out = flash_attention(q, k, v, causal=True, window=window, **tiles)
+    np.testing.assert_allclose(
+        np.asarray(out),
+        np.asarray(mha_reference(q, k, v, causal=True, window=window)),
+        rtol=2e-5, atol=2e-6)
+    want = jax.grad(loss(mha_reference), (0, 1, 2))(q, k, v)
+
+    def flash(backward):
+        # a closure of its own each time: the budget is read as the
+        # backward is traced
+        grad = jax.grad(loss(flash_attention, **tiles), (0, 1, 2))
+        assert pallas_kernels(jax.make_jaxpr(grad)(q, k, v).jaxpr) == \
+            ["_flash_kernel"] + backward
+        return grad(q, k, v)
+
+    fused = flash(["_flash_bwd_fused_kernel"])
+    monkeypatch.setattr(attn, "_FUSED_BWD_DQ_BYTES", 0)
+    pair = flash(["_flash_bwd_dq_kernel", "_flash_bwd_dkv_kernel"])
+    for a, b, c in zip(fused, pair, want):
+        assert a.shape == c.shape and bool(jnp.all(a == b))
+        np.testing.assert_allclose(np.asarray(a), np.asarray(c), rtol=1e-4,
+                                   atol=1e-5)
+
+
+def test_a_window_is_a_mask_inside_the_causal_one():
+    """``0 <= t - j < window``: a window of one sees the token alone, a
+    window of the sequence is the causal mask (and takes its kernels), a
+    shorter one differs from it; without ``causal`` it is refused, as are
+    query heads the kv heads do not divide."""
+    q, k, v, _ = _gqa_qkv(32, 32, 4, 2)
+    causal = flash_attention(q, k, v, causal=True, block_q=8, block_k=8)
+    np.testing.assert_allclose(
+        np.asarray(flash_attention(q, k, v, causal=True, window=1,
+                                   block_q=8, block_k=8)),
+        np.asarray(jnp.repeat(v, 2, axis=2)), rtol=1e-6, atol=1e-6)
+    whole = jax.make_jaxpr(lambda *a: flash_attention(
+        *a, causal=True, window=32, block_q=8, block_k=8))(q, k, v)
+    plain = jax.make_jaxpr(lambda *a: flash_attention(
+        *a, causal=True, block_q=8, block_k=8))(q, k, v)
+    assert str(whole) == str(plain)
+    short = flash_attention(q, k, v, causal=True, window=9, block_q=8,
+                            block_k=8)
+    assert float(jnp.abs(short - causal).max()) > 1e-3
+    with pytest.raises(ValueError, match="causal"):
+        flash_attention(q, k, v, window=8)
+    with pytest.raises(ValueError, match="heads"):
+        flash_attention(q[:, :, :3], k, v, causal=True)
+
+
+@pytest.mark.parametrize("window,block,band", [(40, 16, 4), (16, 16, 2),
+                                               (100, 32, 5)])
+def test_a_windowed_grid_walks_the_bands_alone(window, block, band):
+    """The innermost grid extent of every windowed kernel is the widest
+    band's tiles, not the sequence's: tiles wholly outside the window are
+    no grid steps at all, and ``zoo_attention_window_tiles_total`` counts
+    as many tiles visited as the mask needs, forward and backward. Run as
+    plain causal the same call would compute several times as many."""
+    import analytics_zoo_tpu.ops.attention as attn
+    s, h, h_kv = 256, 4, 2
+    q, k, v, w = _gqa_qkv(s, s, h, h_kv)
+    before = (attn._TILES_VISITED.value, attn._TILES_NEEDED.value)
+    jaxpr = jax.make_jaxpr(jax.grad(lambda q, k, v: jnp.sum(flash_attention(
+        q, k, v, causal=True, window=window, block_q=block,
+        block_k=block) * w), (0, 1, 2)))(q, k, v).jaxpr
+    grids = [tuple(e.params["grid_mapping"].grid) for e in equations(jaxpr)
+             if e.primitive.name == "pallas_call"]
+    n = s // block
+    assert grids == [(h, n, band), (h_kv, n, (h // h_kv) * band)]
+    visited, needed = (attn._TILES_VISITED.value - before[0],
+                       attn._TILES_NEEDED.value - before[1])
+    assert visited == needed == 2 * h * attn._window_tiles(
+        s, s, block, block, window)["needed"]
+    assert needed < 2 * h * n * (n + 1) // 2       # the causal triangle's
+
+
+def test_the_mla_paths_results_are_the_parents_to_the_bit():
+    """Equal heads, no window: the flash kernels' float32 output and
+    gradients are, to the bit, what the tree before grouped queries and
+    windows gave on the same inputs (the digests were taken from commit
+    86d9285's ``ops/attention.py`` in this installation: the fused backward
+    at 48/32 and 192/128, the pair at the decode shape, the non-causal
+    64/64 call site)."""
+    import hashlib
+    import analytics_zoo_tpu.ops.attention as attn
+    parents = {(48, 32, True, 64, None): "bc162117368110dd",
+               (192, 128, True, 64, None): "0bbe4d8b75470898",
+               (192, 128, True, 32, 0): "10cbe3aa4faac686",
+               (64, 64, False, 64, None): "4eceb98b5745e9ea"}
+    keep = attn._FUSED_BWD_DQ_BYTES
+    try:
+        for (d_qk, d_v, causal, s_q, budget), want in parents.items():
+            q, k, v, w = _mla_qkv(d_qk, d_v, s_q)
+            attn._FUSED_BWD_DQ_BYTES = keep if budget is None else budget
+            out = flash_attention(q, k, v, causal=causal, block_q=16,
+                                  block_k=16)
+            grads = _flash_grads(q, k, v, w, causal)
+            digest = hashlib.sha256()
+            for a in (out, *grads):
+                digest.update(np.asarray(a, np.float32).tobytes())
+            assert digest.hexdigest()[:16] == want, (d_qk, d_v, causal, s_q)
+    finally:
+        attn._FUSED_BWD_DQ_BYTES = keep
+
+
+def test_grouped_windowed_flash_lowers_to_mosaic_for_tpu(monkeypatch):
+    """The band-walking kernels through the Pallas -> Mosaic lowering, 8
+    query heads on 2 kv heads: the fused backward (2 custom calls a
+    gradient) and, past the budget, the pair (3); no k or v of 8 heads is
+    an operand of any of them."""
+    import analytics_zoo_tpu.ops.attention as attn
+    monkeypatch.setattr(attn, "_interpret", lambda: False)
+    q = jax.ShapeDtypeStruct((1, 2048, 8, 128), jnp.bfloat16)
+    kv = jax.ShapeDtypeStruct((1, 2048, 2, 128), jnp.bfloat16)
+
+    def loss(q, k, v):
+        return flash_attention(q, k, v, causal=True, window=640).astype(
+            jnp.float32).sum()
+
+    for budget, calls in ((None, 2), (0, 3)):
+        if budget is not None:
+            monkeypatch.setattr(attn, "_FUSED_BWD_DQ_BYTES", budget)
+        grad = jax.export.export(
+            jax.jit(jax.grad(loss, argnums=(0, 1, 2))),
+            platforms=["tpu"])(q, kv, kv).mlir_module()
+        assert grad.count("tpu_custom_call") == calls
+        assert "tensor<8x2048x128xbf16>" in grad          # q, by (b*h, s, d)
+        assert "tensor<2x2048x128xbf16>" in grad          # k and v as given
