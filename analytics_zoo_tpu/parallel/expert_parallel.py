@@ -30,9 +30,10 @@ a correction bias, the chosen scores renormalised and scaled) and
 :func:`held_experts_ffn` is told which contiguous range of experts it holds,
 sorts the token-choices routed to them by expert and runs grouped matrix
 products over those rows alone. No capacity, no dropped token: rows beyond
-the static buffer are worked off in further chunks. What the experts held
-elsewhere would add is not computed here; on one chip the layer runs
-without its exchange.
+the static buffer are worked off in further chunks, and inside a chunk the
+gather and the scatter-add move the rows the router sent, rounded up to a
+sub-block, not the buffer's size. What the experts held elsewhere would add is
+not computed here; on one chip the layer runs without its exchange.
 """
 
 from __future__ import annotations
@@ -282,6 +283,141 @@ def _round_up(x: int, to: int) -> int:
     return -(-x // to) * to
 
 
+def _sub_rows(chunk_rows: int, tile: int) -> int:
+    """Rows of one sub-block of a chunk's row movement: about an eighth of
+    the chunk, a whole number of the grouped product's row tiles, and a
+    divisor of the chunk, so no sub-block straddles its end."""
+    tiles = chunk_rows // tile
+    return tile * max(k for k in range(1, -(-tiles // 8) + 1)
+                      if tiles % k == 0)
+
+
+# A chunk's row movement follows the rows it was sent, rounded up to a
+# sub-block: ``lax.switch`` picks, by the number of sub-blocks that hold a
+# routed row (which only the device knows), the branch that moves that many
+# rows in ONE gather or scatter-add. XLA's row scatter-add costs about a
+# millisecond before its first row at these shapes (so a loop over sub-blocks
+# loses what it skips) and 2.5 MiB of program a branch (so the scatter-adds
+# take sub-blocks twice the gathers': their rows cost less than their
+# branches). ``choice`` is the chunk's slice of the sorted token-choices
+# (``token * top_k + k``). The switches are not differentiated: each
+# direction has its rule, which takes the same rows (the gather's transpose
+# is a scatter-add, the combine's a gather). The rules open the
+# ``moe.experts`` scope themselves, so the backward's operations are counted
+# where the forward's are.
+
+def _for_routed_rows(rows, sub, total, move, *args):
+    """``move(m, *args)`` for ``m`` = ``rows`` rounded up to whole sub-blocks
+    of ``sub`` rows (``total`` at most): a branch for each such ``m``."""
+    sizes = tuple(range(0, total, sub)) + (total,)
+    return lax.switch(jnp.minimum(-(-rows // sub), len(sizes) - 1),
+                      [functools.partial(move, m) for m in sizes], *args)
+
+
+def _pad_rows(a, total):
+    return jnp.pad(a, ((0, total - a.shape[0]),) + ((0, 0),) * (a.ndim - 1))
+
+
+# The branches are jitted at module level: a layer's switch then calls the
+# program another layer (or pass) already traced and lowered for the same
+# ``m`` and shapes, where tracing every branch anew took seconds of set-up.
+
+@functools.partial(jax.jit, static_argnums=(0, 1))
+def _fetch(top_k, m, x, choice):
+    """``x``'s rows for the first ``m`` token-choices, zeros after them."""
+    got = x.at[choice[:m] // top_k].get(mode="promise_in_bounds")
+    return _pad_rows(got, choice.shape[0]), jnp.int32(m)
+
+
+@functools.partial(jax.jit, static_argnums=(0, 1))
+def _add_rows(top_k, m, y, update, choice):
+    """``y[token of choice[p]] += update[p]`` for ``p < m``."""
+    return y.at[choice[:m] // top_k].add(update[:m],
+                                         mode="promise_in_bounds")
+
+
+def _live_gates(gates, choice, rows):
+    """``(live, g)`` of the first ``len(choice)`` rows of a chunk: which are
+    routed here (past the last routed row the choices are of experts held
+    elsewhere) and their gates, 0 where not live."""
+    live = jnp.arange(choice.shape[0]) < rows
+    return live, jnp.where(
+        live, gates.at[choice].get(mode="promise_in_bounds"), 0.0)
+
+
+@functools.partial(jax.jit, static_argnums=(0, 1))
+def _pull_combine(top_k, m, d_y, out, gates, choice, rows):
+    """The combine's transpose over the first ``m`` rows: ``(d_out, zeros
+    past them; d_gates)``."""
+    live, g = _live_gates(gates, choice[:m], rows)
+    got = d_y.at[choice[:m] // top_k].get(mode="promise_in_bounds")
+    d_g = jnp.where(live, jnp.sum(got * out[:m].astype(jnp.float32), -1), 0.0)
+    return (_pad_rows((got * g[:, None]).astype(out.dtype), out.shape[0]),
+            jnp.zeros_like(gates).at[choice[:m]].add(
+                d_g, mode="promise_in_bounds"))
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5))
+def _gather_rows(x, choice, rows, subs, top_k, n):
+    return _gather_rows_fwd(x, choice, rows, subs, top_k, n)[0]
+
+
+def _gather_rows_fwd(x, choice, rows, subs, top_k, n):
+    """``(buf, moved)``: ``buf[p] = x[token of choice[p]]`` for the ``moved``
+    rows ``p`` of the sub-blocks that hold a row ``p < rows``, zeros after
+    them. ``subs``: the gathers' and the scatter-adds' sub-block."""
+    del n
+    return (_for_routed_rows(rows, subs[0], choice.shape[0],
+                             functools.partial(_fetch, top_k), x, choice),
+            (choice, rows))
+
+
+def _gather_rows_bwd(subs, top_k, n, res, ct):
+    choice, rows = res
+    d_buf = ct[0]
+    with jax.named_scope("moe.experts"):
+        d_x = _for_routed_rows(
+            rows, subs[1], choice.shape[0],
+            functools.partial(_add_rows, top_k),
+            jnp.zeros((n, d_buf.shape[1]), d_buf.dtype), d_buf, choice)
+    return d_x, None, None
+
+
+_gather_rows.defvjp(_gather_rows_fwd, _gather_rows_bwd)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6))
+def _combine_rows(y, out, gates, choice, rows, subs, top_k):
+    return _combine_rows_fwd(y, out, gates, choice, rows, subs, top_k)[0]
+
+
+def _combine_rows_fwd(y, out, gates, choice, rows, subs, top_k):
+    """``y[token of choice[p]] += float32(out[p]) * gates[choice[p]]`` over
+    the sub-blocks that hold a row ``p < rows``; ``gates`` is (N * top_k,)
+    float32. The update is float32 and so is the sum, in the float32 ``y``
+    (the whole chunk's update is formed outside the branches, where it
+    fuses with the product's own last pass)."""
+    update = out.astype(jnp.float32) * _live_gates(gates, choice,
+                                                   rows)[1][:, None]
+    y = _for_routed_rows(rows, subs[1], choice.shape[0],
+                         functools.partial(_add_rows, top_k), y, update,
+                         choice)
+    return y, (out, gates, choice, rows)
+
+
+def _combine_rows_bwd(subs, top_k, res, d_y):
+    out, gates, choice, rows = res
+    with jax.named_scope("moe.experts"):
+        d_out, d_gates = _for_routed_rows(
+            rows, subs[0], choice.shape[0],
+            functools.partial(_pull_combine, top_k), d_y, out, gates, choice,
+            rows)
+    return d_y, d_out, d_gates, None, None
+
+
+_combine_rows.defvjp(_combine_rows_fwd, _combine_rows_bwd)
+
+
 def held_experts_ffn(x: jax.Array, idx: jax.Array, gates: jax.Array,
                      w_gate: jax.Array, w_up: jax.Array, w_down: jax.Array,
                      *, first_expert: int, n_experts: int
@@ -296,17 +432,93 @@ def held_experts_ffn(x: jax.Array, idx: jax.Array, gates: jax.Array,
     gather, three grouped products and a gate-weighted scatter-add into the
     float32 result. The first chunk (twice the rows a balanced router sends
     here) always runs; the further ones, up to the worst case
-    of every choice of every token landing here, run only when rows are left
-    and are rematerialised in the backward pass, so imbalance costs time and
+    of every choice of every token landing here, run only while rows are
+    left and are rebuilt in the backward pass, so imbalance costs time and
     never a token.
 
+    What the first chunk moves follows the rows it was sent, not its size:
+    the gather and the scatter-add, and their transposes in the backward
+    pass, take its rows up to the end of the sub-block that holds its last
+    routed row (an eighth of the chunk for the gathers, a quarter for the
+    scatter-adds), in one operation each. The gathered buffer is zeros past
+    it, and the grouped products, which run once a chunk over the whole
+    buffer, skip those tiles. An overflow's chunks move whole chunks.
+
     Returns ``(y (N, d) float32, counters)``: ``local_rows`` (token-choices
-    routed here), ``rows_max_over_mean`` (largest held expert's rows over
-    the held experts' mean), ``dropped_rows`` (routed here and not computed:
-    0 by construction, counted from what the chunks did)."""
+    routed here), ``moved_rows`` (rows the gathers fetched: the first
+    chunk's rows rounded up to a sub-block, an overflow's chunks whole,
+    counted from the branches that ran), ``rows_max_over_mean`` (largest
+    held expert's rows over the held experts' mean), ``dropped_rows``
+    (routed here and not computed: 0 by construction, counted from what the
+    chunks did)."""
     with jax.named_scope("moe.experts"):
         return _held_experts_ffn(x, idx, gates, w_gate, w_up, w_down,
                                  first_expert, n_experts)
+
+
+def _chunk(c, y, x, gates, ws, plan, static):
+    """Chunk ``c`` of the sorted token-choices added into ``y``: ``(y, rows
+    it held, rows its gather fetched)``."""
+    order, starts, ends = plan
+    chunk_rows, subs, top_k = static
+    lo = c * chunk_rows
+    choice = lax.dynamic_slice(order, (lo,), (chunk_rows,))
+    here = (jnp.clip(ends, lo, lo + chunk_rows)
+            - jnp.clip(starts, lo, lo + chunk_rows))
+    rows = jnp.sum(here)
+    sizes_c = jnp.concatenate([here, (chunk_rows - rows)[None]])
+    xs, moved = _gather_rows(x, choice, rows, subs, top_k, x.shape[0])
+    wg, wu, wd = ws
+    h = (jax.nn.silu(grouped_matmul(xs, wg, sizes_c))
+         * grouped_matmul(xs, wu, sizes_c))
+    out = grouped_matmul(h, wd, sizes_c)
+    return _combine_rows(y, out, gates, choice, rows, subs, top_k), rows, moved
+
+
+# The chunks after the first, while rows are left: a loop whose trip count
+# follows the rows routed here. Its backward rule walks the same chunks and
+# rebuilds each before transposing it, so an overflow holds one chunk's
+# intermediates at a time and a step without one pays for neither direction.
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5,))
+def _further_chunks(y, x, gates, ws, plan, static):
+    return _further_chunks_fwd(y, x, gates, ws, plan, static)[0]
+
+
+def _chunks_with_rows(plan, static):
+    return -(-plan[2][-1] // static[0])
+
+
+def _further_chunks_fwd(y, x, gates, ws, plan, static):
+    """``(y, rows the chunks held, rows their gathers fetched)``."""
+    def body(c, carry):
+        y, done, moved = carry
+        y, rows, fetched = _chunk(c, y, x, gates, ws, plan, static)
+        return y, done + rows, moved + fetched
+
+    out = lax.fori_loop(1, _chunks_with_rows(plan, static), body,
+                        (y, jnp.int32(0), jnp.int32(0)))
+    return out, (x, gates, ws, plan)
+
+
+def _further_chunks_bwd(static, res, ct):
+    x, gates, ws, plan = res
+    d_y = ct[0]
+
+    def body(c, acc):
+        # a chunk adds into y: its transpose does not depend on that y
+        _, pull = jax.vjp(
+            lambda *args: _chunk(c, jnp.zeros_like(d_y), *args, plan,
+                                 static)[0], x, gates, ws)
+        return jax.tree.map(jnp.add, acc, pull(d_y))
+
+    with jax.named_scope("moe.experts"):
+        acc = lax.fori_loop(1, _chunks_with_rows(plan, static), body,
+                            jax.tree.map(jnp.zeros_like, (x, gates, ws)))
+    return (d_y, *acc, None)
+
+
+_further_chunks.defvjp(_further_chunks_fwd, _further_chunks_bwd)
 
 
 def _held_experts_ffn(x, idx, gates, w_gate, w_up, w_down, first_expert,
@@ -315,52 +527,33 @@ def _held_experts_ffn(x, idx, gates, w_gate, w_up, w_down, first_expert,
     top_k = idx.shape[1]
     e_held = w_gate.shape[0]
     local = idx.reshape(-1) - first_expert
-    held = (local >= 0) & (local < e_held)
-    key = jnp.where(held, local, e_held)        # elsewhere: one trailing group
+    key = jnp.where((local >= 0) & (local < e_held), local, e_held)
+    # held experts first, by expert; elsewhere: one trailing group
     order = jnp.argsort(key, stable=True).astype(jnp.int32)
-    sizes = jnp.bincount(key, length=e_held + 1)[:e_held].astype(jnp.int32)
+    sizes = jnp.sum(key[None, :] == jnp.arange(e_held)[:, None], axis=1,
+                    dtype=jnp.int32)
     local_rows = jnp.sum(sizes)
     ends = jnp.cumsum(sizes)
-    starts = ends - sizes
 
     worst = n * min(top_k, e_held)
     share = 2 * n * top_k * e_held // n_experts
     tile = 512 if share >= 512 else 8
     chunk_rows = min(_round_up(max(share, 1), tile), _round_up(worst, tile))
     n_chunks = -(-worst // chunk_rows)
-    pad = max(n_chunks * chunk_rows - n * top_k, 0)
-    tok_sorted = jnp.pad(order // top_k, (0, pad))
-    gate_sorted = jnp.pad(
-        jnp.where(held[order], gates.reshape(-1)[order], 0.0), (0, pad))
-    wg, wu, wd = (w.astype(x.dtype) for w in (w_gate, w_up, w_down))
-
-    def chunk(c, y, done):
-        lo = c * chunk_rows
-        tok = lax.dynamic_slice(tok_sorted, (lo,), (chunk_rows,))
-        g = lax.dynamic_slice(gate_sorted, (lo,), (chunk_rows,))
-        here = (jnp.clip(ends, lo, lo + chunk_rows)
-                - jnp.clip(starts, lo, lo + chunk_rows))
-        rows = jnp.sum(here)
-        sizes_c = jnp.concatenate([here, (chunk_rows - rows)[None]])
-        xs = x[tok]
-        h = (jax.nn.silu(grouped_matmul(xs, wg, sizes_c))
-             * grouped_matmul(xs, wu, sizes_c))
-        out = grouped_matmul(h, wd, sizes_c)
-        y = y.at[tok].add(out.astype(jnp.float32) * g[:, None])
-        return y, done + rows
-
-    y, done = chunk(0, jnp.zeros((n, d), jnp.float32), jnp.int32(0))
+    order = jnp.pad(order, (0, max(n_chunks * chunk_rows - n * top_k, 0)))
+    args = (x, gates.reshape(-1),
+            tuple(w.astype(x.dtype) for w in (w_gate, w_up, w_down)),
+            (order, ends - sizes, ends))
+    sub = _sub_rows(chunk_rows, tile)
+    y, done, moved = _chunk(0, jnp.zeros((n, d), jnp.float32), *args,
+                            (chunk_rows, (sub, 2 * sub), top_k))
     if n_chunks > 1:
-        def rest(carry):
-            def body(carry, c):
-                # a chunk past the last routed row has nothing to do
-                return lax.cond(c * chunk_rows < local_rows,
-                                lambda cr: jax.checkpoint(chunk)(c, *cr),
-                                lambda cr: cr, carry), None
-            return lax.scan(body, carry, jnp.arange(1, n_chunks))[0]
-        y, done = lax.cond(local_rows > chunk_rows, rest,
-                           lambda carry: carry, (y, done))
+        # an overflow's chunks are full but for the last: they move whole
+        # chunks
+        y, held, fetched = _further_chunks(
+            y, *args, (chunk_rows, (chunk_rows, chunk_rows), top_k))
+        done, moved = done + held, moved + fetched
     mean = jnp.maximum(local_rows.astype(jnp.float32) / e_held, 1e-9)
-    return y, {"local_rows": local_rows,
+    return y, {"local_rows": local_rows, "moved_rows": moved,
                "rows_max_over_mean": jnp.max(sizes).astype(jnp.float32) / mean,
                "dropped_rows": local_rows - done}

@@ -75,6 +75,9 @@ _GAUGE_DOC = {
                               "experts' mean, worst layer, last step read",
     "moe_dropped_rows": "token-choices routed here and not computed since "
                         "the state was made: 0 by construction",
+    "moe_rows_moved_over_routed": "rows the expert layers' gathers fetched "
+                                  "over the token-choices routed here, all "
+                                  "steps counted: 1 is no padding moved",
 }
 
 INIT_POSITIONS = 128
@@ -252,9 +255,15 @@ class SparseExperts(nn.Module):
             idx, gates = route_noaux_tc(
                 flat, router, bias.value, top_k=self.num_experts_per_tok,
                 scaling=self.routed_scaling_factor)
-        y, counters = held_experts_ffn(
-            flat, idx, gates, w_gate, w_up, w_down,
-            first_expert=self.first_expert, n_experts=e)
+        if self.is_initializing():
+            # no parameter's shape depends on the held experts' output, and
+            # the engine's eager init would compile every operation of the
+            # dispatch for its prefix's shapes: seconds of set-up a run
+            y, counters = jnp.zeros((b * s, hidden), jnp.float32), None
+        else:
+            y, counters = held_experts_ffn(
+                flat, idx, gates, w_gate, w_up, w_down,
+                first_expert=self.first_expert, n_experts=e)
         y = y.astype(self.dtype).reshape(b, s, hidden)
         if self.n_shared_experts:
             with jax.named_scope("moe.shared"):
@@ -270,6 +279,8 @@ class SparseExperts(nn.Module):
         collections are read-only and nothing is written."""
         stats = {
             "rows_total": self.variable(MOE_STATS, "rows_total", jnp.zeros,
+                                        (), jnp.float32),
+            "rows_moved": self.variable(MOE_STATS, "rows_moved", jnp.zeros,
                                         (), jnp.float32),
             "steps": self.variable(MOE_STATS, "steps", jnp.zeros, (),
                                    jnp.int32),
@@ -289,6 +300,8 @@ class SparseExperts(nn.Module):
         if self.is_mutable_collection(MOE_STATS):
             stats["rows_total"].value += \
                 counters["local_rows"].astype(jnp.float32)
+            stats["rows_moved"].value += \
+                counters["moved_rows"].astype(jnp.float32)
             stats["steps"].value += 1
             stats["dropped_rows"].value += counters["dropped_rows"]
             stats["rows_max_over_mean"].value = counters["rows_max_over_mean"]
@@ -560,13 +573,15 @@ def moe_counters(extra_vars: Dict[str, Any]) -> Dict[str, float]:
     token-choices routed to the experts held, a step, summed over the
     layers), ``moe_rows_max_over_mean`` (the last step's imbalance over the
     experts held, the worst layer), ``moe_dropped_rows`` (must read 0),
-    ``moe_steps``."""
+    ``moe_rows_moved_over_routed`` (rows the gathers fetched over the rows
+    routed here, over the steps counted), ``moe_steps``."""
     stats = jax.device_get(extra_vars.get(MOE_STATS, {}))
     layers = [v["mlp"] for v in stats.values() if "mlp" in v]
     if not layers:
         return {}
     steps = max(int(l["steps"]) for l in layers)
     rows = float(sum(float(l["rows_total"]) for l in layers))
+    moved = float(sum(float(l["rows_moved"]) for l in layers))
     out = {
         "moe_steps": steps,
         "moe_rows_total": rows,
@@ -574,8 +589,9 @@ def moe_counters(extra_vars: Dict[str, Any]) -> Dict[str, float]:
         "moe_rows_max_over_mean": float(max(float(l["rows_max_over_mean"])
                                             for l in layers)),
         "moe_dropped_rows": int(sum(int(l["dropped_rows"]) for l in layers)),
+        "moe_rows_moved_over_routed": moved / max(rows, 1.0),
     }
     for name in ("moe_local_rows", "moe_rows_max_over_mean",
-                 "moe_dropped_rows"):
+                 "moe_dropped_rows", "moe_rows_moved_over_routed"):
         _REGISTRY.gauge(f"zoo_{name}", _GAUGE_DOC[name]).set(out[name])
     return out

@@ -4,6 +4,7 @@ float32 reference, at small widths on the CPU; the flash kernels at a head
 size of v's own; the expert layer's share, no-drop and bias-update rules; the
 model trained through ``TPUEstimator.fit`` on arrays."""
 
+import functools
 import json
 import os
 import sys
@@ -23,8 +24,10 @@ from harness import spec, work_lm                               # noqa: E402
 
 from analytics_zoo_tpu.ops.attention import (                   # noqa: E402
     flash_attention, mha_reference)
+from analytics_zoo_tpu.parallel import expert_parallel as ep    # noqa: E402
 from analytics_zoo_tpu.parallel.expert_parallel import (        # noqa: E402
     grouped_matmul, held_experts_ffn, noaux_bias_update, route_noaux_tc)
+from analytics_zoo_tpu.pipeline.api.keras.layers import decoder_lm  # noqa: E402
 from analytics_zoo_tpu.pipeline.api.keras.layers.decoder_lm import (  # noqa: E402
     DecoderLM, moe_counters, next_token_loss, rope_interleaved)
 from test_attention import equations, pallas_kernels            # noqa: E402
@@ -153,6 +156,39 @@ def test_a_training_forward_moves_bias_and_counters(sides):
     counters = moe_counters(new)
     assert counters["moe_dropped_rows"] == 0 and counters["moe_steps"] == 1
     assert 0 < counters["moe_local_rows"] < 3 * 2 * 32 * 4
+
+
+def test_init_does_not_run_the_held_experts():
+    """``init`` shapes the parameters and the state; the dispatch and the
+    grouped products, which shape none, are not in its program."""
+    model = DecoderLM.from_config(CFG)
+    jaxpr = jax.make_jaxpr(model.init)(
+        jax.random.PRNGKey(0), jnp.zeros((1, 32), jnp.uint16)).jaxpr
+    assert not [n for n in pallas_kernels(jaxpr) if "gmm" in n]
+    assert not [e for e in equations(jaxpr)
+                if e.primitive.name in ("sort", "scatter-add")]
+    shapes = jax.eval_shape(model.init, jax.random.PRNGKey(0),
+                            jnp.zeros((1, 32), jnp.uint16))
+    assert set(shapes["moe_stats"]["layers_1"]["mlp"]) >= {
+        "rows_total", "rows_moved", "dropped_rows", "load"}
+
+
+def test_the_counters_report_rows_moved_over_routed(sides):
+    """``moe_counters`` divides what the layers' gathers fetched by what the
+    router sent: at these sizes (64 tokens, 4 of 16 experts held, top-4: a
+    chunk of 128 rows in sub-blocks of 16) each layer moved its rows rounded
+    up to 16."""
+    from analytics_zoo_tpu.obs.registry import REGISTRY
+    layers = [v["mlp"] for v in sides["new"]["moe_stats"].values()]
+    moved = sum(float(l["rows_moved"]) for l in layers)
+    routed = sum(float(l["rows_total"]) for l in layers)
+    assert moved == sum(-(-float(l["rows_total"]) // 16) * 16 for l in layers)
+    counters = moe_counters(sides["new"])
+    assert counters["moe_rows_moved_over_routed"] == pytest.approx(
+        moved / routed)
+    assert 1.0 <= counters["moe_rows_moved_over_routed"] < 2.0
+    assert REGISTRY.gauge("zoo_moe_rows_moved_over_routed", "").value == \
+        pytest.approx(moved / routed)
 
 
 @pytest.mark.parametrize("causal", [False, True])
@@ -286,6 +322,121 @@ def test_no_token_is_dropped_where_one_expert_gets_the_rows(hot_share):
         cfg, held_p, "m", x, idx, gates, None, first=4, held=4))))(x)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want_g),
                                rtol=1e-3, atol=1e-5)
+
+
+# the sub-block walk: the rows a chunk moves follow the rows routed here
+_WALK = {8: dict(n=32, top_k=4, held=4, experts=16, d=16, f=8),
+         512: dict(n=1024, top_k=4, held=4, experts=16, d=16, f=8)}
+_WALK_ROWS = ("0", "1", "B-1", "B", "B+1", "straddle", "chunk", "past")
+
+
+def _walk_sizes(tile):
+    s = _WALK[tile]
+    chunk_rows = 2 * s["n"] * s["top_k"] * s["held"] // s["experts"]
+    assert chunk_rows % tile == 0 and (chunk_rows >= 512) == (tile == 512)
+    return chunk_rows, ep._sub_rows(chunk_rows, tile)
+
+
+def _walk_rows(tile, case):
+    chunk_rows, b = _walk_sizes(tile)
+    return {"0": 0, "1": 1, "B-1": b - 1, "B": b, "B+1": b + 1,
+            "straddle": chunk_rows - b - b // 2, "chunk": chunk_rows,
+            "past": chunk_rows + b + b // 2}[case]
+
+
+def _walk_inputs(tile, rows, first=4, seed=7):
+    """``rows`` token-choices on the held experts ``first .. first + held -
+    1``, every token's choices distinct, the rest elsewhere."""
+    s = _WALK[tile]
+    n, top_k, held, experts = s["n"], s["top_k"], s["held"], s["experts"]
+    rng = np.random.RandomState(seed + rows)
+    here = np.arange(first, first + held)
+    away = np.setdiff1d(np.arange(experts), here)
+    idx = np.empty((n, top_k), np.int32)
+    for t in range(n):
+        c = rows // n + (t < rows % n)
+        idx[t] = rng.permutation(np.concatenate(
+            [rng.permutation(here)[:c], rng.permutation(away)[:top_k - c]]))
+    assert ((idx >= first) & (idx < first + held)).sum() == rows
+    w = [jnp.asarray(rng.randn(*shape) * .3, jnp.float32)
+         for shape in ((held, s["d"], s["f"]), (held, s["d"], s["f"]),
+                       (held, s["f"], s["d"]))]
+    return (jnp.asarray(rng.randn(n, s["d"]), jnp.float32),
+            jnp.asarray(idx), jnp.asarray(rng.rand(n, top_k), jnp.float32),
+            w)
+
+
+def _dense_held(x, idx, gates, w, first=4):
+    """``sum_i g_ti F_i(x_t)`` over the held experts, every expert applied
+    to every token, float32."""
+    y = jnp.zeros_like(x)
+    for i in range(w[0].shape[0]):
+        g = jnp.sum(jnp.where(idx == first + i, gates, 0.0), axis=-1)
+        y = y + g[:, None] * ((jax.nn.silu(x @ w[0][i]) * (x @ w[1][i]))
+                              @ w[2][i])
+    return y
+
+
+@functools.lru_cache(maxsize=None)
+def _walk_programs(tile, remat):
+    experts = _WALK[tile]["experts"]
+
+    def layer(x, gates, w, idx):
+        return held_experts_ffn(x, idx, gates, *w, first_expert=4,
+                                n_experts=experts)
+
+    if remat:
+        layer = jax.checkpoint(layer, policy=decoder_lm._KEEP_FLASH_RESULTS)
+
+    def loss(x, gates, w, idx):
+        y, counters = layer(x, gates, w, idx)
+        return jnp.sum(jnp.sin(y)), (y, counters)
+
+    def dense(x, gates, w, idx):
+        y = _dense_held(x, idx, gates, w)
+        return jnp.sum(jnp.sin(y)), y
+
+    return tuple(jax.jit(jax.value_and_grad(f, argnums=(0, 1, 2),
+                                            has_aux=True))
+                 for f in (loss, dense))
+
+
+def _assert_walk_matches_dense(tile, case, remat=False):
+    chunk_rows, b = _walk_sizes(tile)
+    rows = _walk_rows(tile, case)
+    x, idx, gates, w = _walk_inputs(tile, rows)
+    program, dense = _walk_programs(tile, remat)
+    (_, (y, counters)), grads = program(x, gates, w, idx)
+    (_, want), want_grads = dense(x, gates, w, idx)
+    assert int(counters["local_rows"]) == rows
+    assert int(counters["dropped_rows"]) == 0
+    # the first chunk's gathers fetch its rows rounded up to a sub-block;
+    # an overflow's chunks are fetched whole
+    first = min(rows, chunk_rows)
+    assert int(counters["moved_rows"]) == \
+        -(-first // b) * b + -(-(rows - first) // chunk_rows) * chunk_rows
+    np.testing.assert_allclose(np.asarray(y), np.asarray(want), rtol=1e-4,
+                               atol=1e-5)
+    for got, ref_g in zip(jax.tree.leaves(grads),
+                          jax.tree.leaves(want_grads)):
+        np.testing.assert_allclose(np.asarray(got), np.asarray(ref_g),
+                                   rtol=1e-3, atol=1e-5)
+
+
+@pytest.mark.parametrize("case", _WALK_ROWS)
+@pytest.mark.parametrize("tile", [8, 512])
+def test_a_chunk_moves_the_rows_routed_and_equals_the_dense_layer(tile,
+                                                                  case):
+    """Output and gradients (x, gates, the three weight stacks) against a
+    dense float32 evaluation, nothing dropped, and the gathers fetched the
+    routed rows rounded up to a sub-block (an overflow's chunks whole); at
+    the 512-row tile the grouped products run interpreted."""
+    _assert_walk_matches_dense(tile, case)
+
+
+@pytest.mark.parametrize("case", ["0", "straddle", "past"])
+def test_the_walk_is_the_same_under_the_blocks_remat_policy(case):
+    _assert_walk_matches_dense(8, case, remat=True)
 
 
 def test_grouped_matmul_matches_ragged_dot():
