@@ -40,7 +40,7 @@ from __future__ import annotations
 
 import functools
 import math
-from typing import Any, Callable, Dict, Tuple
+from typing import Any, Callable, Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -419,18 +419,22 @@ _combine_rows.defvjp(_combine_rows_fwd, _combine_rows_bwd)
 
 
 def held_experts_ffn(x: jax.Array, idx: jax.Array, gates: jax.Array,
-                     w_gate: jax.Array, w_up: jax.Array, w_down: jax.Array,
-                     *, first_expert: int, n_experts: int
+                     w_gate: Optional[jax.Array], w_up: jax.Array,
+                     w_down: jax.Array, *, first_expert: int, n_experts: int,
+                     activation: Callable = jax.nn.silu
                      ) -> Tuple[jax.Array, Dict[str, jax.Array]]:
     """The part of ``y_t = sum_i g_ti F_i(x_t)`` that the experts
-    ``first_expert .. first_expert + E_held - 1`` give, ``F`` a SwiGLU.
+    ``first_expert .. first_expert + E_held - 1`` give. The expert's form
+    follows its stacks: with ``w_gate`` ``F(x) = (act(x W_gate) * x W_up)
+    W_down`` (a SwiGLU at the default ``activation``), with ``w_gate`` None
+    ``F(x) = act(x W_up) W_down``.
 
     x: (N, d); idx, gates: (N, top_k) from the router over all ``n_experts``;
     w_gate, w_up: (E_held, d, f); w_down: (E_held, f, d). The token-choices
     routed here are sorted by expert (a stable sort: tokens ascending inside
     an expert) and worked off in chunks of a static number of rows, each a
-    gather, three grouped products and a gate-weighted scatter-add into the
-    float32 result. The first chunk (twice the rows a balanced router sends
+    gather, a grouped product a stack and a gate-weighted scatter-add into
+    the float32 result. The first chunk (twice the rows a balanced router sends
     here) always runs; the further ones, up to the worst case
     of every choice of every token landing here, run only while rows are
     left and are rebuilt in the backward pass, so imbalance costs time and
@@ -453,14 +457,14 @@ def held_experts_ffn(x: jax.Array, idx: jax.Array, gates: jax.Array,
     chunks did)."""
     with jax.named_scope("moe.experts"):
         return _held_experts_ffn(x, idx, gates, w_gate, w_up, w_down,
-                                 first_expert, n_experts)
+                                 first_expert, n_experts, activation)
 
 
 def _chunk(c, y, x, gates, ws, plan, static):
     """Chunk ``c`` of the sorted token-choices added into ``y``: ``(y, rows
     it held, rows its gather fetched)``."""
     order, starts, ends = plan
-    chunk_rows, subs, top_k = static
+    chunk_rows, subs, top_k, act = static
     lo = c * chunk_rows
     choice = lax.dynamic_slice(order, (lo,), (chunk_rows,))
     here = (jnp.clip(ends, lo, lo + chunk_rows)
@@ -469,8 +473,11 @@ def _chunk(c, y, x, gates, ws, plan, static):
     sizes_c = jnp.concatenate([here, (chunk_rows - rows)[None]])
     xs, moved = _gather_rows(x, choice, rows, subs, top_k, x.shape[0])
     wg, wu, wd = ws
-    h = (jax.nn.silu(grouped_matmul(xs, wg, sizes_c))
-         * grouped_matmul(xs, wu, sizes_c))
+    if wg is None:
+        h = act(grouped_matmul(xs, wu, sizes_c))
+    else:
+        h = (act(grouped_matmul(xs, wg, sizes_c))
+             * grouped_matmul(xs, wu, sizes_c))
     out = grouped_matmul(h, wd, sizes_c)
     return _combine_rows(y, out, gates, choice, rows, subs, top_k), rows, moved
 
@@ -522,10 +529,10 @@ _further_chunks.defvjp(_further_chunks_fwd, _further_chunks_bwd)
 
 
 def _held_experts_ffn(x, idx, gates, w_gate, w_up, w_down, first_expert,
-                      n_experts):
+                      n_experts, act):
     n, d = x.shape
     top_k = idx.shape[1]
-    e_held = w_gate.shape[0]
+    e_held = w_up.shape[0]
     local = idx.reshape(-1) - first_expert
     key = jnp.where((local >= 0) & (local < e_held), local, e_held)
     # held experts first, by expert; elsewhere: one trailing group
@@ -542,16 +549,17 @@ def _held_experts_ffn(x, idx, gates, w_gate, w_up, w_down, first_expert,
     n_chunks = -(-worst // chunk_rows)
     order = jnp.pad(order, (0, max(n_chunks * chunk_rows - n * top_k, 0)))
     args = (x, gates.reshape(-1),
-            tuple(w.astype(x.dtype) for w in (w_gate, w_up, w_down)),
+            tuple(None if w is None else w.astype(x.dtype)
+                  for w in (w_gate, w_up, w_down)),
             (order, ends - sizes, ends))
     sub = _sub_rows(chunk_rows, tile)
     y, done, moved = _chunk(0, jnp.zeros((n, d), jnp.float32), *args,
-                            (chunk_rows, (sub, 2 * sub), top_k))
+                            (chunk_rows, (sub, 2 * sub), top_k, act))
     if n_chunks > 1:
         # an overflow's chunks are full but for the last: they move whole
         # chunks
         y, held, fetched = _further_chunks(
-            y, *args, (chunk_rows, (chunk_rows, chunk_rows), top_k))
+            y, *args, (chunk_rows, (chunk_rows, chunk_rows), top_k, act))
         done, moved = done + held, moved + fetched
     mean = jnp.maximum(local_rows.astype(jnp.float32) / e_held, 1e-9)
     return y, {"local_rows": local_rows, "moved_rows": moved,

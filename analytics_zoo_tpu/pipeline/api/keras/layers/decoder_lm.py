@@ -1,5 +1,5 @@
-"""A decoder language model of two published families, chosen by the keys of
-the ``config.json`` it is built from (:meth:`DecoderLM.from_config`):
+"""A decoder language model of three published families, chosen by the keys
+of the ``config.json`` it is built from (:meth:`DecoderLM.from_config`):
 
 * the DeepSeek-V3 family: pre-norm, RMSNorm, interleaved RoPE, multi-head
   latent attention (MLA), SwiGLU, leading dense blocks, sparse-expert blocks
@@ -9,7 +9,14 @@ the ``config.json`` it is built from (:meth:`DecoderLM.from_config`):
   grouped-query attention with q/k norms whose layers are, by
   ``layer_types``, sliding-window (rotate-half RoPE, a causal window) or
   global (causal, no position encoding), the embedding scaled by
-  ``sqrt(hidden)``, the same expert layer, no MTP module.
+  ``sqrt(hidden)``, the same expert layer, no MTP module;
+* the ``nemotron_h`` family (a ``hybrid_override_pattern`` key): every block
+  is ONE mixer behind one norm, by the pattern's letter a Mamba-2 mixer
+  (``M``), plain grouped-query attention (``*``: no gate, no q/k norms, no
+  position encoding) or the expert layer in its latent form (``E``: experts
+  of two matrices and a squared ReLU in a ``moe_latent_size``-wide space, a
+  shared expert of its own width on the hidden rows); an MTP module built
+  from ``mtp_hybrid_override_pattern``.
 
 Trained through the estimator like any other module::
 
@@ -46,9 +53,25 @@ Layer equations (x: (batch, seq, hidden)):
   kv_heads`` query heads reads one key/value head, inside the flash kernel;
   ``out = (softmax(q k^T / sqrt(d)) v * sigmoid(g)) W_o``.
 
+* ``nemotron_h``: ``x = x + Mixer(RMSNorm(x))`` a block. ``M``: ``[z | xBC |
+  dt] = x W_in``; ``xBC = silu(conv1d_causal(xBC))`` (depthwise, with bias);
+  ``[x' | B | C] = xBC``; ``dt = softplus(dt + dt_bias)``, ``A = -exp(A_log)``;
+  a head's state ``S_t = exp(dt_t A) S_{t-1} + dt_t x'_t B_t^T``, ``y_t = S_t
+  C_t + D x'_t`` over the whole sequence (``ops/ssm.py``, in chunks of
+  ``chunk_size``); ``y = RMSNorm_grouped(y * silu(z))`` over each group's
+  channels; ``y W_out``. ``E``: the router scores the hidden rows; ``u = x
+  W_down_latent``; ``r = sum_k gate_k W2_k relu(W1_k u)^2`` over the experts
+  held; ``r W_up_latent + W2_s relu(W1_s x)^2``.
+
 One rank of an expert-parallel deployment holds ``experts_held`` of the
 ``n_routed_experts`` experts of each layer, from ``first_expert`` on: the
 router keeps its full width and the layer computes its own experts' part.
+One rank of a ``mixer_parallel_size``-way tensor-parallel deployment of the
+``nemotron_h`` mixers holds that share of the Mamba-2 heads with their B/C
+and norm groups, and of the query heads with the kv head they read: the
+matching columns of the input projections and rows of the output
+projection, whose result is then a partial sum. On one chip either layer
+runs without its exchange.
 """
 
 from __future__ import annotations
@@ -64,6 +87,7 @@ import jax.numpy as jnp
 from analytics_zoo_tpu.obs.registry import REGISTRY as _REGISTRY
 from analytics_zoo_tpu.ops.attention import (
     FLASH_RESIDUAL_NAMES, flash_attention)
+from analytics_zoo_tpu.ops.ssm import causal_conv1d, ssd_scan
 from analytics_zoo_tpu.parallel.expert_parallel import (
     held_experts_ffn, noaux_bias_update, route_noaux_tc)
 from ..engine.graph import keras_call
@@ -176,16 +200,19 @@ class MLAttention(nn.Module):
 
 
 class GQAttention(nn.Module):
-    """Gated grouped-query attention with q/k norms. ``window`` makes the
-    layer a sliding one (RoPE, the causal window); without it the layer is
-    global: causal, no position encoding. k and v go to the flash kernel
-    with their own ``num_kv_heads``."""
+    """Grouped-query attention, by default gated and with q/k norms (the
+    ``afmoe`` family's; ``gated`` and ``qk_norm`` off leave the plain form).
+    ``window`` makes the layer a sliding one (RoPE, the causal window);
+    without it the layer is global: causal, no position encoding. k and v go
+    to the flash kernel with their own ``num_kv_heads``."""
     num_heads: int
     num_kv_heads: int
     head_dim: int
     rope_theta: float
     eps: float
     window: Optional[int] = None
+    gated: bool = True
+    qk_norm: bool = True
     dtype: Any = jnp.bfloat16
 
     @nn.compact
@@ -197,8 +224,10 @@ class GQAttention(nn.Module):
             q = _dense(h * d, self.dtype, "q_proj")(x).reshape(b, s, h, d)
             k = _dense(hk * d, self.dtype, "k_proj")(x).reshape(b, s, hk, d)
             v = _dense(hk * d, self.dtype, "v_proj")(x).reshape(b, s, hk, d)
-            gate = _dense(h * d, self.dtype, "gate_proj")(x)
-            q, k = norm(name="q_norm")(q), norm(name="k_norm")(k)
+            if self.gated:
+                gate = _dense(h * d, self.dtype, "gate_proj")(x)
+            if self.qk_norm:
+                q, k = norm(name="q_norm")(q), norm(name="k_norm")(k)
             if self.window is not None:
                 q, k = (rope_half(t, self.rope_theta) for t in (q, k))
             with jax.named_scope("attn.global" if self.window is None
@@ -206,28 +235,122 @@ class GQAttention(nn.Module):
                 out = flash_attention(q, k, v, causal=True,
                                       window=self.window,
                                       sm_scale=1.0 / math.sqrt(d))
-            out = out.reshape(b, s, h * d) * jax.nn.sigmoid(gate)
+            out = out.reshape(b, s, h * d)
+            if self.gated:
+                out = out * jax.nn.sigmoid(gate)
             return _dense(hidden, self.dtype, "o_proj")(out)
 
 
 _ATTENTION = {"mla": MLAttention, "gqa": GQAttention}
 
 
+def relu_squared(x):
+    return jnp.square(jax.nn.relu(x))
+
+
+_ACTIVATIONS = {"silu": jax.nn.silu, "relu2": relu_squared}
+
+
 class SwiGLU(nn.Module):
+    """``(act(x W_gate) * x W_up) W_down``; with ``gated`` off the feed-forward
+    of two matrices, ``act(x W_up) W_down``."""
     width: int
+    dtype: Any = jnp.bfloat16
+    activation: str = "silu"
+    gated: bool = True
+
+    @nn.compact
+    def __call__(self, x):
+        act = _ACTIVATIONS[self.activation]
+        if self.gated:
+            gate = _dense(self.width, self.dtype, "gate_proj")(x)
+        up = _dense(self.width, self.dtype, "up_proj")(x)
+        return _dense(x.shape[-1], self.dtype, "down_proj")(
+            act(gate) * up if self.gated else act(up))
+
+
+def _log_uniform(lo: float, hi: float):
+    """log of a uniform draw in [lo, hi] (``A_log``)."""
+    def init(key, shape, dtype=jnp.float32):
+        return jnp.log(jax.random.uniform(key, shape, dtype, lo, hi))
+    return init
+
+
+def _inverse_softplus_log_uniform(lo: float, hi: float):
+    """The inverse softplus of a log-uniform draw in [lo, hi] (``dt_bias``:
+    ``softplus(dt_bias)`` is the draw)."""
+    def init(key, shape, dtype=jnp.float32):
+        dt = jnp.exp(jax.random.uniform(key, shape, dtype, math.log(lo),
+                                        math.log(hi)))
+        return dt + jnp.log(-jnp.expm1(-dt))
+    return init
+
+
+class Mamba2Mixer(nn.Module):
+    """A Mamba-2 mixer, or the share of one that ``num_heads`` of its heads
+    with their ``n_groups`` B/C and norm groups make (the module docstring
+    has the equations). ``in_proj``'s columns are ``[z | x' | B | C | dt]``;
+    the convolution runs over ``[x' | B | C]``; the gated norm is over each
+    group's ``num_heads * head_dim / n_groups`` channels."""
+    num_heads: int
+    head_dim: int
+    n_groups: int
+    state_size: int
+    conv_kernel: int
+    chunk_size: int
+    eps: float
     dtype: Any = jnp.bfloat16
 
     @nn.compact
     def __call__(self, x):
-        gate = _dense(self.width, self.dtype, "gate_proj")(x)
-        up = _dense(self.width, self.dtype, "up_proj")(x)
-        return _dense(x.shape[-1], self.dtype, "down_proj")(
-            jax.nn.silu(gate) * up)
+        b, s, hidden = x.shape
+        h, p, g, n = (self.num_heads, self.head_dim, self.n_groups,
+                      self.state_size)
+        inner, f32 = h * p, jnp.float32
+        conv_dim = inner + 2 * g * n
+        with jax.named_scope("ssm.mixer"):
+            zxbcdt = _dense(inner + conv_dim + h, self.dtype, "in_proj")(x)
+            conv_w = self.param(
+                "conv1d_weight", nn.initializers.normal(
+                    1.0 / math.sqrt(self.conv_kernel)),
+                (self.conv_kernel, conv_dim))
+            conv_b = self.param("conv1d_bias", nn.initializers.zeros,
+                                (conv_dim,))
+            a_log = self.param("A_log", _log_uniform(1.0, 16.0), (h,))
+            dt_bias = self.param("dt_bias", _inverse_softplus_log_uniform(
+                1e-3, 1e-1), (h,))
+            d = self.param("D", nn.initializers.ones, (h,))
+            norm_w = self.param("norm_weight", nn.initializers.ones, (inner,))
+            z, xbc, dt = jnp.split(zxbcdt, [inner, inner + conv_dim], axis=-1)
+            if self.is_initializing():
+                # no parameter's shape depends on the scan: the engine's
+                # eager init does not compile it for its prefix
+                y = z
+            else:
+                xbc = jax.nn.silu(causal_conv1d(xbc, conv_w, conv_b))
+                xs, bm, cm = jnp.split(xbc, [inner, inner + g * n], axis=-1)
+                y = ssd_scan(
+                    xs.reshape(b, s, h, p),
+                    jax.nn.softplus(dt.astype(f32) + dt_bias),
+                    -jnp.exp(a_log.astype(f32)), bm.reshape(b, s, g, n),
+                    cm.reshape(b, s, g, n), d,
+                    chunk_size=self.chunk_size).reshape(b, s, inner)
+            y = (y.astype(f32) * jax.nn.silu(z.astype(f32))).reshape(
+                b, s, g, inner // g)
+            y = y * jax.lax.rsqrt(jnp.mean(jnp.square(y), -1, keepdims=True)
+                                  + self.eps)
+            y = (y.reshape(b, s, inner) * norm_w).astype(self.dtype)
+            return _dense(hidden, self.dtype, "out_proj")(y)
 
 
 class SparseExperts(nn.Module):
     """The expert layer, as the rank that holds ``experts_held`` experts
-    from ``first_expert`` on computes it, plus the shared expert."""
+    from ``first_expert`` on computes it, plus the shared expert. With a
+    ``latent_size`` the routed experts work on rows of that width, between a
+    projection down and one up (the router and the shared expert read the
+    hidden rows); ``gated`` off makes every expert, the shared one too,
+    ``act(x W_up) W_down``; ``shared_width`` is the shared expert's own
+    (else ``moe_intermediate_size * n_shared_experts``)."""
     n_routed_experts: int
     experts_held: int
     first_expert: int
@@ -237,38 +360,55 @@ class SparseExperts(nn.Module):
     routed_scaling_factor: float
     bias_update_rate: float
     dtype: Any = jnp.bfloat16
+    latent_size: int = 0
+    activation: str = "silu"
+    gated: bool = True
+    shared_width: int = 0
 
     @nn.compact
     def __call__(self, x):
         b, s, hidden = x.shape
         e, held, f = (self.n_routed_experts, self.experts_held,
                       self.moe_intermediate_size)
+        d = self.latent_size or hidden       # the routed experts' rows
         flat = x.reshape(b * s, hidden)
         router = self.param("gate", nn.initializers.normal(0.02), (hidden, e))
         bias = self.variable(ROUTER_STATE, "e_score_correction_bias",
                              jnp.zeros, (e,), jnp.float32)
         init = nn.initializers.normal(0.02)
-        w_gate = self.param("experts_gate_proj", init, (held, hidden, f))
-        w_up = self.param("experts_up_proj", init, (held, hidden, f))
-        w_down = self.param("experts_down_proj", init, (held, f, hidden))
+        w_gate = self.param("experts_gate_proj", init, (held, d, f)) \
+            if self.gated else None
+        w_up = self.param("experts_up_proj", init, (held, d, f))
+        w_down = self.param("experts_down_proj", init, (held, f, d))
         with jax.named_scope("moe.router"):
             idx, gates = route_noaux_tc(
                 flat, router, bias.value, top_k=self.num_experts_per_tok,
                 scaling=self.routed_scaling_factor)
+        rows = flat
+        if self.latent_size:
+            with jax.named_scope("moe.latent"):
+                rows = _dense(d, self.dtype, "latent_down_proj")(flat)
         if self.is_initializing():
             # no parameter's shape depends on the held experts' output, and
             # the engine's eager init would compile every operation of the
             # dispatch for its prefix's shapes: seconds of set-up a run
-            y, counters = jnp.zeros((b * s, hidden), jnp.float32), None
+            y, counters = jnp.zeros((b * s, d), jnp.float32), None
         else:
             y, counters = held_experts_ffn(
-                flat, idx, gates, w_gate, w_up, w_down,
-                first_expert=self.first_expert, n_experts=e)
-        y = y.astype(self.dtype).reshape(b, s, hidden)
+                rows, idx, gates, w_gate, w_up, w_down,
+                first_expert=self.first_expert, n_experts=e,
+                activation=_ACTIVATIONS[self.activation])
+        y = y.astype(self.dtype)
+        if self.latent_size:
+            with jax.named_scope("moe.latent"):
+                y = _dense(hidden, self.dtype, "latent_up_proj")(y)
+        y = y.reshape(b, s, hidden)
         if self.n_shared_experts:
             with jax.named_scope("moe.shared"):
-                y = y + SwiGLU(f * self.n_shared_experts, self.dtype,
-                               name="shared_experts")(x)
+                y = y + SwiGLU(
+                    self.shared_width or f * self.n_shared_experts,
+                    self.dtype, self.activation, self.gated,
+                    name="shared_experts")(x)
         self._after_step(bias, idx, counters)
         return y
 
@@ -347,6 +487,28 @@ class DecoderBlock(nn.Module):
         return x + y
 
 
+# a ``nemotron_h`` block's one mixer by its letter in the pattern, and the
+# name it takes in the tree (the expert layer's is the one the counters and
+# the other families' trees have)
+_MIXERS = {"M": (Mamba2Mixer, "mixer"), "*": (GQAttention, "self_attn"),
+           "E": (SparseExperts, "mlp")}
+
+
+class MixerBlock(nn.Module):
+    """``x + Mixer(RMSNorm(x))``: ``kind`` a key of ``_MIXERS``, ``sizes``
+    that module's."""
+    kind: str
+    sizes: Dict[str, Any]
+    eps: float
+    dtype: Any = jnp.bfloat16
+
+    @nn.compact
+    def __call__(self, x):
+        module, name = _MIXERS[self.kind]
+        return x + module(dtype=self.dtype, name=name, **self.sizes)(
+            RMSNorm(self.eps, self.dtype, name="norm")(x))
+
+
 class DecoderLM(nn.Module):
     """ids (batch, seq) -> ``(logits, mtp_logits)``, both (batch, seq, vocab)
     in float32. ``logits[:, t]`` scores ``tok_{t+1}``; ``mtp_logits[:, t]``
@@ -369,6 +531,11 @@ class DecoderLM(nn.Module):
     layer_windows: Optional[tuple] = None
     sandwich_norms: bool = False
     embed_scale: float = 1.0
+    # the nemotron_h family's: each block's one mixer by its letter (M, *,
+    # E), the MTP module's blocks likewise, the Mamba-2 mixer's sizes
+    layer_kinds: Optional[tuple] = None
+    mtp_kinds: tuple = ()
+    mamba: Any = None
 
     @classmethod
     def from_config(cls, cfg: Dict[str, Any], **overrides) -> "DecoderLM":
@@ -376,7 +543,12 @@ class DecoderLM(nn.Module):
         ``experts_held`` / ``first_expert`` (this rank's share of each
         layer's ``n_routed_experts``; by default all of them),
         ``bias_update_rate`` (gamma), ``mtp_loss_weight`` (lambda),
-        ``compute_dtype``."""
+        ``compute_dtype``; for the ``nemotron_h`` family also
+        ``mixer_parallel_size`` / ``mixer_parallel_rank`` (this rank's share
+        of each Mamba-2 mixer's and attention layer's heads; by default the
+        whole layer)."""
+        if "hybrid_override_pattern" in cfg:
+            return cls(**dict(_nemotron_h_fields(cfg), **overrides))
         if "layer_types" in cfg:
             return cls(**dict(_afmoe_fields(cfg), **overrides))
         if cfg.get("scoring_func", "sigmoid") != "sigmoid" or \
@@ -429,6 +601,15 @@ class DecoderLM(nn.Module):
         return functools.partial(next_token_loss,
                                  mtp_weight=self.mtp_loss_weight)
 
+    def _mixer_block(self, kind: str, name: str):
+        """A rematerialised one-mixer block (the same policy object as
+        :meth:`_block`: an attention block keeps its flash results, the
+        others keep nothing)."""
+        sizes = {"M": self.mamba, "*": self.attention, "E": self.experts}
+        return nn.remat(MixerBlock, policy=_KEEP_FLASH_RESULTS)(
+            kind=kind, sizes=dict(sizes[kind]), eps=self.rms_norm_eps,
+            dtype=self.dtype, name=name)
+
     def _block(self, moe: bool, name: str, layer: Optional[int] = None):
         """A rematerialised block that keeps the flash forward kernel's two
         results across the step: the attention output, B*S*H*d_v values of
@@ -476,8 +657,11 @@ class DecoderLM(nn.Module):
         if self.embed_scale != 1.0:
             x = x * jnp.asarray(self.embed_scale, x.dtype)
         for i in range(self.num_hidden_layers):
-            x = self._block(i >= self.first_k_dense_replace,
-                            f"layers_{i}", i)(x)
+            if self.layer_kinds is not None:
+                x = self._mixer_block(self.layer_kinds[i], f"layers_{i}")(x)
+            else:
+                x = self._block(i >= self.first_k_dense_replace,
+                                f"layers_{i}", i)(x)
         logits = logits_of(norm(name="norm")(x))
         if not self.num_nextn_predict_layers:
             return logits, None
@@ -487,7 +671,11 @@ class DecoderLM(nn.Module):
                 [norm(name="mtp_hnorm")(x), norm(name="mtp_enorm")(embed(nxt))],
                 axis=-1)
             h = _dense(self.hidden_size, self.dtype, "mtp_eh_proj")(merged)
-            h = self._block(True, "mtp_block")(h)
+            if self.layer_kinds is not None:
+                for i, kind in enumerate(self.mtp_kinds):
+                    h = self._mixer_block(kind, f"mtp_layers_{i}")(h)
+            else:
+                h = self._block(True, "mtp_block")(h)
             mtp_logits = logits_of(norm(name="mtp_norm")(h))
         return logits, mtp_logits
 
@@ -542,6 +730,91 @@ def _afmoe_fields(cfg: Dict[str, Any]) -> Dict[str, Any]:
         embed_scale=math.sqrt(hidden) if cfg.get("mup_enabled") else 1.0,
         rms_norm_eps=float(cfg.get("rms_norm_eps", 1e-5)),
         dtype=jnp.dtype(cfg.get("compute_dtype", "bfloat16")))
+
+
+def _nemotron_h_fields(cfg: Dict[str, Any]) -> Dict[str, Any]:
+    """``DecoderLM``'s fields from the ``nemotron_h`` family's ``config.json``
+    keys (``hybrid_override_pattern``, ``mamba_num_heads``,
+    ``mamba_head_dim``, ``ssm_state_size``, ``n_groups``, ``conv_kernel``,
+    ``chunk_size``, ``moe_latent_size``, ``moe_shared_expert_intermediate_size``,
+    ``mtp_hybrid_override_pattern``, ...) plus this repo's share keys. The
+    pattern names each layer's mixer; the first ``num_hidden_layers`` letters
+    are used. ``mixer_parallel_size`` t gives every Mamba-2 mixer ``1/t`` of
+    its heads and of its B/C and norm groups, every attention layer ``1/t``
+    of its query heads and the kv heads they read (one where t exceeds
+    them); ``mixer_parallel_rank`` says which: it picks a checkpoint's
+    columns and rows, never a shape."""
+    if cfg.get("n_group", 1) != 1 or not cfg.get("norm_topk_prob", True):
+        raise ValueError("DecoderLM routes by sigmoid scores, one group, "
+                         "renormalised gates")
+    if cfg.get("mlp_hidden_act", "relu2") != "relu2" or \
+            cfg.get("mamba_hidden_act", "silu") != "silu":
+        raise ValueError("DecoderLM computes this family's experts with a "
+                         "squared ReLU and its Mamba-2 mixers with SiLU")
+    if cfg.get("tie_word_embeddings") or cfg.get("attention_bias") or \
+            cfg.get("mlp_bias") or cfg.get("mamba_proj_bias") or \
+            cfg.get("use_bias") or not cfg.get("use_conv_bias", True):
+        raise ValueError("DecoderLM keeps an untied head and no bias but "
+                         "the convolution's")
+    from flax.core import FrozenDict
+    layers = int(cfg["num_hidden_layers"])
+    kinds = tuple(cfg["hybrid_override_pattern"])[:layers]
+    mtp = int(cfg.get("num_nextn_predict_layers", 0))
+    mtp_kinds = tuple(cfg.get("mtp_hybrid_override_pattern", "")) if mtp \
+        else ()
+    if len(kinds) != layers or set(kinds + mtp_kinds) - set(_MIXERS):
+        raise ValueError(f"hybrid_override_pattern names {layers} layers by "
+                         f"M, * or E, got {''.join(kinds)!r} (MTP "
+                         f"{''.join(mtp_kinds)!r})")
+    if mtp not in (0, 1) or (mtp and not mtp_kinds):
+        raise ValueError("DecoderLM has an MTP module of depth 0 or 1, built "
+                         "from mtp_hybrid_override_pattern")
+    t = int(cfg.get("mixer_parallel_size", 1))
+    rank = int(cfg.get("mixer_parallel_rank", 0))
+    hidden, e = int(cfg["hidden_size"]), int(cfg["n_routed_experts"])
+    heads, kv = int(cfg["num_attention_heads"]), \
+        int(cfg["num_key_value_heads"])
+    m_heads, groups = int(cfg["mamba_num_heads"]), int(cfg["n_groups"])
+    if not 0 <= rank < t or heads % t or m_heads % t or groups % t or \
+            (kv % t and t % kv):
+        raise ValueError(
+            f"mixer_parallel_size {t} (rank {rank}) does not divide "
+            f"{m_heads} Mamba-2 heads in {groups} groups and {heads} query "
+            f"heads over {kv} kv heads into whole shares")
+    eps = float(cfg.get("layer_norm_epsilon", cfg.get("norm_eps", 1e-5)))
+    return dict(
+        vocab_size=int(cfg["vocab_size"]), hidden_size=hidden,
+        num_hidden_layers=layers, first_k_dense_replace=0,
+        intermediate_size=int(cfg.get("intermediate_size", 0)),
+        layer_kinds=kinds, mtp_kinds=mtp_kinds,
+        num_nextn_predict_layers=mtp,
+        mamba=FrozenDict(
+            num_heads=m_heads // t, head_dim=int(cfg["mamba_head_dim"]),
+            n_groups=groups // t, state_size=int(cfg["ssm_state_size"]),
+            conv_kernel=int(cfg["conv_kernel"]),
+            chunk_size=int(cfg["chunk_size"]), eps=eps),
+        attention=FrozenDict(
+            num_heads=heads // t, num_kv_heads=max(kv // t, 1),
+            head_dim=int(cfg.get("head_dim", hidden // heads)),
+            rope_theta=float(cfg.get("rope_theta", 10000.0)),
+            eps=eps, gated=False, qk_norm=False),
+        experts=FrozenDict(
+            n_routed_experts=e,
+            experts_held=int(cfg.get("experts_held", e)),
+            first_expert=int(cfg.get("first_expert", 0)),
+            num_experts_per_tok=int(cfg["num_experts_per_tok"]),
+            moe_intermediate_size=int(cfg["moe_intermediate_size"]),
+            n_shared_experts=int(cfg.get("n_shared_experts", 0)),
+            routed_scaling_factor=float(cfg.get("routed_scaling_factor",
+                                                1.0)),
+            bias_update_rate=float(cfg.get("bias_update_rate", 1e-3)),
+            latent_size=int(cfg.get("moe_latent_size") or 0),
+            activation="relu2", gated=False,
+            shared_width=int(cfg.get("moe_shared_expert_intermediate_size",
+                                     0))),
+        rms_norm_eps=eps,
+        dtype=jnp.dtype(cfg.get("compute_dtype", "bfloat16")),
+        mtp_loss_weight=float(cfg.get("mtp_loss_weight", 0.3)))
 
 
 def _shifted_nll(logits, ids, shift: int):
