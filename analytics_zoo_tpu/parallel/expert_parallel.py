@@ -45,6 +45,7 @@ from typing import Any, Callable, Dict, Optional, Tuple
 import jax
 import jax.numpy as jnp
 from jax import lax
+from jax.ad_checkpoint import checkpoint_name
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from ..ops import attention as _attention
@@ -186,33 +187,83 @@ def moe_apply(expert_fn: Callable, expert_params: Any,
 # One rank's share of a sigmoid-routed, bias-balanced expert layer
 # ---------------------------------------------------------------------------
 
+# the name under which a block's rematerialisation policy keeps the routing
+# decision: the backward pass reads the forward's choice, it does not select
+# a second time
+ROUTER_CHOICE_NAME = "moe_router_choice"
+
+
+def _chosen_mask(idx: jax.Array, n_experts: int) -> jax.Array:
+    """``hot[n, j, e] = (idx[n, j] == e)``: never materialised, each use
+    below is one compare-and-reduce fusion over N x top_k x E entries."""
+    return idx[:, :, None] == lax.broadcasted_iota(
+        jnp.int32, (1, 1, n_experts), 2)
+
+
+@jax.custom_vjp
+def _take_chosen(scores: jax.Array, idx: jax.Array) -> jax.Array:
+    """``scores[n, idx[n, j]]`` as a compare-and-sum over the expert axis
+    (exact: one non-zero term), where a gather would move N x top_k scalars
+    one by one."""
+    hot = _chosen_mask(idx, scores.shape[-1])
+    return jnp.sum(jnp.where(hot, scores[:, None, :], 0), axis=-1)
+
+
+def _take_chosen_fwd(scores, idx):
+    # the scores come along for their static shape alone (the sigmoid's own
+    # rule keeps them already)
+    return _take_chosen(scores, idx), (scores, idx)
+
+
+def _take_chosen_bwd(res, g):
+    # the transpose written out (exact too: a row's choices are distinct),
+    # so that XLA is not left to scatter-add N x top_k scalars
+    scores, idx = res
+    hot = _chosen_mask(idx, scores.shape[-1])
+    return jnp.sum(jnp.where(hot, g[:, :, None], 0), axis=1), None
+
+
+_take_chosen.defvjp(_take_chosen_fwd, _take_chosen_bwd)
+
+
 def route_noaux_tc(x: jax.Array, router_w: jax.Array, bias: jax.Array, *,
                    top_k: int, scaling: float = 1.0
                    ) -> Tuple[jax.Array, jax.Array]:
     """Auxiliary-loss-free routing (``scoring_func: sigmoid``, ``topk_method:
     noaux_tc`` with one group): ``s = sigmoid(x W_r)`` in float32 over ALL
-    experts; the ``top_k`` experts of a token are the largest ``s + bias``;
-    their gates are ``s[chosen] / sum(s[chosen]) * scaling``. ``bias`` only
-    steers the choice and takes no gradient.
+    experts; the ``top_k`` experts of a token are the largest ``s + bias``
+    (``lax.top_k``'s choice: ties to the lower index); their gates are
+    ``s[chosen] / sum(s[chosen]) * scaling``. ``bias`` only steers the choice
+    and takes no gradient. The choice carries :data:`ROUTER_CHOICE_NAME` for a
+    rematerialisation policy to keep.
 
     x: (N, d); router_w: (d, E); bias: (E,). Returns ``(idx (N, top_k) int32,
     gates (N, top_k) float32)``."""
     scores = jax.nn.sigmoid(jnp.dot(x.astype(jnp.float32),
                                     router_w.astype(jnp.float32)))
-    _, idx = lax.top_k(scores + lax.stop_gradient(bias.astype(jnp.float32)),
+    _, idx = lax.top_k(lax.stop_gradient(scores + bias.astype(jnp.float32)),
                        top_k)
-    chosen = jnp.take_along_axis(scores, idx, axis=-1)
+    idx = checkpoint_name(idx.astype(jnp.int32), ROUTER_CHOICE_NAME)
+    chosen = _take_chosen(scores, idx)
     gates = chosen / (jnp.sum(chosen, axis=-1, keepdims=True) + 1e-20)
-    return idx.astype(jnp.int32), gates * scaling
+    return idx, gates * scaling
 
 
-def noaux_bias_update(bias: jax.Array, idx: jax.Array, rate: float
+def expert_load(idx: jax.Array, n_experts: int) -> jax.Array:
+    """The step's token-choices for each of ALL experts, (n_experts,) int32:
+    a compare-and-sum like the chosen scores', where ``jnp.bincount`` lowers
+    to a scatter of every choice."""
+    return jnp.sum(_chosen_mask(idx, n_experts), axis=(0, 1),
+                   dtype=jnp.int32)
+
+
+def noaux_bias_update(bias: jax.Array, load: jax.Array, rate: float
                       ) -> jax.Array:
     """The correction bias after a step: ``b_i += rate * sign(mean load -
-    load_i)`` with ``load_i`` the step's token-choices for expert i, so an
-    overloaded expert is chosen less and an idle one more."""
-    load = jnp.bincount(idx.reshape(-1), length=bias.shape[0]
-                        ).astype(jnp.float32)
+    load_i)`` with ``load_i`` the step's token-choices for expert i
+    (:func:`expert_load`), so an overloaded expert is chosen less and an idle
+    one more."""
+    load = load.astype(jnp.float32)
     return bias + rate * jnp.sign(jnp.mean(load) - load).astype(bias.dtype)
 
 

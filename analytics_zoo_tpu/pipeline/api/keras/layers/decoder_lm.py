@@ -89,7 +89,8 @@ from analytics_zoo_tpu.ops.attention import (
     FLASH_RESIDUAL_NAMES, flash_attention)
 from analytics_zoo_tpu.ops.ssm import causal_conv1d, ssd_scan
 from analytics_zoo_tpu.parallel.expert_parallel import (
-    held_experts_ffn, noaux_bias_update, route_noaux_tc)
+    ROUTER_CHOICE_NAME, expert_load, held_experts_ffn, noaux_bias_update,
+    route_noaux_tc)
 from ..engine.graph import keras_call
 
 _GAUGE_DOC = {
@@ -433,9 +434,10 @@ class SparseExperts(nn.Module):
         }
         if self.is_initializing():
             return
-        if self.is_mutable_collection(ROUTER_STATE):
-            with jax.named_scope("moe.router"):
-                bias.value = noaux_bias_update(bias.value, idx,
+        with jax.named_scope("moe.router"):
+            load = expert_load(idx, self.n_routed_experts)
+            if self.is_mutable_collection(ROUTER_STATE):
+                bias.value = noaux_bias_update(bias.value, load,
                                                self.bias_update_rate)
         if self.is_mutable_collection(MOE_STATS):
             stats["rows_total"].value += \
@@ -445,15 +447,15 @@ class SparseExperts(nn.Module):
             stats["steps"].value += 1
             stats["dropped_rows"].value += counters["dropped_rows"]
             stats["rows_max_over_mean"].value = counters["rows_max_over_mean"]
-            stats["load"].value = jnp.bincount(
-                idx.reshape(-1), length=self.n_routed_experts
-            ).astype(jnp.int32)
+            stats["load"].value = load
 
 
 # one policy object for every block: blocks whose remat parameters are the
-# same object share one lowered function, as blocks under a plain remat do
-_KEEP_FLASH_RESULTS = jax.checkpoint_policies.save_only_these_names(
-    *FLASH_RESIDUAL_NAMES)
+# same object share one lowered function, as blocks under a plain remat do.
+# It keeps the flash forward kernel's two results and an expert layer's
+# routing decision ((tokens, top_k) int32: the backward pass selects nothing)
+_KEPT_ACROSS_REMAT = jax.checkpoint_policies.save_only_these_names(
+    *FLASH_RESIDUAL_NAMES, ROUTER_CHOICE_NAME)
 
 
 class DecoderBlock(nn.Module):
@@ -603,10 +605,10 @@ class DecoderLM(nn.Module):
 
     def _mixer_block(self, kind: str, name: str):
         """A rematerialised one-mixer block (the same policy object as
-        :meth:`_block`: an attention block keeps its flash results, the
-        others keep nothing)."""
+        :meth:`_block`: an attention block keeps its flash results, an
+        expert block its routing decision, a Mamba-2 block nothing)."""
         sizes = {"M": self.mamba, "*": self.attention, "E": self.experts}
-        return nn.remat(MixerBlock, policy=_KEEP_FLASH_RESULTS)(
+        return nn.remat(MixerBlock, policy=_KEPT_ACROSS_REMAT)(
             kind=kind, sizes=dict(sizes[kind]), eps=self.rms_norm_eps,
             dtype=self.dtype, name=name)
 
@@ -617,14 +619,15 @@ class DecoderLM(nn.Module):
         (134 MB and 2 MB of values a block at 2 x 8192 positions, 32 heads,
         v of 128, bfloat16; the step's peak on a v5e fell by 0.11 GB), so
         the backward pass runs dQ and dK/dV on the first launch's results
-        and not the forward kernel a second time. Everything else of the
-        block is rebuilt in the backward pass as before: norms,
-        projections, RoPE (so q, k and v), the expert layer, the dense
-        FFN."""
+        and not the forward kernel a second time; an expert layer's choice
+        of experts is kept too (0.5 MB a block at 16384 rows, top-8).
+        Everything else of the block is rebuilt in the backward pass as
+        before: norms, projections, RoPE (so q, k and v), the router's
+        scores, the expert layer, the dense FFN."""
         attention = dict(self.attention)
         if self.layer_windows is not None:
             attention["window"] = self.layer_windows[layer]
-        return nn.remat(DecoderBlock, policy=_KEEP_FLASH_RESULTS)(
+        return nn.remat(DecoderBlock, policy=_KEPT_ACROSS_REMAT)(
             attention=attention,
             ffn_width=0 if moe else self.intermediate_size,
             experts=dict(self.experts) if moe else None,

@@ -1,23 +1,32 @@
 #!/usr/bin/env python
-"""Where the time under ``moe.experts`` goes, op by op, on the chip.
+"""Where the time under one scope of the expert layers goes, op by op, on
+the chip.
 
     python scripts/moe_dispatch_probe.py [--shapes gqa mla] [--steps 4]
     python scripts/moe_dispatch_probe.py --cell trinity_mini.fit.packed16k
+    python scripts/moe_dispatch_probe.py --scope moe.router \\
+        --layer nemotron3_super.fit.packed8k [--cell ...]
 
-Without ``--cell``: ``held_experts_ffn`` alone, forward + rematerialised
-forward + backward (under ``jax.checkpoint`` with the decoder's policy, as a
-block runs it), at the two token cells' shapes (16384 x 2048 tokens, top-8;
-``gqa``: 16 held of 128 experts at width 1024, ``mla``: 16 of 256 at width
-768) under three seeded routings: ``balanced`` (every expert as likely),
-``zipf`` (a few hot experts, some of them held), ``one_hot`` (every token's
-first choice is one held expert). With ``--cell``: one traced run of that
-benchmark cell, the table taken from the cell's own trace.
+Without ``--cell`` or ``--layer``: ``held_experts_ffn`` alone, forward +
+rematerialised forward + backward (under ``jax.checkpoint`` with the
+decoder's policy, as a block runs it), at the two older token cells' shapes
+(16384 x 2048 tokens, top-8; ``gqa``: 16 held of 128 experts at width 1024,
+``mla``: 16 of 256 at width 768) under three seeded routings: ``balanced``
+(every expert as likely), ``zipf`` (a few hot experts, some of them held),
+``one_hot`` (every token's first choice is one held expert). With ``--layer``:
+the whole expert layer of those cells' configurations alone (router, held and
+shared experts, the bias's update and the counters, under the same policy) on
+the cell's rows a step. With ``--cell``: one traced run of that benchmark
+cell, the table taken from the cell's own trace.
 
-Either way the table is the device's SELF time of every operation whose
-``op_name`` path holds the scope, a step, by phase (forward, the
-rematerialised forward, backward) and kind (sort, gather, scatter, gmm, tgmm,
-...), and the whole rows go to ``chiprun_out/moe_ops_<label>.json``. Refuses
-any platform but ``tpu``: a time comes from the chip.
+Every table is the device's SELF time of every operation whose ``op_name``
+path holds ``--scope`` (``moe.experts`` unless given), a step, by phase
+(forward, the rematerialised forward, backward) and kind (under
+``moe.experts``: sort, gather, scatter, gmm, tgmm, ...; under any other
+scope, the router's: dot, top-k / sort, scalar gather, scalar scatter,
+elementwise), and the whole rows go to ``chiprun_out/moe_ops_<label>.json``
+(``moe_ops_<scope>_<label>.json`` for another scope). Refuses any platform
+but ``tpu``: a time comes from the chip.
 """
 
 import argparse
@@ -50,11 +59,29 @@ def phase_of(path: str) -> str:
     return "backward" if "transpose(" in path else "forward"
 
 
-def kind_of(hlo: str, path: str, category: str) -> str:
+def router_kind_of(path: str, category: str, scope: str) -> str:
+    """A row of the router's table: the scores' products, the selection
+    (``lax.top_k``, or a sort it lowers to), gathers and scatters of single
+    scores or choices, the rest (sigmoid, casts, compare-and-sum fusions)."""
+    tail = path.rsplit(scope, 1)[-1]
+    if "top_k" in tail or "sort" in tail:
+        return "top-k / sort"
+    for name in ("scatter", "gather"):
+        if name in tail:
+            return f"scalar {name}"
+    if "dot_general" in tail or "convolution" in category:
+        return "dot"
+    return "elementwise and other"
+
+
+def kind_of(hlo: str, path: str, category: str, scope: str = SCOPE) -> str:
     """A row of the table: the grouped products, the sort, the row and the
     scalar gathers and scatter-adds (by the result's type and rank), the
     weights' casts, what a ``cond`` or a ``scan`` adds around its branches
-    (zeros for the branch not taken, copies), the rest elementwise."""
+    (zeros for the branch not taken, copies), the rest elementwise. Under
+    another scope than ``moe.experts``: :func:`router_kind_of`'s rows."""
+    if scope != SCOPE:
+        return router_kind_of(path, category, scope)
     tail = path.rsplit(SCOPE, 1)[-1]
     m = _SHAPE.match(hlo)
     dtype, rank = (m.group(1), len([n for n in m.group(2).split(",") if n])) \
@@ -155,7 +182,7 @@ def op_rows(path: str, scope: str = SCOPE):
     found = [r for r in rows.values() if r is not None]
     for r in found:
         r["phase"] = phase_of(r["op_name"])
-        r["kind"] = kind_of(r["hlo"], r["op_name"], r["category"])
+        r["kind"] = kind_of(r["hlo"], r["op_name"], r["category"], scope)
     found.sort(key=lambda r: -r["seconds"])
     return found, steps, all_s
 
@@ -170,12 +197,13 @@ def table(rows, steps: int):
     return out
 
 
-def report(label: str, xplane_path: str, extra=None, steps=None):
-    rows, counted, all_s = op_rows(xplane_path)
+def report(label: str, xplane_path: str, extra=None, steps=None,
+           scope: str = SCOPE):
+    rows, counted, all_s = op_rows(xplane_path, scope)
     steps = steps or counted
     tab = table(rows, steps)
     total = sum(ms for _, ms in tab.values())
-    print(f"== {label}: {total:.2f} ms a step under {SCOPE} ({steps} steps, "
+    print(f"== {label}: {total:.2f} ms a step under {scope} ({steps} steps, "
           f"all ops {1e3 * all_s / steps:.2f} ms a step); ms (ops) a step")
     print(f"{'':32}" + "".join(f"{p:>17}" for p in PHASES) + f"{'sum':>9}")
     by_kind = {}
@@ -190,8 +218,11 @@ def report(label: str, xplane_path: str, extra=None, steps=None):
         f"{sum(c.get(p, (0, 0.0))[1] for c in by_kind.values()):9.2f}"
         + " " * 8 for p in PHASES) + f"{total:9.2f}")
     os.makedirs(OUT_DIR, exist_ok=True)
-    with open(os.path.join(OUT_DIR, f"moe_ops_{label}.json"), "w") as f:
-        json.dump({"label": label, "steps": steps, "all_s": all_s,
+    stem = f"moe_ops_{label}" if scope == SCOPE \
+        else f"moe_ops_{scope}_{label}"
+    with open(os.path.join(OUT_DIR, f"{stem}.json"), "w") as f:
+        json.dump({"label": label, "scope": scope, "steps": steps,
+                   "all_s": all_s,
                    "scope_ms_per_step": total, "extra": extra or {},
                    "table": [[p, k, n, ms] for (p, k), (n, ms)
                              in sorted(tab.items())],
@@ -239,7 +270,7 @@ def probe(shape: str, kind: str, seed: int, steps: int):
 
     def loss(x, gates, ws):
         y, counters = jax.checkpoint(
-            layer, policy=decoder_lm._KEEP_FLASH_RESULTS)(x, gates, ws)
+            layer, policy=decoder_lm._KEPT_ACROSS_REMAT)(x, gates, ws)
         return jnp.sum(y * probe_w), counters
 
     step = jax.jit(jax.value_and_grad(loss, argnums=(0, 1, 2), has_aux=True))
@@ -252,23 +283,72 @@ def probe(shape: str, kind: str, seed: int, steps: int):
              "moved": moved, "rows_max_over_mean":
                  counters["rows_max_over_mean"],
              "dropped_rows": counters["dropped_rows"]}
+    ratio = "not counted" if moved is None or not routed \
+        else f"{moved / routed:.3f}"
+    print(f"-- {shape} {kind}: routed {routed:.0f} rows, moved over "
+          f"routed {ratio}, max over mean "
+          f"{counters['rows_max_over_mean']:.2f}, dropped "
+          f"{counters['dropped_rows']:.0f}")
+    return traced_table(f"{shape}_{kind}", step, (x, gates, ws), steps, extra,
+                        SCOPE)
+
+
+def traced_table(label: str, step, args, steps: int, extra, scope: str):
+    """``steps`` calls of a compiled ``step`` under a profiler session, and
+    the table of its trace."""
+    import jax
     with tempfile.TemporaryDirectory() as tmp:
         with jax.profiler.trace(tmp):
             for _ in range(steps):
-                out = step(x, gates, ws)
+                out = step(*args)
             jax.block_until_ready(out)
         found = glob.glob(os.path.join(tmp, "plugins", "profile", "*",
                                        "*.xplane.pb"))
-        ratio = "not counted" if moved is None or not routed \
-            else f"{moved / routed:.3f}"
-        print(f"-- {shape} {kind}: routed {routed:.0f} rows, moved over "
-              f"routed {ratio}, max over mean "
-              f"{counters['rows_max_over_mean']:.2f}, dropped "
-              f"{counters['dropped_rows']:.0f}")
-        return report(f"{shape}_{kind}", found[0], extra, steps)
+        return report(label, found[0], extra, steps, scope)
 
 
-def run_cell(name: str, seed: int, seconds: float) -> int:
+def probe_layer(cell_name: str, seed: int, steps: int, scope: str):
+    """The expert layer of a cell's configuration alone, as a block runs it:
+    ``SparseExperts`` under the blocks' remat policy on the cell's rows a
+    step, forward and backward, the bias and the counters written."""
+    import flax.linen as nn
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+    from harness import spec
+    from analytics_zoo_tpu.pipeline.api.keras.layers import decoder_lm
+    cell = spec.load_cell(cell_name)
+    model = decoder_lm.DecoderLM.from_config(
+        cell.load("factory").model_config(cell.config))
+    layer = nn.remat(decoder_lm.SparseExperts,
+                     policy=decoder_lm._KEPT_ACROSS_REMAT)(
+        dtype=model.dtype, **model.experts)
+    shape = (int(cell.config["per_chip_batch"]),
+             int(cell.traffic["sequence_length"]), model.hidden_size)
+    rng = np.random.default_rng(seed)
+    x = jnp.asarray(rng.standard_normal(shape, np.float32), model.dtype)
+    probe_w = jnp.asarray(rng.standard_normal(shape, np.float32))
+    variables = jax.jit(layer.init)(jax.random.PRNGKey(seed % 2 ** 31),
+                                    x[:, :128])
+    state = {k: v for k, v in variables.items() if k != "params"}
+
+    def loss(params, x):
+        y, new = layer.apply({"params": params, **state}, x,
+                             mutable=list(state))
+        return jnp.sum(y * probe_w), new
+
+    step = jax.jit(jax.value_and_grad(loss, argnums=(0, 1), has_aux=True))
+    args = (variables["params"], x)
+    jax.block_until_ready(step(*args))
+    print(f"-- {cell_name}: the expert layer alone, {shape[0] * shape[1]} "
+          f"rows of {shape[2]}, top-{model.experts['num_experts_per_tok']} "
+          f"of {model.experts['n_routed_experts']}")
+    return traced_table(f"layer_{cell_name}", step, args, steps,
+                        {"cell": cell_name, "seed": seed, "rows": shape},
+                        scope)
+
+
+def run_cell(name: str, seed: int, seconds: float, scope: str) -> int:
     """One traced run of a benchmark cell; the table from its own trace."""
     import time
     t_start = time.perf_counter()
@@ -276,7 +356,7 @@ def run_cell(name: str, seed: int, seconds: float) -> int:
     inner = scopes.scope_seconds
 
     def and_table(path, names):
-        report(name, path, {"cell": name, "seed": seed})
+        report(name, path, {"cell": name, "seed": seed}, scope=scope)
         return inner(path, names)
 
     scopes.scope_seconds = and_table
@@ -287,6 +367,11 @@ def run_cell(name: str, seed: int, seconds: float) -> int:
 def main(argv=None) -> int:
     ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
     ap.add_argument("--cell")
+    ap.add_argument("--layer", nargs="*", default=[], metavar="CELL",
+                    help="the expert layer of these cells alone")
+    ap.add_argument("--scope", default=SCOPE,
+                    help="the named scope the table is of (default "
+                         "%(default)s)")
     ap.add_argument("--seconds", type=float, default=20.0)
     ap.add_argument("--seed", type=int, default=40)
     ap.add_argument("--shapes", nargs="*", default=list(SHAPES),
@@ -300,8 +385,12 @@ def main(argv=None) -> int:
         print("moe_dispatch_probe: a device time comes from a TPU; found "
               f"{jax.devices()[0].platform!r}. No result.", file=sys.stderr)
         return 3
+    for cell in args.layer:
+        probe_layer(cell, args.seed, args.steps, args.scope)
     if args.cell:
-        return run_cell(args.cell, args.seed, args.seconds)
+        return run_cell(args.cell, args.seed, args.seconds, args.scope)
+    if args.layer:
+        return 0
     for shape in args.shapes:
         for kind in args.routings:
             probe(shape, kind, args.seed, args.steps)

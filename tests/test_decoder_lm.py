@@ -26,7 +26,8 @@ from analytics_zoo_tpu.ops.attention import (                   # noqa: E402
     flash_attention, mha_reference)
 from analytics_zoo_tpu.parallel import expert_parallel as ep    # noqa: E402
 from analytics_zoo_tpu.parallel.expert_parallel import (        # noqa: E402
-    grouped_matmul, held_experts_ffn, noaux_bias_update, route_noaux_tc)
+    expert_load, grouped_matmul, held_experts_ffn, noaux_bias_update,
+    route_noaux_tc)
 from analytics_zoo_tpu.pipeline.api.keras.layers import decoder_lm  # noqa: E402
 from analytics_zoo_tpu.pipeline.api.keras.layers.decoder_lm import (  # noqa: E402
     DecoderLM, moe_counters, next_token_loss, rope_interleaved)
@@ -386,7 +387,7 @@ def _walk_programs(tile, remat):
                                 n_experts=experts)
 
     if remat:
-        layer = jax.checkpoint(layer, policy=decoder_lm._KEEP_FLASH_RESULTS)
+        layer = jax.checkpoint(layer, policy=decoder_lm._KEPT_ACROSS_REMAT)
 
     def loss(x, gates, w, idx):
         y, counters = layer(x, gates, w, idx)
@@ -479,10 +480,141 @@ def test_router_gates_and_bias():
 
 def test_bias_update_moves_against_the_load():
     idx = jnp.asarray([[0, 1], [0, 2], [0, 1], [0, 3]])   # loads 4,2,1,1,0,0
-    new = noaux_bias_update(jnp.zeros((6,)), idx, 0.001)
+    new = noaux_bias_update(jnp.zeros((6,)), expert_load(idx, 6), 0.001)
     # mean load 8/6: experts 0 and 1 are over it, the others under
     np.testing.assert_allclose(
         np.asarray(new), [-.001, -.001, .001, .001, .001, .001], atol=1e-9)
+
+
+def _plain_route(x, router_w, bias, top_k, scaling):
+    """The router as it was written before the choice was kept and the
+    scalars stopped moving: ``lax.top_k``, a gather of the chosen scores."""
+    scores = jax.nn.sigmoid(jnp.dot(x.astype(jnp.float32),
+                                    router_w.astype(jnp.float32)))
+    _, idx = jax.lax.top_k(
+        scores + jax.lax.stop_gradient(bias.astype(jnp.float32)), top_k)
+    chosen = jnp.take_along_axis(scores, idx, axis=-1)
+    gates = chosen / (jnp.sum(chosen, axis=-1, keepdims=True) + 1e-20)
+    return idx.astype(jnp.int32), gates * scaling
+
+
+def _router_inputs(experts, top_k, n=48, tied=16):
+    """One-hot rows, so that ``x @ w`` is ``w``'s rows to the bit and a tie
+    can be planted: in the first ``tied`` rows an expert outside the choice
+    is given the logit (and has the bias) of the last expert chosen."""
+    rng = np.random.RandomState(experts + top_k)
+    logits = rng.randn(n, experts).astype(np.float32)
+    bias = rng.choice([-0.25, 0.25], experts).astype(np.float32)
+    pairs = []
+    for row in range(tied):
+        key = 1.0 / (1.0 + np.exp(-logits[row].astype(np.float64))) + bias
+        order = np.argsort(-key)
+        last = order[top_k - 1]
+        other = next(e for e in order[top_k:] if bias[e] == bias[last])
+        logits[row, other] = logits[row, last]
+        pairs.append((int(last), int(other)))
+    return jnp.eye(n, dtype=jnp.float32), jnp.asarray(logits), \
+        jnp.asarray(bias), pairs
+
+
+@pytest.mark.parametrize("experts,top_k", [(256, 8), (128, 8), (512, 22)])
+def test_the_router_is_the_plain_one_with_no_scalar_moved(experts, top_k):
+    """The three cells' routers (experts / choices a token) on few rows:
+    ``lax.top_k``'s choice to the bit, planted ties at the cut included;
+    gates, ``d x`` and ``d router_w`` under the blocks' remat policy within
+    float32 rounding; the load is ``bincount``'s; the bias takes no
+    gradient."""
+    x, w, bias, pairs = _router_inputs(experts, top_k)
+    probe = jnp.asarray(np.random.RandomState(1).randn(x.shape[0], top_k),
+                        jnp.float32)
+
+    def loss(route, x, w, bias):
+        idx, gates = route(x, w, bias, top_k=top_k, scaling=2.5)
+        return jnp.sum(jnp.sin(gates) * probe), (idx, gates)
+
+    def new_route(x, w, bias, **kw):
+        return jax.checkpoint(
+            functools.partial(route_noaux_tc, **kw),
+            policy=decoder_lm._KEPT_ACROSS_REMAT)(x, w, bias)
+
+    (_, (idx, gates)), grads = jax.jit(jax.value_and_grad(
+        functools.partial(loss, new_route), argnums=(0, 1, 2),
+        has_aux=True))(x, w, bias)
+    (_, (want_idx, want_gates)), want_grads = jax.jit(jax.value_and_grad(
+        functools.partial(loss, _plain_route), argnums=(0, 1, 2),
+        has_aux=True))(x, w, bias)
+    assert idx.dtype == jnp.int32 and np.array_equal(idx, want_idx)
+    for row, (last, other) in enumerate(pairs):
+        # the tie is at the cut: the lower index is in, the other out
+        chosen = set(np.asarray(idx[row]).tolist())
+        assert (min(last, other) in chosen) and \
+            (max(last, other) not in chosen)
+    np.testing.assert_allclose(np.asarray(gates), np.asarray(want_gates),
+                               rtol=1e-6, atol=0)
+    for got, want in zip(grads[:2], want_grads[:2]):
+        np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                                   rtol=1e-5, atol=1e-7)
+    assert not np.asarray(grads[2]).any()
+    load = expert_load(idx, experts)
+    assert load.dtype == jnp.int32 and load.shape == (experts,)
+    assert np.array_equal(load, jnp.bincount(idx.reshape(-1),
+                                             length=experts))
+
+
+def _scoped_equations(jaxpr, path=""):
+    """``(named-scope path, equation)`` of every equation, the path carried
+    down into the jaxprs of an equation's parameters (a ``jit`` inside a
+    scope starts its own name stack)."""
+    for eqn in jaxpr.eqns:
+        here = f"{path}/{eqn.source_info.name_stack}"
+        yield here, eqn
+        if eqn.primitive.name != "pallas_call":
+            for sub in jax.core.jaxprs_in_params(eqn.params):
+                yield from _scoped_equations(sub, here)
+
+
+def _family_cfgs():
+    # imported here: both modules import this one
+    from test_decoder_gqa import CFG as gqa_cfg
+    from test_decoder_nemotron_h import CFG as hybrid_cfg
+    # name: (toy configuration, expert layers with the MTP module's, the
+    # functions the parent's step lowered to)
+    return {"mla": (CFG, 3, 291), "gqa": (gqa_cfg, 3, 299),
+            "hybrid": (hybrid_cfg, 3, 320)}
+
+
+@pytest.mark.parametrize("family", ["mla", "gqa", "hybrid"])
+def test_the_router_decides_once_a_step_and_moves_no_scalar(family):
+    """A count, never a rate: in the gradient of each family's toy model one
+    top-k an expert layer (the rematerialised forward reads the choice the
+    policy kept), no gather or scatter under ``moe.router``, and no more
+    lowered functions than before the choice was named (one policy object
+    for every block)."""
+    cfg, expert_layers, parent_functions = _family_cfgs()[family]
+    model = DecoderLM.from_config(cfg)
+    ids = jnp.asarray(np.random.RandomState(0).randint(0, 96, (2, 32)),
+                      jnp.uint16)
+    variables = jax.jit(model.init)(jax.random.PRNGKey(0), ids[:1])
+    extra = {k: v for k, v in variables.items() if k != "params"}
+
+    def loss_of(p):
+        preds, new = model.apply({"params": p, **extra}, ids, train=True,
+                                 mutable=list(extra))
+        return jnp.mean(next_token_loss(ids, preds, 0.3)), new
+
+    grad = jax.grad(loss_of, has_aux=True)
+    found = list(_scoped_equations(
+        jax.make_jaxpr(grad)(variables["params"]).jaxpr))
+    router = [eqn.primitive.name for path, eqn in found
+              if "moe.router" in path]
+    assert router.count("top_k") == expert_layers
+    assert not [n for n in router
+                if n == "sort" or "gather" in n or "scatter" in n]
+    # the selection lives nowhere else
+    assert sum(eqn.primitive.name == "top_k" for _, eqn in found) \
+        == expert_layers
+    lowered = jax.jit(grad).lower(variables["params"]).as_text()
+    assert lowered.count("func.func") <= parent_functions
 
 
 def test_trains_through_the_estimator_on_arrays():
