@@ -20,6 +20,7 @@ import jax
 from jax.sharding import Mesh
 
 from .config import OrcaConfig
+from ..obs import trace as _trace
 from ..parallel.mesh import create_mesh
 
 logger = logging.getLogger("analytics_zoo_tpu")
@@ -145,36 +146,42 @@ def init_orca_context(cluster_mode: str = "local",
         cfg.extra.update(extra)
         _setup_logging(cfg.log_level)
 
-        if cluster_mode in ("tpu", "multihost") and (
-                (num_processes or 1) > 1 or coordinator_address):
-            # multi-host: every host runs this same program (SPMD controller).
-            jax.distributed.initialize(
-                coordinator_address=coordinator_address,
-                num_processes=num_processes,
-                process_id=process_id)
-            logger.info("jax.distributed initialized: process %d/%d",
-                        jax.process_index(), jax.process_count())
-        elif cluster_mode == "cpu-sim":
-            # no-op when already cpu: config updates after backend
-            # initialization are unreliable (silently ignored on this jax
-            # build), so an idempotent guard keeps behavior predictable
-            if jax.config.jax_platforms != "cpu":
-                jax.config.update("jax_platforms", "cpu")
+        # a set-up stage (obs/trace.py): the cluster joined, the backend
+        # found, the caches placed, the mesh made
+        with _trace.stage("context.init", cluster_mode=cluster_mode):
+            if cluster_mode in ("tpu", "multihost") and (
+                    (num_processes or 1) > 1 or coordinator_address):
+                # multi-host: every host runs this same program (SPMD
+                # controller).
+                jax.distributed.initialize(
+                    coordinator_address=coordinator_address,
+                    num_processes=num_processes,
+                    process_id=process_id)
+                logger.info("jax.distributed initialized: process %d/%d",
+                            jax.process_index(), jax.process_count())
+            elif cluster_mode == "cpu-sim":
+                # no-op when already cpu: config updates after backend
+                # initialization are unreliable (silently ignored on this
+                # jax build), so an idempotent guard keeps behavior
+                # predictable
+                if jax.config.jax_platforms != "cpu":
+                    jax.config.update("jax_platforms", "cpu")
 
-        if cluster_mode == "tpu":
-            platforms = sorted({d.platform for d in jax.devices()})
-            if platforms != ["tpu"]:
-                raise RuntimeError(
-                    f'init_orca_context(cluster_mode="tpu") found '
-                    f"{jax.device_count()} device(s) on platform "
-                    f"{'/'.join(platforms)}, not tpu (JAX_PLATFORMS="
-                    f"{os.environ.get('JAX_PLATFORMS', '')!r}); use "
-                    'cluster_mode="local" to run on whatever is there')
-        # after the backend is known: persistence defaults on only where a
-        # compile costs seconds to minutes (configure_compile_cache)
-        from ..compile import configure_compile_cache
-        configure_compile_cache(compile_cache_dir)
-        mesh = create_mesh(cfg.mesh_axes)
+            if cluster_mode == "tpu":
+                platforms = sorted({d.platform for d in jax.devices()})
+                if platforms != ["tpu"]:
+                    raise RuntimeError(
+                        f'init_orca_context(cluster_mode="tpu") found '
+                        f"{jax.device_count()} device(s) on platform "
+                        f"{'/'.join(platforms)}, not tpu (JAX_PLATFORMS="
+                        f"{os.environ.get('JAX_PLATFORMS', '')!r}); use "
+                        'cluster_mode="local" to run on whatever is there')
+            # after the backend is known: persistence defaults on only
+            # where a compile costs seconds to minutes
+            # (configure_compile_cache)
+            from ..compile import configure_compile_cache
+            configure_compile_cache(compile_cache_dir)
+            mesh = create_mesh(cfg.mesh_axes)
         ctx = ClusterContext(cfg, mesh)
         _current = ctx
         atexit.register(stop_orca_context)  # mirrors orca/common.py:179

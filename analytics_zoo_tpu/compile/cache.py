@@ -30,6 +30,7 @@ import threading
 import time
 from typing import Any, Callable, Dict, List, Optional, Tuple
 
+from ..obs import trace as _trace
 from .stats import CompileStats
 
 logger = logging.getLogger("analytics_zoo_tpu")
@@ -153,17 +154,32 @@ class CachedFunction:
         if info is not None:
             return info[0]
         try:
-            lowered = self._fresh_jit().lower(*args)
-            text = lowered.as_text()
-            key = self._cache.key_of(lowered, self._donate, args,
-                                     extra_key=self._extra_key, text=text)
+            info = self._lower_keyed(args)
         except Exception as e:  # noqa: BLE001 — untraceable fn
             logger.debug("cache_key lowering failed (%s: %s)",
                          type(e).__name__, e)
             return None
         with self._lock:
-            self._keyinfo[sig] = (key, lowered, text)
-        return key
+            self._keyinfo[sig] = info
+        return info[0]
+
+    def _lower_keyed(self, args) -> Tuple:
+        """``(key, lowered, text)`` of one signature's first lowering: the
+        trace, the rendered StableHLO and its hash, which a warm start pays
+        like a cold one. The trace is nearly all of it: 22 s of a token
+        cell's 22.3 on a v5e host, its 1.9 MB of text 0.1 s (PERF.md §5)."""
+        with _trace.stage("compile.lower", label=self.label) as st:
+            t0 = time.perf_counter()
+            lowered = self._fresh_jit().lower(*args)
+            t1 = time.perf_counter()
+            text = lowered.as_text()
+            # on the span only: its parts (the rest of it is the key's hash)
+            st.set(text_bytes=len(text), trace_s=round(t1 - t0, 3),
+                   text_s=round(time.perf_counter() - t1, 3))
+            key = self._cache.key_of(lowered, self._donate, args,
+                                     extra_key=self._extra_key, text=text)
+        self._cache.stats.record_lower(self.label, st.duration_s)
+        return key, lowered, text
 
     def lowered_text(self, *args) -> Optional[str]:
         """Rendered StableHLO of the lowering for ``args``, reusing the
@@ -197,7 +213,13 @@ class CachedFunction:
         sig = self._signature(args)
         exe = self._local.get(sig)
         if exe is None:
-            exe = self._ensure_executable(args)
+            # a signature's first call, a set-up stage: the executable
+            # found (the compile.* stages nest here) and run once; the
+            # call made again then takes the cached path below, its
+            # fallback included
+            with _trace.stage("compile.first_call", label=self.label):
+                self._ensure_executable(args)
+                return self(*args)
         try:
             return exe(*args)
         except (TypeError, ValueError) as e:
@@ -313,22 +335,21 @@ class ExecutableCache:
     def obtain(self, cf: CachedFunction, args, sig, keyinfo=None):
         """Resolve the executable for one call signature: shared memory
         store, then disk, then a real (timed, counted) AOT compile."""
-        if keyinfo is not None:
-            key, lowered, text = keyinfo
-        else:
+        if keyinfo is None:
             try:
-                lowered = cf._fresh_jit().lower(*args)
-                text = lowered.as_text()
-                key = self.key_of(lowered, cf._donate, args,
-                                  extra_key=cf._extra_key, text=text)
+                keyinfo = cf._lower_keyed(args)
             except Exception as e:  # noqa: BLE001 — untraceable: plain jit
                 logger.warning(
                     "compile plane cannot lower %r (%s: %s); using plain "
                     "jit", cf.label or cf._fn, type(e).__name__, e)
                 self.stats.record_fallback(cf.label)
                 return cf._plain_jit()
+        key, lowered, text = keyinfo
 
-        self._lint_lowering(cf, key, lowered, args, text=text)
+        # the lint reads the whole text: a part of the lowering's cost
+        with _trace.stage("compile.lower", label=cf.label, part="lint") as st:
+            self._lint_lowering(cf, key, lowered, args, text=text)
+        self.stats.record_lower(cf.label, st.duration_s)
 
         while True:
             with self._lock:
@@ -353,14 +374,14 @@ class ExecutableCache:
         try:
             entry = self._load_disk(cf, key)
             if entry is None:
-                t0 = time.perf_counter()
-                exe = lowered.compile()
-                dt = time.perf_counter() - t0
+                with _trace.stage("compile.xla", label=cf.label) as st:
+                    exe = lowered.compile()
+                dt = st.duration_s
                 entry = {"exe": exe, "cost": dt, "origin": cf._uid}
                 self.stats.record_compile(cf.label, dt)
                 self._notify("compile", label=cf.label, key=key[:16],
                              seconds=round(dt, 4))
-                self._save_disk(key, exe, dt)
+                self._save_disk(cf, key, exe, dt)
             with self._lock:
                 self._mem[key] = entry
             return entry["exe"]
@@ -403,7 +424,7 @@ class ExecutableCache:
         return (os.path.join(self.cache_dir, f"exe-{key}.pkl")
                 if self.cache_dir else None)
 
-    def _save_disk(self, key: str, exe, cost: float):
+    def _save_disk(self, cf: CachedFunction, key: str, exe, cost: float):
         path = self._exe_path(key)
         if path is None:
             return
@@ -411,17 +432,19 @@ class ExecutableCache:
             import jax
             import jaxlib
             from jax.experimental import serialize_executable as se
-            payload, in_tree, out_tree = se.serialize(exe)
-            blob = pickle.dumps({
-                "format": _DISK_FORMAT, "jax": jax.__version__,
-                "jaxlib": jaxlib.__version__,
-                "backend": jax.default_backend(), "cost": float(cost),
-                "payload": payload, "in_tree": in_tree,
-                "out_tree": out_tree})
-            tmp = path + f".tmp{os.getpid()}"
-            with open(tmp, "wb") as f:
-                f.write(blob)
-            os.replace(tmp, path)
+            with _trace.stage("compile.save", label=cf.label) as st:
+                payload, in_tree, out_tree = se.serialize(exe)
+                blob = pickle.dumps({
+                    "format": _DISK_FORMAT, "jax": jax.__version__,
+                    "jaxlib": jaxlib.__version__,
+                    "backend": jax.default_backend(), "cost": float(cost),
+                    "payload": payload, "in_tree": in_tree,
+                    "out_tree": out_tree})
+                st.set(file_bytes=len(blob))
+                tmp = path + f".tmp{os.getpid()}"
+                with open(tmp, "wb") as f:
+                    f.write(blob)
+                os.replace(tmp, path)
         except Exception as e:  # noqa: BLE001 — backend may not serialize
             logger.debug("executable not persisted (%s: %s)",
                          type(e).__name__, e)
@@ -433,20 +456,23 @@ class ExecutableCache:
         try:
             import jax
             from jax.experimental import serialize_executable as se
-            t0 = time.perf_counter()
-            with open(path, "rb") as f:
-                blob = pickle.load(f)
-            if (blob.get("format") != _DISK_FORMAT
-                    or blob.get("jax") != jax.__version__
-                    or blob.get("backend") != jax.default_backend()):
-                return None
-            exe = se.deserialize_and_load(blob["payload"], blob["in_tree"],
-                                          blob["out_tree"])
-            load_s = time.perf_counter() - t0
+            with _trace.stage("compile.load", label=cf.label,
+                              file_bytes=os.path.getsize(path)) as st:
+                with open(path, "rb") as f:
+                    blob = pickle.load(f)
+                if (blob.get("format") != _DISK_FORMAT
+                        or blob.get("jax") != jax.__version__
+                        or blob.get("backend") != jax.default_backend()):
+                    return None
+                exe = se.deserialize_and_load(
+                    blob["payload"], blob["in_tree"], blob["out_tree"])
+            load_s = st.duration_s
             cost = float(blob.get("cost", 0.0))
             self.stats.record_disk_hit(cf.label, saved_s=cost - load_s)
+            self.stats.record_load(cf.label, load_s)
             self._notify("disk_hit", label=cf.label, key=key[:16],
-                         saved_s=round(max(cost - load_s, 0.0), 4))
+                         saved_s=round(max(cost - load_s, 0.0), 4),
+                         load_s=round(load_s, 4))
             return {"exe": exe, "cost": cost, "origin": cf._uid}
         except Exception as e:  # noqa: BLE001 — stale/foreign entry
             logger.debug("disk cache entry %s unusable (%s: %s)", path,

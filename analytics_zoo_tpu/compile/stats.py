@@ -29,10 +29,15 @@ class CompileStats:
       disk hits.
     * ``fallbacks`` — times the plane degraded to plain ``jax.jit``
       (unloadable serialization, aval/sharding mismatch, lowering failure).
+    * ``lower_s`` / ``load_s`` — what a warm start still pays: the seconds
+      a signature's first lowering took (trace, StableHLO text, key hash,
+      lint; paid whether the executable is then compiled, shared or
+      loaded) and the seconds disk hits took to unpickle and load. Fed at
+      the ``compile.lower`` / ``compile.load`` stages' own boundaries.
     """
 
     _FIELDS = ("compiles", "cache_hits", "disk_hits", "fallbacks",
-               "compile_s", "saved_s")
+               "compile_s", "saved_s", "lower_s", "load_s")
 
     def __init__(self):
         self._lock = threading.Lock()
@@ -82,6 +87,12 @@ class CompileStats:
 
     def record_fallback(self, label: str):
         self._add(label, "fallbacks")
+
+    def record_lower(self, label: str, seconds: float):
+        self._add(label, "lower_s", seconds)
+
+    def record_load(self, label: str, seconds: float):
+        self._add(label, "load_s", seconds)
 
     def counts(self, label: str) -> Dict:
         """Counters for one label (zeros when the label never compiled)."""

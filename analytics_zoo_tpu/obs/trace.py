@@ -36,6 +36,14 @@ Finished spans land in a bounded ring (``ZOO_TRACE_RING`` spans, default
 4096, oldest evicted) exported by ``obs/export.py`` as Chrome/Perfetto
 ``trace_event`` JSON (``ZOO_TRACE_PERFETTO=<path>`` writes it at process
 exit).
+
+Set-up stages (:func:`stage`) are the one hook that is never off: a stage
+sits where work runs a handful of times a process (a context, an engine's
+build, a signature's lowering, load and first call), never on a per-step
+path, and always adds its **self time** to ``zoo_setup_seconds_total
+{stage}``; when a span would be live it is that span as well. JAX's own
+``jax.monitoring`` compile events are filed under the stage that was open
+on the thread that compiled (``zoo_jax_compile_*_total{event, stage}``).
 """
 
 from __future__ import annotations
@@ -49,11 +57,14 @@ from collections import deque
 from contextlib import contextmanager
 from typing import Any, Dict, List, Optional, Tuple
 
+from jax import monitoring as _monitoring
 from jax.profiler import TraceAnnotation as _Annotation
 
 from ..common import knobs
+from .registry import REGISTRY
 
-__all__ = ["Span", "span", "span_under", "record_span", "token", "adopt",
+__all__ = ["Span", "span", "span_under", "record_span", "stage",
+           "current_stage", "token", "adopt",
            "current_trace_id", "arm", "disarm", "enabled", "tracing",
            "spans", "drain", "clear", "configure"]
 
@@ -297,6 +308,129 @@ def record_span(name: str, t0: float, t1: float,
     RING.append(Span(name, p[0] if p else _new_id(), _new_id(),
                      p[1] if p else None, t0, t1, t.ident or 0, t.name,
                      attrs))
+
+
+# --- set-up stages ------------------------------------------------------------
+
+_SETUP_SECONDS = REGISTRY.counter(
+    "zoo_setup_seconds_total",
+    "Self seconds of each set-up stage (trace.stage): its duration less the "
+    "stages opened inside it on the same thread, so the stages add up to "
+    "their union and nothing is counted twice. Counted armed or not.",
+    ("stage",))
+_SETUP_EVENTS = REGISTRY.counter(
+    "zoo_setup_events_total", "Times each set-up stage ran.", ("stage",))
+
+# innermost open stage of each thread (``.top``): a worker thread's stages
+# are no part of the stage that happens to be open on the thread beside it
+_stages = threading.local()
+
+
+class _Stage:
+    """One set-up stage: always a pair of clock reads and two counter adds;
+    a span of the same name as well when spans are live."""
+
+    __slots__ = ("name", "attrs", "duration_s", "_outer", "_inner_s",
+                 "_span", "_t0")
+
+    def __init__(self, name: str, attrs: Dict[str, Any]):
+        self.name = name
+        self.attrs = attrs
+        self.duration_s = 0.0       # whole, set at exit: the site's own stats
+        self._outer = None
+        self._inner_s = 0.0
+        self._span = None
+        self._t0 = 0.0
+
+    def __enter__(self):
+        self._outer = getattr(_stages, "top", None)
+        _stages.top = self
+        if enabled():
+            self._span = _LiveSpan(self.name, _ctx.get(), self.attrs)
+            self._span.__enter__()
+        self._t0 = time.perf_counter()
+        return self
+
+    def __exit__(self, exc_type, exc, tb):
+        self.duration_s = time.perf_counter() - self._t0
+        if self._span is not None:
+            self._span.__exit__(exc_type, exc, tb)
+        _stages.top = self._outer
+        if self._outer is not None:
+            self._outer._inner_s += self.duration_s
+        _SETUP_SECONDS.labels(stage=self.name).inc(
+            self.duration_s - self._inner_s)
+        _SETUP_EVENTS.labels(stage=self.name).inc()
+        return False
+
+    def set(self, **attrs):
+        """Attributes known only once the work ran (a program's bytes)."""
+        if self._span is not None:
+            self._span.set(**attrs)
+        return self
+
+
+def stage(name: str, **attrs):
+    """Open a set-up stage. Unlike :func:`span` it is never a no-op: set-up
+    is what nobody arms tracing for, and the benchmark's ``setup_s`` is
+    split by these counters. Only for sites that run a few times a process."""
+    return _Stage(name, attrs)
+
+
+def current_stage() -> str:
+    """Name of the innermost stage open on the calling thread, or ``none``."""
+    top = getattr(_stages, "top", None)
+    return top.name if top is not None else "none"
+
+
+# --- JAX's own compile events, by stage -------------------------------------
+
+_JAX_COMPILE_SECONDS = REGISTRY.counter(
+    "zoo_jax_compile_seconds_total",
+    "Seconds of jax.monitoring's compile events, every program JAX traces, "
+    "lowers, compiles or fetches (eager operations and plain jax.jit "
+    "included), by event and by the set-up stage open on the calling thread "
+    "(none outside one). In JAX 0.9.0 backend_compile is the time around "
+    "compile_or_get_cached (pxla.py): it COVERS a persistent-cache hit's "
+    "retrieval, the key's hash and a miss's write, so cache_retrieval is a "
+    "part of it and the two are not to be added.",
+    ("event", "stage"))
+_JAX_COMPILE_EVENTS = REGISTRY.counter(
+    "zoo_jax_compile_events_total",
+    "Count of the same events, and of the persistent cache's hits and "
+    "misses (a miss is counted where the entry is written).",
+    ("event", "stage"))
+
+_JAX_EVENTS = {
+    "/jax/core/compile/jaxpr_trace_duration": "trace",
+    "/jax/core/compile/jaxpr_to_mlir_module_duration": "to_mlir",
+    "/jax/core/compile/backend_compile_duration": "backend_compile",
+    "/jax/compilation_cache/cache_retrieval_time_sec": "cache_retrieval",
+    "/jax/compilation_cache/cache_hits": "cache_hit",
+    "/jax/compilation_cache/cache_misses": "cache_miss",
+}
+
+
+def _on_jax_duration(event: str, duration_secs: float, **_kw):
+    short = _JAX_EVENTS.get(event)
+    if short is not None:
+        where = current_stage()
+        _JAX_COMPILE_SECONDS.labels(event=short, stage=where).inc(
+            duration_secs)
+        _JAX_COMPILE_EVENTS.labels(event=short, stage=where).inc()
+
+
+def _on_jax_event(event: str, **_kw):
+    short = _JAX_EVENTS.get(event)
+    if short is not None:
+        _JAX_COMPILE_EVENTS.labels(event=short, stage=current_stage()).inc()
+
+
+# JAX calls these only where it traces, compiles or looks a program up,
+# never where it dispatches one: a window that compiles nothing never
+# reaches them
+_monitoring.register_event_duration_secs_listener(_on_jax_duration)
+_monitoring.register_event_listener(_on_jax_event)
 
 
 # --- handoff tokens ----------------------------------------------------------

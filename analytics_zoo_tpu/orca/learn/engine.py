@@ -161,30 +161,37 @@ class TrainEngine:
     def build(self, sample_x: Tuple[np.ndarray, ...]):
         if self.params is not None:
             return
-        rng = jax.random.PRNGKey(self.seed)
-        small = tuple(jnp.asarray(a[:1]) for a in sample_x)
-        if self.prologue is not None:
-            # the module sees post-prologue tensors at init, exactly as it
-            # will inside the jitted steps
-            small = self.prologue.apply_x(small)
-        variables = self._init_vars(rng, small)
-        variables = dict(variables)
-        # a parameterless graph (e.g. a pure merge/functional model) inits
-        # with no "params" collection at all
-        params = variables.pop("params", {})
-        params, variables = self._capture_tp_specs(params, variables)
-        if self.sharding is not None:
-            params = self._build_sharding(params)
-        self.params = jax.device_put(params, self._param_sharding(params))
-        self.extra_vars = jax.device_put(
-            variables, jax.tree.map(lambda _: self._repl, variables))
-        if self.fsdp_plan is not None:
-            self.opt_state = self._init_sharded_tree_opt()
-        else:
-            opt_state = self.tx.init(self.params)
-            self.opt_state = jax.device_put(opt_state,
-                                            self._opt_sharding(opt_state))
-        self.step = 0
+        # set-up stages (obs/trace.py): what a build costs is counted
+        # whether or not anyone traces, the eager init apart from the
+        # placement of the state it shapes
+        with _trace.stage("engine.build"):
+            rng = jax.random.PRNGKey(self.seed)
+            small = tuple(jnp.asarray(a[:1]) for a in sample_x)
+            if self.prologue is not None:
+                # the module sees post-prologue tensors at init, exactly as
+                # it will inside the jitted steps
+                small = self.prologue.apply_x(small)
+            with _trace.stage("engine.init_vars"):
+                variables = dict(self._init_vars(rng, small))
+            with _trace.stage("engine.place_params"):
+                # a parameterless graph (e.g. a pure merge/functional model)
+                # inits with no "params" collection at all
+                params = variables.pop("params", {})
+                params, variables = self._capture_tp_specs(params, variables)
+                if self.sharding is not None:
+                    params = self._build_sharding(params)
+                self.params = jax.device_put(params,
+                                             self._param_sharding(params))
+                self.extra_vars = jax.device_put(
+                    variables, jax.tree.map(lambda _: self._repl, variables))
+            with _trace.stage("engine.opt_init"):
+                if self.fsdp_plan is not None:
+                    self.opt_state = self._init_sharded_tree_opt()
+                else:
+                    opt_state = self.tx.init(self.params)
+                    self.opt_state = jax.device_put(
+                        opt_state, self._opt_sharding(opt_state))
+            self.step = 0
 
     # --- sharding plane (parallel/sharding.py) ------------------------------
     def _build_sharding(self, params):

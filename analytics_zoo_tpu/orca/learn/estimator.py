@@ -91,29 +91,33 @@ class TPUEstimator:
         self.module = module
         self.config = config or {}
         self.model_dir = model_dir
-        self.loss_fn = convert_loss(loss) if loss is not None else None
-        self.metrics = convert_metrics_list(metrics)
-        tx = convert_optimizer(optimizer)
-        # compile plane: default is the process-wide executable cache;
-        # ``compile_cache=False`` (arg or config key) opts out to plain jit
-        if compile_cache is None:
-            compile_cache = self.config.get("compile_cache", None)
-        # transfer plane: an on-device input prologue (orca/learn/prologue.
-        # BatchPrologue) moves cast/normalize/one-hot INSIDE the jitted
-        # step so the wire carries narrow source dtypes (uint8/int32)
-        if prologue is None:
-            prologue = self.config.get("prologue", None)
-        # sharding plane (parallel/sharding.py): SpecLayout-driven fsdp×tp
-        # param sharding over the multi-axis mesh — models bigger than one
-        # chip. Knobs: ``sharding`` arg (SpecLayout | True | False) /
-        # config ``sharding`` / ZOO_SHARDING_PLANE, ZOO_FSDP_BUCKET_MB.
-        # All-default means OFF: the engine's step is byte-identical.
-        from ...parallel.sharding import SpecLayout
-        spec_layout = SpecLayout.resolve(self.config, sharding)
-        self.engine = TrainEngine(module, tx, self.loss_fn, self.metrics,
-                                  self.mesh, seed=seed, fsdp_params=fsdp,
-                                  compile_cache=compile_cache,
-                                  prologue=prologue, sharding=spec_layout)
+        with _trace.stage("estimator.init"):
+            self.loss_fn = convert_loss(loss) if loss is not None else None
+            self.metrics = convert_metrics_list(metrics)
+            tx = convert_optimizer(optimizer)
+            # compile plane: default is the process-wide executable cache;
+            # ``compile_cache=False`` (arg or config key) opts out to plain
+            # jit
+            if compile_cache is None:
+                compile_cache = self.config.get("compile_cache", None)
+            # transfer plane: an on-device input prologue (orca/learn/
+            # prologue.BatchPrologue) moves cast/normalize/one-hot INSIDE
+            # the jitted step so the wire carries narrow source dtypes
+            # (uint8/int32)
+            if prologue is None:
+                prologue = self.config.get("prologue", None)
+            # sharding plane (parallel/sharding.py): SpecLayout-driven
+            # fsdp×tp param sharding over the multi-axis mesh — models
+            # bigger than one chip. Knobs: ``sharding`` arg (SpecLayout |
+            # True | False) / config ``sharding`` / ZOO_SHARDING_PLANE,
+            # ZOO_FSDP_BUCKET_MB. All-default means OFF: the engine's step
+            # is byte-identical.
+            from ...parallel.sharding import SpecLayout
+            spec_layout = SpecLayout.resolve(self.config, sharding)
+            self.engine = TrainEngine(module, tx, self.loss_fn, self.metrics,
+                                      self.mesh, seed=seed, fsdp_params=fsdp,
+                                      compile_cache=compile_cache,
+                                      prologue=prologue, sharding=spec_layout)
         # one stats object spans iterator assembly, the pump's H2D stage and
         # the engine's dispatches — the estimator is where they all meet
         from ...native.infeed import PipelineStats
@@ -468,7 +472,9 @@ class TPUEstimator:
                 for a in tuple(it.x) + tuple(it.y or ()))
             k = self._fuse_probe_cache.get(key)
             if k is None:
-                k = self._auto_probe_fuse(it, batch_bytes, probe_key=key)
+                with _trace.stage("fit.fuse_probe", mode="train"):
+                    k = self._auto_probe_fuse(it, batch_bytes,
+                                              probe_key=key)
                 self._fuse_probe_cache[key] = k
         return self._apply_fuse_caps(k, batch_bytes, it.steps_per_epoch,
                                      trigger)
@@ -855,8 +861,9 @@ class TPUEstimator:
                 for a in tuple(it.x) + tuple(it.y or ()))
             k = self._fuse_probe_cache.get(key)
             if k is None:
-                k = self._auto_probe_eval_fuse(it, sample, batch_bytes,
-                                               probe_key=key)
+                with _trace.stage("fit.fuse_probe", mode="eval"):
+                    k = self._auto_probe_eval_fuse(it, sample, batch_bytes,
+                                                   probe_key=key)
                 self._fuse_probe_cache[key] = k
         return self._apply_fuse_caps(k, batch_bytes, it.steps_per_epoch)
 

@@ -557,7 +557,12 @@ def test_profiler_session_makes_fit_spans_live(profiled_fit):
     assert set(FIT_SPANS) <= set(by)
     (fit,) = by["fit"]
     assert fit.parent_id is None and fit.attrs["steps"] == 8
-    assert {s.trace_id for s in spans} == {fit.trace_id}
+    # the estimator was made inside the session, so its set-up stage is a
+    # span as well: a root of its own, closed before ``fit`` opened; the
+    # build's and the first call's stages hang under the fit call's spans
+    (init,) = by["estimator.init"]
+    assert init.parent_id is None and init.t1 <= fit.t0
+    assert {s.trace_id for s in spans} == {fit.trace_id, init.trace_id}
     by_id = {s.span_id: s for s in spans}
     parent = {"fit.prepare": "fit", "epoch": "fit", "infeed.wait": "epoch",
               "engine.dispatch": "epoch", "epoch.open_ahead": "epoch",
@@ -605,18 +610,38 @@ def test_profiler_trace_holds_zoo_annotations(profiled_fit):
     assert len(found["zoo:engine.dispatch"]) == 8
     assert all(fit_lo <= lo and hi <= fit_hi
                for lo, hi in found["zoo:engine.dispatch"])
+    # the set-up stages that ran inside the session are annotations too:
+    # the estimator made before ``fit``, the build and the step's first call
+    # (found in the shared store: the fixture's first fit compiled it)
+    # inside it
+    ((init_lo, init_hi),) = found["zoo:estimator.init"]
+    assert init_hi <= fit_lo
+    for name in BUILD_STAGES + ("compile.first_call", "compile.lower"):
+        assert all(fit_lo <= lo and hi <= fit_hi
+                   for lo, hi in found["zoo:" + name]), name
+
+
+# the set-up stages of an engine's build: spans inside the ``fit.prepare``
+# of an estimator's first call, where the engine is built
+BUILD_STAGES = ("engine.build", "engine.init_vars", "engine.place_params",
+                "engine.opt_init")
 
 
 def test_fit_prepare_span_and_ready_batch_assembly(profiled_fit):
     """(c) ``fit.prepare`` opens with ``fit`` and closes before the first
-    epoch; a factory of ready batches (the ImageNetPipeline contract) leaves
-    ``infeed.assemble`` spans too, from the producer thread."""
+    epoch, the engine's build inside it; a factory of ready batches (the
+    ImageNetPipeline contract) leaves ``infeed.assemble`` spans too, from
+    the producer thread."""
     from analytics_zoo_tpu.native.infeed import InfeedPump
     by = _by_name(profiled_fit["spans"])
     (fit,), (prep,) = by["fit"], by["fit.prepare"]
     others = [s for s in profiled_fit["spans"]
-              if s.name not in ("fit", "fit.prepare")]
+              if s.name not in ("fit", "fit.prepare", "estimator.init")
+              + BUILD_STAGES]
     assert fit.t0 <= prep.t0 <= prep.t1 <= min(s.t0 for s in others)
+    for name in BUILD_STAGES:
+        (built,) = by[name]
+        assert prep.t0 <= built.t0 <= built.t1 <= prep.t1
     assert prep.t1 <= min(s.t0 for s in by["epoch"])
 
     batches = [np.full((4, 2), i, np.float32) for i in range(5)]
@@ -636,7 +661,8 @@ def test_fit_on_built_engine_prepares_without_a_batch(orca_context):
     """(c') every ``fit`` call but an estimator's first finds its engine
     built: its ``fit.prepare`` assembles nothing and puts nothing (no span
     of any thread starts inside it, the pipeline's counters grow by the
-    epochs' batches alone), where the first call took one sample."""
+    epochs' batches alone), where the first call took one sample and built
+    the engine (the build's set-up stages, and nothing else)."""
     from analytics_zoo_tpu.orca.learn.estimator import TPUEstimator
     rng = np.random.RandomState(0)
     data = {"x": rng.rand(128, 8).astype(np.float32),
@@ -644,7 +670,7 @@ def test_fit_on_built_engine_prepares_without_a_batch(orca_context):
     est = TPUEstimator(_tiny_module(), loss="mse", optimizer="adam", seed=0,
                        config={"steps_per_dispatch": 1})
     grew = []
-    for _ in range(2):
+    for built_here in (BUILD_STAGES, ()):
         before = est.data_pipeline_stats()
         trace.clear()
         with trace.tracing():
@@ -657,7 +683,7 @@ def test_fit_on_built_engine_prepares_without_a_batch(orca_context):
         (prep,) = [s for s in spans if s.name == "fit.prepare"]
         inside = [s.name for s in spans
                   if s is not prep and prep.t0 <= s.t0 <= prep.t1]
-        assert inside == []
+        assert sorted(inside) == sorted(built_here)
     est.shutdown()
     assert grew[0] == {"assemble_n": 9, "h2d_n": 9, "first_batch_n": 2,
                        "open_ahead_n": 1}
