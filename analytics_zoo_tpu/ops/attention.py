@@ -34,14 +34,17 @@ _REFERENCE_ON_TPU = _REGISTRY.counter(
     "flash_attention call sites traced on a TPU that fell through to "
     "mha_reference (sequence not tileable, or causal with s_q > s_k)")
 
-# which backward a run compiled: one fused launch a call site, or the dQ and
-# dK/dV pair (a dQ accumulator past ``_FUSED_BWD_DQ_BYTES``)
+# which backward a run compiled: one fused launch a call site (the group's
+# whole dQ in VMEM, or a query head's at a time), or the dQ and dK/dV pair
+# (neither within ``_FUSED_BWD_DQ_BYTES``)
 _BACKWARD_PATHS = _REGISTRY.counter(
     "zoo_attention_backward_total",
     "flash_attention backward call sites traced, by the kernels they "
-    "launch: fused (dQ beside dK/dV, one launch) or two_kernel",
+    "launch: fused (dQ beside dK/dV, one launch), fused_by_head (one "
+    "launch, a query head of the group at a time) or two_kernel",
     labelnames=("path",))
 _BACKWARD_FUSED = _BACKWARD_PATHS.labels(path="fused")
+_BACKWARD_FUSED_BY_HEAD = _BACKWARD_PATHS.labels(path="fused_by_head")
 _BACKWARD_TWO_KERNEL = _BACKWARD_PATHS.labels(path="two_kernel")
 
 # what a windowed call site's grids compute against what its mask needs
@@ -63,11 +66,14 @@ FLASH_RESIDUAL_NAMES = ("flash_out", "flash_lse")
 MIB = 2 ** 20
 # Mosaic's default scoped VMEM: what every flash kernel's tiles fit today
 _SCOPED_VMEM_BYTES = 16 * MIB
-# The fused backward holds one (batch, head)'s whole dQ in VMEM: a float32
-# accumulator and the double-buffered output block, lanes padded to 128.
-# Up to this many bytes of them (three eighths of a v5e's 128 MiB; the
-# token cell's 8192 x 192 in bf16 takes 16 MiB) dQ rides the dK/dV launch;
-# longer sequences keep the dQ kernel of their own.
+# The fused backward holds whole gradients in VMEM, each a float32
+# accumulator and the double-buffered output block, lanes padded to 128: the
+# dQ of all the query heads of a (batch, kv head), or one query head's dQ
+# and the kv head's dK and dV. Up to this many bytes of them (three eighths
+# of a v5e's 128 MiB) dQ rides the dK/dV launch: the MLA cell's 8192 x 192
+# in bf16 takes 16 MiB, 4 query heads a kv head at 8192 x 128 take 32, and
+# 8 a kv head at 16384 x 128 (128 MiB of dQ) go a head at a time in 3 x 16.
+# Longer sequences keep the dQ kernel of their own.
 _FUSED_BWD_DQ_BYTES = 48 * MIB
 
 
@@ -376,7 +382,7 @@ def _mosaic_params():
     ordered. Parallel dims let Mosaic overlap the next tile's DMA with the
     current tile's compute instead of treating the whole grid as one
     sequential loop. (The fused backward carries dQ across both sequence
-    dims and states its own.)"""
+    dims, by head dK and dV across the group too, and states its own.)"""
     from jax.experimental.pallas import tpu as pltpu
     return pltpu.CompilerParams(
         dimension_semantics=("parallel", "parallel", "arbitrary"))
@@ -577,8 +583,8 @@ def _flash_bwd_dq_kernel(q2_ref, k_ref, v_ref, g_ref, L_ref, D_ref, dq_ref,
     ever reaches HBM (the round-3 pure-JAX backward streamed every P/dS
     tile through HBM between the dot_generals, which bounded fwd+bwd at
     ~1.4x materialized; tiles resident in VMEM are the FA-2 design). Runs
-    only where a kv head's whole dQ is past the fused kernel's VMEM
-    budget (``_flash_bwd``). With a ``window`` the k dim walks the q
+    only where not even one query head's gradients fit the fused kernel's
+    VMEM budget (``_flash_bwd``). With a ``window`` the k dim walks the q
     tile's band, as in ``_flash_kernel``."""
     import jax.experimental.pallas as pl
 
@@ -615,7 +621,8 @@ def _flash_bwd_dq_kernel(q2_ref, k_ref, v_ref, g_ref, L_ref, D_ref, dq_ref,
 def _flash_bwd_dkv_kernel(q2_ref, k_ref, v_ref, g_ref, L_ref, D_ref,
                           dk_ref, dv_ref, dk_acc, dv_acc, *, block_q,
                           block_k, num_q_blocks, causal, q_offset, cd,
-                          dq=None, window=None, group=1, seq_q_blocks=None):
+                          dq=None, window=None, group=1, seq_q_blocks=None,
+                          by_head=False):
     """dK/dV pass: grid (batch*kv heads, num_k, group * num_q), the
     innermost dim walking the q tiles of each of the ``group`` query heads
     that share this kv head in turn, so dK and dV are summed over the
@@ -628,20 +635,36 @@ def _flash_bwd_dkv_kernel(q2_ref, k_ref, v_ref, g_ref, L_ref, D_ref,
     ``seq_q_blocks`` is then the sequence's tiles.
 
     ``dq`` is the fused kernel's (see ``_flash_bwd_fused_kernel``): given,
-    each tile's dS also goes into dQ."""
+    each tile's dS also goes into dQ. ``by_head`` is its grid for a group
+    whose whole dQ does not fit VMEM: (batch*kv heads, group, num_k,
+    num_q), a query head at a time. For a fixed k block the tiles still
+    arrive head by head and, inside a head, q tile by q tile (``step``), but
+    between two heads every other k block passes: dK and dV accumulate in
+    scratch for the whole kv sequence, at the k block's rows, and their
+    output blocks are the kv head's whole sequence."""
     import jax.experimental.pallas as pl
 
-    k_idx = pl.program_id(1)
-    step = pl.program_id(2)
-    if group == 1:
-        head, q_idx = 0, step
+    k_at, out_k_at = Ellipsis, 0      # all of dK's and dV's scratch and block
+    if by_head:
+        head, k_idx, q_idx = (pl.program_id(i) for i in (1, 2, 3))
+        step = head * num_q_blocks + q_idx
+        dq_step = q_idx                  # a head's dQ begins with the head
+        # of the whole kv sequence's, this k block's rows
+        k_at = (pl.ds(pl.multiple_of(k_idx * block_k, block_k), block_k),
+                slice(None))
+        out_k_at = (0, *k_at)
     else:
-        head, q_idx = step // num_q_blocks, step % num_q_blocks
+        k_idx, step = pl.program_id(1), pl.program_id(2)
+        if group == 1:
+            head, q_idx = 0, step
+        else:
+            head, q_idx = step // num_q_blocks, step % num_q_blocks
+        dq_step = step                   # the group's with the kv head
 
     @pl.when(step == 0)
     def _init():
-        dk_acc[...] = jnp.zeros_like(dk_acc)
-        dv_acc[...] = jnp.zeros_like(dv_acc)
+        dk_acc[k_at] = jnp.zeros((block_k, dk_acc.shape[-1]), dk_acc.dtype)
+        dv_acc[k_at] = jnp.zeros((block_k, dv_acc.shape[-1]), dv_acc.dtype)
 
     k_start = k_idx * block_k
     if window is not None:
@@ -653,10 +676,11 @@ def _flash_bwd_dkv_kernel(q2_ref, k_ref, v_ref, g_ref, L_ref, D_ref,
         dq_ref, dq_acc, sm_scale, num_k_blocks = dq
         rows = pl.ds(pl.multiple_of(q_start, block_q), block_q)
         # this step's query head's rows of the tile, in the accumulator
-        # (one head: (s_q, d)) and in the output block (group, s_q, d)
-        acc_at = (rows, slice(None)) if group == 1 else \
+        # (one head's: (s_q, d)) and in the output block, (group, s_q, d)
+        # or by head (1, s_q, d)
+        acc_at = (rows, slice(None)) if group == 1 or by_head else \
             (head, rows, slice(None))
-        out_at = (head, rows, slice(None))
+        out_at = (0 if by_head else head, rows, slice(None))
 
         def _write_dq():
             dq_ref[out_at] = (dq_acc[acc_at] * sm_scale).astype(dq_ref.dtype)
@@ -668,8 +692,9 @@ def _flash_bwd_dkv_kernel(q2_ref, k_ref, v_ref, g_ref, L_ref, D_ref,
                                            dq_acc.dtype)
         else:
             # a band's walk does not pass every q tile under the first k
-            # block: the whole accumulator is cleared as a kv head begins
-            @pl.when((k_idx == 0) & (step == 0))
+            # block: the whole accumulator is cleared as its kv head (by
+            # head: its query head) begins
+            @pl.when((k_idx == 0) & (dq_step == 0))
             def _init_dq():
                 dq_acc[...] = jnp.zeros_like(dq_acc)
 
@@ -678,10 +703,10 @@ def _flash_bwd_dkv_kernel(q2_ref, k_ref, v_ref, g_ref, L_ref, D_ref,
         p, ds = _bwd_tile(masked, q2_ref[0], k_ref[0], v_ref[0],
                           g, L_ref[0], D_ref[0], q_offset, q_start,
                           k_start, cd, window)
-        dv_acc[...] += jax.lax.dot_general(
+        dv_acc[k_at] += jax.lax.dot_general(
             p, g, (((0,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
-        dk_acc[...] += jax.lax.dot_general(
+        dk_acc[k_at] += jax.lax.dot_general(
             ds, q2_ref[0], (((0,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
         if dq is not None:
@@ -705,8 +730,9 @@ def _flash_bwd_dkv_kernel(q2_ref, k_ref, v_ref, g_ref, L_ref, D_ref,
 
     @pl.when(step == group * num_q_blocks - 1)
     def _finalize():
-        dk_ref[0] = (dk_acc[...] * (1.0 / LOG2_E)).astype(dk_ref.dtype)
-        dv_ref[0] = dv_acc[...].astype(dv_ref.dtype)
+        dk_ref[out_k_at] = (dk_acc[k_at] *
+                            (1.0 / LOG2_E)).astype(dk_ref.dtype)
+        dv_ref[out_k_at] = dv_acc[k_at].astype(dv_ref.dtype)
 
     if dq is not None and window is None:
         # a q tile's rows are whole once the last k block has passed them,
@@ -724,9 +750,13 @@ def _flash_bwd_fused_kernel(q2_ref, k_ref, v_ref, g_ref, L_ref, D_ref,
     in VMEM scratch, at the tile's rows. Each (block_q, block_k) score
     tile, its exp2 and dP are rebuilt once a step, not once in each of two
     kernels. ``dq_ref`` is the whole (group, s_q, d) output block, indexed
-    by batch*kv heads alone; for a fixed q tile the contributions arrive in
-    ascending k order, as in ``_flash_bwd_dq_kernel``: the three gradients
-    equal the pair's to the bit."""
+    by batch*kv heads alone. With ``by_head`` (among ``tiles``) the grid
+    takes the group's query heads one after the other, the accumulator and
+    ``dq_ref`` are one head's (s_q, d), and dK and dV wait in VMEM for the
+    group's last head. Either way a fixed q tile's contributions arrive in
+    ascending k order, as in ``_flash_bwd_dq_kernel``, and a fixed k
+    block's in ``_flash_bwd_dkv_kernel``'s: the three gradients equal the
+    pair's to the bit."""
     _flash_bwd_dkv_kernel(q2_ref, k_ref, v_ref, g_ref, L_ref, D_ref, dk_ref,
                           dv_ref, dk_acc, dv_acc,
                           dq=(dq_ref, dq_acc, sm_scale, num_k_blocks),
@@ -734,21 +764,25 @@ def _flash_bwd_fused_kernel(q2_ref, k_ref, v_ref, g_ref, L_ref, D_ref,
 
 
 def _fused_bwd_dq_bytes(s_q: int, d: int, dtype) -> int:
-    """VMEM the fused backward takes for ``s_q`` rows of dQ (a kv head's:
-    the sequence times its group of query heads): the float32 accumulator
-    and the output block, which the pipeline buffers twice; lanes pad to
-    128."""
+    """VMEM the fused backward takes to hold ``s_q`` rows of a gradient
+    whole (dQ of a kv head's group: the sequence times the group; by head:
+    one head's dQ, and likewise dK and dV at their own rows and widths):
+    the float32 accumulator and the output block, which the pipeline
+    buffers twice; lanes pad to 128."""
     lanes = -(-d // 128) * 128
     return s_q * lanes * (4 + 2 * jnp.dtype(dtype).itemsize)
 
 
 def _bwd_tile_sizes(s_q: int, s_k: int, block_q: int, block_k: int):
     """Backward tile sizes: the backward keeps ~4 (bq, bk) f32 tiles +
-    operands live per grid step; 1024x1024 f32 blows the 16M VMEM scoped
-    limit, so halve down to <=512. An ODD user block > 512 that divides S
-    halves to a non-divisor and would silently drop the trailing rows of
-    dq/dk/dv (round-4 advisor) — re-fit via gcd with 512 (the largest
-    power-of-two tile <= 512 that divides S)."""
+    operands live per grid step; at 1024x1024 those are past Mosaic's
+    default 16 MiB of scoped VMEM (``_SCOPED_VMEM_BYTES``: all the pair's
+    kernels have, and what the fused ones leave for tiles when they raise
+    the limit by the gradients they hold), so halve down to <=512. An ODD
+    user block > 512 that divides S halves to a non-divisor and would
+    silently drop the trailing rows of dq/dk/dv (round-4 advisor) — re-fit
+    via gcd with 512 (the largest power-of-two tile <= 512 that divides
+    S)."""
     bq, bk = min(block_q, s_q), min(block_k, s_k)
     while bq > 512:
         bq //= 2
@@ -769,13 +803,22 @@ def _flash_bwd(causal, sm_scale, block_q, block_k, window, res, g):
     (~13 GB per step at S=4096), which bounded fwd+bwd at ~1.4x
     materialized attention on a v5e chip.
 
-    One launch where the whole dQ of a (batch, kv head)'s queries fits
-    VMEM beside the tiles (``_fused_bwd_dq_bytes`` of the sequence times
-    the group, within ``_FUSED_BWD_DQ_BYTES``): ``_flash_bwd_fused_kernel``,
-    the dK/dV pass accumulating dQ too. Past that (8 query heads a kv head
-    at 16384 positions of 128 are 128 MiB), a dQ kernel (k innermost) and
-    the dK/dV kernel (q innermost), each rebuilding the score tiles. The
-    shape decides; both give the same bits and count themselves in
+    Three grids around one tile body, chosen by the bytes each would hold
+    whole in VMEM (``_fused_bwd_dq_bytes``) against ``_FUSED_BWD_DQ_BYTES``:
+
+    1. ``fused``: the dQ of all of a (batch, kv head)'s queries fits (the
+       sequence times the group): ``_flash_bwd_fused_kernel`` on the dK/dV
+       grid, k and v fetched once a kv head.
+    2. ``fused_by_head``: that is past the budget (8 query heads a kv head
+       at 16384 positions of 128 are 128 MiB), but one query head's dQ and
+       the kv head's dK and dV are not (3 x 16 MiB there): the same kernel
+       on the grid (batch*kv heads, group, k blocks, q tiles). A head's dQ
+       is finished and written before the next head begins; dK and dV,
+       summed over the group, stay in scratch until its last head.
+    3. ``two_kernel``: else a dQ kernel (k innermost) and the dK/dV kernel
+       (q innermost), each rebuilding the score tiles.
+
+    All three give the same bits and count themselves in
     ``zoo_attention_backward_total``. k and v keep their own head count
     throughout, and with a ``window`` every grid walks bands
     (``_window_tiles``)."""
@@ -818,12 +861,8 @@ def _flash_bwd(causal, sm_scale, block_q, block_k, window, res, g):
     dkv_tiles = dict(tiles, num_q_blocks=nq_in, group=group,
                      seq_q_blocks=nq)
 
-    # both kernels walk (bh_kv, nk, group * nq_in), q innermost
-    def q_index(bhi, ki, st):
-        if group == 1:
-            row, qi = bhi, st
-        else:
-            row, qi = bhi * group + st // nq_in, st % nq_in
+    def q_tile(row, ki, qi):
+        """Block index of the q tile at step ``qi`` under k block ``ki``."""
         if window is not None:
             first, last = _q_band(ki, bq, bk, off, window, nq)
             return (row, jnp.minimum(first + qi, last), 0)
@@ -839,6 +878,12 @@ def _flash_bwd(causal, sm_scale, block_q, block_k, window, res, g):
             first = jnp.maximum((ki * bk - off) // bq, 0)
             return (row, jnp.maximum(qi, first), 0)
         return (row, qi, 0)
+
+    # the dK/dV and fused kernels walk (bh_kv, nk, group * nq_in), q innermost
+    def q_index(bhi, ki, st):
+        if group == 1:
+            return q_tile(bhi, ki, st)
+        return q_tile(bhi * group + st // nq_in, ki, st % nq_in)
     by_q = [pl.BlockSpec((1, bq, w), q_index)
             for w in (d, d_v, 1, 1)]                     # q2, g, lse, D
     by_k = [pl.BlockSpec((1, bk, w), lambda bhi, ki, qi: (bhi, ki, 0))
@@ -850,33 +895,65 @@ def _flash_bwd(causal, sm_scale, block_q, block_k, window, res, g):
     dkv_scratch = [pltpu.VMEM((bk, d), jnp.float32),
                    pltpu.VMEM((bk, d_v), jnp.float32)]
 
+    # the path, by the bytes a launch would hold whole in VMEM: the group's
+    # dQ; else one query head's dQ and the kv head's dK and dV
     dq_bytes = _fused_bwd_dq_bytes(group * s_q, d, q.dtype)
+    by_head_bytes = (_fused_bwd_dq_bytes(s_q, d, q.dtype) +
+                     _fused_bwd_dq_bytes(s_k, d, k.dtype) +
+                     _fused_bwd_dq_bytes(s_k, d_v, v.dtype))
     fused = dq_bytes <= _FUSED_BWD_DQ_BYTES
+    by_head = not fused and by_head_bytes <= _FUSED_BWD_DQ_BYTES
+    one_launch = fused or by_head
     if tiles_w is not None:
-        passes = 1 if fused else 2
+        passes = 1 if one_launch else 2
         _TILES_NEEDED.inc(bh * tiles_w["needed"] * passes)
         _TILES_VISITED.inc(bh * (tiles_w["by_q"] + (
-            0 if fused else tiles_w["by_k"])))
-    if fused:
-        _BACKWARD_FUSED.inc()
+            0 if one_launch else tiles_w["by_k"])))
+    if one_launch:
+        if fused:
+            _BACKWARD_FUSED.inc()
+            grid, in_specs, held = (bh_kv, nk, group * nq_in), dkv_in_specs, \
+                dq_bytes
+            out_specs = [pl.BlockSpec((group, s_q, d),
+                                      lambda bhi, ki, qi: (bhi, 0, 0)),
+                         *by_k]
+            scratch = [pltpu.VMEM(
+                (s_q, d) if group == 1 else (group, s_q, d), jnp.float32),
+                *dkv_scratch]
+        else:
+            _BACKWARD_FUSED_BY_HEAD.inc()
+            # a query head at a time, its dQ the output block; dK and dV
+            # whole in scratch and as output blocks, at the kv head's row
+            grid, held = (bh_kv, group, nk, nq_in), by_head_bytes
+            seq_q = [pl.BlockSpec((1, bq, w), lambda bhi, hd, ki, qi: q_tile(
+                bhi * group + hd, ki, qi)) for w in (d, d_v, 1, 1)]
+            seq_k = [pl.BlockSpec((1, bk, w),
+                                  lambda bhi, hd, ki, qi: (bhi, ki, 0))
+                     for w in (d, d_v)]
+            in_specs = [seq_q[0], *seq_k, *seq_q[1:]]
+            out_specs = [pl.BlockSpec((1, s_q, d), lambda bhi, hd, ki, qi: (
+                bhi * group + hd, 0, 0))] + [
+                pl.BlockSpec((1, s_k, w),
+                             lambda bhi, hd, ki, qi: (bhi, 0, 0))
+                for w in (d, d_v)]
+            scratch = [pltpu.VMEM((rows, w), jnp.float32)
+                       for rows, w in ((s_q, d), (s_k, d), (s_k, d_v))]
         dq, dk, dv = pl.pallas_call(
             functools.partial(
                 _flash_bwd_fused_kernel, sm_scale=sm_scale,
-                num_k_blocks=nk, **dkv_tiles),
-            grid=(bh_kv, nk, group * nq_in),
-            in_specs=dkv_in_specs,
-            out_specs=[pl.BlockSpec((group, s_q, d),
-                                    lambda bhi, ki, qi: (bhi, 0, 0)),
-                       *by_k],
+                num_k_blocks=nk, by_head=by_head, **dkv_tiles),
+            grid=grid,
+            in_specs=in_specs,
+            out_specs=out_specs,
             out_shape=[dq_shape, *dkv_shapes],
-            scratch_shapes=[pltpu.VMEM(
-                (s_q, d) if group == 1 else (group, s_q, d), jnp.float32),
-                *dkv_scratch],
-            # dQ is carried across both sequence dims: only batch*heads is
-            # parallel, and the scoped limit grows by what dQ takes
+            scratch_shapes=scratch,
+            # the held gradients are carried across the sequence dims (by
+            # head, dK and dV across the group too): only batch*kv heads is
+            # parallel, and the scoped limit grows by what they take
             compiler_params=None if interpret else pltpu.CompilerParams(
-                dimension_semantics=("parallel", "arbitrary", "arbitrary"),
-                vmem_limit_bytes=_SCOPED_VMEM_BYTES + dq_bytes),
+                dimension_semantics=("parallel",) + ("arbitrary",) * (
+                    len(grid) - 1),
+                vmem_limit_bytes=_SCOPED_VMEM_BYTES + held),
             interpret=interpret,
         )(*operands)
     else:
@@ -965,9 +1042,10 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
     fed; 2048-wide tiles spill VMEM and regress). The backward caps its
     tiles at 512 internally (``_bwd_tile_sizes``: its working set is ~4
     score tiles) and is one launch, dQ accumulated beside dK/dV, wherever
-    the whole dQ of a kv head's queries fits VMEM beside them; past
-    ``_FUSED_BWD_DQ_BYTES`` it is a dQ launch and a dK/dV launch on the
-    same tiles (``_flash_bwd``). fit_block below shrinks tiles for
+    the dQ of a kv head's queries fits VMEM beside them, whole or, with
+    the kv head's dK and dV, a query head at a time; where not even that is
+    within ``_FUSED_BWD_DQ_BYTES`` it is a dQ launch and a dK/dV launch on
+    the same tiles (``_flash_bwd``). fit_block below shrinks tiles for
     short/odd sequences."""
     if sm_scale is None:
         sm_scale = 1.0 / math.sqrt(q.shape[-1])

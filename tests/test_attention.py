@@ -513,6 +513,112 @@ def test_grouped_and_windowed_flash_matches_reference(monkeypatch, s_q, s_k,
                                    atol=1e-5)
 
 
+def _path_counts():
+    import analytics_zoo_tpu.ops.attention as attn
+    return {path: child.value for path, child in (
+        ("fused", attn._BACKWARD_FUSED),
+        ("fused_by_head", attn._BACKWARD_FUSED_BY_HEAD),
+        ("two_kernel", attn._BACKWARD_TWO_KERNEL))}
+
+
+_BACKWARD_KERNELS = {
+    "fused": ["_flash_bwd_fused_kernel"],
+    "fused_by_head": ["_flash_bwd_fused_kernel"],
+    "two_kernel": ["_flash_bwd_dq_kernel", "_flash_bwd_dkv_kernel"]}
+
+
+@pytest.mark.parametrize("s_q,s_k,group,window,d", [
+    *[(64, 64, group, window, d) for group in (2, 4, 8)
+      for window in (None, 24) for d in (64, 128)],
+    (48, 64, 4, 24, 64),            # the decode shape, s_q < s_k
+])
+def test_a_group_past_the_budget_is_one_launch_a_head_at_a_time(
+        monkeypatch, s_q, s_k, group, window, d):
+    """Between the two: the group's dQ is past the budget, one query
+    head's dQ with the kv head's dK and dV is not. The backward is still one
+    launch, grid (kv heads, group, k blocks, q tiles), and its gradients are
+    the two-kernel pair's to the bit (dK and dV meet their tiles head by
+    head and q tile by q tile, dQ in ascending k order, as the pair's do)
+    and ``mha_reference``'s within 2e-3. Two query heads a kv head never
+    take it: dK and dV held whole are as many bytes as a second head's dQ
+    twice over, so past the group's dQ the pair runs. The counter's three
+    labels say which path each trace took."""
+    import analytics_zoo_tpu.ops.attention as attn
+    q, k, v, w = _gqa_qkv(s_q, s_k, 2 * group, 2, d=d, d_v=d)
+    one_head = attn._fused_bwd_dq_bytes(s_q, d, q.dtype)
+    by_head = one_head + 2 * attn._fused_bwd_dq_bytes(s_k, d, k.dtype)
+    whole_group = group * one_head
+    path = "fused_by_head" if by_head < whole_group else "two_kernel"
+    assert path == ("two_kernel" if group == 2 else "fused_by_head")
+
+    def loss(fn, **kw):
+        return lambda q, k, v: jnp.sum(
+            fn(q, k, v, causal=True, window=window, **kw) * w)
+
+    def flash(budget, path):
+        monkeypatch.setattr(attn, "_FUSED_BWD_DQ_BYTES", budget)
+        before = _path_counts()
+        grad = jax.grad(loss(flash_attention, block_q=16, block_k=16),
+                        (0, 1, 2))
+        jaxpr = jax.make_jaxpr(grad)(q, k, v).jaxpr
+        assert pallas_kernels(jaxpr) == \
+            ["_flash_kernel"] + _BACKWARD_KERNELS[path]
+        moved = {p: n - before[p] for p, n in _path_counts().items()}
+        assert moved == {p: int(p == path) for p in moved}
+        return grad(q, k, v), [tuple(e.params["grid_mapping"].grid)
+                               for e in equations(jaxpr)
+                               if e.primitive.name == "pallas_call"]
+
+    # between one head's bytes and the group's; what by-head holds, if less
+    mine, grids = flash(min(by_head, whole_group - 1), path)
+    if path == "fused_by_head":
+        assert len(grids[1]) == 4 and grids[1][:3] == (2, group, s_k // 16)
+    pair, _ = flash(0, "two_kernel")
+    want = jax.grad(loss(mha_reference), (0, 1, 2))(q, k, v)
+    for a, b, c in zip(mine, pair, want):
+        assert a.shape == c.shape and bool(jnp.all(a == b))
+        assert bool(jnp.any(a != 0))
+        np.testing.assert_allclose(np.asarray(a), np.asarray(c), rtol=2e-3,
+                                   atol=2e-3)
+
+
+@pytest.mark.parametrize("b,s,h,h_kv,d,d_v,window,path", [
+    (1, 16384, 32, 4, 128, 128, None, "fused_by_head"),  # the grouped-query
+    (1, 16384, 32, 4, 128, 128, 2048, "fused_by_head"),  # cell's two kinds
+    (1, 8192, 4, 1, 128, 128, None, "fused"),            # the Nemotron cell
+    (2, 8192, 32, 32, 192, 128, None, "fused"),          # the MLA cell
+    (1, 32768, 32, 4, 128, 128, None, "two_kernel"),     # grouped, past 16384
+    (1, 65536, 2, 2, 128, 128, None, "two_kernel"),      # a head past 49152
+])
+def test_the_shape_alone_chooses_the_backward(b, s, h, h_kv, d, d_v, window,
+                                              path):
+    """No budget patched, nothing computed (the gradient is traced on
+    ``ShapeDtypeStruct``s): the three cells' shapes in bfloat16 and the
+    path each compiles, by bytes against the one ``_FUSED_BWD_DQ_BYTES``;
+    past it, the pair."""
+    import analytics_zoo_tpu.ops.attention as attn
+    assert attn._FUSED_BWD_DQ_BYTES == 48 * attn.MIB
+    q, k, v = (jax.ShapeDtypeStruct((b, s, heads, width), jnp.bfloat16)
+               for heads, width in ((h, d), (h_kv, d), (h_kv, d_v)))
+    before = _path_counts()
+    jaxpr = jax.make_jaxpr(jax.grad(lambda q, k, v: flash_attention(
+        q, k, v, causal=True, window=window).astype(jnp.float32).sum(),
+        (0, 1, 2)))(q, k, v).jaxpr
+    assert pallas_kernels(jaxpr) == ["_flash_kernel"] + \
+        _BACKWARD_KERNELS[path]
+    moved = {p: n - before[p] for p, n in _path_counts().items()}
+    assert moved == {p: int(p == path) for p in moved}
+    backward = [e for e in equations(jaxpr)
+                if e.primitive.name == "pallas_call"][1]
+    if path == "fused_by_head":
+        bands = 5 if window else s // 512    # 2048 / 512 + the diagonal's
+        assert tuple(backward.params["grid_mapping"].grid) == \
+            (b * h_kv, h // h_kv, s // 512, bands)
+        # one query head's dQ, the kv head's whole dK and dV
+        assert [tuple(x.aval.shape) for x in backward.outvars] == \
+            [(b * h, s, d), (b * h_kv, s, d), (b * h_kv, s, d_v)]
+
+
 def test_a_window_is_a_mask_inside_the_causal_one():
     """``0 <= t - j < window``: a window of one sees the token alone, a
     window of the sequence is the causal mask (and takes its kernels), a
@@ -538,17 +644,25 @@ def test_a_window_is_a_mask_inside_the_causal_one():
         flash_attention(q[:, :, :3], k, v, causal=True)
 
 
+@pytest.mark.parametrize("by_head", [False, True])
 @pytest.mark.parametrize("window,block,band", [(40, 16, 4), (16, 16, 2),
                                                (100, 32, 5)])
-def test_a_windowed_grid_walks_the_bands_alone(window, block, band):
+def test_a_windowed_grid_walks_the_bands_alone(monkeypatch, window, block,
+                                               band, by_head):
     """The innermost grid extent of every windowed kernel is the widest
     band's tiles, not the sequence's: tiles wholly outside the window are
     no grid steps at all, and ``zoo_attention_window_tiles_total`` counts
-    as many tiles visited as the mask needs, forward and backward. Run as
-    plain causal the same call would compute several times as many."""
+    as many tiles visited as the mask needs, forward and backward: the
+    fused backward's one pass, whole group or (``by_head``: four query
+    heads on one kv head, a budget that holds one head's gradients) a head
+    at a time. Run as plain causal the same call would compute several
+    times as many."""
     import analytics_zoo_tpu.ops.attention as attn
-    s, h, h_kv = 256, 4, 2
+    s, h, h_kv = 256, 4, 1 if by_head else 2
     q, k, v, w = _gqa_qkv(s, s, h, h_kv)
+    if by_head:
+        monkeypatch.setattr(attn, "_FUSED_BWD_DQ_BYTES",
+                            3 * attn._fused_bwd_dq_bytes(s, 16, q.dtype))
     before = (attn._TILES_VISITED.value, attn._TILES_NEEDED.value)
     jaxpr = jax.make_jaxpr(jax.grad(lambda q, k, v: jnp.sum(flash_attention(
         q, k, v, causal=True, window=window, block_q=block,
@@ -556,7 +670,8 @@ def test_a_windowed_grid_walks_the_bands_alone(window, block, band):
     grids = [tuple(e.params["grid_mapping"].grid) for e in equations(jaxpr)
              if e.primitive.name == "pallas_call"]
     n = s // block
-    assert grids == [(h, n, band), (h_kv, n, (h // h_kv) * band)]
+    assert grids == [(h, n, band), (h_kv, h // h_kv, n, band) if by_head
+                     else (h_kv, n, (h // h_kv) * band)]
     visited, needed = (attn._TILES_VISITED.value - before[0],
                        attn._TILES_NEEDED.value - before[1])
     assert visited == needed == 2 * h * attn._window_tiles(
@@ -593,11 +708,17 @@ def test_the_mla_paths_results_are_the_parents_to_the_bit():
         attn._FUSED_BWD_DQ_BYTES = keep
 
 
-def test_grouped_windowed_flash_lowers_to_mosaic_for_tpu(monkeypatch):
+@pytest.mark.parametrize("budget,path,calls", [
+    (None, "fused", 2), (6 * 2 ** 20, "fused_by_head", 2),
+    (0, "two_kernel", 3)])
+def test_grouped_windowed_flash_lowers_to_mosaic_for_tpu(monkeypatch, budget,
+                                                         path, calls):
     """The band-walking kernels through the Pallas -> Mosaic lowering, 8
     query heads on 2 kv heads: the fused backward (2 custom calls a
-    gradient) and, past the budget, the pair (3); no k or v of 8 heads is
-    an operand of any of them."""
+    gradient); with 6 MiB, which hold a head's dQ and the kv head's dK and
+    dV (2 MiB each) and not the group's 8 MiB of dQ, the fused backward a
+    head at a time (2); past the budget, the pair (3); no k or v of 8 heads
+    is an operand of any of them."""
     import analytics_zoo_tpu.ops.attention as attn
     monkeypatch.setattr(attn, "_interpret", lambda: False)
     q = jax.ShapeDtypeStruct((1, 2048, 8, 128), jnp.bfloat16)
@@ -607,12 +728,13 @@ def test_grouped_windowed_flash_lowers_to_mosaic_for_tpu(monkeypatch):
         return flash_attention(q, k, v, causal=True, window=640).astype(
             jnp.float32).sum()
 
-    for budget, calls in ((None, 2), (0, 3)):
-        if budget is not None:
-            monkeypatch.setattr(attn, "_FUSED_BWD_DQ_BYTES", budget)
-        grad = jax.export.export(
-            jax.jit(jax.grad(loss, argnums=(0, 1, 2))),
-            platforms=["tpu"])(q, kv, kv).mlir_module()
-        assert grad.count("tpu_custom_call") == calls
-        assert "tensor<8x2048x128xbf16>" in grad          # q, by (b*h, s, d)
-        assert "tensor<2x2048x128xbf16>" in grad          # k and v as given
+    if budget is not None:
+        monkeypatch.setattr(attn, "_FUSED_BWD_DQ_BYTES", budget)
+    before = _path_counts()
+    grad = jax.export.export(
+        jax.jit(jax.grad(loss, argnums=(0, 1, 2))),
+        platforms=["tpu"])(q, kv, kv).mlir_module()
+    assert _path_counts() == dict(before, **{path: before[path] + 1})
+    assert grad.count("tpu_custom_call") == calls
+    assert "tensor<8x2048x128xbf16>" in grad          # q, by (b*h, s, d)
+    assert "tensor<2x2048x128xbf16>" in grad          # k and v as given

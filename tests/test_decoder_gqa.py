@@ -124,6 +124,15 @@ def test_the_references_faults_are_another_model(sides, fault):
     assert abs(float(faulty) - float(clean)) > 1e-3 * float(clean)
 
 
+def _cells_configuration():
+    """The grouped-query cell's configuration file and the model's keys its
+    factory makes of it."""
+    with open(os.path.join(BENCH, "configs", "trinity_mini_ep8.json")) as f:
+        cfg = json.load(f)
+    factory = spec.load_py(os.path.join(BENCH, cfg["factory"]))
+    return cfg, factory.model_config(cfg)
+
+
 def test_each_layer_takes_the_kernels_of_its_kind(sides):
     """Two flash kernels a block (forward, fused backward: the remat policy
     keeps the forward's results), k and v handed over at their own two
@@ -144,6 +153,39 @@ def test_each_layer_takes_the_kernels_of_its_kind(sides):
         # (v carries its ones column at this head size)
         assert q == (2 * 4, SEQ, 16) and k == (2 * 2, SEQ, 16) == v[:2] + (16,)
     assert attn._TILES_NEEDED.value > before
+
+
+def test_the_cells_own_blocks_take_one_backward_launch_each():
+    """The same at the cell's own 16384 positions, 32 query heads on 4 kv
+    heads of 128, bfloat16, traced on shapes (nothing computed; a sliding
+    and a global block of the published widths with dense FFNs, a small
+    vocabulary): under the one remat policy two flash kernels a block, the
+    forward and ONE backward. A kv head's 8 x 16384 x 128 of dQ do not fit
+    the fused kernel's VMEM budget at once, so its grid takes the group's
+    query heads in turn, (kv heads, group, k blocks, q tiles or a band's),
+    and ``zoo_attention_backward_total`` counts ``fused_by_head``."""
+    import analytics_zoo_tpu.ops.attention as attn
+    model = DecoderLM.from_config(dict(
+        _cells_configuration()[1], num_hidden_layers=2, num_dense_layers=2,
+        layer_types=[SLIDING, FULL], vocab_size=256))
+    assert model.layer_windows == (2048, None)
+    params = jax.eval_shape(lambda: model.init(
+        jax.random.PRNGKey(0), jnp.zeros((1, 128), jnp.uint16)))["params"]
+    ids = jax.ShapeDtypeStruct((1, 16384), jnp.uint16)
+    before = (attn._BACKWARD_FUSED_BY_HEAD.value,
+              attn._BACKWARD_FUSED.value + attn._BACKWARD_TWO_KERNEL.value)
+    jaxpr = jax.make_jaxpr(jax.grad(lambda p, ids: jnp.mean(next_token_loss(
+        ids, model.apply({"params": p}, ids, train=True)))))(params, ids).jaxpr
+    assert pallas_kernels(jaxpr) == \
+        2 * ["_flash_kernel"] + 2 * ["_flash_bwd_fused_kernel"]
+    grids = [tuple(e.params["grid_mapping"].grid) for e in equations(jaxpr)
+             if e.primitive.name == "pallas_call"]
+    # forward 1024 x 1024 tiles, the window's band 3 of them; backward 512 x
+    # 512, the band 5; the global block's backward comes first
+    assert grids == [(32, 16, 3), (32, 16, 16), (4, 8, 32, 32), (4, 8, 32, 5)]
+    assert (attn._BACKWARD_FUSED_BY_HEAD.value,
+            attn._BACKWARD_FUSED.value + attn._BACKWARD_TWO_KERNEL.value) == \
+        (before[0] + 2, before[1])
 
 
 def test_rope_half_rotates_the_two_halves():
@@ -254,8 +296,7 @@ def test_trains_through_the_estimator_on_arrays():
 def test_the_configurations_parameter_count_is_pinned():
     """705.5 M parameters, 11.29 GB at 16 B a parameter: the cut of
     ISSUE 39, counted three ways; every width the catalog row's."""
-    with open(os.path.join(BENCH, "configs", "trinity_mini_ep8.json")) as f:
-        cfg = json.load(f)
+    cfg, mcfg = _cells_configuration()
     for key, width in (("hidden_size", 2048), ("num_attention_heads", 32),
                        ("num_key_value_heads", 4), ("head_dim", 128),
                        ("intermediate_size", 6144),
@@ -263,8 +304,6 @@ def test_the_configurations_parameter_count_is_pinned():
                        ("num_experts_per_tok", 8), ("sliding_window", 2048)):
         assert cfg[key] == width
     assert len(cfg["layer_types"]) == 32               # kept whole
-    factory = spec.load_py(os.path.join(BENCH, cfg["factory"]))
-    mcfg = factory.model_config(cfg)
     assert mcfg["num_experts"] == 128 and mcfg["experts_held"] == 16
     assert mcfg["layer_types"] == [SLIDING, SLIDING, FULL, SLIDING, SLIDING]
     n = ref.param_count(mcfg)
