@@ -57,6 +57,17 @@ _WINDOW_TILES = _REGISTRY.counter(
 _TILES_VISITED = _WINDOW_TILES.labels(kind="visited")
 _TILES_NEEDED = _WINDOW_TILES.labels(kind="needed")
 
+# what a block-diffusion call site's grids compute against what its mask keeps
+_BD_ENTRIES = _REGISTRY.counter(
+    "zoo_attention_bd_entries_total",
+    "score entries of block-diffusion flash_attention call sites traced, "
+    "forward and backward kernels, each at its own tile size: computed (the "
+    "entries of the tiles the grids walk) and needed (the entries the mask "
+    "keeps)", labelnames=("kind",))
+_BD_COMPUTED = _BD_ENTRIES.labels(kind="computed")
+_BD_NEEDED = _BD_ENTRIES.labels(kind="needed")
+BLOCK_DIFFUSION = "block_diffusion"
+
 NEG_INF = -1e30
 LOG2_E = 1.4426950408889634      # the flash kernel softmaxes in base 2
 # the forward kernel's output and logsumexp, as ``checkpoint_name`` marks
@@ -77,15 +88,34 @@ _SCOPED_VMEM_BYTES = 16 * MIB
 _FUSED_BWD_DQ_BYTES = 48 * MIB
 
 
+def block_diffusion_mask(seq: int, block: int) -> np.ndarray:
+    """The block-diffusion mask over ``seq`` positions ``[x_t | x_0]`` (a
+    noisy copy of a sequence of ``seq / 2`` tokens, then the clean one), as a
+    (seq, seq) boolean array, True where query i sees key j. With ``L = seq /
+    2`` and ``blk(i) = (i mod L) // block``: a noisy query sees its own
+    noisy block (both ways) and the clean blocks before its own; a clean
+    query sees the clean blocks up to its own; no clean query sees a noisy
+    key."""
+    half = seq // 2
+    pos = np.arange(seq)
+    blk, noisy = (pos % half) // block, pos < half
+    qb, kb = blk[:, None], blk[None, :]
+    qn, kn = noisy[:, None], noisy[None, :]
+    return (qn & kn & (qb == kb)) | (qn & ~kn & (kb < qb)) | \
+        (~qn & ~kn & (kb <= qb))
+
+
 def mha_reference(q: jax.Array, k: jax.Array, v: jax.Array,
                   *, causal: bool = False, sm_scale: Optional[float] = None,
                   bias: Optional[jax.Array] = None,
-                  window: Optional[int] = None) -> jax.Array:
+                  window: Optional[int] = None, mask: Optional[str] = None,
+                  block: Optional[int] = None) -> jax.Array:
     """Plain materialised-scores attention. q,k: (B, S, H, D); v: (B, S, H,
     D) or a head size of its own. k and v may have fewer heads than q
     (grouped queries: each run of H / H_kv query heads shares one, repeated
     here); ``window`` keeps, of a causal mask, the keys ``0 <= t - j <
-    window`` of query position t."""
+    window`` of query position t; ``mask="block_diffusion"`` (not causal)
+    keeps :func:`block_diffusion_mask`'s entries for blocks of ``block``."""
     if sm_scale is None:
         sm_scale = 1.0 / math.sqrt(q.shape[-1])
     group = q.shape[2] // k.shape[2]
@@ -96,11 +126,14 @@ def mha_reference(q: jax.Array, k: jax.Array, v: jax.Array,
         logits = logits + bias
     if causal:
         s_q, s_k = logits.shape[-2], logits.shape[-1]
-        mask = jnp.tril(jnp.ones((s_q, s_k), dtype=bool), k=s_k - s_q)
+        keep = jnp.tril(jnp.ones((s_q, s_k), dtype=bool), k=s_k - s_q)
         if window is not None:
-            mask &= ~jnp.tril(jnp.ones((s_q, s_k), dtype=bool),
+            keep &= ~jnp.tril(jnp.ones((s_q, s_k), dtype=bool),
                               k=s_k - s_q - window)
-        logits = jnp.where(mask, logits, NEG_INF)
+        logits = jnp.where(keep, logits, NEG_INF)
+    elif mask == BLOCK_DIFFUSION:
+        logits = jnp.where(block_diffusion_mask(logits.shape[-1], block),
+                           logits, NEG_INF)
     probs = jax.nn.softmax(logits, axis=-1)
     return jnp.einsum("bhqk,bkhd->bqhd", probs.astype(v.dtype), v)
 
@@ -266,9 +299,114 @@ def _window_tiles(s_q, s_k, block_q, block_k, window):
             "needed": int(needed.sum())}
 
 
+def _bd_k_walk(qi, block_q, block_k, half, block, xp=jnp):
+    """The k blocks that q tile ``qi`` of a ``[noisy | clean]`` sequence of
+    ``2 * half`` positions sees under the block-diffusion mask, as ``(first,
+    n_diag, n_prefix)``: a noisy tile's own noisy keys are the ``n_diag``
+    blocks from ``first`` on (none for a clean tile); then come the
+    ``n_prefix`` clean blocks from the first on (the clean blocks before the
+    tile's last row's block, or up to it for a clean tile). Tiles never
+    straddle the two halves, and ``block`` divides them."""
+    noisy = qi * block_q < half
+    q0 = qi * block_q - xp.where(noisy, 0, half)   # its first row, in its half
+    first = q0 // block_k
+    n_diag = xp.where(noisy, (q0 + block_q - 1) // block_k - first + 1, 0)
+    end = q0 + block_q - xp.where(noisy, block, 0)  # past its last clean key
+    return first, n_diag, -(-end // block_k)
+
+
+def _bd_k_block(qi, step, block_q, block_k, half, block):
+    """``(k block, active)`` of a k-innermost kernel's ``step`` under q tile
+    ``qi``: the walk's diagonal blocks, then its clean ones; the steps past
+    the walk's last name that block again and do nothing."""
+    first, n_diag, n_prefix = _bd_k_walk(qi, block_q, block_k, half, block)
+    last = n_diag + n_prefix - 1
+    j = jnp.minimum(step, last)
+    return jnp.where(j < n_diag, first + j,
+                     half // block_k + j - n_diag), step <= last
+
+
+def _bd_q_walk(ki, block_q, block_k, half, block, xp=jnp):
+    """The q tiles that see a key of k block ``ki``: ``(first, n_noisy,
+    first_clean, n_clean)``, the noisy tiles ``first ..`` then the clean
+    tiles ``first_clean ..`` (absolute tile indices). A noisy block is seen
+    by the noisy tiles over its own positions; a clean block by the noisy
+    tiles whose last row's block lies past it and by the clean tiles from
+    its own on."""
+    nqh = half // block_q
+    noisy = ki * block_k < half
+    k0 = ki * block_k - xp.where(noisy, 0, half)
+    first = xp.where(noisy, k0 // block_q, (k0 + block) // block_q)
+    n_noisy = xp.where(noisy, (k0 + block_k - 1) // block_q - k0 // block_q
+                       + 1, xp.maximum(nqh - first, 0))
+    n_clean = xp.where(noisy, 0, nqh - k0 // block_q)
+    return first, n_noisy, nqh + k0 // block_q, n_clean
+
+
+def _bd_q_block(ki, step, block_q, block_k, half, block):
+    """``(q tile, active)`` of a q-innermost kernel's ``step`` under k block
+    ``ki``, as :func:`_bd_k_block` for k."""
+    first, n_noisy, first_clean, n_clean = _bd_q_walk(ki, block_q, block_k,
+                                                      half, block)
+    last = n_noisy + n_clean - 1
+    j = jnp.minimum(step, last)
+    return jnp.where(j < n_noisy, first + j,
+                     first_clean + j - n_noisy), step <= last
+
+
+def _bd_tiles(seq, block_q, block_k, half, block):
+    """Static counts of a block-diffusion call's grids: ``k_steps`` /
+    ``q_steps`` (the longest walk by k and by q: the grids' innermost
+    extent), ``by_k`` / ``by_q`` (tiles a head's k-innermost / q-innermost
+    grid computes) and ``needed`` (the score entries the mask keeps: L^2 +
+    L * block a head, L = half)."""
+    qi, ki = np.arange(seq // block_q), np.arange(seq // block_k)
+    _, n_diag, n_prefix = _bd_k_walk(qi, block_q, block_k, half, block, np)
+    _, n_noisy, _, n_clean = _bd_q_walk(ki, block_q, block_k, half, block, np)
+    by_k, by_q = n_diag + n_prefix, n_noisy + n_clean
+    return {"k_steps": int(by_k.max()), "q_steps": int(by_q.max()),
+            "by_k": int(by_k.sum()), "by_q": int(by_q.sum()),
+            "needed": half * half + half * block}
+
+
+def _bd_keep(shape, q0, k0, lim, block, diag):
+    """A (block_q, block_k) tile's visible entries, its first query ``q0``
+    and first key ``k0`` counted in their halves: in a noisy key tile
+    (``diag``) the query's own block; in a clean one the keys before the
+    query's block start plus ``lim`` (0 for a noisy query, ``block`` for a
+    clean one). ``block`` is a power of two."""
+    q = q0 + lax.broadcasted_iota(jnp.int32, shape, 0)
+    k = k0 + lax.broadcasted_iota(jnp.int32, shape, 1)
+    start = q - jnp.bitwise_and(q, block - 1)
+    if diag:
+        return (k >= start) & (k < start + block)
+    return k < start + lim
+
+
+def _bd_compute(compute, active, q_start, k_start, block_q, block_k, bd):
+    """Run ``compute(masked)`` on an active tile of a block-diffusion grid:
+    with the diagonal's mask on a noisy key tile, with the prefix's where
+    the edge of the visible keys crosses a clean tile, unmasked inside it."""
+    import jax.experimental.pallas as pl
+    half, block = bd
+    q_noisy, diag = q_start < half, k_start < half
+    q0 = q_start - jnp.where(q_noisy, 0, half)
+    k0 = k_start - jnp.where(diag, 0, half)
+    lim = jnp.where(q_noisy, 0, block)
+    interior = jnp.logical_not(diag) & (k0 + block_k <= q0 + lim)
+
+    def masked(on_diag):
+        return lambda shape: _bd_keep(shape, q0, k0, lim, block, on_diag)
+
+    pl.when(active & diag)(lambda: compute(masked(True)))
+    pl.when(active & jnp.logical_not(diag) & jnp.logical_not(interior))(
+        lambda: compute(masked(False)))
+    pl.when(active & interior)(lambda: compute(False))
+
+
 def _flash_kernel(q_ref, k_ref, v_ref, o_ref, *rest, block_q,
                   block_k, num_k_blocks, causal, head_dim, q_offset=0,
-                  with_lse=False, ones_column=True, window=None):
+                  with_lse=False, ones_column=True, window=None, bd=None):
     """Grid = (batch*heads, num_q_blocks, num_k_blocks); the k dim is innermost
     so (acc, m) scratch carries the online softmax across k iterations.
     With ``with_lse`` the kernel also emits the log2-domain logsumexp
@@ -281,7 +419,9 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, *rest, block_q,
     diagonal's block again, so nothing is fetched). A row whose first
     blocks are all outside its window carries m = NEG_INF and p = 1
     through them; its first block with a visible key rescales that to
-    exactly 0.
+    exactly 0. With ``bd`` (``(half, block)``: the block-diffusion mask) the
+    k dim walks the q tile's diagonal and clean blocks alone
+    (:func:`_bd_k_block`).
 
     The softmax normaliser l = sum(p) comes one of two ways, by v's head
     size (``_flash_forward`` decides):
@@ -315,7 +455,11 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, *rest, block_q,
             l_ref[...] = jnp.zeros_like(l_ref)
 
     q_start = q_idx * block_q
-    k_start = _k_start(q_idx, k_idx, block_q, block_k, q_offset, window)
+    if bd is not None:
+        k_blk, active = _bd_k_block(q_idx, k_idx, block_q, block_k, *bd)
+        k_start = k_blk * block_k
+    else:
+        k_start = _k_start(q_idx, k_idx, block_q, block_k, q_offset, window)
 
     def _compute(masked):
         # matmuls keep the input dtype (bf16 inputs hit the MXU at full
@@ -328,7 +472,9 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, *rest, block_q,
         s = jax.lax.dot_general(
             q, k, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32)
-        if masked:
+        if callable(masked):             # a block-diffusion tile's entries
+            s = jnp.where(masked(s.shape), s, NEG_INF)
+        elif masked:
             # bottom-right aligned (q_offset = s_k - s_q), matching
             # mha_reference's tril(k=s_k-s_q), _lse_pass and _flash_bwd —
             # the fwd/bwd pair must mask identically or causal s_q != s_k
@@ -351,7 +497,9 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, *rest, block_q,
                         jnp.dot(p.astype(v.dtype), v,
                                 preferred_element_type=jnp.float32))
 
-    if causal:
+    if bd is not None:
+        _bd_compute(_compute, active, q_start, k_start, block_q, block_k, bd)
+    elif causal:
         # Three tile classes: fully masked (skip), diagonal (mask), and
         # interior (q_pos >= k_pos everywhere — no mask work: the two
         # iotas + compare + select are (block_q, block_k) VPU passes that
@@ -413,7 +561,7 @@ def _kv_row(group):
 
 
 def _flash_forward(q, k, v, causal, sm_scale, block_q, block_k, interpret,
-                   with_lse=False, window=None):
+                   with_lse=False, window=None, bd=None):
     import jax.experimental.pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
@@ -449,13 +597,19 @@ def _flash_forward(q, k, v, causal, sm_scale, block_q, block_k, interpret,
         num_k = tiles["k_steps"]
         _TILES_VISITED.inc(b * h * tiles["by_k"])
         _TILES_NEEDED.inc(b * h * tiles["needed"])
+    if bd is not None:
+        # the k dim walks each q tile's diagonal and clean blocks
+        tiles = _bd_tiles(s_q, block_q, block_k, *bd)
+        num_k = tiles["k_steps"]
+        _BD_COMPUTED.inc(b * h * tiles["by_k"] * block_q * block_k)
+        _BD_NEEDED.inc(b * h * tiles["needed"])
 
     grid = (b * h, num_q, num_k)
     kernel = functools.partial(
         _flash_kernel, block_q=block_q, block_k=block_k,
         num_k_blocks=num_k, causal=causal, head_dim=d_v,
         q_offset=s_k - s_q, with_lse=with_lse, ones_column=ones_column,
-        window=window)
+        window=window, bd=bd)
     # Under shard_map (e.g. Ulysses sequence parallelism) the output must
     # declare which mesh axes it varies over. Use the union of the inputs'
     # varying sets and lift any less-varying input up to it so mixed-vma
@@ -470,7 +624,11 @@ def _flash_forward(q, k, v, causal, sm_scale, block_q, block_k, interpret,
             jax.ShapeDtypeStruct((b * h, s_q, 1), jnp.float32, vma=vma))
         out_specs.append(pl.BlockSpec((1, block_q, 1),
                                       lambda bh, qi, ki: (bh, qi, 0)))
-    if causal:
+    if bd is not None:
+        def k_index(bh, qi, ki):
+            return (kv_row(bh), _bd_k_block(qi, ki, block_q, block_k,
+                                            *bd)[0], 0)
+    elif causal:
         # fully-masked steps (k block entirely above the diagonal) skip
         # compute via pl.when; re-referencing the last ACTIVE k block
         # keeps the block index unchanged across the masked tail of each
@@ -532,13 +690,15 @@ def _interpret() -> bool:
     return platform == "cpu"
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7))
-def _flash_attention(q, k, v, causal, sm_scale, block_q, block_k, window):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7, 8))
+def _flash_attention(q, k, v, causal, sm_scale, block_q, block_k, window,
+                     bd):
     return _flash_forward(q, k, v, causal, sm_scale, block_q, block_k,
-                          _interpret(), window=window)
+                          _interpret(), window=window, bd=bd)
 
 
-def _flash_fwd(q, k, v, causal, sm_scale, block_q, block_k, window=None):
+def _flash_fwd(q, k, v, causal, sm_scale, block_q, block_k, window=None,
+               bd=None):
     """The forward kernel, with its two results named
     (``FLASH_RESIDUAL_NAMES``) for a caller's remat policy: the output
     (B, S_q, H, d_v), which is both the primal result and a residual, and
@@ -550,7 +710,8 @@ def _flash_fwd(q, k, v, causal, sm_scale, block_q, block_k, window=None):
     remat whose policy reads the names they are identities."""
     interpret = _interpret()
     out, lse = _flash_forward(q, k, v, causal, sm_scale, block_q, block_k,
-                              interpret, with_lse=True, window=window)
+                              interpret, with_lse=True, window=window,
+                              bd=bd)
     out = checkpoint_name(out, FLASH_RESIDUAL_NAMES[0])
     lse = checkpoint_name(lse, FLASH_RESIDUAL_NAMES[1])
     return out, (q, k, v, out, lse)
@@ -560,10 +721,14 @@ def _bwd_tile(masked, q2, k, v, g, L, D, q_offset, q_start, k_start, cd,
               window=None):
     """Shared (block_q, block_k) backward tile: rebuild P from (q2, k, L),
     then ds = P*(dP - D). All matmuls keep the input dtype (bf16 rides the
-    MXU) with f32 accumulation; returns (p, ds) in compute dtype ``cd``."""
+    MXU) with f32 accumulation; returns (p, ds) in compute dtype ``cd``.
+    ``masked``: False, True (the causal mask and window) or a block-diffusion
+    tile's ``keep(shape)``."""
     s2 = jax.lax.dot_general(q2, k, (((1,), (1,)), ((), ())),
                              preferred_element_type=jnp.float32)
-    if masked:
+    if callable(masked):
+        s2 = jnp.where(masked(s2.shape), s2, NEG_INF)
+    elif masked:
         q_pos = (q_offset + q_start +
                  lax.broadcasted_iota(jnp.int32, s2.shape, 0))
         k_pos = k_start + lax.broadcasted_iota(jnp.int32, s2.shape, 1)
@@ -577,7 +742,8 @@ def _bwd_tile(masked, q2, k, v, g, L, D, q_offset, q_start, k_start, cd,
 
 def _flash_bwd_dq_kernel(q2_ref, k_ref, v_ref, g_ref, L_ref, D_ref, dq_ref,
                          acc_ref, *, sm_scale, block_q, block_k,
-                         num_k_blocks, causal, q_offset, cd, window=None):
+                         num_k_blocks, causal, q_offset, cd, window=None,
+                         bd=None):
     """dQ pass: grid (batch*heads, num_q, num_k), k innermost; the dq tile
     accumulates across k iterations in VMEM scratch — no (S, S) tensor
     ever reaches HBM (the round-3 pure-JAX backward streamed every P/dS
@@ -585,7 +751,8 @@ def _flash_bwd_dq_kernel(q2_ref, k_ref, v_ref, g_ref, L_ref, D_ref, dq_ref,
     ~1.4x materialized; tiles resident in VMEM are the FA-2 design). Runs
     only where not even one query head's gradients fit the fused kernel's
     VMEM budget (``_flash_bwd``). With a ``window`` the k dim walks the q
-    tile's band, as in ``_flash_kernel``."""
+    tile's band, with ``bd`` its block-diffusion walk, as in
+    ``_flash_kernel``."""
     import jax.experimental.pallas as pl
 
     q_idx = pl.program_id(1)
@@ -596,7 +763,11 @@ def _flash_bwd_dq_kernel(q2_ref, k_ref, v_ref, g_ref, L_ref, D_ref, dq_ref,
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
     q_start = q_idx * block_q
-    k_start = _k_start(q_idx, k_idx, block_q, block_k, q_offset, window)
+    if bd is not None:
+        k_blk, active = _bd_k_block(q_idx, k_idx, block_q, block_k, *bd)
+        k_start = k_blk * block_k
+    else:
+        k_start = _k_start(q_idx, k_idx, block_q, block_k, q_offset, window)
 
     def _compute(masked):
         _, ds = _bwd_tile(masked, q2_ref[0], k_ref[0], v_ref[0],
@@ -605,7 +776,9 @@ def _flash_bwd_dq_kernel(q2_ref, k_ref, v_ref, g_ref, L_ref, D_ref, dq_ref,
         acc_ref[...] += jnp.dot(ds, k_ref[0],
                                 preferred_element_type=jnp.float32)
 
-    if causal:
+    if bd is not None:
+        _bd_compute(_compute, active, q_start, k_start, block_q, block_k, bd)
+    elif causal:
         active, diagonal = _tile_class(q_offset + q_start, k_start, block_q,
                                        block_k, window)
         pl.when(active & diagonal)(lambda: _compute(True))
@@ -622,7 +795,7 @@ def _flash_bwd_dkv_kernel(q2_ref, k_ref, v_ref, g_ref, L_ref, D_ref,
                           dk_ref, dv_ref, dk_acc, dv_acc, *, block_q,
                           block_k, num_q_blocks, causal, q_offset, cd,
                           dq=None, window=None, group=1, seq_q_blocks=None,
-                          by_head=False):
+                          by_head=False, bd=None):
     """dK/dV pass: grid (batch*kv heads, num_k, group * num_q), the
     innermost dim walking the q tiles of each of the ``group`` query heads
     that share this kv head in turn, so dK and dV are summed over the
@@ -632,7 +805,9 @@ def _flash_bwd_dkv_kernel(q2_ref, k_ref, v_ref, g_ref, L_ref, D_ref,
     1/log2(e) correction at finalize. With a ``window``, ``num_q_blocks``
     is the widest band's tiles and step j of a head is the tile
     ``_q_band(...)[0] + j``, the steps past the band's last tile padding;
-    ``seq_q_blocks`` is then the sequence's tiles.
+    ``seq_q_blocks`` is then the sequence's tiles. With ``bd`` step j of a
+    head is the j-th tile of the k block's block-diffusion walk
+    (:func:`_bd_q_block`), the steps past its last padding.
 
     ``dq`` is the fused kernel's (see ``_flash_bwd_fused_kernel``): given,
     each tile's dS also goes into dQ. ``by_head`` is its grid for a group
@@ -671,6 +846,8 @@ def _flash_bwd_dkv_kernel(q2_ref, k_ref, v_ref, g_ref, L_ref, D_ref,
         first, last = _q_band(k_idx, block_q, block_k, q_offset, window,
                               seq_q_blocks)
         q_idx = first + q_idx
+    elif bd is not None:
+        q_idx, active = _bd_q_block(k_idx, q_idx, block_q, block_k, *bd)
     q_start = q_idx * block_q
     if dq is not None:
         dq_ref, dq_acc, sm_scale, num_k_blocks = dq
@@ -685,15 +862,15 @@ def _flash_bwd_dkv_kernel(q2_ref, k_ref, v_ref, g_ref, L_ref, D_ref,
         def _write_dq():
             dq_ref[out_at] = (dq_acc[acc_at] * sm_scale).astype(dq_ref.dtype)
 
-        if window is None:
+        if window is None and bd is None:
             @pl.when(k_idx == 0)
             def _init_dq():
                 dq_acc[acc_at] = jnp.zeros((block_q, dq_acc.shape[-1]),
                                            dq_acc.dtype)
         else:
-            # a band's walk does not pass every q tile under the first k
-            # block: the whole accumulator is cleared as its kv head (by
-            # head: its query head) begins
+            # a band's (or a block-diffusion) walk does not pass every q
+            # tile under the first k block: the whole accumulator is cleared
+            # as its kv head (by head: its query head) begins
             @pl.when((k_idx == 0) & (dq_step == 0))
             def _init_dq():
                 dq_acc[...] = jnp.zeros_like(dq_acc)
@@ -713,7 +890,18 @@ def _flash_bwd_dkv_kernel(q2_ref, k_ref, v_ref, g_ref, L_ref, D_ref,
             dq_acc[acc_at] += jnp.dot(ds, k_ref[0],
                                       preferred_element_type=jnp.float32)
 
-    if causal:
+    if bd is not None:
+        _bd_compute(_compute, active, q_start, k_start, block_q, block_k, bd)
+        if dq is not None:
+            # a q tile's rows are whole once the last k block of its walk
+            # has passed them
+            first, n_diag, n_prefix = _bd_k_walk(q_idx, block_q, block_k,
+                                                 *bd)
+            last_k = jnp.where(n_prefix > 0,
+                               bd[0] // block_k + n_prefix - 1,
+                               first + n_diag - 1)
+            pl.when(active & (k_idx == last_k))(_write_dq)
+    elif causal:
         active, diagonal = _tile_class(q_offset + q_start, k_start, block_q,
                                        block_k, window)
         if window is not None:
@@ -734,7 +922,7 @@ def _flash_bwd_dkv_kernel(q2_ref, k_ref, v_ref, g_ref, L_ref, D_ref,
                             (1.0 / LOG2_E)).astype(dk_ref.dtype)
         dv_ref[out_k_at] = dv_acc[k_at].astype(dv_ref.dtype)
 
-    if dq is not None and window is None:
+    if dq is not None and window is None and bd is None:
         # a q tile's rows are whole once the last k block has passed them,
         # masked tiles too: written a tile at a time, never the whole
         # sequence in one statement
@@ -795,7 +983,7 @@ def _bwd_tile_sizes(s_q: int, s_k: int, block_q: int, block_k: int):
     return bq, bk
 
 
-def _flash_bwd(causal, sm_scale, block_q, block_k, window, res, g):
+def _flash_bwd(causal, sm_scale, block_q, block_k, window, bd, res, g):
     """FlashAttention-2-style Pallas backward consuming the forward's
     log2-domain logsumexp. Every (block_q, block_k) P/dS tile lives and
     dies in VMEM — the previous pure-JAX backward streamed each of its
@@ -821,7 +1009,8 @@ def _flash_bwd(causal, sm_scale, block_q, block_k, window, res, g):
     All three give the same bits and count themselves in
     ``zoo_attention_backward_total``. k and v keep their own head count
     throughout, and with a ``window`` every grid walks bands
-    (``_window_tiles``)."""
+    (``_window_tiles``), with ``bd`` the block-diffusion walks
+    (``_bd_tiles``)."""
     import jax.experimental.pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
@@ -837,10 +1026,13 @@ def _flash_bwd(causal, sm_scale, block_q, block_k, window, res, g):
     bh, bh_kv = b * h, b * h_kv
     cd = jnp.bfloat16 if q.dtype == jnp.bfloat16 else jnp.float32
     # innermost extents: every tile, or with a window the widest band's
-    nq_in, nk_in, tiles_w = nq, nk, None
+    nq_in, nk_in, tiles_w, tiles_bd = nq, nk, None, None
     if window is not None:
         tiles_w = _window_tiles(s_q, s_k, bq, bk, window)
         nq_in, nk_in = tiles_w["q_steps"], tiles_w["k_steps"]
+    if bd is not None:
+        tiles_bd = _bd_tiles(s_q, bq, bk, *bd)
+        nq_in, nk_in = tiles_bd["q_steps"], tiles_bd["k_steps"]
 
     def flat(a):                                 # (B,S,H,D) -> (B*H,S,D)
         return jnp.moveaxis(a, 2, 1).reshape(b * a.shape[2], a.shape[1],
@@ -857,12 +1049,14 @@ def _flash_bwd(causal, sm_scale, block_q, block_k, window, res, g):
     operands = tuple(mark_varying(a, vma) for a in (q2, kf, vf, gf, lse, D))
     off = s_k - s_q
     tiles = dict(block_q=bq, block_k=bk, causal=causal, q_offset=off,
-                 cd=cd, window=window)
+                 cd=cd, window=window, bd=bd)
     dkv_tiles = dict(tiles, num_q_blocks=nq_in, group=group,
                      seq_q_blocks=nq)
 
     def q_tile(row, ki, qi):
         """Block index of the q tile at step ``qi`` under k block ``ki``."""
+        if bd is not None:
+            return (row, _bd_q_block(ki, qi, bq, bk, *bd)[0], 0)
         if window is not None:
             first, last = _q_band(ki, bq, bk, off, window, nq)
             return (row, jnp.minimum(first + qi, last), 0)
@@ -909,6 +1103,10 @@ def _flash_bwd(causal, sm_scale, block_q, block_k, window, res, g):
         _TILES_NEEDED.inc(bh * tiles_w["needed"] * passes)
         _TILES_VISITED.inc(bh * (tiles_w["by_q"] + (
             0 if one_launch else tiles_w["by_k"])))
+    if tiles_bd is not None:
+        _BD_NEEDED.inc(bh * tiles_bd["needed"] * (1 if one_launch else 2))
+        _BD_COMPUTED.inc(bh * bq * bk * (tiles_bd["by_q"] + (
+            0 if one_launch else tiles_bd["by_k"])))
     if one_launch:
         if fused:
             _BACKWARD_FUSED.inc()
@@ -959,7 +1157,10 @@ def _flash_bwd(causal, sm_scale, block_q, block_k, window, res, g):
     else:
         _BACKWARD_TWO_KERNEL.inc()
         # --- dQ: grid (bh, nq, nk_in), k innermost -------------------------
-        if causal:
+        if bd is not None:
+            def k_index(bhi, qi, ki):
+                return (kv_row(bhi), _bd_k_block(qi, ki, bq, bk, *bd)[0], 0)
+        elif causal:
             # likewise the k blocks past a q tile's diagonal name the last
             # active one again (clamped at 0 as in _flash_forward)
             def k_index(bhi, qi, ki):
@@ -1014,7 +1215,8 @@ _flash_attention.defvjp(_flash_fwd, _flash_bwd)
 def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
                     causal: bool = False, sm_scale: Optional[float] = None,
                     block_q: int = 1024, block_k: int = 1024,
-                    window: Optional[int] = None) -> jax.Array:
+                    window: Optional[int] = None, mask: Optional[str] = None,
+                    block: Optional[int] = None) -> jax.Array:
     """Flash attention over (B, S, H, D); ``v`` may have a head size of its
     own (q and k (B, S, H, d_qk), v and the output (B, S, H, d_v)), as
     latent attention's training form has. k and v may have fewer heads
@@ -1024,7 +1226,14 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
     (causal only) keeps the keys ``0 <= t - j < window`` of query position
     t: the kernels' grids walk each tile's band and nothing outside it is
     fetched or computed; ``zoo_attention_window_tiles_total`` counts the
-    tiles. Uses the Pallas kernel when the
+    tiles. ``mask="block_diffusion"`` (not causal; s_q == s_k) reads the
+    sequence as ``[x_t | x_0]``, a noisy copy and the clean copy of a
+    sequence of S / 2, and keeps :func:`block_diffusion_mask`'s entries for
+    blocks of ``block`` (a power of two): the grids walk, for each q tile,
+    its own noisy diagonal and the clean tiles up to its own, and a tile
+    does mask arithmetic only where the mask crosses it;
+    ``zoo_attention_bd_entries_total`` counts the score entries computed
+    and needed. Uses the Pallas kernel when the
     sequence tiles evenly (compiled on a TPU, interpret mode on the CPU
     backend), else the reference path — which on a TPU is logged and
     counted (``zoo_attention_reference_on_tpu_total``), never silent.
@@ -1059,6 +1268,18 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
                              "window >= 1")
         if window >= s_k:
             window = None                # every causal key is inside it
+    bd = None
+    if mask is not None:
+        if mask != BLOCK_DIFFUSION:
+            raise ValueError(f"mask {mask!r}: the one named mask is "
+                             f"{BLOCK_DIFFUSION!r}")
+        if causal or window is not None or s_q != s_k or s_q % 2 or \
+                not block or (s_q // 2) % block:
+            raise ValueError(
+                f"the block-diffusion mask takes [x_t | x_0] of two equal "
+                f"halves whose length the block divides, and no causal mask "
+                f"or window: q {s_q}, k {s_k}, block {block}")
+        bd = (s_q // 2, int(block))
 
     def fit_block(s, want):
         # largest tile <= want that divides the sequence, so raising the
@@ -1069,8 +1290,16 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
                 return cand
         return None
 
-    bq = fit_block(s_q, min(block_q, s_q))
-    bk = fit_block(s_k, min(block_k, s_k))
+    if bd is None:
+        bq = fit_block(s_q, min(block_q, s_q))
+        bk = fit_block(s_k, min(block_k, s_k))
+    else:
+        # a tile lies in one half, whole blocks in it, and stays so as the
+        # backward halves it: powers of two the block divides
+        bq, bk = (fit_block(bd[0], min(want, bd[0]))
+                  for want in (block_q, block_k))
+        if any(t is None or t & (t - 1) or t % bd[1] for t in (bq, bk)):
+            bq = None
     # causal s_q < s_k (decode-style) rides the kernel: fwd/bwd both mask
     # bottom-right aligned. s_q > s_k would leave some q rows with no
     # visible key (all -inf) — keep those on the reference path.
@@ -1083,7 +1312,7 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
                 "materializing O(S^2) scores through mha_reference on the "
                 "TPU", tuple(q.shape), tuple(k.shape), causal)
         return mha_reference(q, k, v, causal=causal, sm_scale=sm_scale,
-                             window=window)
+                             window=window, mask=mask, block=block)
     if interpret and varying_axes(q, k, v):
         # Interpret-mode pallas under shard_map: the HLO interpreter's
         # grid dynamic_slice rejects varying operands with invariant
@@ -1091,5 +1320,5 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
         # handles vma (the union logic in _flash_forward); the CPU backend
         # uses the reference math.
         return mha_reference(q, k, v, causal=causal, sm_scale=sm_scale,
-                             window=window)
-    return _flash_attention(q, k, v, causal, sm_scale, bq, bk, window)
+                             window=window, mask=mask, block=block)
+    return _flash_attention(q, k, v, causal, sm_scale, bq, bk, window, bd)

@@ -26,7 +26,9 @@ output.
 The second half of the file is the expert layer of the DeepSeek-V3 family
 as ONE rank of an expert-parallel deployment runs it: :func:`route_noaux_tc`
 scores every token over ALL experts (sigmoid scores, selection by score plus
-a correction bias, the chosen scores renormalised and scaled) and
+a correction bias, the chosen scores renormalised and scaled), or
+:func:`route_softmax_topk` does (softmax over all experts, top-k, the chosen
+probabilities renormalised: the Qwen3-MoE form), and
 :func:`held_experts_ffn` is told which contiguous range of experts it holds,
 sorts the token-choices routed to them by expert and runs grouped matrix
 products over those rows alone. No capacity, no dropped token: rows beyond
@@ -247,6 +249,24 @@ def route_noaux_tc(x: jax.Array, router_w: jax.Array, bias: jax.Array, *,
     chosen = _take_chosen(scores, idx)
     gates = chosen / (jnp.sum(chosen, axis=-1, keepdims=True) + 1e-20)
     return idx, gates * scaling
+
+
+def route_softmax_topk(x: jax.Array, router_w: jax.Array, *, top_k: int
+                       ) -> Tuple[jax.Array, jax.Array]:
+    """Softmax routing with the chosen probabilities renormalised
+    (``norm_topk_prob``, no bias, no group): the logits ``r = x W_r`` in
+    float32 over ALL experts; the ``top_k`` experts of a token are its
+    largest logits (``lax.top_k``'s choice: ties to the lower index); their
+    gates are ``softmax(r)[C] / sum_C softmax(r)``, computed as the equal
+    ``softmax(r[C])``, which has the same gradient. The choice carries
+    :data:`ROUTER_CHOICE_NAME` for a rematerialisation policy to keep.
+
+    x: (N, d); router_w: (d, E). Returns ``(idx (N, top_k) int32, gates (N,
+    top_k) float32)``."""
+    logits = jnp.dot(x.astype(jnp.float32), router_w.astype(jnp.float32))
+    _, idx = lax.top_k(lax.stop_gradient(logits), top_k)
+    idx = checkpoint_name(idx.astype(jnp.int32), ROUTER_CHOICE_NAME)
+    return idx, jax.nn.softmax(_take_chosen(logits, idx), axis=-1)
 
 
 def expert_load(idx: jax.Array, n_experts: int) -> jax.Array:

@@ -1,5 +1,6 @@
-"""A decoder language model of four published families, chosen by the keys
-of the ``config.json`` it is built from (:meth:`DecoderLM.from_config`):
+"""A decoder language model of five published families, chosen by the
+``model_type`` of the ``config.json`` it is built from, or by its keys where
+it names none (:meth:`DecoderLM.from_config`):
 
 * the DeepSeek-V3 family: pre-norm, RMSNorm, interleaved RoPE, multi-head
   latent attention (MLA), SwiGLU, leading dense blocks, sparse-expert blocks
@@ -23,7 +24,13 @@ of the ``config.json`` it is built from (:meth:`DecoderLM.from_config`):
   ``attention_multiplier``) and then a SwiGLU MLP, each behind its norm and
   each branch times ``residual_multiplier``; the embedding times
   ``embedding_multiplier``, the logits over ``logits_scaling`` (the muP
-  scalars); the head tied to the embedding; no expert layer, no MTP module.
+  scalars); the head tied to the embedding; no expert layer, no MTP module;
+* the ``sdar_moe`` family: a Qwen3-MoE-style decoder (plain grouped-query
+  attention with q/k norms and rotate-half RoPE on every layer, every block
+  an expert layer routed by softmax, no shared expert) trained as a
+  block-diffusion language model: a step draws noise, runs a noisy copy and
+  the clean copy of each sequence side by side under the block-diffusion
+  mask, and scores the masked positions of the noisy copy.
 
 Trained through the estimator like any other module::
 
@@ -34,6 +41,8 @@ Trained through the estimator like any other module::
 ``ids`` are (batch, seq) token ids; the labels are the same ids, shifted on
 the device: by one for the main head, by two for the MTP head. Attention is
 causal over the whole sequence (no cross-document mask, as the family trains).
+The ``sdar_moe`` family takes the same ids and labels, unshifted
+(:func:`block_diffusion_loss`).
 
 Layer equations (x: (batch, seq, hidden)):
 
@@ -75,6 +84,22 @@ Layer equations (x: (batch, seq, hidden)):
   with ``h = RMSNorm(x)``; the mixer ``nemotron_h``'s ``M`` or ``*`` with
   ``softmax(q k^T m_att) v``; ``logits = RMSNorm(x) Emb^T / m_logits``.
 
+* ``sdar_moe`` (a sequence x_0 of L tokens, blocks ``blk(i) = (i mod L) //
+  B`` of ``block_length`` B): noise ``t_b ~ U(0, 1)`` a block, ``p_b = (1 -
+  eps) t_b + eps``, ``m_i ~ Bernoulli(p_blk(i))``, ``x_t[i] = MASK if m_i
+  else x_0[i]`` (:func:`block_diffusion_noise`: a pure function of the
+  module's ``noise_seed``, kept in its state, and its own count of training
+  steps); ``z = [x_t | x_0]`` at positions ``[0..L-1 | 0..L-1]``, ``x =
+  Emb(z)`` (MASK is one more row); a block ``x = x + Attn(RMSNorm(x))``, ``x = x + MoE(RMSNorm(
+  x))``; ``q = x W_q``, ``k = x W_k``, ``v = x W_v``, RMSNorm over each
+  head's width on q and k, then RoPE; ``softmax(q k^T / sqrt(d) + M_bd) v``
+  with the block-diffusion mask (``ops/attention.py
+  block_diffusion_mask``: a noisy query sees its own noisy block and the
+  clean blocks before it, a clean one the clean blocks up to its own); the
+  expert layer's gates ``softmax(r)[C] / sum_C softmax(r)`` over its top-k
+  logits ``C``; ``logits = RMSNorm(x[:, :L]) W_head`` (the noisy half);
+  ``loss = (1/L) sum_i m_i / p_blk(i) CE(logits_i, x_0[i])``.
+
 One rank of an expert-parallel deployment holds ``experts_held`` of the
 ``n_routed_experts`` experts of each layer, from ``first_expert`` on: the
 router keeps its full width and the layer computes its own experts' part.
@@ -98,11 +123,11 @@ import jax.numpy as jnp
 
 from analytics_zoo_tpu.obs.registry import REGISTRY as _REGISTRY
 from analytics_zoo_tpu.ops.attention import (
-    FLASH_RESIDUAL_NAMES, flash_attention)
+    BLOCK_DIFFUSION, FLASH_RESIDUAL_NAMES, flash_attention)
 from analytics_zoo_tpu.ops.ssm import causal_conv1d, ssd_scan
 from analytics_zoo_tpu.parallel.expert_parallel import (
     ROUTER_CHOICE_NAME, expert_load, held_experts_ffn, noaux_bias_update,
-    route_noaux_tc)
+    route_noaux_tc, route_softmax_topk)
 from ..engine.graph import keras_call
 
 _GAUGE_DOC = {
@@ -120,6 +145,7 @@ _GAUGE_DOC = {
 INIT_POSITIONS = 128
 ROUTER_STATE = "router_state"      # the correction bias: state, not parameter
 MOE_STATS = "moe_stats"            # counters the step leaves in its outputs
+NOISE_STATE = "noise_state"        # the block-diffusion noise's seed, steps
 
 
 class RMSNorm(nn.Module):
@@ -135,11 +161,14 @@ class RMSNorm(nn.Module):
         return (y * w).astype(self.dtype)
 
 
-def _rope_angles(x, theta: float):
-    """cos and sin of ``pos * theta**(-2i/d)``, (1, seq, 1, d/2), float32."""
+def _rope_angles(x, theta: float, positions=None):
+    """cos and sin of ``pos * theta**(-2i/d)``, (1, seq, 1, d/2), float32;
+    ``pos`` the sequence's index, or ``positions`` (seq,)."""
     d = x.shape[-1]
     inv = theta ** (-jnp.arange(0, d, 2, dtype=jnp.float32) / d)
-    ang = jnp.arange(x.shape[1], dtype=jnp.float32)[:, None] * inv[None, :]
+    pos = jnp.arange(x.shape[1], dtype=jnp.float32) if positions is None \
+        else positions.astype(jnp.float32)
+    ang = pos[:, None] * inv[None, :]
     return jnp.cos(ang)[None, :, None, :], jnp.sin(ang)[None, :, None, :]
 
 
@@ -154,12 +183,13 @@ def rope_interleaved(x, theta: float):
     return out.reshape(x.shape).astype(x.dtype)
 
 
-def rope_half(x, theta: float):
+def rope_half(x, theta: float, positions=None):
     """Rotary position embedding over the pairs ``(x[i], x[i + d/2])`` of the
-    last axis (rotate-half), angle ``pos * theta**(-2i/d)``. x: (batch, seq,
-    heads, d); computed in float32."""
+    last axis (rotate-half), angle ``pos * theta**(-2i/d)`` with ``pos`` the
+    index in the sequence or ``positions`` (seq,). x: (batch, seq, heads,
+    d); computed in float32."""
     d = x.shape[-1]
-    cos, sin = _rope_angles(x, theta)
+    cos, sin = _rope_angles(x, theta, positions)
     x32 = x.astype(jnp.float32)
     lo, hi = x32[..., :d // 2], x32[..., d // 2:]
     return jnp.concatenate([lo * cos - hi * sin, hi * cos + lo * sin],
@@ -218,7 +248,10 @@ class GQAttention(nn.Module):
     ``window`` makes the layer a sliding one (RoPE, the causal window);
     without it the layer is global: causal, no position encoding. k and v go
     to the flash kernel with their own ``num_kv_heads``. ``sm_scale`` is the
-    softmax's scale of the scores (by default ``1 / sqrt(head_dim)``)."""
+    softmax's scale of the scores (by default ``1 / sqrt(head_dim)``).
+    ``block_diffusion`` B reads the sequence as ``[x_t | x_0]``, two copies
+    of a sequence: RoPE at positions ``t mod (seq / 2)`` and the
+    block-diffusion mask for blocks of B (under ``attn.bd``)."""
     num_heads: int
     num_kv_heads: int
     head_dim: int
@@ -228,6 +261,7 @@ class GQAttention(nn.Module):
     gated: bool = True
     qk_norm: bool = True
     sm_scale: Optional[float] = None
+    block_diffusion: int = 0
     dtype: Any = jnp.bfloat16
 
     @nn.compact
@@ -243,13 +277,21 @@ class GQAttention(nn.Module):
                 gate = _dense(h * d, self.dtype, "gate_proj")(x)
             if self.qk_norm:
                 q, k = norm(name="q_norm")(q), norm(name="k_norm")(k)
-            if self.window is not None:
-                q, k = (rope_half(t, self.rope_theta) for t in (q, k))
-            with jax.named_scope("attn.global" if self.window is None
-                                 else "attn.window"):
-                out = flash_attention(q, k, v, causal=True,
-                                      window=self.window,
-                                      sm_scale=self.sm_scale)
+            if self.block_diffusion:
+                pos = jnp.arange(s) % (s // 2)
+                q, k = (rope_half(t, self.rope_theta, pos) for t in (q, k))
+                with jax.named_scope("attn.bd"):
+                    out = flash_attention(q, k, v, sm_scale=self.sm_scale,
+                                          mask=BLOCK_DIFFUSION,
+                                          block=self.block_diffusion)
+            else:
+                if self.window is not None:
+                    q, k = (rope_half(t, self.rope_theta) for t in (q, k))
+                with jax.named_scope("attn.global" if self.window is None
+                                     else "attn.window"):
+                    out = flash_attention(q, k, v, causal=True,
+                                          window=self.window,
+                                          sm_scale=self.sm_scale)
             out = out.reshape(b, s, h * d)
             if self.gated:
                 out = out * jax.nn.sigmoid(gate)
@@ -365,7 +407,10 @@ class SparseExperts(nn.Module):
     projection down and one up (the router and the shared expert read the
     hidden rows); ``gated`` off makes every expert, the shared one too,
     ``act(x W_up) W_down``; ``shared_width`` is the shared expert's own
-    (else ``moe_intermediate_size * n_shared_experts``)."""
+    (else ``moe_intermediate_size * n_shared_experts``). ``scoring``
+    ``"softmax"`` routes by :func:`route_softmax_topk` (no correction bias,
+    no ``router_state`` leaf) where ``"sigmoid"`` routes by
+    :func:`route_noaux_tc`."""
     n_routed_experts: int
     experts_held: int
     first_expert: int
@@ -379,6 +424,7 @@ class SparseExperts(nn.Module):
     activation: str = "silu"
     gated: bool = True
     shared_width: int = 0
+    scoring: str = "sigmoid"
 
     @nn.compact
     def __call__(self, x):
@@ -388,17 +434,23 @@ class SparseExperts(nn.Module):
         d = self.latent_size or hidden       # the routed experts' rows
         flat = x.reshape(b * s, hidden)
         router = self.param("gate", nn.initializers.normal(0.02), (hidden, e))
-        bias = self.variable(ROUTER_STATE, "e_score_correction_bias",
-                             jnp.zeros, (e,), jnp.float32)
+        bias = None if self.scoring == "softmax" else self.variable(
+            ROUTER_STATE, "e_score_correction_bias", jnp.zeros, (e,),
+            jnp.float32)
         init = nn.initializers.normal(0.02)
         w_gate = self.param("experts_gate_proj", init, (held, d, f)) \
             if self.gated else None
         w_up = self.param("experts_up_proj", init, (held, d, f))
         w_down = self.param("experts_down_proj", init, (held, f, d))
         with jax.named_scope("moe.router"):
-            idx, gates = route_noaux_tc(
-                flat, router, bias.value, top_k=self.num_experts_per_tok,
-                scaling=self.routed_scaling_factor)
+            if bias is None:
+                idx, gates = route_softmax_topk(
+                    flat, router, top_k=self.num_experts_per_tok)
+            else:
+                idx, gates = route_noaux_tc(
+                    flat, router, bias.value,
+                    top_k=self.num_experts_per_tok,
+                    scaling=self.routed_scaling_factor)
         rows = flat
         if self.latent_size:
             with jax.named_scope("moe.latent"):
@@ -428,8 +480,8 @@ class SparseExperts(nn.Module):
         return y
 
     def _after_step(self, bias, idx, counters):
-        """What a training step leaves behind: the bias moved against the
-        step's load, the layer's counters (``load``: the step's
+        """What a training step leaves behind: the bias (if any) moved
+        against the step's load, the layer's counters (``load``: the step's
         token-choices for each of ALL experts). Outside training both
         collections are read-only and nothing is written."""
         stats = {
@@ -450,7 +502,7 @@ class SparseExperts(nn.Module):
             return
         with jax.named_scope("moe.router"):
             load = expert_load(idx, self.n_routed_experts)
-            if self.is_mutable_collection(ROUTER_STATE):
+            if bias is not None and self.is_mutable_collection(ROUTER_STATE):
                 bias.value = noaux_bias_update(bias.value, load,
                                                self.bias_update_rate)
         if self.is_mutable_collection(MOE_STATS):
@@ -559,7 +611,10 @@ class DecoderLM(nn.Module):
     in float32. ``logits[:, t]`` scores ``tok_{t+1}``; ``mtp_logits[:, t]``
     scores ``tok_{t+2}`` (its last position, whose input token lies beyond
     the sequence, is fed token 0 and left out of the loss). With no MTP
-    module ``mtp_logits`` is None."""
+    module ``mtp_logits`` is None. Trained by block diffusion
+    (``diffusion_block``) it returns ``(logits, m / p)`` instead: the noisy
+    copy's logits, which score each position's own token, and the loss's
+    weight of each position (:func:`block_diffusion_loss`)."""
     vocab_size: int
     hidden_size: int
     num_hidden_layers: int
@@ -588,23 +643,42 @@ class DecoderLM(nn.Module):
     residual_scale: float = 1.0
     logits_scale: float = 1.0
     tie_word_embeddings: bool = False
+    # the sdar_moe family's: trained as a block-diffusion LM over blocks of
+    # this many tokens (0: next-token prediction), the noise's floor and
+    # seed (the ids' embedding has one more row, the MASK token's)
+    diffusion_block: int = 0
+    noise_eps: float = 1e-3
+    noise_seed: int = 0
 
     @classmethod
     def from_config(cls, cfg: Dict[str, Any], **overrides) -> "DecoderLM":
-        """From a ``config.json`` of the family. Beside the published keys:
-        ``experts_held`` / ``first_expert`` (this rank's share of each
-        layer's ``n_routed_experts``; by default all of them),
+        """From a ``config.json`` of one of the five families, chosen by its
+        ``model_type`` (``MODEL_TYPES``); a config that names none is read
+        by its keys (``hybrid_override_pattern``: ``nemotron_h``;
+        ``layer_types``: ``afmoe``; else the DeepSeek-V3 family). Beside the
+        published keys: ``experts_held`` / ``first_expert`` (this rank's
+        share of each layer's ``n_routed_experts``; by default all of them),
         ``bias_update_rate`` (gamma), ``mtp_loss_weight`` (lambda),
         ``compute_dtype``; for the ``nemotron_h`` family also
         ``mixer_parallel_size`` / ``mixer_parallel_rank`` (this rank's share
         of each Mamba-2 mixer's and attention layer's heads; by default the
-        whole layer)."""
-        if cfg.get("model_type") == "granitemoehybrid":
-            return cls(**dict(_granite_fields(cfg), **overrides))
-        if "hybrid_override_pattern" in cfg:
-            return cls(**dict(_nemotron_h_fields(cfg), **overrides))
-        if "layer_types" in cfg:
-            return cls(**dict(_afmoe_fields(cfg), **overrides))
+        whole layer); for ``sdar_moe`` ``block_length``, ``noise_eps`` and
+        ``noise_seed``."""
+        family = cfg.get("model_type")
+        if family is None:
+            family = "nemotron_h" if "hybrid_override_pattern" in cfg else \
+                "afmoe" if "layer_types" in cfg else "deepseek_v3"
+        for name, types in MODEL_TYPES.items():
+            if family in types:
+                break
+        else:
+            raise ValueError(
+                f"model_type {family!r} is none of DecoderLM's five "
+                f"families: " + "; ".join(
+                    f"{name} ({', '.join(types)})"
+                    for name, types in MODEL_TYPES.items()))
+        if name != "deepseek_v3":
+            return cls(**dict(_FIELDS[name](cfg), **overrides))
         if cfg.get("scoring_func", "sigmoid") != "sigmoid" or \
                 cfg.get("topk_method", "noaux_tc") != "noaux_tc" or \
                 cfg.get("n_group", 1) != 1 or not cfg.get("norm_topk_prob",
@@ -652,8 +726,37 @@ class DecoderLM(nn.Module):
 
     def loss(self) -> Callable:
         """The loss to hand the estimator beside this module."""
+        if self.diffusion_block:
+            return block_diffusion_loss
         return functools.partial(next_token_loss,
                                  mtp_weight=self.mtp_loss_weight)
+
+    def _diffusion_input(self, ids):
+        """``([x_t | x_0] (batch, 2L), m / p (batch, L) float32)``: the noise
+        drawn with the key ``fold_in(PRNGKey(seed), n)``, n the training
+        steps this module has taken; both are its ``noise_state`` (the seed
+        set from ``noise_seed`` at init, so that it is the step's data and
+        not a constant of its program: every seed runs one compiled step),
+        and a training step moves n on. Masked positions take the MASK row,
+        ``vocab_size``. The engine's eager init draws none."""
+        steps = self.variable(NOISE_STATE, "steps", jnp.zeros, (), jnp.int32)
+        seed = self.variable(NOISE_STATE, "seed", jnp.asarray,
+                             self.noise_seed, jnp.int32)
+        if self.is_initializing():
+            masked = jnp.zeros(ids.shape, bool)
+            p = jnp.ones(ids.shape, jnp.float32)
+        else:
+            with jax.named_scope("bd.noise"):
+                key = jax.random.fold_in(jax.random.PRNGKey(seed.value),
+                                         steps.value)
+                masked, p = block_diffusion_noise(
+                    key, ids.shape[0], ids.shape[1], self.diffusion_block,
+                    self.noise_eps)
+                if self.is_mutable_collection(NOISE_STATE):
+                    steps.value += 1
+        noisy = jnp.where(masked, self.vocab_size, ids)
+        return jnp.concatenate([noisy, ids], axis=1), \
+            masked.astype(jnp.float32) / p
 
     def _mixer_block(self, kind: str, name: str):
         """A rematerialised one-mixer block (the same policy object as
@@ -701,8 +804,12 @@ class DecoderLM(nn.Module):
             # no parameter's shape depends on the sequence's length: the
             # engine's eager init runs on a prefix, not on 8192 positions
             ids = ids[:, :INIT_POSITIONS]
+        length = ids.shape[1]
+        if self.diffusion_block:
+            ids, weight = self._diffusion_input(ids)
         norm = functools.partial(RMSNorm, self.rms_norm_eps, self.dtype)
-        embed = nn.Embed(self.vocab_size, self.hidden_size,
+        embed = nn.Embed(self.vocab_size + bool(self.diffusion_block),
+                         self.hidden_size,
                          dtype=self.dtype, param_dtype=jnp.float32,
                          embedding_init=nn.initializers.normal(0.02),
                          name="embed_tokens")
@@ -736,6 +843,9 @@ class DecoderLM(nn.Module):
             else:
                 x = self._block(i >= self.first_k_dense_replace,
                                 f"layers_{i}", i)(x)
+        if self.diffusion_block:
+            # the noisy half's logits; the loss's weights beside them
+            return logits_of(norm(name="norm")(x[:, :length])), weight
         logits = logits_of(norm(name="norm")(x))
         if not self.num_nextn_predict_layers:
             return logits, None
@@ -950,6 +1060,101 @@ def _granite_fields(cfg: Dict[str, Any]) -> Dict[str, Any]:
         tie_word_embeddings=bool(cfg.get("tie_word_embeddings", False)),
         rms_norm_eps=eps,
         dtype=jnp.dtype(cfg.get("compute_dtype", "bfloat16")))
+
+
+def _sdar_fields(cfg: Dict[str, Any]) -> Dict[str, Any]:
+    """``DecoderLM``'s fields from the ``sdar_moe`` family's ``config.json``
+    keys (``num_experts``, ``num_key_value_heads``, ``head_dim``,
+    ``norm_topk_prob``, ``decoder_sparse_step``, ``mlp_only_layers``, ...)
+    plus this repo's ``experts_held`` / ``first_expert`` /
+    ``compute_dtype`` and the block-diffusion training's ``block_length``
+    (the config gives none: SDAR's default, 4), ``noise_eps`` and
+    ``noise_seed``."""
+    if int(cfg.get("decoder_sparse_step", 1)) != 1 or \
+            cfg.get("mlp_only_layers"):
+        raise ValueError("DecoderLM builds every sdar_moe block with the "
+                         "expert layer (decoder_sparse_step 1, no "
+                         "mlp_only_layers)")
+    if not cfg.get("norm_topk_prob", True) or \
+            cfg.get("hidden_act", "silu") != "silu":
+        raise ValueError("DecoderLM routes this family by softmax with the "
+                         "chosen probabilities renormalised and SiLU experts")
+    if cfg.get("rope_scaling") or cfg.get("use_sliding_window") or \
+            cfg.get("tie_word_embeddings") or cfg.get("attention_bias"):
+        raise ValueError("DecoderLM applies RoPE with no scaling and no "
+                         "window, keeps an untied head and no bias")
+    from flax.core import FrozenDict
+    hidden, heads = int(cfg["hidden_size"]), int(cfg["num_attention_heads"])
+    e = int(cfg["num_experts"])
+    block = int(cfg.get("block_length", 4))
+    if block < 1 or block & (block - 1):
+        raise ValueError(f"block_length {block} is no power of two")
+    eps = float(cfg.get("rms_norm_eps", 1e-6))
+    return dict(
+        vocab_size=int(cfg["vocab_size"]), hidden_size=hidden,
+        num_hidden_layers=int(cfg["num_hidden_layers"]),
+        first_k_dense_replace=0,
+        intermediate_size=int(cfg.get("intermediate_size", 0)),
+        attention=FrozenDict(
+            kind="gqa", num_heads=heads,
+            num_kv_heads=int(cfg.get("num_key_value_heads", heads)),
+            head_dim=int(cfg.get("head_dim", hidden // heads)),
+            rope_theta=float(cfg["rope_theta"]), gated=False, qk_norm=True,
+            block_diffusion=block),
+        experts=FrozenDict(
+            n_routed_experts=e,
+            experts_held=int(cfg.get("experts_held", e)),
+            first_expert=int(cfg.get("first_expert", 0)),
+            num_experts_per_tok=int(cfg["num_experts_per_tok"]),
+            moe_intermediate_size=int(cfg["moe_intermediate_size"]),
+            n_shared_experts=0, routed_scaling_factor=1.0,
+            bias_update_rate=0.0, scoring="softmax"),
+        rms_norm_eps=eps,
+        dtype=jnp.dtype(cfg.get("compute_dtype", "bfloat16")),
+        diffusion_block=block,
+        noise_eps=float(cfg.get("noise_eps", 1e-3)),
+        noise_seed=int(cfg.get("noise_seed", 0)))
+
+
+# the five families by the model_type names each is published under, and
+# the readers of their keys (the DeepSeek-V3 family's are from_config's own)
+MODEL_TYPES = {
+    "deepseek_v3": ("deepseek_v3", "joyai_llm_flash"),
+    "afmoe": ("afmoe",),
+    "nemotron_h": ("nemotron_h",),
+    "granitemoehybrid": ("granitemoehybrid",),
+    "sdar_moe": ("sdar_moe",),
+}
+_FIELDS = {"afmoe": _afmoe_fields, "nemotron_h": _nemotron_h_fields,
+           "granitemoehybrid": _granite_fields, "sdar_moe": _sdar_fields}
+
+
+def block_diffusion_noise(key, batch: int, length: int, block: int,
+                          eps: float = 1e-3):
+    """The block-diffusion noise of a batch: ``(masked (batch, length) bool,
+    p (batch, length) float32)``. Each block of ``block`` positions draws
+    ``t ~ U(0, 1)`` and masks each of its positions with ``p = (1 - eps) t +
+    eps`` (LLaDA's schedule), independently: ``jax.random.split(key)``'s
+    first key draws the (batch, length / block) ``t``, its second the
+    (batch, length) uniforms compared with ``p``."""
+    k_t, k_m = jax.random.split(key)
+    t = jax.random.uniform(k_t, (batch, length // block), jnp.float32)
+    p = jnp.repeat((1.0 - eps) * t + eps, block, axis=1)
+    return jax.random.uniform(k_m, (batch, length), jnp.float32) < p, p
+
+
+def block_diffusion_loss(ids, preds):
+    """``(1/L) sum_i w_i CE(logits_i, ids_i)`` for each sequence of L ids
+    (the engine takes the mean over the batch), ``preds`` the model's
+    ``(logits (batch, L, vocab), w = m / p (batch, L))``: a masked
+    position's logits score its own token, no shift; the MASK token is
+    never a label."""
+    logits, weight = preds
+    with jax.named_scope("bd.loss"):
+        ids = ids.astype(jnp.int32)
+        logp = jax.nn.log_softmax(logits.astype(jnp.float32), axis=-1)
+        nll = -jnp.take_along_axis(logp, ids[..., None], axis=-1)[..., 0]
+        return jnp.sum(nll * weight, axis=-1) / ids.shape[1]
 
 
 def _shifted_nll(logits, ids, shift: int):

@@ -738,3 +738,172 @@ def test_grouped_windowed_flash_lowers_to_mosaic_for_tpu(monkeypatch, budget,
     assert grad.count("tpu_custom_call") == calls
     assert "tensor<8x2048x128xbf16>" in grad          # q, by (b*h, s, d)
     assert "tensor<2x2048x128xbf16>" in grad          # k and v as given
+
+
+# --- the block-diffusion mask --------------------------------------------------
+
+def test_the_block_diffusion_mask_is_its_four_quadrants():
+    """Over ``[x_t | x_0]`` of two sequences of 8, blocks of 4: a noisy
+    query sees its own noisy block both ways and the clean blocks before
+    it, a clean query the clean blocks up to its own, never a noisy key."""
+    from analytics_zoo_tpu.ops.attention import block_diffusion_mask
+    m = block_diffusion_mask(16, 4).astype(int)
+    own = np.kron(np.eye(2, dtype=int), np.ones((4, 4), int))
+    earlier = np.kron(np.tril(np.ones((2, 2), int), -1), np.ones((4, 4), int))
+    upto = np.kron(np.tril(np.ones((2, 2), int)), np.ones((4, 4), int))
+    np.testing.assert_array_equal(m[:8, :8], own)
+    np.testing.assert_array_equal(m[:8, 8:], earlier)
+    np.testing.assert_array_equal(m[8:, 8:], upto)
+    assert not m[8:, :8].any()
+    assert m.sum() == 8 * 8 + 8 * 4                     # L^2 + L B
+
+
+@pytest.mark.parametrize("s,h,h_kv,block,tile", [
+    (64, 4, 2, 4, 16),          # grouped, blocks of 4 in tiles of 16
+    (128, 8, 2, 16, 16),        # a block as wide as a tile
+    (128, 4, 4, 4, 32),         # equal heads
+    (128, 8, 1, 16, 64),        # one kv head; q tiles a half wide
+])
+def test_block_diffusion_flash_matches_reference(monkeypatch, s, h, h_kv,
+                                                 block, tile):
+    """Forward and gradients against ``mha_reference`` given the same mask
+    (which writes it out), through each backward the shape can take: the
+    fused launch, a query head at a time (where the group holds several),
+    and the dQ + dK/dV pair, which agree to the bit."""
+    import analytics_zoo_tpu.ops.attention as attn
+    q, k, v, w = _gqa_qkv(s, s, h, h_kv)
+    kw = dict(mask="block_diffusion", block=block)
+
+    def loss(fn, **more):
+        return lambda q, k, v: jnp.sum(fn(q, k, v, **kw, **more) * w)
+
+    tiles = dict(block_q=tile, block_k=tile)
+    out = flash_attention(q, k, v, **kw, **tiles)
+    np.testing.assert_allclose(
+        np.asarray(out), np.asarray(mha_reference(q, k, v, **kw)),
+        rtol=2e-5, atol=2e-6)
+    want = jax.grad(loss(mha_reference), (0, 1, 2))(q, k, v)
+
+    def flash(budget, path):
+        monkeypatch.setattr(attn, "_FUSED_BWD_DQ_BYTES", budget)
+        before = _path_counts()
+        grad = jax.grad(loss(flash_attention, **tiles), (0, 1, 2))
+        assert pallas_kernels(jax.make_jaxpr(grad)(q, k, v).jaxpr) == \
+            ["_flash_kernel"] + _BACKWARD_KERNELS[path]
+        assert _path_counts()[path] == before[path] + 1
+        return grad(q, k, v)
+
+    one_head = attn._fused_bwd_dq_bytes(s, 16, q.dtype)
+    sides = [flash(2 ** 40, "fused"), flash(0, "two_kernel")]
+    if h // h_kv > 2:
+        sides.append(flash(3 * one_head, "fused_by_head"))
+    for got in sides:
+        for a, b, c in zip(got, sides[-1], want):
+            assert a.shape == c.shape and bool(jnp.all(a == b))
+            np.testing.assert_allclose(np.asarray(a), np.asarray(c),
+                                       rtol=1e-4, atol=1e-5)
+
+
+@pytest.mark.parametrize("s,bq,bk,block", [
+    (64, 16, 16, 4), (128, 32, 16, 4), (128, 16, 32, 8), (256, 64, 64, 16),
+    (256, 128, 32, 4), (16384, 1024, 1024, 4), (16384, 512, 512, 4)])
+def test_a_block_diffusion_grid_walks_exactly_the_tiles_with_an_entry(
+        s, bq, bk, block):
+    """Every tile a walk names holds an entry of the mask and every tile
+    that holds one is walked, once, by q tile (the k-innermost grids) and by
+    k tile (the q-innermost ones); ``needed`` is L^2 + L B a head. At the
+    cell's shape the forward's 1024-wide tiles walk 80 of 256, the
+    backward's 512-wide ones 288 of 1024: 1.25 and 1.125 times the entries
+    the mask keeps."""
+    import analytics_zoo_tpu.ops.attention as attn
+    half = s // 2
+    m = attn.block_diffusion_mask(s, block)
+    holds = m.reshape(s // bq, bq, s // bk, bk).any(axis=(1, 3))
+    by_q = np.zeros_like(holds)
+    for qi in range(s // bq):
+        for step in range(s // bk):
+            ki, active = attn._bd_k_block(qi, step, bq, bk, half, block)
+            if bool(active):
+                assert not by_q[qi, int(ki)]
+                by_q[qi, int(ki)] = True
+    by_k = np.zeros_like(holds)
+    for ki in range(s // bk):
+        for step in range(s // bq):
+            qi, active = attn._bd_q_block(ki, step, bq, bk, half, block)
+            if bool(active):
+                assert not by_k[int(qi), ki]
+                by_k[int(qi), ki] = True
+    np.testing.assert_array_equal(by_q, holds)
+    np.testing.assert_array_equal(by_k, holds)
+    tiles = attn._bd_tiles(s, bq, bk, half, block)
+    assert tiles["by_k"] == tiles["by_q"] == int(holds.sum())
+    assert tiles["needed"] == int(m.sum()) == half * half + half * block
+    if s == 16384:
+        assert int(holds.sum()) == (80 if bq == 1024 else 288)
+        assert tiles["by_k"] * bq * bk / tiles["needed"] == pytest.approx(
+            1.25 if bq == 1024 else 1.125, abs=0.001)
+
+
+def test_block_diffusion_entries_are_counted_and_bad_calls_refused():
+    """A call site counts the entries its forward and backward grids compute
+    and the entries the mask keeps; the mask takes no causal flag or window,
+    two equal halves, and a block that divides them."""
+    import analytics_zoo_tpu.ops.attention as attn
+    q, k, v, w = _gqa_qkv(64, 64, 4, 2)
+    before = (attn._BD_COMPUTED.value, attn._BD_NEEDED.value)
+    jax.make_jaxpr(jax.grad(lambda q, k, v: jnp.sum(flash_attention(
+        q, k, v, mask="block_diffusion", block=4, block_q=16,
+        block_k=16) * w), (0, 1, 2)))(q, k, v)
+    tiles = attn._bd_tiles(64, 16, 16, 32, 4)
+    assert attn._BD_NEEDED.value - before[1] == 2 * 4 * tiles["needed"]
+    assert attn._BD_COMPUTED.value - before[0] == \
+        4 * 256 * (tiles["by_k"] + tiles["by_q"])
+    for bad in (dict(causal=True), dict(causal=True, window=8),
+                dict(block=3), dict(block=None)):
+        with pytest.raises(ValueError, match="block-diffusion"):
+            flash_attention(q, k, v, **dict(dict(mask="block_diffusion",
+                                                 block=4), **bad))
+    with pytest.raises(ValueError, match="mask"):
+        flash_attention(q, k, v, mask="prefix_lm")
+
+
+def test_a_block_diffusion_block_off_the_tiles_takes_the_counted_reference(
+        monkeypatch):
+    """A block that is no power of two leaves the kernel for the reference,
+    which on a TPU is counted."""
+    import analytics_zoo_tpu.ops.attention as attn
+    q, k, v, _ = _gqa_qkv(48, 48, 2, 2)
+    monkeypatch.setattr(attn, "_interpret", lambda: False)
+    before = attn._REFERENCE_ON_TPU.value
+    out = flash_attention(q, k, v, mask="block_diffusion", block=6)
+    assert attn._REFERENCE_ON_TPU.value == before + 1
+    np.testing.assert_allclose(
+        np.asarray(out), np.asarray(mha_reference(
+            q, k, v, mask="block_diffusion", block=6)), rtol=1e-6)
+
+
+@pytest.mark.parametrize("budget,path,calls", [
+    (None, "fused", 2), (6 * 2 ** 20, "fused_by_head", 2),
+    (0, "two_kernel", 3)])
+def test_block_diffusion_flash_lowers_to_mosaic_for_tpu(monkeypatch, budget,
+                                                        path, calls):
+    """The block-diffusion walks through the Pallas -> Mosaic lowering, 8
+    query heads on 2 kv heads over 2 x 1024 positions in bfloat16: each of
+    the three backwards."""
+    import analytics_zoo_tpu.ops.attention as attn
+    monkeypatch.setattr(attn, "_interpret", lambda: False)
+    q = jax.ShapeDtypeStruct((1, 2048, 8, 128), jnp.bfloat16)
+    kv = jax.ShapeDtypeStruct((1, 2048, 2, 128), jnp.bfloat16)
+
+    def loss(q, k, v):
+        return flash_attention(q, k, v, mask="block_diffusion",
+                               block=4).astype(jnp.float32).sum()
+
+    if budget is not None:
+        monkeypatch.setattr(attn, "_FUSED_BWD_DQ_BYTES", budget)
+    before = _path_counts()
+    grad = jax.export.export(
+        jax.jit(jax.grad(loss, argnums=(0, 1, 2))),
+        platforms=["tpu"])(q, kv, kv).mlir_module()
+    assert _path_counts() == dict(before, **{path: before[path] + 1})
+    assert grad.count("tpu_custom_call") == calls

@@ -189,3 +189,29 @@ def test_moe_rejects_mismatched_router():
     with pytest.raises(ValueError, match="router_weights"):
         moe_apply(_expert_fn, stack_expert_params(experts), bad_router, x,
                   mesh=mesh)
+
+
+def test_softmax_topk_gates_are_the_renormalised_top_k_of_the_softmax():
+    """``route_softmax_topk``'s gates ``softmax(r[C])`` are ``softmax(r)[C] /
+    sum_C softmax(r)`` over the same choice, in value and in gradient."""
+    from analytics_zoo_tpu.parallel.expert_parallel import route_softmax_topk
+    rng = np.random.RandomState(4)
+    x = jnp.asarray(rng.randn(32, 16), jnp.float32)
+    w = jnp.asarray(rng.randn(16, 64) * 0.5, jnp.float32)
+    c = jnp.asarray(rng.randn(32, 8), jnp.float32)
+
+    def plain(w):
+        probs = jax.nn.softmax(jnp.dot(x, w, precision="highest"), axis=-1)
+        top, idx = jax.lax.top_k(probs, 8)
+        return idx, top / jnp.sum(top, -1, keepdims=True)
+
+    idx, gates = route_softmax_topk(x, w, top_k=8)
+    want_idx, want = plain(w)
+    np.testing.assert_array_equal(np.asarray(idx), np.asarray(want_idx))
+    np.testing.assert_allclose(np.asarray(gates), np.asarray(want),
+                               rtol=1e-5, atol=1e-7)
+    got_g = jax.grad(lambda w: jnp.sum(route_softmax_topk(
+        x, w, top_k=8)[1] * c))(w)
+    want_g = jax.grad(lambda w: jnp.sum(plain(w)[1] * c))(w)
+    np.testing.assert_allclose(np.asarray(got_g), np.asarray(want_g),
+                               rtol=1e-4, atol=1e-6)
