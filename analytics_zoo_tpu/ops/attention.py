@@ -68,6 +68,14 @@ _BD_COMPUTED = _BD_ENTRIES.labels(kind="computed")
 _BD_NEEDED = _BD_ENTRIES.labels(kind="needed")
 BLOCK_DIFFUSION = "block_diffusion"
 
+# how much of a masked call site's grids is steps that compute nothing
+_GRID_STEPS = _REGISTRY.counter(
+    "zoo_attention_grid_steps_total",
+    "grid steps of masked flash_attention call sites traced, forward and "
+    "backward kernels, by mask (causal, window, block_diffusion): issued "
+    "(the steps of the launches' grids) and computed (the steps whose tile "
+    "holds an entry of the mask)", labelnames=("mask", "kind"))
+
 NEG_INF = -1e30
 LOG2_E = 1.4426950408889634      # the flash kernel softmaxes in base 2
 # the forward kernel's output and logsumexp, as ``checkpoint_name`` marks
@@ -316,14 +324,14 @@ def _bd_k_walk(qi, block_q, block_k, half, block, xp=jnp):
 
 
 def _bd_k_block(qi, step, block_q, block_k, half, block):
-    """``(k block, active)`` of a k-innermost kernel's ``step`` under q tile
-    ``qi``: the walk's diagonal blocks, then its clean ones; the steps past
-    the walk's last name that block again and do nothing."""
-    first, n_diag, n_prefix = _bd_k_walk(qi, block_q, block_k, half, block)
-    last = n_diag + n_prefix - 1
-    j = jnp.minimum(step, last)
-    return jnp.where(j < n_diag, first + j,
-                     half // block_k + j - n_diag), step <= last
+    """``(k block, active)`` of ``step`` of q tile ``qi``'s walk (numpy):
+    the walk's diagonal blocks, then its clean ones; a step past its last is
+    inactive."""
+    first, n_diag, n_prefix = _bd_k_walk(qi, block_q, block_k, half, block,
+                                         np)
+    return np.where(step < n_diag, first + step,
+                    half // block_k + step - n_diag), \
+        step < n_diag + n_prefix
 
 
 def _bd_q_walk(ki, block_q, block_k, half, block, xp=jnp):
@@ -344,22 +352,20 @@ def _bd_q_walk(ki, block_q, block_k, half, block, xp=jnp):
 
 
 def _bd_q_block(ki, step, block_q, block_k, half, block):
-    """``(q tile, active)`` of a q-innermost kernel's ``step`` under k block
-    ``ki``, as :func:`_bd_k_block` for k."""
+    """``(q tile, active)`` of ``step`` of k block ``ki``'s walk, as
+    :func:`_bd_k_block` for k."""
     first, n_noisy, first_clean, n_clean = _bd_q_walk(ki, block_q, block_k,
-                                                      half, block)
-    last = n_noisy + n_clean - 1
-    j = jnp.minimum(step, last)
-    return jnp.where(j < n_noisy, first + j,
-                     first_clean + j - n_noisy), step <= last
+                                                      half, block, np)
+    return np.where(step < n_noisy, first + step,
+                    first_clean + step - n_noisy), step < n_noisy + n_clean
 
 
 def _bd_tiles(seq, block_q, block_k, half, block):
-    """Static counts of a block-diffusion call's grids: ``k_steps`` /
-    ``q_steps`` (the longest walk by k and by q: the grids' innermost
-    extent), ``by_k`` / ``by_q`` (tiles a head's k-innermost / q-innermost
-    grid computes) and ``needed`` (the score entries the mask keeps: L^2 +
-    L * block a head, L = half)."""
+    """Static counts of a block-diffusion call's walks: ``k_steps`` /
+    ``q_steps`` (the longest walk by k and by q), ``by_k`` / ``by_q``
+    (tiles a head's walks by q tile / by k block compute: a tile list's
+    entries, ``_bd_walk``) and ``needed`` (the score entries the mask keeps:
+    L^2 + L * block a head, L = half)."""
     qi, ki = np.arange(seq // block_q), np.arange(seq // block_k)
     _, n_diag, n_prefix = _bd_k_walk(qi, block_q, block_k, half, block, np)
     _, n_noisy, _, n_clean = _bd_q_walk(ki, block_q, block_k, half, block, np)
@@ -367,6 +373,116 @@ def _bd_tiles(seq, block_q, block_k, half, block):
     return {"k_steps": int(by_k.max()), "q_steps": int(by_q.max()),
             "by_k": int(by_k.sum()), "by_q": int(by_q.sum()),
             "needed": half * half + half * block}
+
+
+# a tile list's rows (``_bd_walk``), and the bits of its flags row
+_Q, _K, _HEAD, _FLAGS = range(4)
+_FIRST, _LAST, _DQ_LAST = 1, 2, 4
+
+
+def _bd_walk(seq, block_q, block_k, half, block, by, group=1):
+    """The tile list a block-diffusion grid walks, built at trace time: the
+    (q tile, k block) pairs that hold an entry of the mask, in the order
+    the kernels take them, so the grid's innermost extent is the list's
+    length and no step of it computes nothing. ``by="q"``: each q tile's k
+    blocks (:func:`_bd_k_block`), for the k-innermost kernels (the forward,
+    the dQ kernel); ``by="k"``: each k block's q tiles
+    (:func:`_bd_q_block`), for the q-innermost ones, the ``group`` query
+    heads of a kv head one after the other under each k block.
+
+    One flat int32 array of four rows, ``[q tile | k block | head | flags]``
+    (:func:`_walk_at`), the kernels' scalar-prefetch operand: ``_FIRST`` /
+    ``_LAST`` mark the first and last entry of a q tile's walk (``by="q"``)
+    or of a k block's over all the heads (``by="k"``); ``_DQ_LAST`` the
+    entries whose k block is the last of the q tile's own walk, after which
+    its dQ rows are whole."""
+    t = _bd_tiles(seq, block_q, block_k, half, block)
+    if by == "q":
+        run, step = np.meshgrid(np.arange(seq // block_q),
+                                np.arange(t["k_steps"]), indexing="ij")
+        k, active = _bd_k_block(run, step, block_q, block_k, half, block)
+        q = run
+    else:
+        run, step = np.meshgrid(np.arange(seq // block_k),
+                                np.arange(t["q_steps"]), indexing="ij")
+        q, active = _bd_q_block(run, step, block_q, block_k, half, block)
+        k = run
+    q, k, run = q[active], k[active], run[active]
+    # each run once a head of the group, in turn
+    runs = np.split(np.arange(run.size), np.flatnonzero(np.diff(run)) + 1)
+    at = np.concatenate([r for r in runs for _ in range(group)])
+    head = np.concatenate([np.full(r.size, hd) for r in runs
+                           for hd in range(group)])
+    q, k, run = q[at], k[at], run[at]
+    first = np.diff(run, prepend=-1) != 0
+    last = np.append(first[1:], True)
+    diag0, n_diag, n_prefix = _bd_k_walk(q, block_q, block_k, half, block, np)
+    last_k = np.where(n_prefix > 0, half // block_k + n_prefix - 1,
+                      diag0 + n_diag - 1)
+    flags = _FIRST * first + _LAST * last + _DQ_LAST * (k == last_k)
+    return np.concatenate([q, k, head, flags]).astype(np.int32)
+
+
+def _walk_at(walk, e, row):
+    """Row ``row`` (``_Q``, ``_K``, ``_HEAD``, ``_FLAGS``) of entry ``e`` of
+    a tile list (:func:`_bd_walk`), read from its scalar-prefetch ref in a
+    kernel body or an index map."""
+    return walk[row * (walk.shape[0] // 4) + e]
+
+
+def _walk_flag(walk, e, bit):
+    """Whether entry ``e`` of a tile list carries the flag ``bit``."""
+    return jnp.bitwise_and(_walk_at(walk, e, _FLAGS), bit) != 0
+
+
+def _split_walk(refs, bd):
+    """A kernel's tile-list ref and its other refs: with ``bd`` the list
+    is the launch's scalar-prefetch operand, the first of its refs."""
+    return (refs[0], refs[1:]) if bd is not None else (None, refs)
+
+
+def _launch(kernel, operands, walk=None, *, grid, in_specs, out_specs,
+            scratch_shapes, **call):
+    """``pallas_call`` of ``kernel`` over ``operands``. Given a tile list
+    ``walk`` (:func:`_bd_walk`) the grid walks it: the list is the first
+    operand, prefetched into scalar memory, where every index map (given it
+    after the grid's indices) and the kernel body (the first of its refs)
+    read it."""
+    import jax.experimental.pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+    specs = dict(grid=grid, in_specs=in_specs, out_specs=out_specs,
+                 scratch_shapes=scratch_shapes)
+    if walk is not None:
+        specs = dict(grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1, **specs))
+        operands = (mark_varying(jnp.asarray(walk),
+                                 varying_axes(*operands)), *operands)
+    return pl.pallas_call(kernel, **specs, **call)(*operands)
+
+
+def _tile_counts(s_q, s_k, block_q, block_k, causal, window, bd):
+    """``(mask, tiles)`` of a call at these tile sizes: the mask's name in
+    ``zoo_attention_grid_steps_total`` and its static counts
+    (:func:`_window_tiles`, :func:`_bd_tiles`; a causal mask's ``by_k`` /
+    ``by_q`` alone, the tiles on or below the diagonal); ``(None, None)``
+    for a call with no mask."""
+    if bd is not None:
+        return BLOCK_DIFFUSION, _bd_tiles(s_q, block_q, block_k, *bd)
+    if window is not None:
+        return "window", _window_tiles(s_q, s_k, block_q, block_k, window)
+    if not causal:
+        return None, None
+    last = (s_k - s_q + (np.arange(s_q // block_q) + 1) * block_q - 1) \
+        // block_k
+    n = int(np.sum(last + 1))
+    return "causal", {"by_k": n, "by_q": n}
+
+
+def _count_grid(mask, grid, computed):
+    """A launch of a masked call site: its grid's steps (issued) and the
+    steps whose tile computes (computed)."""
+    _GRID_STEPS.labels(mask=mask, kind="issued").inc(math.prod(grid))
+    _GRID_STEPS.labels(mask=mask, kind="computed").inc(computed)
 
 
 def _bd_keep(shape, q0, k0, lim, block, diag):
@@ -383,10 +499,11 @@ def _bd_keep(shape, q0, k0, lim, block, diag):
     return k < start + lim
 
 
-def _bd_compute(compute, active, q_start, k_start, block_q, block_k, bd):
-    """Run ``compute(masked)`` on an active tile of a block-diffusion grid:
-    with the diagonal's mask on a noisy key tile, with the prefix's where
-    the edge of the visible keys crosses a clean tile, unmasked inside it."""
+def _bd_compute(compute, q_start, k_start, block_k, bd):
+    """Run ``compute(masked)`` on a tile of a block-diffusion tile list
+    (every entry holds an entry of the mask): with the diagonal's mask on a
+    noisy key tile, with the prefix's where the edge of the visible keys
+    crosses a clean tile, unmasked inside it."""
     import jax.experimental.pallas as pl
     half, block = bd
     q_noisy, diag = q_start < half, k_start < half
@@ -398,18 +515,19 @@ def _bd_compute(compute, active, q_start, k_start, block_q, block_k, bd):
     def masked(on_diag):
         return lambda shape: _bd_keep(shape, q0, k0, lim, block, on_diag)
 
-    pl.when(active & diag)(lambda: compute(masked(True)))
-    pl.when(active & jnp.logical_not(diag) & jnp.logical_not(interior))(
+    pl.when(diag)(lambda: compute(masked(True)))
+    pl.when(jnp.logical_not(diag) & jnp.logical_not(interior))(
         lambda: compute(masked(False)))
-    pl.when(active & interior)(lambda: compute(False))
+    pl.when(interior)(lambda: compute(False))
 
 
-def _flash_kernel(q_ref, k_ref, v_ref, o_ref, *rest, block_q,
-                  block_k, num_k_blocks, causal, head_dim, q_offset=0,
-                  with_lse=False, ones_column=True, window=None, bd=None):
+def _flash_kernel(*refs, block_q, block_k, num_k_blocks, causal, head_dim,
+                  q_offset=0, with_lse=False, ones_column=True, window=None,
+                  bd=None):
     """Grid = (batch*heads, num_q_blocks, num_k_blocks); the k dim is innermost
     so (acc, m) scratch carries the online softmax across k iterations.
-    With ``with_lse`` the kernel also emits the log2-domain logsumexp
+    ``refs``: q, k, v, the output [, the logsumexp], the scratch. With
+    ``with_lse`` the kernel also emits the log2-domain logsumexp
     (m + log2 l) per q row, which the Pallas backward consumes.
 
     With a ``window`` the k dim walks a q tile's band alone:
@@ -420,8 +538,9 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, *rest, block_q,
     blocks are all outside its window carries m = NEG_INF and p = 1
     through them; its first block with a visible key rescales that to
     exactly 0. With ``bd`` (``(half, block)``: the block-diffusion mask) the
-    k dim walks the q tile's diagonal and clean blocks alone
-    (:func:`_bd_k_block`).
+    grid is (batch*heads, tiles) and step e is entry e of a tile list
+    (``_bd_walk(by="q")``, the first of ``refs``): each q tile's diagonal
+    and clean blocks in turn, every step a tile that computes.
 
     The softmax normaliser l = sum(p) comes one of two ways, by v's head
     size (``_flash_forward`` decides):
@@ -438,16 +557,23 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, *rest, block_q,
       as it is and l is a running float32 row sum in scratch beside m."""
     import jax.experimental.pallas as pl  # local import keeps module cpu-safe
 
+    walk, (q_ref, k_ref, v_ref, o_ref, *rest) = _split_walk(refs, bd)
     if with_lse:
         lse_ref, *rest = rest
     if ones_column:
         acc_ref, m_ref = rest
     else:
         acc_ref, m_ref, l_ref = rest
-    q_idx = pl.program_id(1)
-    k_idx = pl.program_id(2)
+    if bd is not None:
+        e = pl.program_id(1)
+        q_idx = _walk_at(walk, e, _Q)
+        first = _walk_flag(walk, e, _FIRST)
+    else:
+        q_idx = pl.program_id(1)
+        k_idx = pl.program_id(2)
+        first = k_idx == 0
 
-    @pl.when(k_idx == 0)
+    @pl.when(first)
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
         m_ref[...] = jnp.full_like(m_ref, NEG_INF)
@@ -456,8 +582,7 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, *rest, block_q,
 
     q_start = q_idx * block_q
     if bd is not None:
-        k_blk, active = _bd_k_block(q_idx, k_idx, block_q, block_k, *bd)
-        k_start = k_blk * block_k
+        k_start = _walk_at(walk, e, _K) * block_k
     else:
         k_start = _k_start(q_idx, k_idx, block_q, block_k, q_offset, window)
 
@@ -498,7 +623,7 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, *rest, block_q,
                                 preferred_element_type=jnp.float32))
 
     if bd is not None:
-        _bd_compute(_compute, active, q_start, k_start, block_q, block_k, bd)
+        _bd_compute(_compute, q_start, k_start, block_k, bd)
     elif causal:
         # Three tile classes: fully masked (skip), diagonal (mask), and
         # interior (q_pos >= k_pos everywhere — no mask work: the two
@@ -511,7 +636,8 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, *rest, block_q,
     else:
         _compute(False)
 
-    @pl.when(k_idx == num_k_blocks - 1)
+    @pl.when(_walk_flag(walk, e, _LAST) if bd is not None
+             else k_idx == num_k_blocks - 1)
     def _finalize():
         acc = acc_ref[...]
         l = acc[:, head_dim:head_dim + 1] if ones_column else l_ref[:, :1]
@@ -522,18 +648,19 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, *rest, block_q,
             lse_ref[0] = m_ref[:, :1] + jnp.log2(l)
 
 
-@functools.lru_cache(maxsize=1)
-def _mosaic_params():
+@functools.lru_cache(maxsize=None)
+def _mosaic_params(dims=3):
     """Grid dimension semantics of the forward, dQ and dK/dV kernels: dims
     0/1 (batch*heads and the non-carry sequence dim) are parallel, the
     innermost dim carries online-softmax / accumulator state and must stay
     ordered. Parallel dims let Mosaic overlap the next tile's DMA with the
     current tile's compute instead of treating the whole grid as one
     sequential loop. (The fused backward carries dQ across both sequence
-    dims, by head dK and dV across the group too, and states its own.)"""
+    dims, by head dK and dV across the group too, and states its own; a
+    tile list's grid has ``dims=2``: the list carries the state.)"""
     from jax.experimental.pallas import tpu as pltpu
     return pltpu.CompilerParams(
-        dimension_semantics=("parallel", "parallel", "arbitrary"))
+        dimension_semantics=("parallel",) * (dims - 1) + ("arbitrary",))
 
 
 def varying_axes(*arrays) -> frozenset:
@@ -591,20 +718,22 @@ def _flash_forward(q, k, v, causal, sm_scale, block_q, block_k, interpret,
     block_k = min(block_k, s_k)
     num_q = s_q // block_q
     num_k = s_k // block_k
+    mask, tiles = _tile_counts(s_q, s_k, block_q, block_k, causal, window,
+                               bd)
     if window is not None:
         # the k dim walks each q tile's band: its widest band's blocks
-        tiles = _window_tiles(s_q, s_k, block_q, block_k, window)
         num_k = tiles["k_steps"]
         _TILES_VISITED.inc(b * h * tiles["by_k"])
         _TILES_NEEDED.inc(b * h * tiles["needed"])
+    grid = (b * h, num_q, num_k)
     if bd is not None:
-        # the k dim walks each q tile's diagonal and clean blocks
-        tiles = _bd_tiles(s_q, block_q, block_k, *bd)
-        num_k = tiles["k_steps"]
+        # the grid walks a tile list: each q tile's diagonal and clean blocks
+        grid = (b * h, tiles["by_k"])
         _BD_COMPUTED.inc(b * h * tiles["by_k"] * block_q * block_k)
         _BD_NEEDED.inc(b * h * tiles["needed"])
+    if mask is not None:
+        _count_grid(mask, grid, b * h * tiles["by_k"])
 
-    grid = (b * h, num_q, num_k)
     kernel = functools.partial(
         _flash_kernel, block_q=block_q, block_k=block_k,
         num_k_blocks=num_k, causal=causal, head_dim=d_v,
@@ -616,18 +745,22 @@ def _flash_forward(q, k, v, causal, sm_scale, block_q, block_k, interpret,
     # call sites (e.g. cross-attention with replicated q) still compile.
     vma = varying_axes(qf, kf, vf)
     qf, kf, vf = (mark_varying(a, vma) for a in (qf, kf, vf))
+    if bd is not None:
+        # an index map is given the tile list after the grid's indices
+        def q_index(bh, e, walk):
+            return (bh, _walk_at(walk, e, _Q), 0)
+    else:
+        def q_index(bh, qi, ki):
+            return (bh, qi, 0)
     out_shape = [jax.ShapeDtypeStruct((b * h, s_q, d_v), q.dtype, vma=vma)]
-    out_specs = [pl.BlockSpec((1, block_q, d_v),
-                              lambda bh, qi, ki: (bh, qi, 0))]
+    out_specs = [pl.BlockSpec((1, block_q, d_v), q_index)]
     if with_lse:
         out_shape.append(
             jax.ShapeDtypeStruct((b * h, s_q, 1), jnp.float32, vma=vma))
-        out_specs.append(pl.BlockSpec((1, block_q, 1),
-                                      lambda bh, qi, ki: (bh, qi, 0)))
+        out_specs.append(pl.BlockSpec((1, block_q, 1), q_index))
     if bd is not None:
-        def k_index(bh, qi, ki):
-            return (kv_row(bh), _bd_k_block(qi, ki, block_q, block_k,
-                                            *bd)[0], 0)
+        def k_index(bh, e, walk):
+            return (kv_row(bh), _walk_at(walk, e, _K), 0)
     elif causal:
         # fully-masked steps (k block entirely above the diagonal) skip
         # compute via pl.when; re-referencing the last ACTIVE k block
@@ -651,11 +784,12 @@ def _flash_forward(q, k, v, causal, sm_scale, block_q, block_k, interpret,
         def k_index(bh, qi, ki):
             return (kv_row(bh), ki, 0)
 
-    res = pl.pallas_call(
-        kernel,
+    res = _launch(
+        kernel, (qf, kf, vf),
+        None if bd is None else _bd_walk(s_q, block_q, block_k, *bd, by="q"),
         grid=grid,
         in_specs=[
-            pl.BlockSpec((1, block_q, d), lambda bh, qi, ki: (bh, qi, 0)),
+            pl.BlockSpec((1, block_q, d), q_index),
             pl.BlockSpec((1, block_k, d), k_index),
             pl.BlockSpec((1, block_k, d_acc), k_index),
         ],
@@ -670,9 +804,9 @@ def _flash_forward(q, k, v, causal, sm_scale, block_q, block_k, interpret,
         # parallel lets Mosaic double-buffer the next tile's DMA behind
         # this tile's compute; only the k dim (online-softmax carry) is
         # order-dependent
-        compiler_params=None if interpret else _mosaic_params(),
+        compiler_params=None if interpret else _mosaic_params(len(grid)),
         interpret=interpret,
-    )(qf, kf, vf)
+    )
     out = jnp.moveaxis(res[0].reshape(b, h, s_q, d_v), 1, 2)
     if with_lse:
         return out, res[1]
@@ -740,32 +874,38 @@ def _bwd_tile(masked, q2, k, v, g, L, D, q_offset, q_start, k_start, cd,
     return p.astype(cd), ds
 
 
-def _flash_bwd_dq_kernel(q2_ref, k_ref, v_ref, g_ref, L_ref, D_ref, dq_ref,
-                         acc_ref, *, sm_scale, block_q, block_k,
-                         num_k_blocks, causal, q_offset, cd, window=None,
-                         bd=None):
+def _flash_bwd_dq_kernel(*refs, sm_scale, block_q, block_k, num_k_blocks,
+                         causal, q_offset, cd, window=None, bd=None):
     """dQ pass: grid (batch*heads, num_q, num_k), k innermost; the dq tile
     accumulates across k iterations in VMEM scratch — no (S, S) tensor
     ever reaches HBM (the round-3 pure-JAX backward streamed every P/dS
     tile through HBM between the dot_generals, which bounded fwd+bwd at
     ~1.4x materialized; tiles resident in VMEM are the FA-2 design). Runs
     only where not even one query head's gradients fit the fused kernel's
-    VMEM budget (``_flash_bwd``). With a ``window`` the k dim walks the q
-    tile's band, with ``bd`` its block-diffusion walk, as in
+    VMEM budget (``_flash_bwd``). ``refs``: q2, k, v, g, L, D, dq, the
+    scratch. With a ``window`` the k dim walks the q tile's band; with
+    ``bd`` the grid walks a tile list (``_bd_walk(by="q")``), as in
     ``_flash_kernel``."""
     import jax.experimental.pallas as pl
 
-    q_idx = pl.program_id(1)
-    k_idx = pl.program_id(2)
+    walk, (q2_ref, k_ref, v_ref, g_ref, L_ref, D_ref, dq_ref,
+           acc_ref) = _split_walk(refs, bd)
+    if bd is not None:
+        e = pl.program_id(1)
+        q_idx = _walk_at(walk, e, _Q)
+        first = _walk_flag(walk, e, _FIRST)
+    else:
+        q_idx = pl.program_id(1)
+        k_idx = pl.program_id(2)
+        first = k_idx == 0
 
-    @pl.when(k_idx == 0)
+    @pl.when(first)
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
     q_start = q_idx * block_q
     if bd is not None:
-        k_blk, active = _bd_k_block(q_idx, k_idx, block_q, block_k, *bd)
-        k_start = k_blk * block_k
+        k_start = _walk_at(walk, e, _K) * block_k
     else:
         k_start = _k_start(q_idx, k_idx, block_q, block_k, q_offset, window)
 
@@ -777,7 +917,7 @@ def _flash_bwd_dq_kernel(q2_ref, k_ref, v_ref, g_ref, L_ref, D_ref, dq_ref,
                                 preferred_element_type=jnp.float32)
 
     if bd is not None:
-        _bd_compute(_compute, active, q_start, k_start, block_q, block_k, bd)
+        _bd_compute(_compute, q_start, k_start, block_k, bd)
     elif causal:
         active, diagonal = _tile_class(q_offset + q_start, k_start, block_q,
                                        block_k, window)
@@ -786,28 +926,31 @@ def _flash_bwd_dq_kernel(q2_ref, k_ref, v_ref, g_ref, L_ref, D_ref, dq_ref,
     else:
         _compute(False)
 
-    @pl.when(k_idx == num_k_blocks - 1)
+    @pl.when(_walk_flag(walk, e, _LAST) if bd is not None
+             else k_idx == num_k_blocks - 1)
     def _finalize():
         dq_ref[0] = (acc_ref[...] * sm_scale).astype(dq_ref.dtype)
 
 
-def _flash_bwd_dkv_kernel(q2_ref, k_ref, v_ref, g_ref, L_ref, D_ref,
-                          dk_ref, dv_ref, dk_acc, dv_acc, *, block_q,
-                          block_k, num_q_blocks, causal, q_offset, cd,
-                          dq=None, window=None, group=1, seq_q_blocks=None,
-                          by_head=False, bd=None):
+def _flash_bwd_dkv_kernel(*refs, block_q, block_k, num_q_blocks, causal,
+                          q_offset, cd, dq=None, window=None, group=1,
+                          seq_q_blocks=None, by_head=False, bd=None):
     """dK/dV pass: grid (batch*kv heads, num_k, group * num_q), the
     innermost dim walking the q tiles of each of the ``group`` query heads
     that share this kv head in turn, so dK and dV are summed over the
     group where they are made; both accumulators live in VMEM scratch.
+    ``refs``: q2, k, v, g, L, D, dk, dv, their accumulators.
     dv += P^T g and dk += dS^T q2 are expressed as dot_generals contracting
     the q (sublane) dim. q2 is the log2-prescaled q, so dk carries a
     1/log2(e) correction at finalize. With a ``window``, ``num_q_blocks``
     is the widest band's tiles and step j of a head is the tile
     ``_q_band(...)[0] + j``, the steps past the band's last tile padding;
-    ``seq_q_blocks`` is then the sequence's tiles. With ``bd`` step j of a
-    head is the j-th tile of the k block's block-diffusion walk
-    (:func:`_bd_q_block`), the steps past its last padding.
+    ``seq_q_blocks`` is then the sequence's tiles. With ``bd`` the grid is
+    (batch*kv heads, tiles) and step e is entry e of a tile list
+    (``_bd_walk(by="k", group=group)``, the first of ``refs``): each k
+    block's q tiles (:func:`_bd_q_block`), a query head's after another's;
+    by head (below) the list is one head's and the grid (batch*kv heads,
+    group, tiles).
 
     ``dq`` is the fused kernel's (see ``_flash_bwd_fused_kernel``): given,
     each tile's dS also goes into dQ. ``by_head`` is its grid for a group
@@ -819,15 +962,25 @@ def _flash_bwd_dkv_kernel(q2_ref, k_ref, v_ref, g_ref, L_ref, D_ref,
     output blocks are the kv head's whole sequence."""
     import jax.experimental.pallas as pl
 
+    walk, (q2_ref, k_ref, v_ref, g_ref, L_ref, D_ref, dk_ref, dv_ref,
+           dk_acc, dv_acc) = _split_walk(refs, bd)
     k_at, out_k_at = Ellipsis, 0      # all of dK's and dV's scratch and block
-    if by_head:
+    if bd is not None:
+        # a head's (by head) or the group's dQ begins with the list
+        e = dq_step = pl.program_id(2 if by_head else 1)
+        k_idx, q_idx = _walk_at(walk, e, _K), _walk_at(walk, e, _Q)
+        # the k block's first and last entry over the group
+        begins, ends = _walk_flag(walk, e, _FIRST), _walk_flag(walk, e, _LAST)
+        if by_head:
+            head = pl.program_id(1)
+            begins &= head == 0
+            ends &= head == group - 1
+        else:
+            head = _walk_at(walk, e, _HEAD) if group > 1 else 0
+    elif by_head:
         head, k_idx, q_idx = (pl.program_id(i) for i in (1, 2, 3))
         step = head * num_q_blocks + q_idx
         dq_step = q_idx                  # a head's dQ begins with the head
-        # of the whole kv sequence's, this k block's rows
-        k_at = (pl.ds(pl.multiple_of(k_idx * block_k, block_k), block_k),
-                slice(None))
-        out_k_at = (0, *k_at)
     else:
         k_idx, step = pl.program_id(1), pl.program_id(2)
         if group == 1:
@@ -835,8 +988,13 @@ def _flash_bwd_dkv_kernel(q2_ref, k_ref, v_ref, g_ref, L_ref, D_ref,
         else:
             head, q_idx = step // num_q_blocks, step % num_q_blocks
         dq_step = step                   # the group's with the kv head
+    if by_head:
+        # of the whole kv sequence's, this k block's rows
+        k_at = (pl.ds(pl.multiple_of(k_idx * block_k, block_k), block_k),
+                slice(None))
+        out_k_at = (0, *k_at)
 
-    @pl.when(step == 0)
+    @pl.when(begins if bd is not None else step == 0)
     def _init():
         dk_acc[k_at] = jnp.zeros((block_k, dk_acc.shape[-1]), dk_acc.dtype)
         dv_acc[k_at] = jnp.zeros((block_k, dv_acc.shape[-1]), dv_acc.dtype)
@@ -846,8 +1004,6 @@ def _flash_bwd_dkv_kernel(q2_ref, k_ref, v_ref, g_ref, L_ref, D_ref,
         first, last = _q_band(k_idx, block_q, block_k, q_offset, window,
                               seq_q_blocks)
         q_idx = first + q_idx
-    elif bd is not None:
-        q_idx, active = _bd_q_block(k_idx, q_idx, block_q, block_k, *bd)
     q_start = q_idx * block_q
     if dq is not None:
         dq_ref, dq_acc, sm_scale, num_k_blocks = dq
@@ -891,16 +1047,11 @@ def _flash_bwd_dkv_kernel(q2_ref, k_ref, v_ref, g_ref, L_ref, D_ref,
                                       preferred_element_type=jnp.float32)
 
     if bd is not None:
-        _bd_compute(_compute, active, q_start, k_start, block_q, block_k, bd)
+        _bd_compute(_compute, q_start, k_start, block_k, bd)
         if dq is not None:
             # a q tile's rows are whole once the last k block of its walk
             # has passed them
-            first, n_diag, n_prefix = _bd_k_walk(q_idx, block_q, block_k,
-                                                 *bd)
-            last_k = jnp.where(n_prefix > 0,
-                               bd[0] // block_k + n_prefix - 1,
-                               first + n_diag - 1)
-            pl.when(active & (k_idx == last_k))(_write_dq)
+            pl.when(_walk_flag(walk, e, _DQ_LAST))(_write_dq)
     elif causal:
         active, diagonal = _tile_class(q_offset + q_start, k_start, block_q,
                                        block_k, window)
@@ -916,7 +1067,7 @@ def _flash_bwd_dkv_kernel(q2_ref, k_ref, v_ref, g_ref, L_ref, D_ref,
     else:
         _compute(False)
 
-    @pl.when(step == group * num_q_blocks - 1)
+    @pl.when(ends if bd is not None else step == group * num_q_blocks - 1)
     def _finalize():
         dk_ref[out_k_at] = (dk_acc[k_at] *
                             (1.0 / LOG2_E)).astype(dk_ref.dtype)
@@ -929,24 +1080,24 @@ def _flash_bwd_dkv_kernel(q2_ref, k_ref, v_ref, g_ref, L_ref, D_ref,
         pl.when(k_idx == num_k_blocks - 1)(_write_dq)
 
 
-def _flash_bwd_fused_kernel(q2_ref, k_ref, v_ref, g_ref, L_ref, D_ref,
-                            dq_ref, dk_ref, dv_ref, dq_acc, dk_acc, dv_acc,
-                            *, sm_scale, num_k_blocks, **tiles):
+def _flash_bwd_fused_kernel(*refs, sm_scale, num_k_blocks, **tiles):
     """The whole backward in one launch: the dK/dV pass (same grid, q
     innermost) whose every tile also adds dS . k into a float32 accumulator
     for ALL the queries that share this (batch, kv head), (group, s_q, d)
     in VMEM scratch, at the tile's rows. Each (block_q, block_k) score
     tile, its exp2 and dP are rebuilt once a step, not once in each of two
-    kernels. ``dq_ref`` is the whole (group, s_q, d) output block, indexed
-    by batch*kv heads alone. With ``by_head`` (among ``tiles``) the grid
-    takes the group's query heads one after the other, the accumulator and
-    ``dq_ref`` are one head's (s_q, d), and dK and dV wait in VMEM for the
-    group's last head. Either way a fixed q tile's contributions arrive in
-    ascending k order, as in ``_flash_bwd_dq_kernel``, and a fixed k
-    block's in ``_flash_bwd_dkv_kernel``'s: the three gradients equal the
-    pair's to the bit."""
-    _flash_bwd_dkv_kernel(q2_ref, k_ref, v_ref, g_ref, L_ref, D_ref, dk_ref,
-                          dv_ref, dk_acc, dv_acc,
+    kernels. ``refs``: [the tile list,] q2, k, v, g, L, D, dq, dk, dv,
+    their accumulators. ``dq_ref`` is the whole (group, s_q, d) output
+    block, indexed by batch*kv heads alone. With ``by_head`` (among
+    ``tiles``) the grid takes the group's query heads one after the other,
+    the accumulator and ``dq_ref`` are one head's (s_q, d), and dK and dV
+    wait in VMEM for the group's last head. Either way a fixed q tile's
+    contributions arrive in ascending k order, as in
+    ``_flash_bwd_dq_kernel``, and a fixed k block's in
+    ``_flash_bwd_dkv_kernel``'s: the three gradients equal the pair's to
+    the bit."""
+    *ins, dq_ref, dk_ref, dv_ref, dq_acc, dk_acc, dv_acc = refs
+    _flash_bwd_dkv_kernel(*ins, dk_ref, dv_ref, dk_acc, dv_acc,
                           dq=(dq_ref, dq_acc, sm_scale, num_k_blocks),
                           **tiles)
 
@@ -1009,8 +1160,11 @@ def _flash_bwd(causal, sm_scale, block_q, block_k, window, bd, res, g):
     All three give the same bits and count themselves in
     ``zoo_attention_backward_total``. k and v keep their own head count
     throughout, and with a ``window`` every grid walks bands
-    (``_window_tiles``), with ``bd`` the block-diffusion walks
-    (``_bd_tiles``)."""
+    (``_window_tiles``). With ``bd`` every grid walks a tile list instead
+    (``_bd_walk``): ``fused`` (batch*kv heads, the group's tiles),
+    ``fused_by_head`` (batch*kv heads, group, a head's tiles), the pair's
+    dQ (batch*heads, tiles by q tile) and dK/dV (batch*kv heads, the
+    group's tiles by k block)."""
     import jax.experimental.pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
@@ -1025,14 +1179,16 @@ def _flash_bwd(causal, sm_scale, block_q, block_k, window, bd, res, g):
     nq, nk = s_q // bq, s_k // bk
     bh, bh_kv = b * h, b * h_kv
     cd = jnp.bfloat16 if q.dtype == jnp.bfloat16 else jnp.float32
+    mask, tiles_m = _tile_counts(s_q, s_k, bq, bk, causal, window, bd)
     # innermost extents: every tile, or with a window the widest band's
-    nq_in, nk_in, tiles_w, tiles_bd = nq, nk, None, None
+    nq_in, nk_in = nq, nk
     if window is not None:
-        tiles_w = _window_tiles(s_q, s_k, bq, bk, window)
-        nq_in, nk_in = tiles_w["q_steps"], tiles_w["k_steps"]
-    if bd is not None:
-        tiles_bd = _bd_tiles(s_q, bq, bk, *bd)
-        nq_in, nk_in = tiles_bd["q_steps"], tiles_bd["k_steps"]
+        nq_in, nk_in = tiles_m["q_steps"], tiles_m["k_steps"]
+
+    def tile_list(by, heads=1):
+        """The tile list a launch walks (``bd``), else None."""
+        return None if bd is None else _bd_walk(s_q, bq, bk, *bd, by=by,
+                                                group=heads)
 
     def flat(a):                                 # (B,S,H,D) -> (B*H,S,D)
         return jnp.moveaxis(a, 2, 1).reshape(b * a.shape[2], a.shape[1],
@@ -1055,8 +1211,6 @@ def _flash_bwd(causal, sm_scale, block_q, block_k, window, bd, res, g):
 
     def q_tile(row, ki, qi):
         """Block index of the q tile at step ``qi`` under k block ``ki``."""
-        if bd is not None:
-            return (row, _bd_q_block(ki, qi, bq, bk, *bd)[0], 0)
         if window is not None:
             first, last = _q_band(ki, bq, bk, off, window, nq)
             return (row, jnp.minimum(first + qi, last), 0)
@@ -1073,14 +1227,29 @@ def _flash_bwd(causal, sm_scale, block_q, block_k, window, bd, res, g):
             return (row, jnp.maximum(qi, first), 0)
         return (row, qi, 0)
 
-    # the dK/dV and fused kernels walk (bh_kv, nk, group * nq_in), q innermost
-    def q_index(bhi, ki, st):
-        if group == 1:
-            return q_tile(bhi, ki, st)
-        return q_tile(bhi * group + st // nq_in, ki, st % nq_in)
+    if bd is not None:
+        # the dK/dV and fused kernels walk (bh_kv, the group's tiles): each
+        # entry names its q tile, k block and query head
+        def q_index(bhi, e, walk):
+            row = bhi if group == 1 else \
+                bhi * group + _walk_at(walk, e, _HEAD)
+            return (row, _walk_at(walk, e, _Q), 0)
+
+        def kv_index(bhi, e, walk):
+            return (bhi, _walk_at(walk, e, _K), 0)
+    else:
+        # the dK/dV and fused kernels walk (bh_kv, nk, group * nq_in), q
+        # innermost
+        def q_index(bhi, ki, st):
+            if group == 1:
+                return q_tile(bhi, ki, st)
+            return q_tile(bhi * group + st // nq_in, ki, st % nq_in)
+
+        def kv_index(bhi, ki, qi):
+            return (bhi, ki, 0)
     by_q = [pl.BlockSpec((1, bq, w), q_index)
             for w in (d, d_v, 1, 1)]                     # q2, g, lse, D
-    by_k = [pl.BlockSpec((1, bk, w), lambda bhi, ki, qi: (bhi, ki, 0))
+    by_k = [pl.BlockSpec((1, bk, w), kv_index)
             for w in (d, d_v)]                           # k | dk, v | dv
     dkv_in_specs = [by_q[0], *by_k, *by_q[1:]]
     dq_shape = jax.ShapeDtypeStruct((bh, s_q, d), q.dtype, vma=vma)
@@ -1098,22 +1267,26 @@ def _flash_bwd(causal, sm_scale, block_q, block_k, window, bd, res, g):
     fused = dq_bytes <= _FUSED_BWD_DQ_BYTES
     by_head = not fused and by_head_bytes <= _FUSED_BWD_DQ_BYTES
     one_launch = fused or by_head
-    if tiles_w is not None:
+    if window is not None or bd is not None:
         passes = 1 if one_launch else 2
-        _TILES_NEEDED.inc(bh * tiles_w["needed"] * passes)
-        _TILES_VISITED.inc(bh * (tiles_w["by_q"] + (
-            0 if one_launch else tiles_w["by_k"])))
-    if tiles_bd is not None:
-        _BD_NEEDED.inc(bh * tiles_bd["needed"] * (1 if one_launch else 2))
-        _BD_COMPUTED.inc(bh * bq * bk * (tiles_bd["by_q"] + (
-            0 if one_launch else tiles_bd["by_k"])))
+        visited = tiles_m["by_q"] + (0 if one_launch else tiles_m["by_k"])
+        if bd is not None:
+            _BD_NEEDED.inc(bh * tiles_m["needed"] * passes)
+            _BD_COMPUTED.inc(bh * bq * bk * visited)
+        else:
+            _TILES_NEEDED.inc(bh * tiles_m["needed"] * passes)
+            _TILES_VISITED.inc(bh * visited)
+    # the q-innermost grids: (bh_kv, nk, group * nq_in), or a tile list's
+    dkv_grid = (bh_kv, nk, group * nq_in) if bd is None else \
+        (bh_kv, group * tiles_m["by_q"])
     if one_launch:
         if fused:
             _BACKWARD_FUSED.inc()
-            grid, in_specs, held = (bh_kv, nk, group * nq_in), dkv_in_specs, \
-                dq_bytes
+            grid, in_specs, held = dkv_grid, dkv_in_specs, dq_bytes
+            tiles_in = tile_list("k", group)
+            # the group's whole dQ, whatever the later grid indices
             out_specs = [pl.BlockSpec((group, s_q, d),
-                                      lambda bhi, ki, qi: (bhi, 0, 0)),
+                                      lambda bhi, *_: (bhi, 0, 0)),
                          *by_k]
             scratch = [pltpu.VMEM(
                 (s_q, d) if group == 1 else (group, s_q, d), jnp.float32),
@@ -1123,23 +1296,37 @@ def _flash_bwd(causal, sm_scale, block_q, block_k, window, bd, res, g):
             # a query head at a time, its dQ the output block; dK and dV
             # whole in scratch and as output blocks, at the kv head's row
             grid, held = (bh_kv, group, nk, nq_in), by_head_bytes
-            seq_q = [pl.BlockSpec((1, bq, w), lambda bhi, hd, ki, qi: q_tile(
-                bhi * group + hd, ki, qi)) for w in (d, d_v, 1, 1)]
-            seq_k = [pl.BlockSpec((1, bk, w),
-                                  lambda bhi, hd, ki, qi: (bhi, ki, 0))
-                     for w in (d, d_v)]
+            tiles_in = tile_list("k")
+            if bd is not None:
+                # a head walks the tile list, one head's
+                grid = (bh_kv, group, tiles_m["by_q"])
+                seq_q = [pl.BlockSpec((1, bq, w), lambda bhi, hd, e, walk: (
+                    bhi * group + hd, _walk_at(walk, e, _Q), 0))
+                    for w in (d, d_v, 1, 1)]
+                seq_k = [pl.BlockSpec((1, bk, w), lambda bhi, hd, e, walk: (
+                    bhi, _walk_at(walk, e, _K), 0)) for w in (d, d_v)]
+            else:
+                seq_q = [pl.BlockSpec((1, bq, w),
+                                      lambda bhi, hd, ki, qi: q_tile(
+                                          bhi * group + hd, ki, qi))
+                         for w in (d, d_v, 1, 1)]
+                seq_k = [pl.BlockSpec((1, bk, w),
+                                      lambda bhi, hd, ki, qi: (bhi, ki, 0))
+                         for w in (d, d_v)]
             in_specs = [seq_q[0], *seq_k, *seq_q[1:]]
-            out_specs = [pl.BlockSpec((1, s_q, d), lambda bhi, hd, ki, qi: (
+            out_specs = [pl.BlockSpec((1, s_q, d), lambda bhi, hd, *_: (
                 bhi * group + hd, 0, 0))] + [
-                pl.BlockSpec((1, s_k, w),
-                             lambda bhi, hd, ki, qi: (bhi, 0, 0))
+                pl.BlockSpec((1, s_k, w), lambda bhi, *_: (bhi, 0, 0))
                 for w in (d, d_v)]
             scratch = [pltpu.VMEM((rows, w), jnp.float32)
                        for rows, w in ((s_q, d), (s_k, d), (s_k, d_v))]
-        dq, dk, dv = pl.pallas_call(
+        if mask is not None:
+            _count_grid(mask, grid, bh * tiles_m["by_q"])
+        dq, dk, dv = _launch(
             functools.partial(
                 _flash_bwd_fused_kernel, sm_scale=sm_scale,
                 num_k_blocks=nk, by_head=by_head, **dkv_tiles),
+            operands, tiles_in,
             grid=grid,
             in_specs=in_specs,
             out_specs=out_specs,
@@ -1153,55 +1340,71 @@ def _flash_bwd(causal, sm_scale, block_q, block_k, window, bd, res, g):
                     len(grid) - 1),
                 vmem_limit_bytes=_SCOPED_VMEM_BYTES + held),
             interpret=interpret,
-        )(*operands)
+        )
     else:
         _BACKWARD_TWO_KERNEL.inc()
-        # --- dQ: grid (bh, nq, nk_in), k innermost -------------------------
+        # --- dQ: grid (bh, nq, nk_in), k innermost, or a tile list's -------
         if bd is not None:
-            def k_index(bhi, qi, ki):
-                return (kv_row(bhi), _bd_k_block(qi, ki, bq, bk, *bd)[0], 0)
-        elif causal:
-            # likewise the k blocks past a q tile's diagonal name the last
-            # active one again (clamped at 0 as in _flash_forward)
-            def k_index(bhi, qi, ki):
-                last = jnp.maximum((off + (qi + 1) * bq - 1) // bk, 0)
-                if window is not None:
-                    ki = ki + _k_band(qi, bq, bk, off, window)[0]
-                return (kv_row(bhi), jnp.minimum(ki, last), 0)
+            dq_grid = (bh, tiles_m["by_k"])
+
+            def row_index(bhi, e, walk):
+                return (bhi, _walk_at(walk, e, _Q), 0)
+
+            def k_index(bhi, e, walk):
+                return (kv_row(bhi), _walk_at(walk, e, _K), 0)
         else:
-            def k_index(bhi, qi, ki):
-                return (kv_row(bhi), ki, 0)
-        dq = pl.pallas_call(
+            dq_grid = (bh, nq, nk_in)
+
+            def row_index(bhi, qi, ki):
+                return (bhi, qi, 0)
+            if causal:
+                # likewise the k blocks past a q tile's diagonal name the
+                # last active one again (clamped at 0 as in _flash_forward)
+                def k_index(bhi, qi, ki):
+                    last = jnp.maximum((off + (qi + 1) * bq - 1) // bk, 0)
+                    if window is not None:
+                        ki = ki + _k_band(qi, bq, bk, off, window)[0]
+                    return (kv_row(bhi), jnp.minimum(ki, last), 0)
+            else:
+                def k_index(bhi, qi, ki):
+                    return (kv_row(bhi), ki, 0)
+        if mask is not None:
+            _count_grid(mask, dq_grid, bh * tiles_m["by_k"])
+            _count_grid(mask, dkv_grid, bh * tiles_m["by_q"])
+        dq = _launch(
             functools.partial(
                 _flash_bwd_dq_kernel, sm_scale=sm_scale, num_k_blocks=nk_in,
                 **tiles),
-            grid=(bh, nq, nk_in),
+            operands, tile_list("q"),
+            grid=dq_grid,
             in_specs=[
-                pl.BlockSpec((1, bq, d), lambda bhi, qi, ki: (bhi, qi, 0)),
+                pl.BlockSpec((1, bq, d), row_index),
                 pl.BlockSpec((1, bk, d), k_index),
                 pl.BlockSpec((1, bk, d_v), k_index),
-                pl.BlockSpec((1, bq, d_v), lambda bhi, qi, ki: (bhi, qi, 0)),
-                pl.BlockSpec((1, bq, 1), lambda bhi, qi, ki: (bhi, qi, 0)),
-                pl.BlockSpec((1, bq, 1), lambda bhi, qi, ki: (bhi, qi, 0)),
+                pl.BlockSpec((1, bq, d_v), row_index),
+                pl.BlockSpec((1, bq, 1), row_index),
+                pl.BlockSpec((1, bq, 1), row_index),
             ],
-            out_specs=pl.BlockSpec((1, bq, d),
-                                   lambda bhi, qi, ki: (bhi, qi, 0)),
+            out_specs=pl.BlockSpec((1, bq, d), row_index),
             out_shape=dq_shape,
             scratch_shapes=[pltpu.VMEM((bq, d), jnp.float32)],
-            compiler_params=None if interpret else _mosaic_params(),
+            compiler_params=None if interpret else _mosaic_params(
+                len(dq_grid)),
             interpret=interpret,
-        )(*operands)
+        )
         # --- dK/dV: grid (bh_kv, nk, group * nq_in), q innermost -----------
-        dk, dv = pl.pallas_call(
+        dk, dv = _launch(
             functools.partial(_flash_bwd_dkv_kernel, **dkv_tiles),
-            grid=(bh_kv, nk, group * nq_in),
+            operands, tile_list("k", group),
+            grid=dkv_grid,
             in_specs=dkv_in_specs,
             out_specs=by_k,
             out_shape=dkv_shapes,
             scratch_shapes=dkv_scratch,
-            compiler_params=None if interpret else _mosaic_params(),
+            compiler_params=None if interpret else _mosaic_params(
+                len(dkv_grid)),
             interpret=interpret,
-        )(*operands)
+        )
 
     def unflat(a, s_len):
         return jnp.moveaxis(a.reshape(b, -1, s_len, a.shape[-1]), 1, 2)
@@ -1230,10 +1433,12 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
     sequence as ``[x_t | x_0]``, a noisy copy and the clean copy of a
     sequence of S / 2, and keeps :func:`block_diffusion_mask`'s entries for
     blocks of ``block`` (a power of two): the grids walk, for each q tile,
-    its own noisy diagonal and the clean tiles up to its own, and a tile
-    does mask arithmetic only where the mask crosses it;
-    ``zoo_attention_bd_entries_total`` counts the score entries computed
-    and needed. Uses the Pallas kernel when the
+    its own noisy diagonal and the clean tiles up to its own, a list of
+    those tiles and no step more, and a tile does mask arithmetic only
+    where the mask crosses it; ``zoo_attention_bd_entries_total`` counts
+    the score entries computed and needed. A masked call's grid steps,
+    issued and computed, are in ``zoo_attention_grid_steps_total``. Uses
+    the Pallas kernel when the
     sequence tiles evenly (compiled on a TPU, interpret mode on the CPU
     backend), else the reference path — which on a TPU is logged and
     counted (``zoo_attention_reference_on_tpu_total``), never silent.

@@ -664,19 +664,66 @@ def test_a_windowed_grid_walks_the_bands_alone(monkeypatch, window, block,
         monkeypatch.setattr(attn, "_FUSED_BWD_DQ_BYTES",
                             3 * attn._fused_bwd_dq_bytes(s, 16, q.dtype))
     before = (attn._TILES_VISITED.value, attn._TILES_NEEDED.value)
+    steps = _grid_steps("window")
     jaxpr = jax.make_jaxpr(jax.grad(lambda q, k, v: jnp.sum(flash_attention(
         q, k, v, causal=True, window=window, block_q=block,
         block_k=block) * w), (0, 1, 2)))(q, k, v).jaxpr
-    grids = [tuple(e.params["grid_mapping"].grid) for e in equations(jaxpr)
-             if e.primitive.name == "pallas_call"]
+    calls = [e for e in equations(jaxpr) if e.primitive.name == "pallas_call"]
+    grids = [tuple(e.params["grid_mapping"].grid) for e in calls]
     n = s // block
     assert grids == [(h, n, band), (h_kv, h // h_kv, n, band) if by_head
                      else (h_kv, n, (h // h_kv) * band)]
+    # no tile list: the bands' grids as they were
+    assert [e.params["grid_mapping"].num_index_operands for e in calls] == \
+        [0, 0]
     visited, needed = (attn._TILES_VISITED.value - before[0],
                        attn._TILES_NEEDED.value - before[1])
     assert visited == needed == 2 * h * attn._window_tiles(
         s, s, block, block, window)["needed"]
     assert needed < 2 * h * n * (n + 1) // 2       # the causal triangle's
+    # the grid steps issued, and of them the visited tiles computed
+    assert _grid_steps("window") == {
+        "issued": steps["issued"] + 2 * h * n * band,
+        "computed": steps["computed"] + visited}
+
+
+def _grid_steps(mask):
+    """``zoo_attention_grid_steps_total`` of a mask, by kind."""
+    import analytics_zoo_tpu.ops.attention as attn
+    return {kind: attn._GRID_STEPS.labels(mask=mask, kind=kind).value
+            for kind in ("issued", "computed")}
+
+
+@pytest.mark.parametrize("heads_budget,backward", [
+    (None, lambda h, g, n: [(h // g, n, g * n)]),                 # fused
+    (3, lambda h, g, n: [(h // g, g, n, n)]),                     # by head
+    (0, lambda h, g, n: [(h, n, n), (h // g, n, g * n)])])        # the pair
+def test_a_causal_grid_is_a_counted_rectangle(monkeypatch, heads_budget,
+                                              backward):
+    """A causal call keeps its rectangular grids and no tile list, whichever
+    backward it takes: every launch issues n x n steps a head, of which the
+    n (n + 1) / 2 tiles on or below the diagonal compute, as
+    ``zoo_attention_grid_steps_total`` counts them."""
+    import analytics_zoo_tpu.ops.attention as attn
+    s, h, h_kv, n = 128, 8, 2, 8
+    q, k, v, w = _gqa_qkv(s, s, h, h_kv)
+    if heads_budget is not None:
+        # a budget of this many query heads' dQ
+        monkeypatch.setattr(attn, "_FUSED_BWD_DQ_BYTES", heads_budget *
+                            attn._fused_bwd_dq_bytes(s, 16, q.dtype))
+    steps = _grid_steps("causal")
+    jaxpr = jax.make_jaxpr(jax.grad(lambda q, k, v: jnp.sum(flash_attention(
+        q, k, v, causal=True, block_q=16, block_k=16) * w), (0, 1, 2)))(
+        q, k, v).jaxpr
+    calls = [e for e in equations(jaxpr) if e.primitive.name == "pallas_call"]
+    assert [tuple(e.params["grid_mapping"].grid) for e in calls] == \
+        [(h, n, n)] + backward(h, h // h_kv, n)
+    assert all(e.params["grid_mapping"].num_index_operands == 0
+               for e in calls)
+    launches = len(calls)
+    assert _grid_steps("causal") == {
+        "issued": steps["issued"] + launches * h * n * n,
+        "computed": steps["computed"] + launches * h * n * (n + 1) // 2}
 
 
 def test_the_mla_paths_results_are_the_parents_to_the_bit():
@@ -706,6 +753,25 @@ def test_the_mla_paths_results_are_the_parents_to_the_bit():
             assert digest.hexdigest()[:16] == want, (d_qk, d_v, causal, s_q)
     finally:
         attn._FUSED_BWD_DQ_BYTES = keep
+
+
+def _scalar_prefetch(module_text):
+    """The ``scalar_prefetch`` operand count of each Mosaic kernel in an
+    exported module's text (its custom calls' serialized bodies)."""
+    import base64
+    import re
+    from jax._src.lib.mlir import ir
+    counts = []
+    for body in re.findall(
+            r'custom_call_config\\22: \{\\22body\\22: \\22([^\\]+)',
+            module_text):
+        with ir.Context() as ctx:
+            ctx.allow_unregistered_dialects = True
+            asm = ir.Module.parse(base64.b64decode(body)).operation.get_asm(
+                enable_debug_info=False)
+        counts.append(int(re.search(r"scalar_prefetch = (\d+)",
+                                    asm).group(1)))
+    return counts
 
 
 @pytest.mark.parametrize("budget,path,calls", [
@@ -738,6 +804,7 @@ def test_grouped_windowed_flash_lowers_to_mosaic_for_tpu(monkeypatch, budget,
     assert grad.count("tpu_custom_call") == calls
     assert "tensor<8x2048x128xbf16>" in grad          # q, by (b*h, s, d)
     assert "tensor<2x2048x128xbf16>" in grad          # k and v as given
+    assert _scalar_prefetch(grad) == [0] * calls      # no tile list
 
 
 # --- the block-diffusion mask --------------------------------------------------
@@ -814,25 +881,31 @@ def test_a_block_diffusion_grid_walks_exactly_the_tiles_with_an_entry(
     k tile (the q-innermost ones); ``needed`` is L^2 + L B a head. At the
     cell's shape the forward's 1024-wide tiles walk 80 of 256, the
     backward's 512-wide ones 288 of 1024: 1.25 and 1.125 times the entries
-    the mask keeps."""
+    the mask keeps. The tile lists the grids walk (``_bd_walk``) are those
+    walks' active steps, entry for entry and in order (ascending tiles:
+    noisy before clean), with their flags: a walk's first and last entry,
+    and the entries after which a q tile's dQ rows are whole; by k block
+    with a group, each walk once a query head, in turn."""
     import analytics_zoo_tpu.ops.attention as attn
     half = s // 2
     m = attn.block_diffusion_mask(s, block)
     holds = m.reshape(s // bq, bq, s // bk, bk).any(axis=(1, 3))
-    by_q = np.zeros_like(holds)
+    by_q, walked_q = np.zeros_like(holds), []
     for qi in range(s // bq):
         for step in range(s // bk):
             ki, active = attn._bd_k_block(qi, step, bq, bk, half, block)
             if bool(active):
                 assert not by_q[qi, int(ki)]
                 by_q[qi, int(ki)] = True
-    by_k = np.zeros_like(holds)
+                walked_q.append((qi, int(ki)))
+    by_k, walked_k = np.zeros_like(holds), []
     for ki in range(s // bk):
         for step in range(s // bq):
             qi, active = attn._bd_q_block(ki, step, bq, bk, half, block)
             if bool(active):
                 assert not by_k[int(qi), ki]
                 by_k[int(qi), ki] = True
+                walked_k.append((int(qi), ki))
     np.testing.assert_array_equal(by_q, holds)
     np.testing.assert_array_equal(by_k, holds)
     tiles = attn._bd_tiles(s, bq, bk, half, block)
@@ -842,6 +915,68 @@ def test_a_block_diffusion_grid_walks_exactly_the_tiles_with_an_entry(
         assert int(holds.sum()) == (80 if bq == 1024 else 288)
         assert tiles["by_k"] * bq * bk / tiles["needed"] == pytest.approx(
             1.25 if bq == 1024 else 1.125, abs=0.001)
+    last_k = {qi: max(np.flatnonzero(holds[qi])) for qi in range(s // bq)}
+    for by, walked, group in (("q", walked_q, 1), ("k", walked_k, 1),
+                              ("k", walked_k, 3)):
+        q, k, head, flags = attn._bd_walk(s, bq, bk, half, block, by=by,
+                                          group=group).reshape(4, -1)
+        run = q if by == "q" else k
+        assert len(q) == group * len(walked)
+        # the same walks, in ascending tiles, each once a head in turn
+        order = sorted(walked) if by == "q" else \
+            sorted(walked, key=lambda t: (t[1], t[0]))
+        assert order == walked
+        want = [(qi, ki, hd) for r in np.unique(run)
+                for hd in range(group) for qi, ki in walked
+                if (qi if by == "q" else ki) == r]
+        assert list(zip(q.tolist(), k.tolist(), head.tolist())) == want
+        first = np.r_[True, run[1:] != run[:-1]]
+        last = np.r_[run[1:] != run[:-1], True]
+        np.testing.assert_array_equal(flags & attn._FIRST != 0, first)
+        np.testing.assert_array_equal(flags & attn._LAST != 0, last)
+        np.testing.assert_array_equal(
+            flags & attn._DQ_LAST != 0,
+            [ki == last_k[qi] for qi, ki in zip(q, k)])
+
+
+@pytest.mark.parametrize("heads_budget,path", [
+    (None, "fused"), (3, "fused_by_head"), (0, "two_kernel")])
+def test_a_block_diffusion_grid_is_its_tile_list(monkeypatch, heads_budget,
+                                                 path):
+    """Every block-diffusion launch walks a tile list, its one
+    scalar-prefetch operand (four int32 rows an entry): the grid's last
+    extent is the list's length, the tiles the launch computes, whichever
+    backward the shape takes, and ``zoo_attention_grid_steps_total``
+    counts as many steps issued as computed."""
+    import analytics_zoo_tpu.ops.attention as attn
+    s, h, h_kv = 128, 8, 2
+    g = h // h_kv
+    q, k, v, w = _gqa_qkv(s, s, h, h_kv)
+    if heads_budget is not None:
+        monkeypatch.setattr(attn, "_FUSED_BWD_DQ_BYTES", heads_budget *
+                            attn._fused_bwd_dq_bytes(s, 16, q.dtype))
+    steps = _grid_steps(attn.BLOCK_DIFFUSION)
+    before = _path_counts()
+    jaxpr = jax.make_jaxpr(jax.grad(lambda q, k, v: jnp.sum(flash_attention(
+        q, k, v, mask="block_diffusion", block=4, block_q=16,
+        block_k=16) * w), (0, 1, 2)))(q, k, v).jaxpr
+    assert _path_counts()[path] == before[path] + 1
+    calls = [e for e in equations(jaxpr) if e.primitive.name == "pallas_call"]
+    t = attn._bd_tiles(s, 16, 16, s // 2, 4)
+    by_k, by_q = t["by_k"], t["by_q"]
+    assert by_k == by_q < (s // 16) ** 2 // 2
+    grids = [(h, by_k)] + {"fused": [(h_kv, g * by_q)],
+                           "fused_by_head": [(h_kv, g, by_q)],
+                           "two_kernel": [(h, by_k), (h_kv, g * by_q)]}[path]
+    assert [tuple(e.params["grid_mapping"].grid) for e in calls] == grids
+    for e, grid in zip(calls, grids):
+        assert e.params["grid_mapping"].num_index_operands == 1
+        assert e.invars[0].aval.shape == (4 * grid[-1],)
+    computed = sum(int(np.prod(grid)) for grid in grids)
+    assert computed == h * by_k * len(grids)
+    assert _grid_steps(attn.BLOCK_DIFFUSION) == {
+        "issued": steps["issued"] + computed,
+        "computed": steps["computed"] + computed}
 
 
 def test_block_diffusion_entries_are_counted_and_bad_calls_refused():
@@ -889,7 +1024,8 @@ def test_block_diffusion_flash_lowers_to_mosaic_for_tpu(monkeypatch, budget,
                                                         path, calls):
     """The block-diffusion walks through the Pallas -> Mosaic lowering, 8
     query heads on 2 kv heads over 2 x 1024 positions in bfloat16: each of
-    the three backwards."""
+    the three backwards, every kernel with its tile list prefetched into
+    scalar memory."""
     import analytics_zoo_tpu.ops.attention as attn
     monkeypatch.setattr(attn, "_interpret", lambda: False)
     q = jax.ShapeDtypeStruct((1, 2048, 8, 128), jnp.bfloat16)
@@ -907,3 +1043,4 @@ def test_block_diffusion_flash_lowers_to_mosaic_for_tpu(monkeypatch, budget,
         platforms=["tpu"])(q, kv, kv).mlir_module()
     assert _path_counts() == dict(before, **{path: before[path] + 1})
     assert grad.count("tpu_custom_call") == calls
+    assert _scalar_prefetch(grad) == [1] * calls

@@ -176,7 +176,8 @@ def test_the_references_faults_are_another_model(sides, fault):
 def test_each_block_takes_the_block_diffusion_kernels(sides):
     """Two flash kernels a block (forward, fused backward: the remat policy
     keeps the forward's results), at 2 x SEQ positions with k and v at
-    their own two heads, and the block-diffusion call sites counted."""
+    their own two heads (after the tile list the grid walks), and the
+    block-diffusion call sites counted."""
     import analytics_zoo_tpu.ops.attention as attn
     before = (attn._BD_COMPUTED.value, attn._BD_NEEDED.value)
     jaxpr = jax.make_jaxpr(jax.grad(lambda p: sides["loss_of"](p)[0]))(
@@ -187,7 +188,8 @@ def test_each_block_takes_the_block_diffusion_kernels(sides):
                if e.primitive.name == "pallas_call"
                and e.params["jaxpr"].debug_info.func_name == "_flash_kernel"]
     for e in forward:
-        q, k = (x.aval.shape for x in e.invars[:2])
+        walk, q, k = (x.aval.shape for x in e.invars[:3])
+        assert len(walk) == 1 and walk[0] % 4 == 0
         assert q == (2 * 4, 2 * SEQ, 16) and k == (2 * 2, 2 * SEQ, 16)
     computed = attn._BD_COMPUTED.value - before[0]
     needed = attn._BD_NEEDED.value - before[1]
@@ -210,13 +212,14 @@ def _cells_configuration():
 def test_the_cells_own_blocks_walk_only_the_masks_tiles():
     """At the cell's own 8192 tokens (16384 positions), 32 query heads on 4
     kv heads of 128, bfloat16, traced on shapes (nothing computed; one
-    block, a small vocabulary): the forward's k dim walks at most 9 of the
-    16 1024-wide k tiles (a noisy q tile's diagonal and up to 8 clean
-    tiles), the backward's q dim at most 32 of the 512-wide q tiles; the
-    kv head's 8 x 16384 x 128 of dQ do not fit VMEM at once, so it goes a
-    query head at a time (``fused_by_head``); the score entries computed
-    are 1.25 times those the mask keeps in the forward, 1.125 in the
-    backward (counted as traced: the forward twice, 1.21 together)."""
+    block, a small vocabulary): the forward's grid walks a head's 80 of the
+    256 1024-wide tiles (a noisy q tile's diagonal and up to 8 clean
+    tiles), the backward's a head's 288 of the 1024 512-wide tiles, and no
+    step more; the kv head's 8 x 16384 x 128 of dQ do not fit
+    VMEM at once, so it goes a query head at a time (``fused_by_head``);
+    the score entries computed are 1.25 times those the mask keeps in the
+    forward, 1.125 in the backward (counted as traced: the forward twice,
+    1.21 together)."""
     import analytics_zoo_tpu.ops.attention as attn
     model = DecoderLM.from_config(dict(_cells_configuration()[1],
                                        num_hidden_layers=1, vocab_size=256))
@@ -236,7 +239,7 @@ def test_the_cells_own_blocks_walk_only_the_masks_tiles():
     grids = [tuple(e.params["grid_mapping"].grid) for e in equations(jaxpr)
              if e.primitive.name == "pallas_call"
              and "flash" in e.params["jaxpr"].debug_info.func_name]
-    assert grids == [(32, 16, 9), (4, 8, 32, 32)]
+    assert grids == [(32, 80), (4, 8, 288)]
     assert attn._BACKWARD_FUSED_BY_HEAD.value == before[0] + 1
     ratio = (attn._BD_COMPUTED.value - before[1]) / (
         attn._BD_NEEDED.value - before[2])
