@@ -113,13 +113,16 @@ runs without its exchange.
 
 from __future__ import annotations
 
+import contextlib
 import functools
 import math
+import threading
 from typing import Any, Callable, Dict, Optional
 
 import flax.linen as nn
 import jax
 import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
 
 from analytics_zoo_tpu.obs.registry import REGISTRY as _REGISTRY
 from analytics_zoo_tpu.ops.attention import (
@@ -146,6 +149,9 @@ INIT_POSITIONS = 128
 ROUTER_STATE = "router_state"      # the correction bias: state, not parameter
 MOE_STATS = "moe_stats"            # counters the step leaves in its outputs
 NOISE_STATE = "noise_state"        # the block-diffusion noise's seed, steps
+# a SwiGLU's first products (gate, up), as ``checkpoint_name`` marks them: an
+# identity wherever no remat policy keeps them
+FFN_PRODUCT_NAMES = ("ffn_gate", "ffn_up")
 
 
 class RMSNorm(nn.Module):
@@ -319,9 +325,12 @@ class SwiGLU(nn.Module):
     @nn.compact
     def __call__(self, x):
         act = _ACTIVATIONS[self.activation]
+        gate_name, up_name = FFN_PRODUCT_NAMES
         if self.gated:
-            gate = _dense(self.width, self.dtype, "gate_proj")(x)
-        up = _dense(self.width, self.dtype, "up_proj")(x)
+            gate = checkpoint_name(
+                _dense(self.width, self.dtype, "gate_proj")(x), gate_name)
+        up = checkpoint_name(_dense(self.width, self.dtype, "up_proj")(x),
+                             up_name)
         return _dense(x.shape[-1], self.dtype, "down_proj")(
             act(gate) * up if self.gated else act(up))
 
@@ -516,12 +525,64 @@ class SparseExperts(nn.Module):
             stats["load"].value = load
 
 
-# one policy object for every block: blocks whose remat parameters are the
-# same object share one lowered function, as blocks under a plain remat do.
-# It keeps the flash forward kernel's two results and an expert layer's
-# routing decision ((tokens, top_k) int32: the backward pass selects nothing)
-_KEPT_ACROSS_REMAT = jax.checkpoint_policies.save_only_these_names(
-    *FLASH_RESIDUAL_NAMES, ROUTER_CHOICE_NAME)
+_REMAT_KEPT_BYTES = _REGISTRY.gauge(
+    "zoo_remat_kept_bytes",
+    "bytes a training step keeps across its blocks' rematerialisation under "
+    "each saved name, summed over the blocks of the last model call traced "
+    "for a gradient", labelnames=("name",))
+_TALLY = threading.local()         # the model call being traced: name -> bytes
+
+
+class _KeepNames:
+    """``save_only_these_names(*names)`` as a policy object that adds the
+    bytes of each value it keeps to the tally of the model call being traced
+    (``_remat_tally``). A remat's partial evaluation asks it once for each
+    equation of the block's forward pass, while the block is traced."""
+
+    def __init__(self, *names):
+        self.names = names
+        self._keeps = jax.checkpoint_policies.save_only_these_names(*names)
+
+    def __call__(self, prim, *avals, **params):
+        keep = self._keeps(prim, *avals, **params)
+        tally = getattr(_TALLY, "kept", None)
+        if keep and tally is not None:
+            tally[params["name"]] = tally.get(params["name"], 0) + \
+                avals[0].size * avals[0].dtype.itemsize
+        return keep
+
+
+# one policy object for every block of a kind: blocks whose remat parameters
+# are the same object share one lowered function, as blocks under a plain
+# remat do. Every block keeps the flash forward kernel's two results and an
+# expert layer's routing decision ((tokens, top_k) int32: the backward pass
+# selects nothing)
+_KEPT_ACROSS_REMAT = _KeepNames(*FLASH_RESIDUAL_NAMES, ROUTER_CHOICE_NAME)
+# The hybrid families' blocks (MixerMLPBlock, MixerBlock) keep their
+# SwiGLU's first products too, so the backward pass does not run gate_proj
+# and up_proj again (Granite: 2 x 134 MB a block at 8192 tokens; Nemotron's
+# shared experts: 88 MB an E block). The DecoderBlock families do not: their
+# steps use 86-91% of a v5e's memory, and their only SwiGLUs (a dense first
+# layer's FFN, the shared experts) would give back ~1% of the step. Revisit
+# once their memory is freed.
+_KEPT_WITH_FFN_PRODUCTS = _KeepNames(
+    *FLASH_RESIDUAL_NAMES, ROUTER_CHOICE_NAME, *FFN_PRODUCT_NAMES)
+
+
+@contextlib.contextmanager
+def _remat_tally():
+    """Tallies what the blocks' policies keep while one model call is
+    traced. A call traced for a gradient (one in which a policy kept
+    something) sets ``zoo_remat_kept_bytes`` for every name either policy
+    keeps, 0 where none was kept; a forward-only trace leaves it alone."""
+    _TALLY.kept = tally = {}
+    try:
+        yield
+    finally:
+        _TALLY.kept = None
+    if tally:
+        for name in _KEPT_WITH_FFN_PRODUCTS.names:
+            _REMAT_KEPT_BYTES.labels(name=name).set(tally.get(name, 0))
 
 
 class DecoderBlock(nn.Module):
@@ -759,17 +820,19 @@ class DecoderLM(nn.Module):
             masked.astype(jnp.float32) / p
 
     def _mixer_block(self, kind: str, name: str):
-        """A rematerialised one-mixer block (the same policy object as
-        :meth:`_block`: an attention block keeps its flash results, an
-        expert block its routing decision, a Mamba-2 block nothing)."""
+        """A rematerialised one-mixer block, every one under the policy
+        object ``_KEPT_WITH_FFN_PRODUCTS``: an attention block keeps its
+        flash results, an expert block its routing decision and its shared
+        expert's up product, a block with an MLP (``granitemoehybrid``) the
+        MLP's gate and up products; of a Mamba-2 mixer nothing is kept."""
         sizes = {"M": self.mamba, "*": self.attention, "E": self.experts}
         if self.block_mlp_width:
-            return nn.remat(MixerMLPBlock, policy=_KEPT_ACROSS_REMAT)(
+            return nn.remat(MixerMLPBlock, policy=_KEPT_WITH_FFN_PRODUCTS)(
                 kind=kind, sizes=dict(sizes[kind]),
                 mlp_width=self.block_mlp_width,
                 residual_scale=self.residual_scale, eps=self.rms_norm_eps,
                 dtype=self.dtype, name=name)
-        return nn.remat(MixerBlock, policy=_KEPT_ACROSS_REMAT)(
+        return nn.remat(MixerBlock, policy=_KEPT_WITH_FFN_PRODUCTS)(
             kind=kind, sizes=dict(sizes[kind]), eps=self.rms_norm_eps,
             dtype=self.dtype, name=name)
 
@@ -798,8 +861,13 @@ class DecoderLM(nn.Module):
     @keras_call
     @nn.compact
     def __call__(self, ids, train: bool = False):
-        del train                        # no dropout; the step's state is
-        ids = ids.astype(jnp.int32)      # written where it is mutable
+        # no dropout; the step's state is written where it is mutable
+        del train
+        with _remat_tally():
+            return self._forward(ids)
+
+    def _forward(self, ids):
+        ids = ids.astype(jnp.int32)
         if self.is_initializing():
             # no parameter's shape depends on the sequence's length: the
             # engine's eager init runs on a prefix, not on 8192 positions

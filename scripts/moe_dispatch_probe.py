@@ -320,8 +320,11 @@ def probe_layer(cell_name: str, seed: int, steps: int, scope: str):
     cell = spec.load_cell(cell_name)
     model = decoder_lm.DecoderLM.from_config(
         cell.load("factory").model_config(cell.config))
-    layer = nn.remat(decoder_lm.SparseExperts,
-                     policy=decoder_lm._KEPT_ACROSS_REMAT)(
+    # the policy of the cell's blocks: a hybrid family's keeps the shared
+    # expert's up product too
+    policy = decoder_lm._KEPT_ACROSS_REMAT if model.layer_kinds is None \
+        else decoder_lm._KEPT_WITH_FFN_PRODUCTS
+    layer = nn.remat(decoder_lm.SparseExperts, policy=policy)(
         dtype=model.dtype, **model.experts)
     shape = (int(cell.config["per_chip_batch"]),
              int(cell.traffic["sequence_length"]), model.hidden_size)

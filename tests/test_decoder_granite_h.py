@@ -28,7 +28,10 @@ from harness import spec, work_granite                          # noqa: E402
 from analytics_zoo_tpu.ops import ssm                           # noqa: E402
 from analytics_zoo_tpu.pipeline.api.keras.layers.decoder_lm import (  # noqa: E402
     DecoderLM, moe_counters, next_token_loss)
-from test_decoder_lm import _flat, _tree                        # noqa: E402
+from analytics_zoo_tpu.pipeline.api.keras.layers import decoder_lm  # noqa: E402
+from test_attention import equations                            # noqa: E402
+from test_decoder_lm import (                                   # noqa: E402
+    _bit_equal, _flat, _tree, remat_block_sides, remat_kept_bytes)
 
 CFG = dict(
     model_type="granitemoehybrid", vocab_size=96, hidden_size=32,
@@ -301,6 +304,61 @@ def test_trains_through_the_estimator_on_arrays():
     assert np.all(np.isfinite(losses)) and losses[-1] < losses[0]
     assert moe_counters(est.engine.extra_vars) == {}
     est.shutdown()
+
+
+@pytest.mark.parametrize("kind", ["M", "*"])
+def test_a_block_keeps_its_mlps_gate_and_up_across_the_remat(kind):
+    """A ``MixerMLPBlock`` under its policy keeps its MLP's gate and up
+    products for the backward pass: two more saved values of (batch, seq,
+    width), two fewer products in the gradient's program (``gate_proj`` and
+    ``up_proj`` not run again), neither name marked again in the backward
+    pass, and the gradients of the policy that keeps them not, to the bit."""
+    model = DecoderLM.from_config(CFG)
+    fields = dict(kind=kind,
+                  sizes=dict({"M": model.mamba, "*": model.attention}[kind]),
+                  mlp_width=model.block_mlp_width,
+                  residual_scale=model.residual_scale, eps=model.rms_norm_eps,
+                  dtype=model.dtype)
+    shape = (2, SEQ, CFG["hidden_size"])
+    before = remat_block_sides(decoder_lm.MixerMLPBlock, fields, shape,
+                               decoder_lm._KEPT_ACROSS_REMAT)
+    now = remat_block_sides(decoder_lm.MixerMLPBlock, fields, shape,
+                            decoder_lm._KEPT_WITH_FFN_PRODUCTS)
+    assert now["dots"] == before["dots"] - 2
+    product = ((2, SEQ, CFG["intermediate_size"]), jnp.dtype(jnp.float32))
+    assert now["saved"].count(product) == before["saved"].count(product) + 2
+    assert set(decoder_lm.FFN_PRODUCT_NAMES) <= before["recomputed"]
+    assert not set(decoder_lm.FFN_PRODUCT_NAMES) & now["recomputed"]
+    assert _bit_equal(now["grads"], before["grads"])
+
+
+def test_the_gauge_reads_the_mlps_products_a_step_keeps():
+    """``zoo_remat_kept_bytes`` after a gradient's trace of the bfloat16
+    model: under each of ``ffn_gate`` and ``ffn_up`` blocks x tokens x width
+    x 2 bytes, the attention block's flash results, no routing decision;
+    every block under the one policy that keeps them."""
+    model = DecoderLM.from_config(dict(CFG, compute_dtype="bfloat16"))
+    ids = jnp.asarray(np.random.RandomState(0).randint(0, 96, (2, SEQ)),
+                      jnp.uint16)
+    params = jax.eval_shape(model.init, jax.random.PRNGKey(0), ids)
+
+    def loss(p):
+        return jnp.mean(next_token_loss(ids, model.apply(p, ids)))
+
+    jaxpr = jax.make_jaxpr(jax.grad(loss))(params).jaxpr
+    assert {id(e.params["policy"]) for e in equations(jaxpr)
+            if e.primitive.name == "remat2"} == \
+        {id(decoder_lm._KEPT_WITH_FFN_PRODUCTS)}
+    kept = remat_kept_bytes()
+    blocks, tokens = CFG["num_hidden_layers"], 2 * SEQ
+    assert kept["ffn_gate"] == kept["ffn_up"] == \
+        blocks * tokens * CFG["intermediate_size"] * 2
+    # the attention block's output: its heads are the hidden width
+    assert kept["flash_out"] == tokens * CFG["hidden_size"] * 2
+    assert kept["moe_router_choice"] == 0
+    # a second trace sets the same numbers; it does not add to them
+    jax.make_jaxpr(jax.grad(loss))(params)
+    assert remat_kept_bytes() == kept
 
 
 def _leaves(tree):

@@ -145,6 +145,95 @@ def test_each_block_runs_the_flash_forward_kernel_once(sides):
     assert len(policies) == blocks and len(set(map(id, policies))) == 1
 
 
+def remat_block_sides(cls, fields, shape, policy):
+    """A block of ``cls`` under ``nn.remat(policy=policy)`` on a seeded input
+    of ``shape``: the gradient of a weighted sum of its output as to its
+    parameters and its input, the ``dot_general`` equations of that
+    gradient's program, the avals of the values its forward pass saves for
+    the backward pass, and the names the backward pass marks again (what it
+    recomputes of the named values)."""
+    import flax.linen as nn
+    from jax._src.ad_checkpoint import saved_residuals
+    block = nn.remat(cls, policy=policy)(**fields)
+    rng = np.random.RandomState(1)
+    x, w = (jnp.asarray(rng.randn(*shape), jnp.float32) for _ in range(2))
+    variables = block.init(jax.random.PRNGKey(0), x)
+
+    def loss(p, x):
+        return jnp.sum(block.apply({**variables, "params": p}, x) * w)
+
+    grad = jax.grad(loss, argnums=(0, 1))
+    jaxpr = jax.make_jaxpr(grad)(variables["params"], x).jaxpr
+    backward = [e for e in jaxpr.eqns if e.primitive.name == "remat2"]
+    assert len(backward) == 1
+    saved = saved_residuals(loss, variables["params"], x)
+    return dict(
+        grads=grad(variables["params"], x),
+        dots=sum(e.primitive.name == "dot_general" for e in equations(jaxpr)),
+        saved=[(a.shape, a.dtype) for a, _ in saved],
+        saved_from=[d for _, d in saved],
+        recomputed={e.params["name"] for e in equations(
+            backward[0].params["jaxpr"]) if e.primitive.name == "name"})
+
+
+def remat_kept_bytes():
+    """``zoo_remat_kept_bytes`` by name, every name either policy keeps."""
+    return {name: decoder_lm._REMAT_KEPT_BYTES.labels(name=name).value
+            for name in decoder_lm._KEPT_WITH_FFN_PRODUCTS.names}
+
+
+def _bit_equal(a, b):
+    return all(u.shape == v.shape and bool(jnp.all(u == v))
+               for u, v in zip(jax.tree.leaves(a), jax.tree.leaves(b)))
+
+
+@pytest.mark.parametrize("moe", [False, True])
+def test_a_decoder_blocks_remat_keeps_what_it_kept(moe):
+    """A ``DecoderBlock`` (the dense first layer's SwiGLU; an expert layer
+    with its shared expert) keeps under ``_KEPT_ACROSS_REMAT`` exactly what
+    the flash results and the routing decision alone keep: the same saved
+    values, the same products, and its FFN's first products recomputed in
+    the backward pass (the hybrid blocks' policy keeps them)."""
+    model = DecoderLM.from_config(CFG)
+    fields = dict(attention=dict(model.attention),
+                  ffn_width=0 if moe else model.intermediate_size,
+                  experts=dict(model.experts) if moe else None,
+                  eps=model.rms_norm_eps, dtype=model.dtype)
+    shape = (2, 32, CFG["hidden_size"])
+    before = remat_block_sides(
+        decoder_lm.DecoderBlock, fields, shape,
+        jax.checkpoint_policies.save_only_these_names(
+            *decoder_lm.FLASH_RESIDUAL_NAMES, ep.ROUTER_CHOICE_NAME))
+    now = remat_block_sides(decoder_lm.DecoderBlock, fields, shape,
+                            decoder_lm._KEPT_ACROSS_REMAT)
+    assert now["saved_from"] == before["saved_from"]
+    assert now["saved"] == before["saved"]
+    assert now["dots"] == before["dots"]
+    assert set(decoder_lm.FFN_PRODUCT_NAMES) <= now["recomputed"]
+    assert _bit_equal(now["grads"], before["grads"])
+
+
+def test_a_decoder_lm_call_tallies_what_its_blocks_keep(sides):
+    """``zoo_remat_kept_bytes`` after a gradient's trace of the MLA model:
+    each block's flash output (batch, seq, heads, v) and logsumexp, each
+    expert block's routing decision, and nothing under the FFN's names: its
+    blocks keep no FFN product."""
+    jaxpr = jax.make_jaxpr(jax.grad(lambda p: sides["loss_of"](p)[0]))(
+        _tree(sides["weights"])).jaxpr
+    assert {id(e.params["policy"]) for e in equations(jaxpr)
+            if e.primitive.name == "remat2"} == \
+        {id(decoder_lm._KEPT_ACROSS_REMAT)}
+    kept = remat_kept_bytes()
+    blocks = CFG["num_hidden_layers"] + CFG["num_nextn_predict_layers"]
+    heads, tokens = CFG["num_attention_heads"], 2 * 32
+    assert kept["flash_out"] == blocks * tokens * heads * \
+        CFG["v_head_dim"] * 4
+    assert kept["flash_lse"] == blocks * tokens * heads * 4
+    assert kept["moe_router_choice"] == (blocks - 1) * tokens * \
+        CFG["num_experts_per_tok"] * 4
+    assert kept["ffn_gate"] == kept["ffn_up"] == 0
+
+
 def test_a_training_forward_moves_bias_and_counters(sides):
     new = sides["new"]
     for block in ("layers_1", "layers_2", "mtp_block"):

@@ -31,7 +31,10 @@ from analytics_zoo_tpu.pipeline.api.keras.layers.decoder_lm import (  # noqa: E4
     DecoderLM, GQAttention, Mamba2Mixer, moe_counters, next_token_loss,
     relu_squared)
 from test_attention import pallas_kernels                       # noqa: E402
-from test_decoder_lm import _flat, _tree                        # noqa: E402
+from analytics_zoo_tpu.pipeline.api.keras.layers import decoder_lm  # noqa: E402
+from test_attention import equations                            # noqa: E402
+from test_decoder_lm import (                                   # noqa: E402
+    _bit_equal, _flat, _tree, remat_block_sides, remat_kept_bytes)
 
 CFG = dict(
     model_type="nemotron_h", vocab_size=96, hidden_size=32,
@@ -170,6 +173,50 @@ def test_a_training_forward_moves_bias_and_counters(sides):
 
 
 # --- the scan ------------------------------------------------------------------
+
+def test_an_expert_block_keeps_its_shared_experts_up_across_the_remat():
+    """An ``E`` block under its policy keeps its shared expert's up product
+    (relu^2 of it is what ``down_proj`` reads): one more saved value of
+    (batch, seq, shared width), one product fewer in the gradient's program,
+    ``ffn_up`` not marked again in the backward pass, and the gradients of
+    the policy that does not keep it, to the bit."""
+    model = DecoderLM.from_config(CFG)
+    fields = dict(kind="E", sizes=dict(model.experts),
+                  eps=model.rms_norm_eps, dtype=model.dtype)
+    shape = (2, SEQ, CFG["hidden_size"])
+    before = remat_block_sides(decoder_lm.MixerBlock, fields, shape,
+                               decoder_lm._KEPT_ACROSS_REMAT)
+    now = remat_block_sides(decoder_lm.MixerBlock, fields, shape,
+                            decoder_lm._KEPT_WITH_FFN_PRODUCTS)
+    assert now["dots"] == before["dots"] - 1
+    product = ((2, SEQ, CFG["moe_shared_expert_intermediate_size"]),
+               jnp.dtype(jnp.float32))
+    assert now["saved"].count(product) == before["saved"].count(product) + 1
+    assert "ffn_up" in before["recomputed"]
+    assert "ffn_up" not in now["recomputed"]
+    assert _bit_equal(now["grads"], before["grads"])
+
+
+def test_the_gauge_reads_the_shared_experts_up_a_step_keeps(sides):
+    """``zoo_remat_kept_bytes`` after a gradient's trace: under ``ffn_up``
+    the shared expert's up product of every ``E`` block (two in the pattern,
+    one in the MTP module's), under ``ffn_gate`` nothing (its experts are
+    ungated); every block, Mamba-2 and attention too, under one policy."""
+    jaxpr = jax.make_jaxpr(jax.grad(lambda p: sides["loss_of"](p)[0]))(
+        _tree(sides["weights"])).jaxpr
+    assert {id(e.params["policy"]) for e in equations(jaxpr)
+            if e.primitive.name == "remat2"} == \
+        {id(decoder_lm._KEPT_WITH_FFN_PRODUCTS)}
+    kept = remat_kept_bytes()
+    blocks = (CFG["hybrid_override_pattern"][:CFG["num_hidden_layers"]]
+              + CFG["mtp_hybrid_override_pattern"]).count("E")
+    assert blocks == 3
+    assert kept["ffn_up"] == blocks * 2 * SEQ * \
+        CFG["moe_shared_expert_intermediate_size"] * 4
+    assert kept["ffn_gate"] == 0
+    assert kept["moe_router_choice"] == blocks * 2 * SEQ * \
+        CFG["num_experts_per_tok"] * 4
+
 
 def scan_inputs(seed, bsz=2, seq=48, h=4, p=8, g=2, n=16):
     rs = np.random.RandomState(seed)
