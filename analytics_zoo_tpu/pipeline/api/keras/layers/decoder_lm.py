@@ -32,6 +32,10 @@ it names none (:meth:`DecoderLM.from_config`):
   the clean copy of each sequence side by side under the block-diffusion
   mask, and scores the masked positions of the noisy copy.
 
+A family's reader turns its config into a per-layer plan, one :class:`Layer`
+a block (its sublayers with their sizes and norms, the residual scale, the
+remat policy), and one :class:`Block` builds every entry.
+
 Trained through the estimator like any other module::
 
     model = DecoderLM.from_config(cfg)
@@ -117,9 +121,10 @@ import contextlib
 import functools
 import math
 import threading
-from typing import Any, Callable, Dict, Optional
+from typing import Any, Callable, Dict, NamedTuple, Optional
 
 import flax.linen as nn
+from flax.core import FrozenDict
 import jax
 import jax.numpy as jnp
 from jax.ad_checkpoint import checkpoint_name
@@ -302,9 +307,6 @@ class GQAttention(nn.Module):
             if self.gated:
                 out = out * jax.nn.sigmoid(gate)
             return _dense(hidden, self.dtype, "o_proj")(out)
-
-
-_ATTENTION = {"mla": MLAttention, "gqa": GQAttention}
 
 
 def relu_squared(x):
@@ -552,19 +554,24 @@ class _KeepNames:
         return keep
 
 
-# one policy object for every block of a kind: blocks whose remat parameters
-# are the same object share one lowered function, as blocks under a plain
-# remat do. Every block keeps the flash forward kernel's two results and an
-# expert layer's routing decision ((tokens, top_k) int32: the backward pass
-# selects nothing)
+# Two policies, each ONE object: blocks whose remat parameters are the same
+# object share one lowered function, as blocks under a plain remat do. Each
+# family's reader gives its blocks one of them. Both keep the flash forward
+# kernel's two results, the attention output (B*S*H*d_v values of the compute
+# dtype) and its logsumexp ((B*H, S, 1) floats): 134 MB and 2 MB a block at 2
+# x 8192 positions, 32 heads, v of 128, bfloat16, so the backward pass runs
+# dQ and dK/dV on the first launch's results and not the forward kernel a
+# second time. Both keep an expert layer's routing decision ((tokens, top_k)
+# int32, 0.5 MB a block at 16384 rows, top-8: the backward pass selects
+# nothing). Everything else of a block is rebuilt in the backward pass.
 _KEPT_ACROSS_REMAT = _KeepNames(*FLASH_RESIDUAL_NAMES, ROUTER_CHOICE_NAME)
-# The hybrid families' blocks (MixerMLPBlock, MixerBlock) keep their
+# The hybrid families' blocks (nemotron_h, granitemoehybrid) keep their
 # SwiGLU's first products too, so the backward pass does not run gate_proj
 # and up_proj again (Granite: 2 x 134 MB a block at 8192 tokens; Nemotron's
-# shared experts: 88 MB an E block). The DecoderBlock families do not: their
-# steps use 86-91% of a v5e's memory, and their only SwiGLUs (a dense first
-# layer's FFN, the shared experts) would give back ~1% of the step. Revisit
-# once their memory is freed.
+# shared experts: 88 MB an E block). The other families' blocks do not:
+# their steps use 86-91% of a v5e's memory, and their only SwiGLUs (a dense
+# first layer's FFN, the shared experts) would give back ~1% of the step.
+# Revisit once their memory is freed.
 _KEPT_WITH_FFN_PRODUCTS = _KeepNames(
     *FLASH_RESIDUAL_NAMES, ROUTER_CHOICE_NAME, *FFN_PRODUCT_NAMES)
 
@@ -585,86 +592,82 @@ def _remat_tally():
             _REMAT_KEPT_BYTES.labels(name=name).set(tally.get(name, 0))
 
 
-class DecoderBlock(nn.Module):
-    attention: Dict[str, Any]            # its "kind" picks the module
-    ffn_width: int                       # the dense block's; 0 for experts
-    experts: Optional[Dict[str, Any]]
+# a block's sublayers by kind: the module, and the name it takes in the tree
+# (the expert layer's is the one the counters read)
+_SUBLAYERS = {"mla": (MLAttention, "self_attn"),
+              "gqa": (GQAttention, "self_attn"),
+              "mamba2": (Mamba2Mixer, "mixer"),
+              "experts": (SparseExperts, "mlp"),
+              "swiglu": (SwiGLU, "mlp")}
+
+
+class Sublayer(NamedTuple):
+    """One sublayer of a block: ``kind`` a key of ``_SUBLAYERS``, ``sizes``
+    that module's fields (a FrozenDict), ``norm`` the name of the RMSNorm on
+    its input, ``post_norm`` that of one on its output (sandwich norms),
+    ``scope`` a named scope around the module."""
+    kind: str
+    sizes: Any
+    norm: str
+    post_norm: Optional[str] = None
+    scope: Optional[str] = None
+
+
+class Layer(NamedTuple):
+    """One entry of a ``DecoderLM``'s plan: its sublayers (:class:`Sublayer`)
+    in order, the remat ``policy`` object its block runs under, and the
+    ``residual_scale`` of every branch."""
+    sublayers: tuple
+    policy: _KeepNames
+    residual_scale: float = 1.0
+
+
+def _two_sublayers(mixer, ffn, policy: _KeepNames, sandwich: bool = False,
+                   residual_scale: float = 1.0,
+                   ffn_scope: Optional[str] = None) -> Layer:
+    """A mixer then a feed-forward, each ``(kind, sizes)``, behind the
+    pre-norms ``input_layernorm`` and ``post_attention_layernorm``, or behind
+    sandwich norms: ``input_layernorm`` and ``pre_mlp_layernorm`` before,
+    ``post_attention_layernorm`` and ``post_mlp_layernorm`` after."""
+    if sandwich:
+        return Layer((Sublayer(*mixer, "input_layernorm",
+                               "post_attention_layernorm"),
+                      Sublayer(*ffn, "pre_mlp_layernorm", "post_mlp_layernorm",
+                               ffn_scope)), policy, residual_scale)
+    return Layer((Sublayer(*mixer, "input_layernorm"),
+                  Sublayer(*ffn, "post_attention_layernorm", None, ffn_scope)),
+                 policy, residual_scale)
+
+
+class Block(nn.Module):
+    """A plan's :class:`Layer`: for each sublayer in turn ``x = x + s
+    N_post(Sub(N(x)))`` (``N_post`` where it has one), ``s`` the residual
+    scale, applied in float32 where it is not 1. ``window`` is the
+    attention's (``DecoderLM.layer_windows``; None: no window)."""
+    layer: Layer
     eps: float
-    sandwich_norms: bool = False         # a norm after each sublayer too
+    window: Optional[int] = None
     dtype: Any = jnp.bfloat16
 
     @nn.compact
     def __call__(self, x):
         norm = functools.partial(RMSNorm, self.eps, self.dtype)
-        sizes = dict(self.attention)
-        attend = _ATTENTION[sizes.pop("kind", "mla")](
-            eps=self.eps, dtype=self.dtype, name="self_attn", **sizes)
-        a = attend(norm(name="input_layernorm")(x))
-        if self.sandwich_norms:
-            x = x + norm(name="post_attention_layernorm")(a)
-            h = norm(name="pre_mlp_layernorm")(x)
-        else:
-            x = x + a
-            h = norm(name="post_attention_layernorm")(x)
-        if self.experts is None:
-            y = SwiGLU(self.ffn_width, self.dtype, name="mlp")(h)
-        else:
-            y = SparseExperts(dtype=self.dtype, name="mlp",
-                              **self.experts)(h)
-        if self.sandwich_norms:
-            y = norm(name="post_mlp_layernorm")(y)
-        return x + y
-
-
-# a ``nemotron_h`` block's one mixer by its letter in the pattern, and the
-# name it takes in the tree (the expert layer's is the one the counters and
-# the other families' trees have)
-_MIXERS = {"M": (Mamba2Mixer, "mixer"), "*": (GQAttention, "self_attn"),
-           "E": (SparseExperts, "mlp")}
-
-
-class MixerBlock(nn.Module):
-    """``x + Mixer(RMSNorm(x))``: ``kind`` a key of ``_MIXERS``, ``sizes``
-    that module's."""
-    kind: str
-    sizes: Dict[str, Any]
-    eps: float
-    dtype: Any = jnp.bfloat16
-
-    @nn.compact
-    def __call__(self, x):
-        module, name = _MIXERS[self.kind]
-        return x + module(dtype=self.dtype, name=name, **self.sizes)(
-            RMSNorm(self.eps, self.dtype, name="norm")(x))
-
-
-class MixerMLPBlock(nn.Module):
-    """``x + s Mixer(RMSNorm(x))``, then ``x + s MLP(RMSNorm(x))``: ``kind``
-    a mixer's key of ``_MIXERS``, ``sizes`` that module's, the MLP a SwiGLU
-    of ``mlp_width`` (under ``mlp.dense``), ``s`` the ``residual_scale``
-    applied in float32."""
-    kind: str
-    sizes: Dict[str, Any]
-    mlp_width: int
-    residual_scale: float
-    eps: float
-    dtype: Any = jnp.bfloat16
-
-    @nn.compact
-    def __call__(self, x):
-        module, name = _MIXERS[self.kind]
-        norm = functools.partial(RMSNorm, self.eps, self.dtype)
-
-        def branch(y):
-            return (y.astype(jnp.float32)
-                    * self.residual_scale).astype(self.dtype)
-
-        x = x + branch(module(dtype=self.dtype, name=name, **self.sizes)(
-            norm(name="input_layernorm")(x)))
-        h = norm(name="post_attention_layernorm")(x)
-        with jax.named_scope("mlp.dense"):
-            y = SwiGLU(self.mlp_width, self.dtype, name="mlp")(h)
-        return x + branch(y)
+        scale = self.layer.residual_scale
+        for sub in self.layer.sublayers:
+            module, name = _SUBLAYERS[sub.kind]
+            sizes = dict(sub.sizes)
+            if self.window is not None and module is GQAttention:
+                sizes["window"] = self.window
+            h = norm(name=sub.norm)(x)
+            with jax.named_scope(sub.scope) if sub.scope else \
+                    contextlib.nullcontext():
+                y = module(dtype=self.dtype, name=name, **sizes)(h)
+            if sub.post_norm:
+                y = norm(name=sub.post_norm)(y)
+            if scale != 1.0:
+                y = (y.astype(jnp.float32) * scale).astype(self.dtype)
+            x = x + y
+        return x
 
 
 class DecoderLM(nn.Module):
@@ -675,44 +678,35 @@ class DecoderLM(nn.Module):
     module ``mtp_logits`` is None. Trained by block diffusion
     (``diffusion_block``) it returns ``(logits, m / p)`` instead: the noisy
     copy's logits, which score each position's own token, and the loss's
-    weight of each position (:func:`block_diffusion_loss`)."""
+    weight of each position (:func:`block_diffusion_loss`).
+
+    ``layers`` is the plan, one :class:`Layer` a block (``layers_{i}`` in the
+    tree); ``mtp_layers`` the MTP module's, ``(name, Layer)`` pairs (empty:
+    no MTP module). A family's reader (``_FIELDS``) writes both."""
     vocab_size: int
     hidden_size: int
-    num_hidden_layers: int
-    first_k_dense_replace: int
-    intermediate_size: int
-    attention: Any                       # FrozenDict of the attention's sizes
-    experts: Any                         # FrozenDict of SparseExperts' sizes
-    num_nextn_predict_layers: int = 0
+    layers: tuple
+    mtp_layers: tuple = ()
+    # each layer's attention window (None: a global layer; None overall: no
+    # layer has one)
+    layer_windows: Optional[tuple] = None
     rms_norm_eps: float = 1e-6
     dtype: Any = jnp.bfloat16
     mtp_loss_weight: float = 0.3
-    # the afmoe family's: each layer's window (None: a global layer), a norm
-    # after each sublayer, the embedding's scale
-    layer_windows: Optional[tuple] = None
-    sandwich_norms: bool = False
+    # the embedding's scale, the logits' divisor; the head tied to the
+    # embedding (no ``lm_head`` leaf: one leaf takes both gradients)
     embed_scale: float = 1.0
-    # the nemotron_h family's: each block's one mixer by its letter (M, *,
-    # E), the MTP module's blocks likewise, the Mamba-2 mixer's sizes
-    layer_kinds: Optional[tuple] = None
-    mtp_kinds: tuple = ()
-    mamba: Any = None
-    # the granitemoehybrid family's: an MLP of this width after each block's
-    # mixer, both branches scaled; the logits' divisor; the head tied to
-    # the embedding (no ``lm_head`` leaf: one leaf takes both gradients)
-    block_mlp_width: int = 0
-    residual_scale: float = 1.0
     logits_scale: float = 1.0
     tie_word_embeddings: bool = False
-    # the sdar_moe family's: trained as a block-diffusion LM over blocks of
-    # this many tokens (0: next-token prediction), the noise's floor and
-    # seed (the ids' embedding has one more row, the MASK token's)
+    # trained as a block-diffusion LM over blocks of this many tokens (0:
+    # next-token prediction), the noise's floor and seed (the ids' embedding
+    # has one more row, the MASK token's)
     diffusion_block: int = 0
     noise_eps: float = 1e-3
     noise_seed: int = 0
 
     @classmethod
-    def from_config(cls, cfg: Dict[str, Any], **overrides) -> "DecoderLM":
+    def from_config(cls, cfg: Dict[str, Any]) -> "DecoderLM":
         """From a ``config.json`` of one of the five families, chosen by its
         ``model_type`` (``MODEL_TYPES``); a config that names none is read
         by its keys (``hybrid_override_pattern``: ``nemotron_h``;
@@ -731,59 +725,11 @@ class DecoderLM(nn.Module):
                 "afmoe" if "layer_types" in cfg else "deepseek_v3"
         for name, types in MODEL_TYPES.items():
             if family in types:
-                break
-        else:
-            raise ValueError(
-                f"model_type {family!r} is none of DecoderLM's five "
-                f"families: " + "; ".join(
-                    f"{name} ({', '.join(types)})"
-                    for name, types in MODEL_TYPES.items()))
-        if name != "deepseek_v3":
-            return cls(**dict(_FIELDS[name](cfg), **overrides))
-        if cfg.get("scoring_func", "sigmoid") != "sigmoid" or \
-                cfg.get("topk_method", "noaux_tc") != "noaux_tc" or \
-                cfg.get("n_group", 1) != 1 or not cfg.get("norm_topk_prob",
-                                                          True):
-            raise ValueError("DecoderLM routes by sigmoid scores, noaux_tc, "
-                             "one group, renormalised gates")
-        if not cfg.get("rope_interleave", True) or cfg.get("rope_scaling"):
-            raise ValueError("DecoderLM applies interleaved RoPE with no "
-                             "scaling")
-        from flax.core import FrozenDict
-        e = int(cfg["n_routed_experts"])
-        attention = FrozenDict(
-            num_heads=int(cfg["num_attention_heads"]),
-            q_lora_rank=int(cfg["q_lora_rank"]),
-            kv_lora_rank=int(cfg["kv_lora_rank"]),
-            qk_nope_head_dim=int(cfg["qk_nope_head_dim"]),
-            qk_rope_head_dim=int(cfg["qk_rope_head_dim"]),
-            v_head_dim=int(cfg["v_head_dim"]),
-            rope_theta=float(cfg["rope_theta"]))
-        experts = FrozenDict(
-            n_routed_experts=e,
-            experts_held=int(cfg.get("experts_held", e)),
-            first_expert=int(cfg.get("first_expert", 0)),
-            num_experts_per_tok=int(cfg["num_experts_per_tok"]),
-            moe_intermediate_size=int(cfg["moe_intermediate_size"]),
-            n_shared_experts=int(cfg.get("n_shared_experts", 0)),
-            routed_scaling_factor=float(cfg.get("routed_scaling_factor", 1.0)),
-            bias_update_rate=float(cfg.get("bias_update_rate", 1e-3)))
-        kw = dict(
-            vocab_size=int(cfg["vocab_size"]),
-            hidden_size=int(cfg["hidden_size"]),
-            num_hidden_layers=int(cfg["num_hidden_layers"]),
-            first_k_dense_replace=int(cfg.get("first_k_dense_replace", 0)),
-            intermediate_size=int(cfg["intermediate_size"]),
-            attention=attention, experts=experts,
-            num_nextn_predict_layers=int(
-                cfg.get("num_nextn_predict_layers", 0)),
-            rms_norm_eps=float(cfg.get("rms_norm_eps", 1e-6)),
-            dtype=jnp.dtype(cfg.get("compute_dtype", "bfloat16")),
-            mtp_loss_weight=float(cfg.get("mtp_loss_weight", 0.3)))
-        kw.update(overrides)
-        if kw["num_nextn_predict_layers"] not in (0, 1):
-            raise ValueError("DecoderLM has an MTP module of depth 0 or 1")
-        return cls(**kw)
+                return cls(**_FIELDS[name](cfg))
+        raise ValueError(
+            f"model_type {family!r} is none of DecoderLM's five families: "
+            + "; ".join(f"{name} ({', '.join(types)})"
+                        for name, types in MODEL_TYPES.items()))
 
     def loss(self) -> Callable:
         """The loss to hand the estimator beside this module."""
@@ -819,43 +765,10 @@ class DecoderLM(nn.Module):
         return jnp.concatenate([noisy, ids], axis=1), \
             masked.astype(jnp.float32) / p
 
-    def _mixer_block(self, kind: str, name: str):
-        """A rematerialised one-mixer block, every one under the policy
-        object ``_KEPT_WITH_FFN_PRODUCTS``: an attention block keeps its
-        flash results, an expert block its routing decision and its shared
-        expert's up product, a block with an MLP (``granitemoehybrid``) the
-        MLP's gate and up products; of a Mamba-2 mixer nothing is kept."""
-        sizes = {"M": self.mamba, "*": self.attention, "E": self.experts}
-        if self.block_mlp_width:
-            return nn.remat(MixerMLPBlock, policy=_KEPT_WITH_FFN_PRODUCTS)(
-                kind=kind, sizes=dict(sizes[kind]),
-                mlp_width=self.block_mlp_width,
-                residual_scale=self.residual_scale, eps=self.rms_norm_eps,
-                dtype=self.dtype, name=name)
-        return nn.remat(MixerBlock, policy=_KEPT_WITH_FFN_PRODUCTS)(
-            kind=kind, sizes=dict(sizes[kind]), eps=self.rms_norm_eps,
-            dtype=self.dtype, name=name)
-
-    def _block(self, moe: bool, name: str, layer: Optional[int] = None):
-        """A rematerialised block that keeps the flash forward kernel's two
-        results across the step: the attention output, B*S*H*d_v values of
-        the compute dtype, and the logsumexp, a (B*H, S, 1) column of floats
-        (134 MB and 2 MB of values a block at 2 x 8192 positions, 32 heads,
-        v of 128, bfloat16; the step's peak on a v5e fell by 0.11 GB), so
-        the backward pass runs dQ and dK/dV on the first launch's results
-        and not the forward kernel a second time; an expert layer's choice
-        of experts is kept too (0.5 MB a block at 16384 rows, top-8).
-        Everything else of the block is rebuilt in the backward pass as
-        before: norms, projections, RoPE (so q, k and v), the router's
-        scores, the expert layer, the dense FFN."""
-        attention = dict(self.attention)
-        if self.layer_windows is not None:
-            attention["window"] = self.layer_windows[layer]
-        return nn.remat(DecoderBlock, policy=_KEPT_ACROSS_REMAT)(
-            attention=attention,
-            ffn_width=0 if moe else self.intermediate_size,
-            experts=dict(self.experts) if moe else None,
-            eps=self.rms_norm_eps, sandwich_norms=self.sandwich_norms,
+    def _block(self, layer: Layer, name: str, window: Optional[int] = None):
+        """``layer``'s block, rematerialised under its policy object."""
+        return nn.remat(Block, policy=layer.policy)(
+            layer=layer, eps=self.rms_norm_eps, window=window,
             dtype=self.dtype, name=name)
 
     @keras_call
@@ -905,17 +818,14 @@ class DecoderLM(nn.Module):
             x = embed(ids)
         if self.embed_scale != 1.0:
             x = x * jnp.asarray(self.embed_scale, x.dtype)
-        for i in range(self.num_hidden_layers):
-            if self.layer_kinds is not None:
-                x = self._mixer_block(self.layer_kinds[i], f"layers_{i}")(x)
-            else:
-                x = self._block(i >= self.first_k_dense_replace,
-                                f"layers_{i}", i)(x)
+        windows = self.layer_windows or (None,) * len(self.layers)
+        for i, (layer, window) in enumerate(zip(self.layers, windows)):
+            x = self._block(layer, f"layers_{i}", window)(x)
         if self.diffusion_block:
             # the noisy half's logits; the loss's weights beside them
             return logits_of(norm(name="norm")(x[:, :length])), weight
         logits = logits_of(norm(name="norm")(x))
-        if not self.num_nextn_predict_layers:
+        if not self.mtp_layers:
             return logits, None
         with jax.named_scope("mtp"):
             nxt = jnp.pad(ids[:, 1:], ((0, 0), (0, 1)))
@@ -923,13 +833,64 @@ class DecoderLM(nn.Module):
                 [norm(name="mtp_hnorm")(x), norm(name="mtp_enorm")(embed(nxt))],
                 axis=-1)
             h = _dense(self.hidden_size, self.dtype, "mtp_eh_proj")(merged)
-            if self.layer_kinds is not None:
-                for i, kind in enumerate(self.mtp_kinds):
-                    h = self._mixer_block(kind, f"mtp_layers_{i}")(h)
-            else:
-                h = self._block(True, "mtp_block")(h)
+            for name, layer in self.mtp_layers:
+                h = self._block(layer, name)(h)
             mtp_logits = logits_of(norm(name="mtp_norm")(h))
         return logits, mtp_logits
+
+
+def _deepseek_fields(cfg: Dict[str, Any]) -> Dict[str, Any]:
+    """``DecoderLM``'s fields from the DeepSeek-V3 family's ``config.json``
+    keys (``first_k_dense_replace``, ``n_routed_experts``, ``q_lora_rank``,
+    ``kv_lora_rank``, ``qk_nope_head_dim``, ``qk_rope_head_dim``,
+    ``v_head_dim``, ``num_nextn_predict_layers``, ...) plus this repo's
+    ``experts_held`` / ``first_expert`` / ``bias_update_rate`` /
+    ``mtp_loss_weight`` / ``compute_dtype``: ``first_k_dense_replace`` dense
+    blocks, then expert blocks, and an MTP module of one expert block where
+    ``num_nextn_predict_layers`` is 1."""
+    if cfg.get("scoring_func", "sigmoid") != "sigmoid" or \
+            cfg.get("topk_method", "noaux_tc") != "noaux_tc" or \
+            cfg.get("n_group", 1) != 1 or not cfg.get("norm_topk_prob", True):
+        raise ValueError("DecoderLM routes by sigmoid scores, noaux_tc, "
+                         "one group, renormalised gates")
+    if not cfg.get("rope_interleave", True) or cfg.get("rope_scaling"):
+        raise ValueError("DecoderLM applies interleaved RoPE with no "
+                         "scaling")
+    mtp = int(cfg.get("num_nextn_predict_layers", 0))
+    if mtp not in (0, 1):
+        raise ValueError("DecoderLM has an MTP module of depth 0 or 1")
+    eps = float(cfg.get("rms_norm_eps", 1e-6))
+    e = int(cfg["n_routed_experts"])
+    attention = ("mla", FrozenDict(
+        num_heads=int(cfg["num_attention_heads"]),
+        q_lora_rank=int(cfg["q_lora_rank"]),
+        kv_lora_rank=int(cfg["kv_lora_rank"]),
+        qk_nope_head_dim=int(cfg["qk_nope_head_dim"]),
+        qk_rope_head_dim=int(cfg["qk_rope_head_dim"]),
+        v_head_dim=int(cfg["v_head_dim"]),
+        rope_theta=float(cfg["rope_theta"]), eps=eps))
+    experts = ("experts", FrozenDict(
+        n_routed_experts=e,
+        experts_held=int(cfg.get("experts_held", e)),
+        first_expert=int(cfg.get("first_expert", 0)),
+        num_experts_per_tok=int(cfg["num_experts_per_tok"]),
+        moe_intermediate_size=int(cfg["moe_intermediate_size"]),
+        n_shared_experts=int(cfg.get("n_shared_experts", 0)),
+        routed_scaling_factor=float(cfg.get("routed_scaling_factor", 1.0)),
+        bias_update_rate=float(cfg.get("bias_update_rate", 1e-3))))
+    dense, moe = (_two_sublayers(attention, ffn, _KEPT_ACROSS_REMAT) for ffn
+                  in (("swiglu", FrozenDict(width=int(
+                      cfg["intermediate_size"]))), experts))
+    k = int(cfg.get("first_k_dense_replace", 0))
+    return dict(
+        vocab_size=int(cfg["vocab_size"]),
+        hidden_size=int(cfg["hidden_size"]),
+        layers=tuple(dense if i < k else moe
+                     for i in range(int(cfg["num_hidden_layers"]))),
+        mtp_layers=(("mtp_block", moe),) if mtp else (),
+        rms_norm_eps=eps,
+        dtype=jnp.dtype(cfg.get("compute_dtype", "bfloat16")),
+        mtp_loss_weight=float(cfg.get("mtp_loss_weight", 0.3)))
 
 
 def _afmoe_fields(cfg: Dict[str, Any]) -> Dict[str, Any]:
@@ -939,7 +900,9 @@ def _afmoe_fields(cfg: Dict[str, Any]) -> Dict[str, Any]:
     ``head_dim``, ``route_scale``, ``load_balance_coeff``, ``mup_enabled``,
     ...) plus this repo's ``experts_held`` / ``first_expert`` /
     ``compute_dtype``. ``layer_types`` names each layer's attention; the
-    first ``num_hidden_layers`` entries are used."""
+    first ``num_hidden_layers`` entries are used. Every block has sandwich
+    norms; the first ``num_dense_layers`` a dense FFN, the rest the expert
+    layer."""
     if cfg.get("score_func", "sigmoid") != "sigmoid" or \
             not cfg.get("route_norm", True) or cfg.get("n_group", 1) != 1:
         raise ValueError("DecoderLM routes by sigmoid scores, one group, "
@@ -947,7 +910,6 @@ def _afmoe_fields(cfg: Dict[str, Any]) -> Dict[str, Any]:
     if cfg.get("rope_scaling") or cfg.get("tie_word_embeddings"):
         raise ValueError("DecoderLM applies RoPE with no scaling and keeps "
                          "an untied head")
-    from flax.core import FrozenDict
     layers = int(cfg["num_hidden_layers"])
     kinds = tuple(cfg["layer_types"])[:layers]
     if len(kinds) != layers or \
@@ -956,31 +918,34 @@ def _afmoe_fields(cfg: Dict[str, Any]) -> Dict[str, Any]:
                          f"sliding_attention or full_attention, got {kinds}")
     hidden, e = int(cfg["hidden_size"]), int(cfg["num_experts"])
     heads = int(cfg["num_attention_heads"])
+    eps = float(cfg.get("rms_norm_eps", 1e-5))
+    attention = ("gqa", FrozenDict(
+        num_heads=heads,
+        num_kv_heads=int(cfg.get("num_key_value_heads", heads)),
+        head_dim=int(cfg.get("head_dim", hidden // heads)),
+        rope_theta=float(cfg["rope_theta"]), eps=eps))
+    experts = ("experts", FrozenDict(
+        n_routed_experts=e,
+        experts_held=int(cfg.get("experts_held", e)),
+        first_expert=int(cfg.get("first_expert", 0)),
+        num_experts_per_tok=int(cfg["num_experts_per_tok"]),
+        moe_intermediate_size=int(cfg["moe_intermediate_size"]),
+        n_shared_experts=int(cfg.get("num_shared_experts", 0)),
+        routed_scaling_factor=float(cfg.get("route_scale", 1.0)),
+        bias_update_rate=float(cfg.get("load_balance_coeff", 1e-3))))
+    dense, moe = (_two_sublayers(attention, ffn, _KEPT_ACROSS_REMAT,
+                                 sandwich=True) for ffn
+                  in (("swiglu", FrozenDict(width=int(
+                      cfg["intermediate_size"]))), experts))
+    k = int(cfg.get("num_dense_layers", 0))
     return dict(
         vocab_size=int(cfg["vocab_size"]), hidden_size=hidden,
-        num_hidden_layers=layers,
-        first_k_dense_replace=int(cfg.get("num_dense_layers", 0)),
-        intermediate_size=int(cfg["intermediate_size"]),
-        attention=FrozenDict(
-            kind="gqa", num_heads=heads,
-            num_kv_heads=int(cfg.get("num_key_value_heads", heads)),
-            head_dim=int(cfg.get("head_dim", hidden // heads)),
-            rope_theta=float(cfg["rope_theta"])),
-        experts=FrozenDict(
-            n_routed_experts=e,
-            experts_held=int(cfg.get("experts_held", e)),
-            first_expert=int(cfg.get("first_expert", 0)),
-            num_experts_per_tok=int(cfg["num_experts_per_tok"]),
-            moe_intermediate_size=int(cfg["moe_intermediate_size"]),
-            n_shared_experts=int(cfg.get("num_shared_experts", 0)),
-            routed_scaling_factor=float(cfg.get("route_scale", 1.0)),
-            bias_update_rate=float(cfg.get("load_balance_coeff", 1e-3))),
+        layers=tuple(dense if i < k else moe for i in range(layers)),
         layer_windows=tuple(int(cfg["sliding_window"])
                             if kind == "sliding_attention" else None
                             for kind in kinds),
-        sandwich_norms=True,
         embed_scale=math.sqrt(hidden) if cfg.get("mup_enabled") else 1.0,
-        rms_norm_eps=float(cfg.get("rms_norm_eps", 1e-5)),
+        rms_norm_eps=eps,
         dtype=jnp.dtype(cfg.get("compute_dtype", "bfloat16")))
 
 
@@ -990,12 +955,12 @@ def _nemotron_h_fields(cfg: Dict[str, Any]) -> Dict[str, Any]:
     ``mamba_head_dim``, ``ssm_state_size``, ``n_groups``, ``conv_kernel``,
     ``chunk_size``, ``moe_latent_size``, ``moe_shared_expert_intermediate_size``,
     ``mtp_hybrid_override_pattern``, ...) plus this repo's share keys. The
-    pattern names each layer's mixer; the first ``num_hidden_layers`` letters
-    are used. ``mixer_parallel_size`` t gives every Mamba-2 mixer ``1/t`` of
-    its heads and of its B/C and norm groups, every attention layer ``1/t``
-    of its query heads and the kv heads they read (one where t exceeds
-    them); ``mixer_parallel_rank`` says which: it picks a checkpoint's
-    columns and rows, never a shape."""
+    pattern names each layer's one mixer; the first ``num_hidden_layers``
+    letters are used. ``mixer_parallel_size`` t gives every Mamba-2 mixer
+    ``1/t`` of its heads and of its B/C and norm groups, every attention
+    layer ``1/t`` of its query heads and the kv heads they read (one where t
+    exceeds them); ``mixer_parallel_rank`` says which: it picks a
+    checkpoint's columns and rows, never a shape."""
     if cfg.get("n_group", 1) != 1 or not cfg.get("norm_topk_prob", True):
         raise ValueError("DecoderLM routes by sigmoid scores, one group, "
                          "renormalised gates")
@@ -1008,13 +973,12 @@ def _nemotron_h_fields(cfg: Dict[str, Any]) -> Dict[str, Any]:
             cfg.get("use_bias") or not cfg.get("use_conv_bias", True):
         raise ValueError("DecoderLM keeps an untied head and no bias but "
                          "the convolution's")
-    from flax.core import FrozenDict
     layers = int(cfg["num_hidden_layers"])
     kinds = tuple(cfg["hybrid_override_pattern"])[:layers]
     mtp = int(cfg.get("num_nextn_predict_layers", 0))
     mtp_kinds = tuple(cfg.get("mtp_hybrid_override_pattern", "")) if mtp \
         else ()
-    if len(kinds) != layers or set(kinds + mtp_kinds) - set(_MIXERS):
+    if len(kinds) != layers or set(kinds + mtp_kinds) - set("M*E"):
         raise ValueError(f"hybrid_override_pattern names {layers} layers by "
                          f"M, * or E, got {''.join(kinds)!r} (MTP "
                          f"{''.join(mtp_kinds)!r})")
@@ -1034,23 +998,18 @@ def _nemotron_h_fields(cfg: Dict[str, Any]) -> Dict[str, Any]:
             f"{m_heads} Mamba-2 heads in {groups} groups and {heads} query "
             f"heads over {kv} kv heads into whole shares")
     eps = float(cfg.get("layer_norm_epsilon", cfg.get("norm_eps", 1e-5)))
-    return dict(
-        vocab_size=int(cfg["vocab_size"]), hidden_size=hidden,
-        num_hidden_layers=layers, first_k_dense_replace=0,
-        intermediate_size=int(cfg.get("intermediate_size", 0)),
-        layer_kinds=kinds, mtp_kinds=mtp_kinds,
-        num_nextn_predict_layers=mtp,
-        mamba=FrozenDict(
+    mixers = {
+        "M": ("mamba2", FrozenDict(
             num_heads=m_heads // t, head_dim=int(cfg["mamba_head_dim"]),
             n_groups=groups // t, state_size=int(cfg["ssm_state_size"]),
             conv_kernel=int(cfg["conv_kernel"]),
-            chunk_size=int(cfg["chunk_size"]), eps=eps),
-        attention=FrozenDict(
+            chunk_size=int(cfg["chunk_size"]), eps=eps)),
+        "*": ("gqa", FrozenDict(
             num_heads=heads // t, num_kv_heads=max(kv // t, 1),
             head_dim=int(cfg.get("head_dim", hidden // heads)),
             rope_theta=float(cfg.get("rope_theta", 10000.0)),
-            eps=eps, gated=False, qk_norm=False),
-        experts=FrozenDict(
+            eps=eps, gated=False, qk_norm=False)),
+        "E": ("experts", FrozenDict(
             n_routed_experts=e,
             experts_held=int(cfg.get("experts_held", e)),
             first_expert=int(cfg.get("first_expert", 0)),
@@ -1063,7 +1022,16 @@ def _nemotron_h_fields(cfg: Dict[str, Any]) -> Dict[str, Any]:
             latent_size=int(cfg.get("moe_latent_size") or 0),
             activation="relu2", gated=False,
             shared_width=int(cfg.get("moe_shared_expert_intermediate_size",
-                                     0))),
+                                     0))))}
+    # every block ONE mixer behind one norm
+    block = {letter: Layer((Sublayer(*mixer, "norm"),),
+                           _KEPT_WITH_FFN_PRODUCTS)
+             for letter, mixer in mixers.items()}
+    return dict(
+        vocab_size=int(cfg["vocab_size"]), hidden_size=hidden,
+        layers=tuple(block[k] for k in kinds),
+        mtp_layers=tuple((f"mtp_layers_{i}", block[k])
+                         for i, k in enumerate(mtp_kinds)),
         rms_norm_eps=eps,
         dtype=jnp.dtype(cfg.get("compute_dtype", "bfloat16")),
         mtp_loss_weight=float(cfg.get("mtp_loss_weight", 0.3)))
@@ -1077,7 +1045,9 @@ def _granite_fields(cfg: Dict[str, Any]) -> Dict[str, Any]:
     ``embedding_multiplier``, ``residual_multiplier``,
     ``attention_multiplier``, ``logits_scaling``, ...) plus
     ``compute_dtype``. ``layer_types`` names each layer's mixer; the first
-    ``num_hidden_layers`` entries are used."""
+    ``num_hidden_layers`` entries are used. Every block is its mixer, then a
+    SwiGLU MLP (under ``mlp.dense``), each branch times
+    ``residual_multiplier``."""
     if int(cfg.get("num_local_experts", 0)):
         raise ValueError("DecoderLM builds this family's dense form: no "
                          "expert layer (num_local_experts 0)")
@@ -1091,7 +1061,6 @@ def _granite_fields(cfg: Dict[str, Any]) -> Dict[str, Any]:
     if cfg.get("attention_bias") or cfg.get("mamba_proj_bias") or \
             not cfg.get("mamba_conv_bias", True):
         raise ValueError("DecoderLM keeps no bias but the convolution's")
-    from flax.core import FrozenDict
     layers = int(cfg["num_hidden_layers"])
     kinds = tuple(cfg["layer_types"])[:layers]
     if len(kinds) != layers or set(kinds) - {"mamba", "attention"}:
@@ -1103,27 +1072,29 @@ def _granite_fields(cfg: Dict[str, Any]) -> Dict[str, Any]:
         raise ValueError(f"{m_heads} Mamba-2 heads of {m_dim} are not "
                          f"mamba_expand x hidden_size")
     eps = float(cfg.get("rms_norm_eps", 1e-5))
-    return dict(
-        vocab_size=int(cfg["vocab_size"]), hidden_size=hidden,
-        num_hidden_layers=layers, first_k_dense_replace=0,
-        intermediate_size=0, experts=None,
-        layer_kinds=tuple("M" if k == "mamba" else "*" for k in kinds),
-        mamba=FrozenDict(
+    mixers = {
+        "mamba": ("mamba2", FrozenDict(
             num_heads=m_heads, head_dim=m_dim,
             n_groups=int(cfg["mamba_n_groups"]),
             state_size=int(cfg["mamba_d_state"]),
             conv_kernel=int(cfg["mamba_d_conv"]),
-            chunk_size=int(cfg["mamba_chunk_size"]), eps=eps),
-        attention=FrozenDict(
+            chunk_size=int(cfg["mamba_chunk_size"]), eps=eps)),
+        "attention": ("gqa", FrozenDict(
             num_heads=heads,
             num_kv_heads=int(cfg.get("num_key_value_heads", heads)),
             head_dim=hidden // heads,
             rope_theta=float(cfg.get("rope_theta", 10000.0)),
             eps=eps, gated=False, qk_norm=False,
-            sm_scale=float(cfg["attention_multiplier"])),
-        block_mlp_width=int(cfg["shared_intermediate_size"]),
-        embed_scale=float(cfg["embedding_multiplier"]),
+            sm_scale=float(cfg["attention_multiplier"])))}
+    mlp = ("swiglu", FrozenDict(width=int(cfg["shared_intermediate_size"])))
+    block = {kind: _two_sublayers(
+        mixer, mlp, _KEPT_WITH_FFN_PRODUCTS,
         residual_scale=float(cfg["residual_multiplier"]),
+        ffn_scope="mlp.dense") for kind, mixer in mixers.items()}
+    return dict(
+        vocab_size=int(cfg["vocab_size"]), hidden_size=hidden,
+        layers=tuple(block[k] for k in kinds),
+        embed_scale=float(cfg["embedding_multiplier"]),
         logits_scale=float(cfg["logits_scaling"]),
         tie_word_embeddings=bool(cfg.get("tie_word_embeddings", False)),
         rms_norm_eps=eps,
@@ -1137,7 +1108,7 @@ def _sdar_fields(cfg: Dict[str, Any]) -> Dict[str, Any]:
     plus this repo's ``experts_held`` / ``first_expert`` /
     ``compute_dtype`` and the block-diffusion training's ``block_length``
     (the config gives none: SDAR's default, 4), ``noise_eps`` and
-    ``noise_seed``."""
+    ``noise_seed``. Every block is attention, then the expert layer."""
     if int(cfg.get("decoder_sparse_step", 1)) != 1 or \
             cfg.get("mlp_only_layers"):
         raise ValueError("DecoderLM builds every sdar_moe block with the "
@@ -1151,32 +1122,31 @@ def _sdar_fields(cfg: Dict[str, Any]) -> Dict[str, Any]:
             cfg.get("tie_word_embeddings") or cfg.get("attention_bias"):
         raise ValueError("DecoderLM applies RoPE with no scaling and no "
                          "window, keeps an untied head and no bias")
-    from flax.core import FrozenDict
     hidden, heads = int(cfg["hidden_size"]), int(cfg["num_attention_heads"])
     e = int(cfg["num_experts"])
     block = int(cfg.get("block_length", 4))
     if block < 1 or block & (block - 1):
         raise ValueError(f"block_length {block} is no power of two")
     eps = float(cfg.get("rms_norm_eps", 1e-6))
-    return dict(
-        vocab_size=int(cfg["vocab_size"]), hidden_size=hidden,
-        num_hidden_layers=int(cfg["num_hidden_layers"]),
-        first_k_dense_replace=0,
-        intermediate_size=int(cfg.get("intermediate_size", 0)),
-        attention=FrozenDict(
-            kind="gqa", num_heads=heads,
+    layer = _two_sublayers(
+        ("gqa", FrozenDict(
+            num_heads=heads,
             num_kv_heads=int(cfg.get("num_key_value_heads", heads)),
             head_dim=int(cfg.get("head_dim", hidden // heads)),
-            rope_theta=float(cfg["rope_theta"]), gated=False, qk_norm=True,
-            block_diffusion=block),
-        experts=FrozenDict(
+            rope_theta=float(cfg["rope_theta"]), eps=eps, gated=False,
+            qk_norm=True, block_diffusion=block)),
+        ("experts", FrozenDict(
             n_routed_experts=e,
             experts_held=int(cfg.get("experts_held", e)),
             first_expert=int(cfg.get("first_expert", 0)),
             num_experts_per_tok=int(cfg["num_experts_per_tok"]),
             moe_intermediate_size=int(cfg["moe_intermediate_size"]),
             n_shared_experts=0, routed_scaling_factor=1.0,
-            bias_update_rate=0.0, scoring="softmax"),
+            bias_update_rate=0.0, scoring="softmax")),
+        _KEPT_ACROSS_REMAT)
+    return dict(
+        vocab_size=int(cfg["vocab_size"]), hidden_size=hidden,
+        layers=(layer,) * int(cfg["num_hidden_layers"]),
         rms_norm_eps=eps,
         dtype=jnp.dtype(cfg.get("compute_dtype", "bfloat16")),
         diffusion_block=block,
@@ -1185,7 +1155,7 @@ def _sdar_fields(cfg: Dict[str, Any]) -> Dict[str, Any]:
 
 
 # the five families by the model_type names each is published under, and
-# the readers of their keys (the DeepSeek-V3 family's are from_config's own)
+# the readers of their keys
 MODEL_TYPES = {
     "deepseek_v3": ("deepseek_v3", "joyai_llm_flash"),
     "afmoe": ("afmoe",),
@@ -1193,7 +1163,8 @@ MODEL_TYPES = {
     "granitemoehybrid": ("granitemoehybrid",),
     "sdar_moe": ("sdar_moe",),
 }
-_FIELDS = {"afmoe": _afmoe_fields, "nemotron_h": _nemotron_h_fields,
+_FIELDS = {"deepseek_v3": _deepseek_fields, "afmoe": _afmoe_fields,
+           "nemotron_h": _nemotron_h_fields,
            "granitemoehybrid": _granite_fields, "sdar_moe": _sdar_fields}
 
 

@@ -320,12 +320,12 @@ def probe_layer(cell_name: str, seed: int, steps: int, scope: str):
     cell = spec.load_cell(cell_name)
     model = decoder_lm.DecoderLM.from_config(
         cell.load("factory").model_config(cell.config))
-    # the policy of the cell's blocks: a hybrid family's keeps the shared
-    # expert's up product too
-    policy = decoder_lm._KEPT_ACROSS_REMAT if model.layer_kinds is None \
-        else decoder_lm._KEPT_WITH_FFN_PRODUCTS
-    layer = nn.remat(decoder_lm.SparseExperts, policy=policy)(
-        dtype=model.dtype, **model.experts)
+    # the plan's expert layer, under its block's policy (a hybrid family's
+    # keeps the shared expert's up product too)
+    block, experts = next((b, s.sizes) for b in model.layers
+                          for s in b.sublayers if s.kind == "experts")
+    layer = nn.remat(decoder_lm.SparseExperts, policy=block.policy)(
+        dtype=model.dtype, **experts)
     shape = (int(cell.config["per_chip_batch"]),
              int(cell.traffic["sequence_length"]), model.hidden_size)
     rng = np.random.default_rng(seed)
@@ -344,8 +344,8 @@ def probe_layer(cell_name: str, seed: int, steps: int, scope: str):
     args = (variables["params"], x)
     jax.block_until_ready(step(*args))
     print(f"-- {cell_name}: the expert layer alone, {shape[0] * shape[1]} "
-          f"rows of {shape[2]}, top-{model.experts['num_experts_per_tok']} "
-          f"of {model.experts['n_routed_experts']}")
+          f"rows of {shape[2]}, top-{experts['num_experts_per_tok']} "
+          f"of {experts['n_routed_experts']}")
     return traced_table(f"layer_{cell_name}", step, args, steps,
                         {"cell": cell_name, "seed": seed, "rows": shape},
                         scope)

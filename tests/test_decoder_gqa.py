@@ -29,7 +29,7 @@ from analytics_zoo_tpu.parallel.expert_parallel import (        # noqa: E402
 from analytics_zoo_tpu.pipeline.api.keras.layers.decoder_lm import (  # noqa: E402
     DecoderLM, moe_counters, next_token_loss, rope_half)
 from test_attention import equations, pallas_kernels            # noqa: E402
-from test_decoder_lm import _flat, _tree                        # noqa: E402
+from test_decoder_lm import _flat, _tree, sublayer_sizes        # noqa: E402
 
 SLIDING, FULL = "sliding_attention", "full_attention"
 CFG = dict(
@@ -256,14 +256,17 @@ def test_from_config_reads_the_published_key_names():
     model = DecoderLM.from_config(dict(CFG, layer_types=CFG["layer_types"]
                                        + [FULL] * 3))
     assert model.layer_windows == (12, 12, None, 12)     # the first four
-    assert model.first_k_dense_replace == 1 and model.sandwich_norms
+    # num_dense_layers 1: a dense layer, then expert layers
+    assert [[s.kind for s in layer.sublayers] for layer in model.layers] == \
+        [["gqa", "swiglu"]] + [["gqa", "experts"]] * 3
     assert model.embed_scale == pytest.approx(32 ** 0.5)
-    assert dict(model.attention) == dict(
-        kind="gqa", num_heads=4, num_kv_heads=2, head_dim=16,
-        rope_theta=10000.0)
-    assert model.experts["routed_scaling_factor"] == 2.826
-    assert model.experts["n_routed_experts"] == 16
-    assert model.experts["experts_held"] == 4
+    sizes = sublayer_sizes(model)
+    assert sizes["gqa"] == dict(
+        num_heads=4, num_kv_heads=2, head_dim=16, rope_theta=10000.0,
+        eps=1e-5)
+    assert sizes["experts"]["routed_scaling_factor"] == 2.826
+    assert sizes["experts"]["n_routed_experts"] == 16
+    assert sizes["experts"]["experts_held"] == 4
     assert DecoderLM.from_config(dict(CFG, mup_enabled=False)
                                  ).embed_scale == 1.0
 
@@ -312,6 +315,11 @@ def test_the_configurations_parameter_count_is_pinned():
     assert abs(16 * n - 11.29e9) / 11.29e9 < 0.001
     module = DecoderLM.from_config(mcfg)
     assert module.layer_windows == (2048, 2048, None, 2048, 2048)
+    # sandwich norms: one before and one after each sublayer
+    assert {tuple((s.norm, s.post_norm) for s in layer.sublayers)
+            for layer in module.layers} == {(
+                ("input_layernorm", "post_attention_layernorm"),
+                ("pre_mlp_layernorm", "post_mlp_layernorm"))}
     shapes = jax.eval_shape(lambda: module.init(
         jax.random.PRNGKey(0), jnp.zeros((1, 128), jnp.uint16)))
     assert sum(int(np.prod(a.shape))

@@ -31,7 +31,8 @@ from analytics_zoo_tpu.pipeline.api.keras.layers.decoder_lm import (  # noqa: E4
 from analytics_zoo_tpu.pipeline.api.keras.layers import decoder_lm  # noqa: E402
 from test_attention import equations                            # noqa: E402
 from test_decoder_lm import (                                   # noqa: E402
-    _bit_equal, _flat, _tree, remat_block_sides, remat_kept_bytes)
+    _bit_equal, _flat, _tree, remat_block_sides, remat_kept_bytes,
+    sublayer_sizes)
 
 CFG = dict(
     model_type="granitemoehybrid", vocab_size=96, hidden_size=32,
@@ -259,13 +260,18 @@ def test_the_scan_at_chunk_256_with_one_group_is_the_recurrence(what):
 
 def test_model_type_picks_this_family_before_layer_types():
     module = DecoderLM.from_config(CFG)
-    assert module.layer_kinds == ("M", "*", "M")
-    assert module.block_mlp_width == 48 and module.tie_word_embeddings
-    assert module.layer_windows is None and not module.sandwich_norms
-    assert (module.embed_scale, module.residual_scale,
-            module.logits_scale) == (12.0, 0.22, 8.0)
-    assert dict(module.attention)["sm_scale"] == 0.0625
-    assert dict(module.mamba)["chunk_size"] == 8
+    assert [[(s.kind, s.norm, s.post_norm, s.scope) for s in layer.sublayers]
+            for layer in module.layers] == [
+        [(mixer, "input_layernorm", None, None),
+         ("swiglu", "post_attention_layernorm", None, "mlp.dense")]
+        for mixer in ("mamba2", "gqa", "mamba2")]
+    sizes = sublayer_sizes(module)
+    assert sizes["swiglu"] == dict(width=48) and module.tie_word_embeddings
+    assert module.layer_windows is None and not module.mtp_layers
+    assert {layer.residual_scale for layer in module.layers} == {0.22}
+    assert (module.embed_scale, module.logits_scale) == (12.0, 8.0)
+    assert sizes["gqa"]["sm_scale"] == 0.0625
+    assert sizes["mamba2"]["chunk_size"] == 8
     # the afmoe family reads layer_types too, and would refuse the tie
     afmoe = {k: v for k, v in CFG.items() if k != "model_type"}
     with pytest.raises((ValueError, KeyError)):
@@ -308,21 +314,20 @@ def test_trains_through_the_estimator_on_arrays():
 
 @pytest.mark.parametrize("kind", ["M", "*"])
 def test_a_block_keeps_its_mlps_gate_and_up_across_the_remat(kind):
-    """A ``MixerMLPBlock`` under its policy keeps its MLP's gate and up
-    products for the backward pass: two more saved values of (batch, seq,
-    width), two fewer products in the gradient's program (``gate_proj`` and
-    ``up_proj`` not run again), neither name marked again in the backward
-    pass, and the gradients of the policy that keeps them not, to the bit."""
+    """A Granite block (its mixer, then its MLP) under its policy keeps its
+    MLP's gate and up products for the backward pass: two more saved values
+    of (batch, seq, width), two fewer products in the gradient's program
+    (``gate_proj`` and ``up_proj`` not run again), neither name marked again
+    in the backward pass, and the gradients of the policy that keeps them
+    not, to the bit."""
     model = DecoderLM.from_config(CFG)
-    fields = dict(kind=kind,
-                  sizes=dict({"M": model.mamba, "*": model.attention}[kind]),
-                  mlp_width=model.block_mlp_width,
-                  residual_scale=model.residual_scale, eps=model.rms_norm_eps,
-                  dtype=model.dtype)
+    layer = model.layers[{"M": 0, "*": 1}[kind]]        # layer_types M * M
+    assert layer.policy is decoder_lm._KEPT_WITH_FFN_PRODUCTS
+    fields = dict(layer=layer, eps=model.rms_norm_eps, dtype=model.dtype)
     shape = (2, SEQ, CFG["hidden_size"])
-    before = remat_block_sides(decoder_lm.MixerMLPBlock, fields, shape,
+    before = remat_block_sides(decoder_lm.Block, fields, shape,
                                decoder_lm._KEPT_ACROSS_REMAT)
-    now = remat_block_sides(decoder_lm.MixerMLPBlock, fields, shape,
+    now = remat_block_sides(decoder_lm.Block, fields, shape,
                             decoder_lm._KEPT_WITH_FFN_PRODUCTS)
     assert now["dots"] == before["dots"] - 2
     product = ((2, SEQ, CFG["intermediate_size"]), jnp.dtype(jnp.float32))
@@ -392,7 +397,14 @@ def test_the_configurations_parameter_count_is_pinned():
     n = ref.param_count(mcfg)
     assert n == 772_160_448 == work_granite.param_count(mcfg)
     assert abs(16 * n - 12.35e9) / 12.35e9 < 0.001
-    shapes = jax.eval_shape(lambda: DecoderLM.from_config(mcfg).init(
+    module = DecoderLM.from_config(mcfg)
+    # nine Mamba-2 mixers and one attention mixer, each then an MLP, every
+    # branch at the residual scale 0.22
+    assert [[s.kind for s in layer.sublayers] for layer in module.layers] \
+        == [["mamba2", "swiglu"]] * 5 + [["gqa", "swiglu"]] + \
+        [["mamba2", "swiglu"]] * 4
+    assert {layer.residual_scale for layer in module.layers} == {0.22}
+    shapes = jax.eval_shape(lambda: module.init(
         jax.random.PRNGKey(0), jnp.zeros((1, 128), jnp.uint16)))
     assert _leaves(shapes["params"]) == n
 
@@ -405,9 +417,9 @@ def test_the_catalog_rows_keys_build_the_whole_models_structure():
     whole = dict(cfg, **{k[:-len("_published")]: v for k, v in cfg.items()
                          if k.endswith("_published")})
     module = DecoderLM.from_config(whole)
-    assert len(module.layer_kinds) == 40
-    assert [i for i, k in enumerate(module.layer_kinds) if k == "*"] == \
-        [5, 15, 25, 35]
+    assert len(module.layers) == 40
+    assert [i for i, layer in enumerate(module.layers)
+            if layer.sublayers[0].kind == "gqa"] == [5, 15, 25, 35]
     shapes = jax.eval_shape(lambda: module.init(
         jax.random.PRNGKey(0), jnp.zeros((1, 128), jnp.uint16)))["params"]
     flat = {k: tuple(v.shape) for k, v in _flat(shapes).items()}

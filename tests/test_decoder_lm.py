@@ -182,6 +182,14 @@ def remat_kept_bytes():
             for name in decoder_lm._KEPT_WITH_FFN_PRODUCTS.names}
 
 
+def sublayer_sizes(model):
+    """``{kind: sizes}`` of every sublayer of the model's plans (each kind's
+    sizes are one in every family's plan)."""
+    layers = model.layers + tuple(layer for _, layer in model.mtp_layers)
+    return {s.kind: dict(s.sizes) for layer in layers
+            for s in layer.sublayers}
+
+
 def _bit_equal(a, b):
     return all(u.shape == v.shape and bool(jnp.all(u == v))
                for u, v in zip(jax.tree.leaves(a), jax.tree.leaves(b)))
@@ -189,22 +197,24 @@ def _bit_equal(a, b):
 
 @pytest.mark.parametrize("moe", [False, True])
 def test_a_decoder_blocks_remat_keeps_what_it_kept(moe):
-    """A ``DecoderBlock`` (the dense first layer's SwiGLU; an expert layer
-    with its shared expert) keeps under ``_KEPT_ACROSS_REMAT`` exactly what
-    the flash results and the routing decision alone keep: the same saved
-    values, the same products, and its FFN's first products recomputed in
-    the backward pass (the hybrid blocks' policy keeps them)."""
+    """The block of the plan's dense first layer (its SwiGLU) or of an
+    expert layer (with its shared expert) keeps under ``_KEPT_ACROSS_REMAT``,
+    the policy the family's reader gives it, exactly what the flash results
+    and the routing decision alone keep: the same saved values, the same
+    products, and its FFN's first products recomputed in the backward pass
+    (the hybrid blocks' policy keeps them)."""
     model = DecoderLM.from_config(CFG)
-    fields = dict(attention=dict(model.attention),
-                  ffn_width=0 if moe else model.intermediate_size,
-                  experts=dict(model.experts) if moe else None,
-                  eps=model.rms_norm_eps, dtype=model.dtype)
+    layer = model.layers[1 if moe else 0]
+    assert layer.policy is decoder_lm._KEPT_ACROSS_REMAT
+    assert [s.kind for s in layer.sublayers] == \
+        ["mla", "experts" if moe else "swiglu"]
+    fields = dict(layer=layer, eps=model.rms_norm_eps, dtype=model.dtype)
     shape = (2, 32, CFG["hidden_size"])
     before = remat_block_sides(
-        decoder_lm.DecoderBlock, fields, shape,
+        decoder_lm.Block, fields, shape,
         jax.checkpoint_policies.save_only_these_names(
             *decoder_lm.FLASH_RESIDUAL_NAMES, ep.ROUTER_CHOICE_NAME))
-    now = remat_block_sides(decoder_lm.DecoderBlock, fields, shape,
+    now = remat_block_sides(decoder_lm.Block, fields, shape,
                             decoder_lm._KEPT_ACROSS_REMAT)
     assert now["saved_from"] == before["saved_from"]
     assert now["saved"] == before["saved"]
@@ -744,6 +754,12 @@ def test_the_configurations_parameter_count_is_pinned():
     assert n == 680_439_808 == work_lm.param_count(mcfg)
     assert abs(n - 680.4e6) / 680.4e6 < 0.01
     module = DecoderLM.from_config(mcfg)
+    # the plan: a dense layer 0, then expert layers, and one MTP layer
+    assert [[s.kind for s in layer.sublayers] for layer in module.layers] \
+        == [["mla", "swiglu"]] + [["mla", "experts"]] * 4
+    assert [(name, [s.kind for s in layer.sublayers])
+            for name, layer in module.mtp_layers] == \
+        [("mtp_block", ["mla", "experts"])]
     shapes = jax.eval_shape(lambda: module.init(
         jax.random.PRNGKey(0), jnp.zeros((1, 128), jnp.uint16)))
     assert sum(int(np.prod(a.shape))

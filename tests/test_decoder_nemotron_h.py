@@ -34,7 +34,8 @@ from test_attention import pallas_kernels                       # noqa: E402
 from analytics_zoo_tpu.pipeline.api.keras.layers import decoder_lm  # noqa: E402
 from test_attention import equations                            # noqa: E402
 from test_decoder_lm import (                                   # noqa: E402
-    _bit_equal, _flat, _tree, remat_block_sides, remat_kept_bytes)
+    _bit_equal, _flat, _tree, remat_block_sides, remat_kept_bytes,
+    sublayer_sizes)
 
 CFG = dict(
     model_type="nemotron_h", vocab_size=96, hidden_size=32,
@@ -51,6 +52,14 @@ CFG = dict(
     bias_update_rate=1e-3, compute_dtype="float32",
     init=dict(embedding_std=1.0, out_proj_scale=0.5, router_std=1.0))
 SEQ = 32             # four chunks of the scan
+LETTERS = {"mamba2": "M", "gqa": "*", "experts": "E"}
+
+
+def pattern(layers):
+    """A plan's blocks as ``hybrid_override_pattern`` letters (each block is
+    one mixer)."""
+    return "".join(LETTERS[s.kind] for layer in layers
+                   for s in layer.sublayers)
 
 
 @pytest.fixture(scope="module")
@@ -181,12 +190,14 @@ def test_an_expert_block_keeps_its_shared_experts_up_across_the_remat():
     ``ffn_up`` not marked again in the backward pass, and the gradients of
     the policy that does not keep it, to the bit."""
     model = DecoderLM.from_config(CFG)
-    fields = dict(kind="E", sizes=dict(model.experts),
-                  eps=model.rms_norm_eps, dtype=model.dtype)
+    layer = model.layers[1]                               # the pattern's E
+    assert [s.kind for s in layer.sublayers] == ["experts"]
+    assert layer.policy is decoder_lm._KEPT_WITH_FFN_PRODUCTS
+    fields = dict(layer=layer, eps=model.rms_norm_eps, dtype=model.dtype)
     shape = (2, SEQ, CFG["hidden_size"])
-    before = remat_block_sides(decoder_lm.MixerBlock, fields, shape,
+    before = remat_block_sides(decoder_lm.Block, fields, shape,
                                decoder_lm._KEPT_ACROSS_REMAT)
-    now = remat_block_sides(decoder_lm.MixerBlock, fields, shape,
+    now = remat_block_sides(decoder_lm.Block, fields, shape,
                             decoder_lm._KEPT_WITH_FFN_PRODUCTS)
     assert now["dots"] == before["dots"] - 1
     product = ((2, SEQ, CFG["moe_shared_expert_intermediate_size"]),
@@ -468,23 +479,26 @@ def test_from_config_refuses_what_the_model_does_not_compute(key, value,
 def test_from_config_reads_the_published_key_names():
     model = DecoderLM.from_config(dict(CFG, mixer_parallel_size=2,
                                        mixer_parallel_rank=1))
-    assert model.layer_kinds == tuple("ME*EM")          # the first five
-    assert model.mtp_kinds == ("*", "E")
-    assert dict(model.mamba) == dict(
+    sizes = sublayer_sizes(model)
+    assert pattern(model.layers) == "ME*EM"             # the first five
+    assert [name for name, _ in model.mtp_layers] == \
+        ["mtp_layers_0", "mtp_layers_1"]
+    assert pattern(layer for _, layer in model.mtp_layers) == "*E"
+    assert sizes["mamba2"] == dict(
         num_heads=4, head_dim=4, n_groups=1, state_size=8, conv_kernel=4,
         chunk_size=8, eps=1e-5)
-    assert dict(model.attention) == dict(
+    assert sizes["gqa"] == dict(
         num_heads=2, num_kv_heads=1, head_dim=16, rope_theta=10000.0,
         eps=1e-5, gated=False, qk_norm=False)
-    experts = dict(model.experts)
+    experts = sizes["experts"]
     assert experts["latent_size"] == 16 and experts["shared_width"] == 40
     assert experts["activation"] == "relu2" and experts["gated"] is False
     assert experts["num_experts_per_tok"] == 6
     assert experts["routed_scaling_factor"] == 5.0
     assert model.rms_norm_eps == 1e-5
     # four ranks over two kv heads: each kv head on two ranks
-    assert DecoderLM.from_config(dict(
-        CFG, n_groups=4, mixer_parallel_size=4)).attention["num_kv_heads"] == 1
+    assert sublayer_sizes(DecoderLM.from_config(dict(
+        CFG, n_groups=4, mixer_parallel_size=4)))["gqa"]["num_kv_heads"] == 1
 
 
 def test_trains_through_the_estimator_on_arrays():
@@ -543,8 +557,9 @@ def test_the_configurations_parameter_count_is_pinned():
     assert abs(n - 700.9e6) / 700.9e6 < 0.001
     assert abs(16 * n - 11.21e9) / 11.21e9 < 0.001
     module = DecoderLM.from_config(mcfg)
-    assert dict(module.mamba)["num_heads"] == 16
-    assert dict(module.attention)["num_heads"] == 4
+    assert pattern(module.layers) == "MEMEMEMEM*E"
+    assert sublayer_sizes(module)["mamba2"]["num_heads"] == 16
+    assert sublayer_sizes(module)["gqa"]["num_heads"] == 4
     shapes = jax.eval_shape(lambda: module.init(
         jax.random.PRNGKey(0), jnp.zeros((1, 128), jnp.uint16)))
     assert _leaves(shapes["params"]) == n
@@ -566,8 +581,10 @@ def test_the_catalog_rows_keys_build_the_whole_models_structure():
     whole = {k: v for k, v in dict(cfg, **published).items()
              if k not in ("mixer_parallel_size", "mixer_parallel_rank")}
     module = DecoderLM.from_config(whole)
-    assert len(module.layer_kinds) == 88 and module.mtp_kinds == ("*", "E")
-    assert [module.layer_kinds.count(k) for k in "ME*"] == [40, 40, 8]
+    kinds = pattern(module.layers)
+    assert len(kinds) == 88
+    assert pattern(layer for _, layer in module.mtp_layers) == "*E"
+    assert [kinds.count(k) for k in "ME*"] == [40, 40, 8]
     shapes = jax.eval_shape(lambda: module.init(
         jax.random.PRNGKey(0), jnp.zeros((1, 128), jnp.uint16)))["params"]
     flat = {k: tuple(v.shape) for k, v in _flat(shapes).items()}

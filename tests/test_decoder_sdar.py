@@ -29,7 +29,7 @@ from analytics_zoo_tpu.pipeline.api.keras.layers.decoder_lm import (  # noqa: E4
     MODEL_TYPES, NOISE_STATE, DecoderLM, block_diffusion_loss,
     block_diffusion_noise, moe_counters)
 from test_attention import equations, pallas_kernels            # noqa: E402
-from test_decoder_lm import _flat, _tree                        # noqa: E402
+from test_decoder_lm import _flat, _tree, sublayer_sizes        # noqa: E402
 
 CFG = dict(
     model_type="sdar_moe", vocab_size=96, hidden_size=32,
@@ -353,14 +353,20 @@ def test_from_config_reads_the_published_key_names():
                                        num_hidden_layers=48,
                                        vocab_size=151936))
     assert model.diffusion_block == 4 and model.noise_eps == 1e-3
-    assert dict(model.attention) == dict(
-        kind="gqa", num_heads=32, num_kv_heads=4, head_dim=128,
-        rope_theta=1e6, gated=False, qk_norm=True, block_diffusion=4)
-    assert model.experts["scoring"] == "softmax"
-    assert model.experts["n_routed_experts"] == 128 == \
-        model.experts["experts_held"]
-    assert model.experts["n_shared_experts"] == 0
-    assert model.first_k_dense_replace == 0 and not model.sandwich_norms
+    sizes = sublayer_sizes(model)
+    assert sizes["gqa"] == dict(
+        num_heads=32, num_kv_heads=4, head_dim=128, rope_theta=1e6,
+        eps=1e-6, gated=False, qk_norm=True, block_diffusion=4)
+    assert sizes["experts"]["scoring"] == "softmax"
+    assert sizes["experts"]["n_routed_experts"] == 128 == \
+        sizes["experts"]["experts_held"]
+    assert sizes["experts"]["n_shared_experts"] == 0
+    # every block an expert layer behind pre-norms, no MTP module
+    assert {tuple((s.kind, s.norm, s.post_norm) for s in layer.sublayers)
+            for layer in model.layers} == {(
+                ("gqa", "input_layernorm", None),
+                ("experts", "post_attention_layernorm", None))}
+    assert len(model.layers) == 48 and not model.mtp_layers
 
 
 @pytest.mark.parametrize("key,value,match", [
@@ -427,6 +433,9 @@ def test_the_configurations_parameter_count_is_pinned():
     assert n == 645_625_344 == work_bd.param_count(mcfg)
     assert abs(16 * n - 10.33e9) / 10.33e9 < 0.001
     module = DecoderLM.from_config(mcfg)
+    # six expert layers
+    assert [[s.kind for s in layer.sublayers] for layer in module.layers] \
+        == [["gqa", "experts"]] * 6
     shapes = jax.eval_shape(lambda: module.init(
         jax.random.PRNGKey(0), jnp.zeros((1, 128), jnp.uint16)))
     assert sum(int(np.prod(a.shape))
