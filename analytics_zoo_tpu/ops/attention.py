@@ -68,6 +68,17 @@ _BD_COMPUTED = _BD_ENTRIES.labels(kind="computed")
 _BD_NEEDED = _BD_ENTRIES.labels(kind="needed")
 BLOCK_DIFFUSION = "block_diffusion"
 
+# what a selected-key call site's walks compute against what its selection
+# keeps: the selection is data, so it is read from a step's outputs
+# (``selection_entries``) by whoever runs the step, not counted as traced
+SA_ENTRIES = _REGISTRY.counter(
+    "zoo_attention_sa_entries_total",
+    "score entries of selected-key flash_attention call sites, a head and a "
+    "forward pass, as the steps read so far computed them: computed (the "
+    "entries of the tiles the walk visits) and needed (the selected "
+    "entries)", labelnames=("kind",))
+SELECTED = "selected"
+
 # how much of a masked call site's grids is steps that compute nothing
 _GRID_STEPS = _REGISTRY.counter(
     "zoo_attention_grid_steps_total",
@@ -113,17 +124,45 @@ def block_diffusion_mask(seq: int, block: int) -> np.ndarray:
         (~qn & ~kn & (kb <= qb))
 
 
+def pack_selection(keep: jax.Array, rows: int) -> jax.Array:
+    """A selection (B, S_q, S_k) bool, True where query t attends to key s,
+    packed as (B, S_q / 32, S_k) uint32: the queries in groups of ``rows``
+    (a kernel tile's height, a multiple of 32), and bit j of word w of a
+    group is its row ``j * rows / 32 + w``, so that a tile's (rows / 32,
+    block_k) words unpack into its (rows, block_k) entries by shifts and a
+    stack along sublanes, never across lanes."""
+    b, s_q, s_k = keep.shape
+    r = rows // 32
+    planes = keep.reshape(b, s_q // rows, 32, r, s_k).astype(jnp.uint32)
+    shift = jnp.arange(32, dtype=jnp.uint32)[:, None, None]
+    return jnp.sum(planes << shift, axis=2, dtype=jnp.uint32).reshape(
+        b, s_q // 32, s_k)
+
+
+def unpack_selection(bits: jax.Array, rows: int) -> jax.Array:
+    """:func:`pack_selection`'s inverse: (B, S_q / 32, S_k) -> (B, S_q, S_k)
+    bool."""
+    b, w, s_k = bits.shape
+    r = rows // 32
+    words = bits.reshape(b, w // r, 1, r, s_k)
+    planes = (words >> jnp.arange(32, dtype=jnp.uint32)[:, None, None]) & 1
+    return planes.reshape(b, w * 32, s_k).astype(bool)
+
+
 def mha_reference(q: jax.Array, k: jax.Array, v: jax.Array,
                   *, causal: bool = False, sm_scale: Optional[float] = None,
                   bias: Optional[jax.Array] = None,
                   window: Optional[int] = None, mask: Optional[str] = None,
-                  block: Optional[int] = None) -> jax.Array:
+                  block: Optional[int] = None,
+                  selection: Optional[jax.Array] = None) -> jax.Array:
     """Plain materialised-scores attention. q,k: (B, S, H, D); v: (B, S, H,
     D) or a head size of its own. k and v may have fewer heads than q
     (grouped queries: each run of H / H_kv query heads shares one, repeated
     here); ``window`` keeps, of a causal mask, the keys ``0 <= t - j <
     window`` of query position t; ``mask="block_diffusion"`` (not causal)
-    keeps :func:`block_diffusion_mask`'s entries for blocks of ``block``."""
+    keeps :func:`block_diffusion_mask`'s entries for blocks of ``block``;
+    ``mask="selected"`` the entries of ``selection``, packed in groups of
+    ``block`` rows (:func:`pack_selection`)."""
     if sm_scale is None:
         sm_scale = 1.0 / math.sqrt(q.shape[-1])
     group = q.shape[2] // k.shape[2]
@@ -141,6 +180,9 @@ def mha_reference(q: jax.Array, k: jax.Array, v: jax.Array,
         logits = jnp.where(keep, logits, NEG_INF)
     elif mask == BLOCK_DIFFUSION:
         logits = jnp.where(block_diffusion_mask(logits.shape[-1], block),
+                           logits, NEG_INF)
+    elif mask == SELECTED:
+        logits = jnp.where(unpack_selection(selection, block)[:, None],
                            logits, NEG_INF)
     probs = jax.nn.softmax(logits, axis=-1)
     return jnp.einsum("bhqk,bkhd->bqhd", probs.astype(v.dtype), v)
@@ -407,8 +449,29 @@ def _bd_walk(seq, block_q, block_k, half, block, by, group=1):
                                 np.arange(t["q_steps"]), indexing="ij")
         q, active = _bd_q_block(run, step, block_q, block_k, half, block)
         k = run
-    q, k, run = q[active], k[active], run[active]
-    # each run once a head of the group, in turn
+    diag0, n_diag, n_prefix = _bd_k_walk(np.arange(seq // block_q), block_q,
+                                         block_k, half, block, np)
+    last_k = np.where(n_prefix > 0, half // block_k + n_prefix - 1,
+                      diag0 + n_diag - 1)
+    return _walk_rows(q[active], k[active], run[active], last_k, group)
+
+
+def _causal_walk(n, by, group=1):
+    """The tile list a selected-key grid walks, built at trace time: every
+    (q tile, k block) on or below the diagonal of ``n`` square tiles, in
+    :func:`_bd_walk`'s order, rows and flags (``by="q"``: each q tile's k
+    blocks ascending; ``by="k"``: each k block's q tiles, the ``group`` query
+    heads in turn). Each entry is masked by the selection's bits."""
+    tri = np.tri(n, dtype=bool)
+    run, other = np.nonzero(tri if by == "q" else tri.T)
+    q, k = (run, other) if by == "q" else (other, run)
+    return _walk_rows(q, k, run, np.arange(n), group)
+
+
+def _walk_rows(q, k, run, last_k, group):
+    """A tile list's four rows from its entries in walk order (``run`` the
+    q tile or k block each belongs to), each run once a head of the
+    ``group`` in turn; ``last_k`` each q tile's last k block."""
     runs = np.split(np.arange(run.size), np.flatnonzero(np.diff(run)) + 1)
     at = np.concatenate([r for r in runs for _ in range(group)])
     head = np.concatenate([np.full(r.size, hd) for r in runs
@@ -416,10 +479,7 @@ def _bd_walk(seq, block_q, block_k, half, block, by, group=1):
     q, k, run = q[at], k[at], run[at]
     first = np.diff(run, prepend=-1) != 0
     last = np.append(first[1:], True)
-    diag0, n_diag, n_prefix = _bd_k_walk(q, block_q, block_k, half, block, np)
-    last_k = np.where(n_prefix > 0, half // block_k + n_prefix - 1,
-                      diag0 + n_diag - 1)
-    flags = _FIRST * first + _LAST * last + _DQ_LAST * (k == last_k)
+    flags = _FIRST * first + _LAST * last + _DQ_LAST * (k == last_k[q])
     return np.concatenate([q, k, head, flags]).astype(np.int32)
 
 
@@ -435,10 +495,38 @@ def _walk_flag(walk, e, bit):
     return jnp.bitwise_and(_walk_at(walk, e, _FLAGS), bit) != 0
 
 
-def _split_walk(refs, bd):
-    """A kernel's tile-list ref and its other refs: with ``bd`` the list
-    is the launch's scalar-prefetch operand, the first of its refs."""
-    return (refs[0], refs[1:]) if bd is not None else (None, refs)
+def selection_entries(bits, block: int):
+    """``(computed, needed)`` score entries of a selected-key call a head
+    and a forward pass, float32: the entries of the causal tiles its walk
+    visits (``block`` square) and the selected ones (on the device)."""
+    n = bits.shape[-1] // block
+    needed = jnp.sum(lax.population_count(bits).astype(jnp.float32))
+    return jnp.float32(n * (n + 1) // 2 * block * block), needed
+
+
+def _sel_keep(words, shape):
+    """A (rows, block_k) tile's selected entries from its (rows / 32,
+    block_k) int32 words (:func:`pack_selection`): each bit plane shifted
+    down and the 32 planes stacked along sublanes."""
+    planes = jnp.broadcast_to(words[None], (32, *words.shape))
+    shift = lax.broadcasted_iota(jnp.int32, planes.shape, 0)
+    return (lax.shift_right_logical(planes, shift) & 1).reshape(shape) != 0
+
+
+def _sel_masked(bits_ref):
+    """A selected-key tile's mask, from its words in ``bits_ref``."""
+    return lambda shape: _sel_keep(bits_ref[0], shape)
+
+
+def _split_walk(refs, bd, sel=False):
+    """A kernel's tile-list ref, its selection's ref and its other refs:
+    with ``bd`` or ``sel`` the list is the launch's scalar-prefetch operand,
+    the first of its refs; with ``sel`` the packed selection is the
+    second."""
+    if sel:
+        return refs[0], refs[1], refs[2:]
+    return (refs[0], None, refs[1:]) if bd is not None else \
+        (None, None, refs)
 
 
 def _launch(kernel, operands, walk=None, *, grid, in_specs, out_specs,
@@ -523,7 +611,7 @@ def _bd_compute(compute, q_start, k_start, block_k, bd):
 
 def _flash_kernel(*refs, block_q, block_k, num_k_blocks, causal, head_dim,
                   q_offset=0, with_lse=False, ones_column=True, window=None,
-                  bd=None):
+                  bd=None, sel=False):
     """Grid = (batch*heads, num_q_blocks, num_k_blocks); the k dim is innermost
     so (acc, m) scratch carries the online softmax across k iterations.
     ``refs``: q, k, v, the output [, the logsumexp], the scratch. With
@@ -540,7 +628,11 @@ def _flash_kernel(*refs, block_q, block_k, num_k_blocks, causal, head_dim,
     exactly 0. With ``bd`` (``(half, block)``: the block-diffusion mask) the
     grid is (batch*heads, tiles) and step e is entry e of a tile list
     (``_bd_walk(by="q")``, the first of ``refs``): each q tile's diagonal
-    and clean blocks in turn, every step a tile that computes.
+    and clean blocks in turn, every step a tile that computes. With ``sel``
+    the list is a selected-key call's (``_causal_walk(by="q")``), the packed
+    selection's tile the second of ``refs``, and each entry is masked by
+    its bit (a row's first tiles may hold none of its keys, as a window's
+    band may).
 
     The softmax normaliser l = sum(p) comes one of two ways, by v's head
     size (``_flash_forward`` decides):
@@ -557,14 +649,15 @@ def _flash_kernel(*refs, block_q, block_k, num_k_blocks, causal, head_dim,
       as it is and l is a running float32 row sum in scratch beside m."""
     import jax.experimental.pallas as pl  # local import keeps module cpu-safe
 
-    walk, (q_ref, k_ref, v_ref, o_ref, *rest) = _split_walk(refs, bd)
+    walk, bits, (q_ref, k_ref, v_ref, o_ref, *rest) = _split_walk(refs, bd,
+                                                                  sel)
     if with_lse:
         lse_ref, *rest = rest
     if ones_column:
         acc_ref, m_ref = rest
     else:
         acc_ref, m_ref, l_ref = rest
-    if bd is not None:
+    if walk is not None:
         e = pl.program_id(1)
         q_idx = _walk_at(walk, e, _Q)
         first = _walk_flag(walk, e, _FIRST)
@@ -581,7 +674,7 @@ def _flash_kernel(*refs, block_q, block_k, num_k_blocks, causal, head_dim,
             l_ref[...] = jnp.zeros_like(l_ref)
 
     q_start = q_idx * block_q
-    if bd is not None:
+    if walk is not None:
         k_start = _walk_at(walk, e, _K) * block_k
     else:
         k_start = _k_start(q_idx, k_idx, block_q, block_k, q_offset, window)
@@ -597,7 +690,7 @@ def _flash_kernel(*refs, block_q, block_k, num_k_blocks, causal, head_dim,
         s = jax.lax.dot_general(
             q, k, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32)
-        if callable(masked):             # a block-diffusion tile's entries
+        if callable(masked):     # a block-diffusion or selected tile's
             s = jnp.where(masked(s.shape), s, NEG_INF)
         elif masked:
             # bottom-right aligned (q_offset = s_k - s_q), matching
@@ -622,7 +715,9 @@ def _flash_kernel(*refs, block_q, block_k, num_k_blocks, causal, head_dim,
                         jnp.dot(p.astype(v.dtype), v,
                                 preferred_element_type=jnp.float32))
 
-    if bd is not None:
+    if sel:
+        _compute(_sel_masked(bits))
+    elif bd is not None:
         _bd_compute(_compute, q_start, k_start, block_k, bd)
     elif causal:
         # Three tile classes: fully masked (skip), diagonal (mask), and
@@ -636,7 +731,7 @@ def _flash_kernel(*refs, block_q, block_k, num_k_blocks, causal, head_dim,
     else:
         _compute(False)
 
-    @pl.when(_walk_flag(walk, e, _LAST) if bd is not None
+    @pl.when(_walk_flag(walk, e, _LAST) if walk is not None
              else k_idx == num_k_blocks - 1)
     def _finalize():
         acc = acc_ref[...]
@@ -687,8 +782,27 @@ def _kv_row(group):
     return (lambda bh: bh) if group == 1 else (lambda bh: bh // group)
 
 
+def _selection_words(selection):
+    """The packed selection as the kernels read it: int32, whose shifts
+    Mosaic lowers, with the same bits."""
+    return lax.bitcast_convert_type(selection, jnp.int32)
+
+
+def _selection_spec(block_q, block_k, heads, lead=1):
+    """The packed selection's tile for a tile-list entry: the words of its
+    q tile's rows and k block's keys, in the row's sequence of the batch (a
+    grid's first index over ``heads`` rows a sequence; ``lead`` grid
+    indices before the list's)."""
+    import jax.experimental.pallas as pl
+
+    def index(row, *rest):
+        e, walk = rest[lead - 1], rest[-1]
+        return (row // heads, _walk_at(walk, e, _Q), _walk_at(walk, e, _K))
+    return pl.BlockSpec((1, block_q // 32, block_k), index)
+
+
 def _flash_forward(q, k, v, causal, sm_scale, block_q, block_k, interpret,
-                   with_lse=False, window=None, bd=None):
+                   with_lse=False, window=None, bd=None, selection=None):
     import jax.experimental.pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
@@ -726,11 +840,18 @@ def _flash_forward(q, k, v, causal, sm_scale, block_q, block_k, interpret,
         _TILES_VISITED.inc(b * h * tiles["by_k"])
         _TILES_NEEDED.inc(b * h * tiles["needed"])
     grid = (b * h, num_q, num_k)
+    walk = None
     if bd is not None:
         # the grid walks a tile list: each q tile's diagonal and clean blocks
         grid = (b * h, tiles["by_k"])
         _BD_COMPUTED.inc(b * h * tiles["by_k"] * block_q * block_k)
         _BD_NEEDED.inc(b * h * tiles["needed"])
+        walk = _bd_walk(s_q, block_q, block_k, *bd, by="q")
+    sel = selection is not None
+    if sel:
+        # each q tile's causal k blocks, every entry masked by its bits
+        walk = _causal_walk(s_q // block_q, "q")
+        grid = (b * h, walk.shape[0] // 4)
     if mask is not None:
         _count_grid(mask, grid, b * h * tiles["by_k"])
 
@@ -738,14 +859,14 @@ def _flash_forward(q, k, v, causal, sm_scale, block_q, block_k, interpret,
         _flash_kernel, block_q=block_q, block_k=block_k,
         num_k_blocks=num_k, causal=causal, head_dim=d_v,
         q_offset=s_k - s_q, with_lse=with_lse, ones_column=ones_column,
-        window=window, bd=bd)
+        window=window, bd=bd, sel=sel)
     # Under shard_map (e.g. Ulysses sequence parallelism) the output must
     # declare which mesh axes it varies over. Use the union of the inputs'
     # varying sets and lift any less-varying input up to it so mixed-vma
     # call sites (e.g. cross-attention with replicated q) still compile.
     vma = varying_axes(qf, kf, vf)
     qf, kf, vf = (mark_varying(a, vma) for a in (qf, kf, vf))
-    if bd is not None:
+    if walk is not None:
         # an index map is given the tile list after the grid's indices
         def q_index(bh, e, walk):
             return (bh, _walk_at(walk, e, _Q), 0)
@@ -758,7 +879,7 @@ def _flash_forward(q, k, v, causal, sm_scale, block_q, block_k, interpret,
         out_shape.append(
             jax.ShapeDtypeStruct((b * h, s_q, 1), jnp.float32, vma=vma))
         out_specs.append(pl.BlockSpec((1, block_q, 1), q_index))
-    if bd is not None:
+    if walk is not None:
         def k_index(bh, e, walk):
             return (kv_row(bh), _walk_at(walk, e, _K), 0)
     elif causal:
@@ -784,15 +905,18 @@ def _flash_forward(q, k, v, causal, sm_scale, block_q, block_k, interpret,
         def k_index(bh, qi, ki):
             return (kv_row(bh), ki, 0)
 
+    operands, in_specs = (qf, kf, vf), [
+        pl.BlockSpec((1, block_q, d), q_index),
+        pl.BlockSpec((1, block_k, d), k_index),
+        pl.BlockSpec((1, block_k, d_acc), k_index),
+    ]
+    if sel:
+        operands = (_selection_words(selection), *operands)
+        in_specs = [_selection_spec(block_q, block_k, h)] + in_specs
     res = _launch(
-        kernel, (qf, kf, vf),
-        None if bd is None else _bd_walk(s_q, block_q, block_k, *bd, by="q"),
+        kernel, operands, walk,
         grid=grid,
-        in_specs=[
-            pl.BlockSpec((1, block_q, d), q_index),
-            pl.BlockSpec((1, block_k, d), k_index),
-            pl.BlockSpec((1, block_k, d_acc), k_index),
-        ],
+        in_specs=in_specs,
         out_specs=out_specs,
         out_shape=out_shape,
         scratch_shapes=[
@@ -875,7 +999,8 @@ def _bwd_tile(masked, q2, k, v, g, L, D, q_offset, q_start, k_start, cd,
 
 
 def _flash_bwd_dq_kernel(*refs, sm_scale, block_q, block_k, num_k_blocks,
-                         causal, q_offset, cd, window=None, bd=None):
+                         causal, q_offset, cd, window=None, bd=None,
+                         sel=False):
     """dQ pass: grid (batch*heads, num_q, num_k), k innermost; the dq tile
     accumulates across k iterations in VMEM scratch — no (S, S) tensor
     ever reaches HBM (the round-3 pure-JAX backward streamed every P/dS
@@ -884,13 +1009,13 @@ def _flash_bwd_dq_kernel(*refs, sm_scale, block_q, block_k, num_k_blocks,
     only where not even one query head's gradients fit the fused kernel's
     VMEM budget (``_flash_bwd``). ``refs``: q2, k, v, g, L, D, dq, the
     scratch. With a ``window`` the k dim walks the q tile's band; with
-    ``bd`` the grid walks a tile list (``_bd_walk(by="q")``), as in
-    ``_flash_kernel``."""
+    ``bd`` or ``sel`` the grid walks a tile list (``_bd_walk(by="q")``,
+    ``_causal_walk(by="q")``), as in ``_flash_kernel``."""
     import jax.experimental.pallas as pl
 
-    walk, (q2_ref, k_ref, v_ref, g_ref, L_ref, D_ref, dq_ref,
-           acc_ref) = _split_walk(refs, bd)
-    if bd is not None:
+    walk, bits, (q2_ref, k_ref, v_ref, g_ref, L_ref, D_ref, dq_ref,
+                 acc_ref) = _split_walk(refs, bd, sel)
+    if walk is not None:
         e = pl.program_id(1)
         q_idx = _walk_at(walk, e, _Q)
         first = _walk_flag(walk, e, _FIRST)
@@ -904,7 +1029,7 @@ def _flash_bwd_dq_kernel(*refs, sm_scale, block_q, block_k, num_k_blocks,
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
     q_start = q_idx * block_q
-    if bd is not None:
+    if walk is not None:
         k_start = _walk_at(walk, e, _K) * block_k
     else:
         k_start = _k_start(q_idx, k_idx, block_q, block_k, q_offset, window)
@@ -916,7 +1041,9 @@ def _flash_bwd_dq_kernel(*refs, sm_scale, block_q, block_k, num_k_blocks,
         acc_ref[...] += jnp.dot(ds, k_ref[0],
                                 preferred_element_type=jnp.float32)
 
-    if bd is not None:
+    if sel:
+        _compute(_sel_masked(bits))
+    elif bd is not None:
         _bd_compute(_compute, q_start, k_start, block_k, bd)
     elif causal:
         active, diagonal = _tile_class(q_offset + q_start, k_start, block_q,
@@ -926,7 +1053,7 @@ def _flash_bwd_dq_kernel(*refs, sm_scale, block_q, block_k, num_k_blocks,
     else:
         _compute(False)
 
-    @pl.when(_walk_flag(walk, e, _LAST) if bd is not None
+    @pl.when(_walk_flag(walk, e, _LAST) if walk is not None
              else k_idx == num_k_blocks - 1)
     def _finalize():
         dq_ref[0] = (acc_ref[...] * sm_scale).astype(dq_ref.dtype)
@@ -934,7 +1061,8 @@ def _flash_bwd_dq_kernel(*refs, sm_scale, block_q, block_k, num_k_blocks,
 
 def _flash_bwd_dkv_kernel(*refs, block_q, block_k, num_q_blocks, causal,
                           q_offset, cd, dq=None, window=None, group=1,
-                          seq_q_blocks=None, by_head=False, bd=None):
+                          seq_q_blocks=None, by_head=False, bd=None,
+                          sel=False):
     """dK/dV pass: grid (batch*kv heads, num_k, group * num_q), the
     innermost dim walking the q tiles of each of the ``group`` query heads
     that share this kv head in turn, so dK and dV are summed over the
@@ -950,7 +1078,9 @@ def _flash_bwd_dkv_kernel(*refs, block_q, block_k, num_q_blocks, causal,
     (``_bd_walk(by="k", group=group)``, the first of ``refs``): each k
     block's q tiles (:func:`_bd_q_block`), a query head's after another's;
     by head (below) the list is one head's and the grid (batch*kv heads,
-    group, tiles).
+    group, tiles). With ``sel`` the list is a selected-key call's
+    (``_causal_walk(by="k")``) and the packed selection's tile the second of
+    ``refs``.
 
     ``dq`` is the fused kernel's (see ``_flash_bwd_fused_kernel``): given,
     each tile's dS also goes into dQ. ``by_head`` is its grid for a group
@@ -962,10 +1092,10 @@ def _flash_bwd_dkv_kernel(*refs, block_q, block_k, num_q_blocks, causal,
     output blocks are the kv head's whole sequence."""
     import jax.experimental.pallas as pl
 
-    walk, (q2_ref, k_ref, v_ref, g_ref, L_ref, D_ref, dk_ref, dv_ref,
-           dk_acc, dv_acc) = _split_walk(refs, bd)
+    walk, bits, (q2_ref, k_ref, v_ref, g_ref, L_ref, D_ref, dk_ref, dv_ref,
+                 dk_acc, dv_acc) = _split_walk(refs, bd, sel)
     k_at, out_k_at = Ellipsis, 0      # all of dK's and dV's scratch and block
-    if bd is not None:
+    if walk is not None:
         # a head's (by head) or the group's dQ begins with the list
         e = dq_step = pl.program_id(2 if by_head else 1)
         k_idx, q_idx = _walk_at(walk, e, _K), _walk_at(walk, e, _Q)
@@ -994,7 +1124,7 @@ def _flash_bwd_dkv_kernel(*refs, block_q, block_k, num_q_blocks, causal,
                 slice(None))
         out_k_at = (0, *k_at)
 
-    @pl.when(begins if bd is not None else step == 0)
+    @pl.when(begins if walk is not None else step == 0)
     def _init():
         dk_acc[k_at] = jnp.zeros((block_k, dk_acc.shape[-1]), dk_acc.dtype)
         dv_acc[k_at] = jnp.zeros((block_k, dv_acc.shape[-1]), dv_acc.dtype)
@@ -1018,7 +1148,7 @@ def _flash_bwd_dkv_kernel(*refs, block_q, block_k, num_q_blocks, causal,
         def _write_dq():
             dq_ref[out_at] = (dq_acc[acc_at] * sm_scale).astype(dq_ref.dtype)
 
-        if window is None and bd is None:
+        if window is None and walk is None:
             @pl.when(k_idx == 0)
             def _init_dq():
                 dq_acc[acc_at] = jnp.zeros((block_q, dq_acc.shape[-1]),
@@ -1046,8 +1176,11 @@ def _flash_bwd_dkv_kernel(*refs, block_q, block_k, num_q_blocks, causal,
             dq_acc[acc_at] += jnp.dot(ds, k_ref[0],
                                       preferred_element_type=jnp.float32)
 
-    if bd is not None:
-        _bd_compute(_compute, q_start, k_start, block_k, bd)
+    if walk is not None:
+        if sel:
+            _compute(_sel_masked(bits))
+        else:
+            _bd_compute(_compute, q_start, k_start, block_k, bd)
         if dq is not None:
             # a q tile's rows are whole once the last k block of its walk
             # has passed them
@@ -1067,13 +1200,13 @@ def _flash_bwd_dkv_kernel(*refs, block_q, block_k, num_q_blocks, causal,
     else:
         _compute(False)
 
-    @pl.when(ends if bd is not None else step == group * num_q_blocks - 1)
+    @pl.when(ends if walk is not None else step == group * num_q_blocks - 1)
     def _finalize():
         dk_ref[out_k_at] = (dk_acc[k_at] *
                             (1.0 / LOG2_E)).astype(dk_ref.dtype)
         dv_ref[out_k_at] = dv_acc[k_at].astype(dv_ref.dtype)
 
-    if dq is not None and window is None and bd is None:
+    if dq is not None and window is None and walk is None:
         # a q tile's rows are whole once the last k block has passed them,
         # masked tiles too: written a tile at a time, never the whole
         # sequence in one statement
@@ -1134,7 +1267,8 @@ def _bwd_tile_sizes(s_q: int, s_k: int, block_q: int, block_k: int):
     return bq, bk
 
 
-def _flash_bwd(causal, sm_scale, block_q, block_k, window, bd, res, g):
+def _flash_bwd(causal, sm_scale, block_q, block_k, window, bd, res, g,
+               selection=None):
     """FlashAttention-2-style Pallas backward consuming the forward's
     log2-domain logsumexp. Every (block_q, block_k) P/dS tile lives and
     dies in VMEM — the previous pure-JAX backward streamed each of its
@@ -1164,7 +1298,9 @@ def _flash_bwd(causal, sm_scale, block_q, block_k, window, bd, res, g):
     (``_bd_walk``): ``fused`` (batch*kv heads, the group's tiles),
     ``fused_by_head`` (batch*kv heads, group, a head's tiles), the pair's
     dQ (batch*heads, tiles by q tile) and dK/dV (batch*kv heads, the
-    group's tiles by k block)."""
+    group's tiles by k block). With a ``selection`` (the packed selected
+    keys, square tiles) they walk the causal tiles (``_causal_walk``),
+    every entry masked by its bit."""
     import jax.experimental.pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
@@ -1184,9 +1320,16 @@ def _flash_bwd(causal, sm_scale, block_q, block_k, window, bd, res, g):
     nq_in, nk_in = nq, nk
     if window is not None:
         nq_in, nk_in = tiles_m["q_steps"], tiles_m["k_steps"]
+    sel = selection is not None
+    walked = bd is not None or sel
+    if sel:
+        # the tiles on or below the diagonal
+        tiles_m = {"by_q": nq * (nq + 1) // 2, "by_k": nq * (nq + 1) // 2}
 
     def tile_list(by, heads=1):
-        """The tile list a launch walks (``bd``), else None."""
+        """The tile list a launch walks (``bd``, ``sel``), else None."""
+        if sel:
+            return _causal_walk(nq, by, heads)
         return None if bd is None else _bd_walk(s_q, bq, bk, *bd, by=by,
                                                 group=heads)
 
@@ -1205,7 +1348,9 @@ def _flash_bwd(causal, sm_scale, block_q, block_k, window, bd, res, g):
     operands = tuple(mark_varying(a, vma) for a in (q2, kf, vf, gf, lse, D))
     off = s_k - s_q
     tiles = dict(block_q=bq, block_k=bk, causal=causal, q_offset=off,
-                 cd=cd, window=window, bd=bd)
+                 cd=cd, window=window, bd=bd, sel=sel)
+    if sel:
+        operands = (_selection_words(selection), *operands)
     dkv_tiles = dict(tiles, num_q_blocks=nq_in, group=group,
                      seq_q_blocks=nq)
 
@@ -1227,7 +1372,7 @@ def _flash_bwd(causal, sm_scale, block_q, block_k, window, bd, res, g):
             return (row, jnp.maximum(qi, first), 0)
         return (row, qi, 0)
 
-    if bd is not None:
+    if walked:
         # the dK/dV and fused kernels walk (bh_kv, the group's tiles): each
         # entry names its q tile, k block and query head
         def q_index(bhi, e, walk):
@@ -1252,6 +1397,8 @@ def _flash_bwd(causal, sm_scale, block_q, block_k, window, bd, res, g):
     by_k = [pl.BlockSpec((1, bk, w), kv_index)
             for w in (d, d_v)]                           # k | dk, v | dv
     dkv_in_specs = [by_q[0], *by_k, *by_q[1:]]
+    if sel:
+        dkv_in_specs = [_selection_spec(bq, bk, h_kv)] + dkv_in_specs
     dq_shape = jax.ShapeDtypeStruct((bh, s_q, d), q.dtype, vma=vma)
     dkv_shapes = [jax.ShapeDtypeStruct((bh_kv, s_k, d), k.dtype, vma=vma),
                   jax.ShapeDtypeStruct((bh_kv, s_k, d_v), v.dtype, vma=vma)]
@@ -1277,7 +1424,7 @@ def _flash_bwd(causal, sm_scale, block_q, block_k, window, bd, res, g):
             _TILES_NEEDED.inc(bh * tiles_m["needed"] * passes)
             _TILES_VISITED.inc(bh * visited)
     # the q-innermost grids: (bh_kv, nk, group * nq_in), or a tile list's
-    dkv_grid = (bh_kv, nk, group * nq_in) if bd is None else \
+    dkv_grid = (bh_kv, nk, group * nq_in) if not walked else \
         (bh_kv, group * tiles_m["by_q"])
     if one_launch:
         if fused:
@@ -1297,7 +1444,7 @@ def _flash_bwd(causal, sm_scale, block_q, block_k, window, bd, res, g):
             # whole in scratch and as output blocks, at the kv head's row
             grid, held = (bh_kv, group, nk, nq_in), by_head_bytes
             tiles_in = tile_list("k")
-            if bd is not None:
+            if walked:
                 # a head walks the tile list, one head's
                 grid = (bh_kv, group, tiles_m["by_q"])
                 seq_q = [pl.BlockSpec((1, bq, w), lambda bhi, hd, e, walk: (
@@ -1314,6 +1461,8 @@ def _flash_bwd(causal, sm_scale, block_q, block_k, window, bd, res, g):
                                       lambda bhi, hd, ki, qi: (bhi, ki, 0))
                          for w in (d, d_v)]
             in_specs = [seq_q[0], *seq_k, *seq_q[1:]]
+            if sel:
+                in_specs = [_selection_spec(bq, bk, h_kv, lead=2)] + in_specs
             out_specs = [pl.BlockSpec((1, s_q, d), lambda bhi, hd, *_: (
                 bhi * group + hd, 0, 0))] + [
                 pl.BlockSpec((1, s_k, w), lambda bhi, *_: (bhi, 0, 0))
@@ -1344,7 +1493,7 @@ def _flash_bwd(causal, sm_scale, block_q, block_k, window, bd, res, g):
     else:
         _BACKWARD_TWO_KERNEL.inc()
         # --- dQ: grid (bh, nq, nk_in), k innermost, or a tile list's -------
-        if bd is not None:
+        if walked:
             dq_grid = (bh, tiles_m["by_k"])
 
             def row_index(bhi, e, walk):
@@ -1377,7 +1526,7 @@ def _flash_bwd(causal, sm_scale, block_q, block_k, window, bd, res, g):
                 **tiles),
             operands, tile_list("q"),
             grid=dq_grid,
-            in_specs=[
+            in_specs=([_selection_spec(bq, bk, h)] if sel else []) + [
                 pl.BlockSpec((1, bq, d), row_index),
                 pl.BlockSpec((1, bk, d), k_index),
                 pl.BlockSpec((1, bk, d_v), k_index),
@@ -1415,11 +1564,40 @@ def _flash_bwd(causal, sm_scale, block_q, block_k, window, bd, res, g):
 _flash_attention.defvjp(_flash_fwd, _flash_bwd)
 
 
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5))
+def _flash_selected(q, k, v, bits, sm_scale, block):
+    return _flash_forward(q, k, v, False, sm_scale, block, block,
+                          _interpret(), with_lse=True, selection=bits)
+
+
+def _flash_selected_fwd(q, k, v, bits, sm_scale, block):
+    """As :func:`_flash_fwd`, the output and the logsumexp both results
+    (a caller reads the logsumexp under ``stop_gradient``), and the packed
+    selection a residual beside them."""
+    out, lse = _flash_forward(q, k, v, False, sm_scale, block, block,
+                              _interpret(), with_lse=True, selection=bits)
+    out = checkpoint_name(out, FLASH_RESIDUAL_NAMES[0])
+    lse = checkpoint_name(lse, FLASH_RESIDUAL_NAMES[1])
+    return (out, lse), (q, k, v, out, lse, bits)
+
+
+def _flash_selected_bwd(sm_scale, block, res, g):
+    *res, bits = res
+    dq, dk, dv = _flash_bwd(False, sm_scale, block, block, None, None,
+                            tuple(res), g[0], selection=bits)
+    return dq, dk, dv, np.zeros(bits.shape, jax.dtypes.float0)
+
+
+_flash_selected.defvjp(_flash_selected_fwd, _flash_selected_bwd)
+
+
 def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
                     causal: bool = False, sm_scale: Optional[float] = None,
                     block_q: int = 1024, block_k: int = 1024,
                     window: Optional[int] = None, mask: Optional[str] = None,
-                    block: Optional[int] = None) -> jax.Array:
+                    block: Optional[int] = None,
+                    selection: Optional[jax.Array] = None,
+                    return_lse: bool = False):
     """Flash attention over (B, S, H, D); ``v`` may have a head size of its
     own (q and k (B, S, H, d_qk), v and the output (B, S, H, d_v)), as
     latent attention's training form has. k and v may have fewer heads
@@ -1436,7 +1614,16 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
     its own noisy diagonal and the clean tiles up to its own, a list of
     those tiles and no step more, and a tile does mask arithmetic only
     where the mask crosses it; ``zoo_attention_bd_entries_total`` counts
-    the score entries computed and needed. A masked call's grid steps,
+    the score entries computed and needed. ``mask="selected"`` (not
+    causal; s_q == s_k) keeps, for each query, the keys of ``selection``,
+    one set for all of its heads, none after the query: (B, S / 32, S)
+    uint32, packed in groups of ``block`` rows (:func:`pack_selection`;
+    ``block`` a multiple of 32, at most 512, that divides S, is the tiles'
+    side). Its grids walk a tile list of the causal tiles, every entry
+    masked by its bit, and no gathered key or value is written.
+    ``return_lse`` gives ``(out, lse)``, ``lse`` (B, H, S) the rows'
+    logsumexp in base 2 (over the scores times ``sm_scale``), not
+    differentiated. A masked call's grid steps,
     issued and computed, are in ``zoo_attention_grid_steps_total``. Uses
     the Pallas kernel when the
     sequence tiles evenly (compiled on a TPU, interpret mode on the CPU
@@ -1473,11 +1660,29 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
                              "window >= 1")
         if window >= s_k:
             window = None                # every causal key is inside it
+    if mask == SELECTED:
+        b, s, h = q.shape[:3]
+        if causal or window is not None or s_q != s_k or selection is None \
+                or not block or block % 32 or block > 512 or s % block or \
+                selection.shape != (b, s // 32, s):
+            raise ValueError(
+                f"the selected-key mask takes a (batch, S / 32, S) packed "
+                f"selection in groups of block rows (a multiple of 32, at "
+                f"most 512, that divides S), and no causal mask or window: "
+                f"q {s_q}, k {s_k}, block {block}, selection "
+                f"{None if selection is None else selection.shape}")
+        out, lse = _flash_selected(q, k, v, selection, sm_scale, int(block))
+        if not return_lse:
+            return out
+        return out, lax.stop_gradient(lse.reshape(b, h, s))
+    if return_lse or selection is not None:
+        raise ValueError("a selection and a logsumexp are the selected-key "
+                         "mask's alone")
     bd = None
     if mask is not None:
         if mask != BLOCK_DIFFUSION:
-            raise ValueError(f"mask {mask!r}: the one named mask is "
-                             f"{BLOCK_DIFFUSION!r}")
+            raise ValueError(f"mask {mask!r}: the named masks are "
+                             f"{BLOCK_DIFFUSION!r} and {SELECTED!r}")
         if causal or window is not None or s_q != s_k or s_q % 2 or \
                 not block or (s_q // 2) % block:
             raise ValueError(

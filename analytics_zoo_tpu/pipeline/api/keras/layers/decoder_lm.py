@@ -1,4 +1,4 @@
-"""A decoder language model of five published families, chosen by the
+"""A decoder language model of six published families, chosen by the
 ``model_type`` of the ``config.json`` it is built from, or by its keys where
 it names none (:meth:`DecoderLM.from_config`):
 
@@ -104,6 +104,17 @@ Layer equations (x: (batch, seq, hidden)):
   logits ``C``; ``logits = RMSNorm(x[:, :L]) W_head`` (the noisy half);
   ``loss = (1/L) sum_i m_i / p_blk(i) CE(logits_i, x_0[i])``.
 
+* ``KeyeVL2`` (its language model): ``sdar_moe``'s block trained by
+  next-token prediction, its attention learned sparse attention (DeepSeek
+  Sparse Attention's sparse stage): q and k normed, then MRoPE; a
+  lightning indexer on the detached input, ``I[t, s] = sum_j w_tj
+  ReLU(q^I_tj . k^I_s)``, picks each query's top-k keys S_t
+  (``ops/sparse_attention.py``); ``softmax_{s in S_t}(q k^T / sqrt(d)) v``
+  through the selected-key flash kernel; ``L_I = mean_t KL(p_t ||
+  softmax_{S_t} I_t)``, p_t the attention's probabilities over S_t meaned
+  over the heads and not differentiated; ``loss = CE + lambda mean_layers
+  L_I``.
+
 One rank of an expert-parallel deployment holds ``experts_held`` of the
 ``n_routed_experts`` experts of each layer, from ``first_expert`` on: the
 router keeps its full width and the layer computes its own experts' part.
@@ -127,11 +138,16 @@ import flax.linen as nn
 from flax.core import FrozenDict
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.ad_checkpoint import checkpoint_name
 
 from analytics_zoo_tpu.obs.registry import REGISTRY as _REGISTRY
 from analytics_zoo_tpu.ops.attention import (
-    BLOCK_DIFFUSION, FLASH_RESIDUAL_NAMES, flash_attention)
+    BLOCK_DIFFUSION, FLASH_RESIDUAL_NAMES, SA_ENTRIES, SELECTED,
+    flash_attention, selection_entries)
+from analytics_zoo_tpu.ops.sparse_attention import (
+    INDEXER_GRAD_NAME, INDEXER_SCOPE, SELECTION_NAME, indexer_kl,
+    indexer_select, selection_key_bins)
 from analytics_zoo_tpu.ops.ssm import causal_conv1d, ssd_scan
 from analytics_zoo_tpu.parallel.expert_parallel import (
     ROUTER_CHOICE_NAME, expert_load, held_experts_ffn, noaux_bias_update,
@@ -154,6 +170,9 @@ INIT_POSITIONS = 128
 ROUTER_STATE = "router_state"      # the correction bias: state, not parameter
 MOE_STATS = "moe_stats"            # counters the step leaves in its outputs
 NOISE_STATE = "noise_state"        # the block-diffusion noise's seed, steps
+SA_STATS = "sa_stats"              # the selected-key layers' last step
+SA_KEY_BINS = 128                  # spans of the keys a query's selection
+                                   # is counted in (sa_stats)
 # a SwiGLU's first products (gate, up), as ``checkpoint_name`` marks them: an
 # identity wherever no remat policy keeps them
 FFN_PRODUCT_NAMES = ("ffn_gate", "ffn_up")
@@ -172,14 +191,24 @@ class RMSNorm(nn.Module):
         return (y * w).astype(self.dtype)
 
 
-def _rope_angles(x, theta: float, positions=None):
+def _rope_angles(x, theta: float, positions=None, sections=None):
     """cos and sin of ``pos * theta**(-2i/d)``, (1, seq, 1, d/2), float32;
-    ``pos`` the sequence's index, or ``positions`` (seq,)."""
+    ``pos`` the sequence's index, or ``positions`` (seq,); with
+    ``sections`` (MRoPE) ``positions`` is (len(sections), seq) and pair i
+    takes the row of the section it falls in, the sections in turn over
+    the d/2 pairs."""
     d = x.shape[-1]
     inv = theta ** (-jnp.arange(0, d, 2, dtype=jnp.float32) / d)
     pos = jnp.arange(x.shape[1], dtype=jnp.float32) if positions is None \
         else positions.astype(jnp.float32)
-    ang = pos[:, None] * inv[None, :]
+    if sections:
+        if sum(sections) != d // 2 or pos.shape != (len(sections),
+                                                    x.shape[1]):
+            raise ValueError(f"MRoPE sections {tuple(sections)} over {d // 2} "
+                             f"pairs, positions {pos.shape}")
+        ang = pos[np.repeat(np.arange(len(sections)), sections)].T * inv
+    else:
+        ang = pos[:, None] * inv[None, :]
     return jnp.cos(ang)[None, :, None, :], jnp.sin(ang)[None, :, None, :]
 
 
@@ -194,13 +223,17 @@ def rope_interleaved(x, theta: float):
     return out.reshape(x.shape).astype(x.dtype)
 
 
-def rope_half(x, theta: float, positions=None):
+def rope_half(x, theta: float, positions=None, sections=None):
     """Rotary position embedding over the pairs ``(x[i], x[i + d/2])`` of the
     last axis (rotate-half), angle ``pos * theta**(-2i/d)`` with ``pos`` the
-    index in the sequence or ``positions`` (seq,). x: (batch, seq, heads,
-    d); computed in float32."""
+    index in the sequence or ``positions`` (seq,). With ``sections``
+    (MRoPE, Qwen2-VL's chunked form) ``positions`` is (3, seq), the
+    temporal, height and width positions, and frequency pair i takes the
+    first's for i < sections[0], the second's for the next sections[1], the
+    third's for the rest; with the three equal it is plain rotate-half RoPE.
+    x: (batch, seq, heads, d); computed in float32."""
     d = x.shape[-1]
-    cos, sin = _rope_angles(x, theta, positions)
+    cos, sin = _rope_angles(x, theta, positions, sections)
     x32 = x.astype(jnp.float32)
     lo, hi = x32[..., :d // 2], x32[..., d // 2:]
     return jnp.concatenate([lo * cos - hi * sin, hi * cos + lo * sin],
@@ -262,7 +295,18 @@ class GQAttention(nn.Module):
     softmax's scale of the scores (by default ``1 / sqrt(head_dim)``).
     ``block_diffusion`` B reads the sequence as ``[x_t | x_0]``, two copies
     of a sequence: RoPE at positions ``t mod (seq / 2)`` and the
-    block-diffusion mask for blocks of B (under ``attn.bd``)."""
+    block-diffusion mask for blocks of B (under ``attn.bd``).
+
+    ``indexer_heads`` makes the layer learned sparse attention (DSA's sparse
+    stage, ``ops/sparse_attention.py``): RoPE on q and k (MRoPE with
+    ``mrope_section``, the three position streams a text's index), a
+    lightning indexer of that many heads of ``indexer_head_dim`` on the
+    detached input (under ``sa.indexer``) picks each query's
+    ``indexer_topk`` keys, the flash kernel attends to them alone (under
+    ``attn.sa``), and the layer returns ``(out, L_I)``, the indexer's KL
+    loss of each sequence; ``indexer_rows`` is the chunk of queries the
+    indexer works on, and the kernels' tile. Its last step's numbers go to
+    ``sa_stats``."""
     num_heads: int
     num_kv_heads: int
     head_dim: int
@@ -273,6 +317,11 @@ class GQAttention(nn.Module):
     qk_norm: bool = True
     sm_scale: Optional[float] = None
     block_diffusion: int = 0
+    indexer_heads: int = 0
+    indexer_head_dim: int = 0
+    indexer_topk: int = 0
+    indexer_rows: int = 512
+    mrope_section: Optional[tuple] = None
     dtype: Any = jnp.bfloat16
 
     @nn.compact
@@ -280,6 +329,8 @@ class GQAttention(nn.Module):
         b, s, hidden = x.shape
         h, hk, d = self.num_heads, self.num_kv_heads, self.head_dim
         norm = functools.partial(RMSNorm, self.eps, self.dtype)
+        if self.indexer_heads:
+            indexer = self._indexer(x)
         with jax.named_scope("attn.gqa"):
             q = _dense(h * d, self.dtype, "q_proj")(x).reshape(b, s, h, d)
             k = _dense(hk * d, self.dtype, "k_proj")(x).reshape(b, s, hk, d)
@@ -288,7 +339,14 @@ class GQAttention(nn.Module):
                 gate = _dense(h * d, self.dtype, "gate_proj")(x)
             if self.qk_norm:
                 q, k = norm(name="q_norm")(q), norm(name="k_norm")(k)
-            if self.block_diffusion:
+            if self.indexer_heads:
+                pos = jnp.broadcast_to(jnp.arange(s), (3, s)) \
+                    if self.mrope_section else None
+                q, k = (rope_half(t, self.rope_theta, pos, self.mrope_section)
+                        for t in (q, k))
+                with jax.named_scope("attn.sa"):
+                    out, lse = self._selected(q, k, v, indexer)
+            elif self.block_diffusion:
                 pos = jnp.arange(s) % (s // 2)
                 q, k = (rope_half(t, self.rope_theta, pos) for t in (q, k))
                 with jax.named_scope("attn.bd"):
@@ -306,7 +364,83 @@ class GQAttention(nn.Module):
             out = out.reshape(b, s, h * d)
             if self.gated:
                 out = out * jax.nn.sigmoid(gate)
-            return _dense(hidden, self.dtype, "o_proj")(out)
+            y = _dense(hidden, self.dtype, "o_proj")(out)
+        if not self.indexer_heads:
+            return y
+        return y, self._indexer_loss(q, k, lse, indexer)
+
+    def _rows(self, s):
+        return min(self.indexer_rows, s)
+
+    def _indexer(self, x):
+        """The indexer's ``(q^I, w, k^I, packed selection)`` from the
+        detached input (the selection None while the module is made)."""
+        b, s, _ = x.shape
+        hi, di = self.indexer_heads, self.indexer_head_dim
+        with jax.named_scope(INDEXER_SCOPE):
+            x = jax.lax.stop_gradient(x)
+            q_i = _dense(hi * di, self.dtype, "indexer_q_proj")(x).reshape(
+                b, s, hi, di)
+            k_i = nn.LayerNorm(epsilon=self.eps, dtype=self.dtype,
+                               param_dtype=jnp.float32,
+                               name="indexer_k_norm")(
+                _dense(di, self.dtype, "indexer_k_proj")(x))
+            w = _dense(hi, self.dtype, "indexer_weights_proj")(x).astype(
+                jnp.float32) * (hi * di) ** -0.5
+            q_i = rope_half(q_i, self.rope_theta)
+            k_i = rope_half(k_i[:, :, None], self.rope_theta)[:, :, 0]
+            if self.is_initializing():
+                # no parameter's shape depends on the selection: the
+                # engine's eager init does not compile it for its prefix
+                return q_i, w, k_i, None
+            bits = checkpoint_name(
+                indexer_select(q_i, w, k_i, self.indexer_topk,
+                               self._rows(s)), SELECTION_NAME)
+            return q_i, w, k_i, bits
+
+    def _selected(self, q, k, v, indexer):
+        """The attention to each query's selected keys and its base-2
+        logsumexp (zeros while the module is made)."""
+        bits = indexer[-1]
+        if bits is None:
+            b, s, h, d = q.shape
+            return jnp.zeros_like(q), jnp.zeros((b, h, s), jnp.float32)
+        return flash_attention(q, k, v, sm_scale=self.sm_scale, mask=SELECTED,
+                               selection=bits, block=self._rows(q.shape[1]),
+                               return_lse=True)
+
+    def _indexer_loss(self, q, k, lse, indexer):
+        """``L_I`` of each sequence (batch,), and the layer's last step in
+        ``sa_stats``: its mean ``L_I`` (``kl``), the entries the selected-key
+        walk computed and the selected ones, a head and a forward pass, and
+        how many keys each query chose in each of ``SA_KEY_BINS`` spans of
+        equal width over the keys (``key_bins`` (L, spans), summed over the
+        batch)."""
+        q_i, w, k_i, bits = indexer
+        b, s = q.shape[:2]
+        stats = {name: self.variable(SA_STATS, name, jnp.zeros, (), dt)
+                 for name, dt in (("kl", jnp.float32), ("steps", jnp.int32),
+                                  ("entries_computed", jnp.float32),
+                                  ("entries_needed", jnp.float32))}
+        bins = min(SA_KEY_BINS, s)
+        stats["key_bins"] = self.variable(SA_STATS, "key_bins", jnp.zeros,
+                                          (s, bins), jnp.int32)
+        if bits is None:
+            return jnp.zeros((b,), jnp.float32)
+        rows = self._rows(s)
+        loss = indexer_kl(q_i, w, k_i, q, k, lse, bits,
+                          sm_scale=self.sm_scale or self.head_dim ** -0.5,
+                          rows=rows)
+        if self.is_mutable_collection(SA_STATS):
+            with jax.named_scope(INDEXER_SCOPE):
+                computed, needed = selection_entries(bits, rows)
+                stats["key_bins"].value = jnp.sum(
+                    selection_key_bins(bits, rows, bins), axis=0)
+                stats["kl"].value = jax.lax.stop_gradient(jnp.mean(loss))
+                stats["steps"].value += 1
+                stats["entries_computed"].value = computed
+                stats["entries_needed"].value = needed / b
+        return loss
 
 
 def relu_squared(x):
@@ -574,6 +708,13 @@ _KEPT_ACROSS_REMAT = _KeepNames(*FLASH_RESIDUAL_NAMES, ROUTER_CHOICE_NAME)
 # Revisit once their memory is freed.
 _KEPT_WITH_FFN_PRODUCTS = _KeepNames(
     *FLASH_RESIDUAL_NAMES, ROUTER_CHOICE_NAME, *FFN_PRODUCT_NAMES)
+# The learned-sparse-attention family's blocks keep the packed selection (32
+# MiB a block at 16384 positions) and the indexer's gradients, made beside
+# its loss (35 MiB there), so the backward pass neither selects
+# nor runs the loss's chunks again.
+_KEPT_WITH_SELECTION = _KeepNames(
+    *FLASH_RESIDUAL_NAMES, ROUTER_CHOICE_NAME, SELECTION_NAME,
+    INDEXER_GRAD_NAME)
 
 
 @contextlib.contextmanager
@@ -588,7 +729,8 @@ def _remat_tally():
     finally:
         _TALLY.kept = None
     if tally:
-        for name in _KEPT_WITH_FFN_PRODUCTS.names:
+        for name in dict.fromkeys(_KEPT_WITH_FFN_PRODUCTS.names
+                                  + _KEPT_WITH_SELECTION.names):
             _REMAT_KEPT_BYTES.labels(name=name).set(tally.get(name, 0))
 
 
@@ -643,7 +785,10 @@ class Block(nn.Module):
     """A plan's :class:`Layer`: for each sublayer in turn ``x = x + s
     N_post(Sub(N(x)))`` (``N_post`` where it has one), ``s`` the residual
     scale, applied in float32 where it is not 1. ``window`` is the
-    attention's (``DecoderLM.layer_windows``; None: no window)."""
+    attention's (``DecoderLM.layer_windows``; None: no window). A sublayer
+    that returns ``(y, aux)`` adds an auxiliary loss (a learned-sparse
+    attention's ``L_I``); the block then returns ``(x, aux)``, the sum of
+    its sublayers'."""
     layer: Layer
     eps: float
     window: Optional[int] = None
@@ -653,6 +798,7 @@ class Block(nn.Module):
     def __call__(self, x):
         norm = functools.partial(RMSNorm, self.eps, self.dtype)
         scale = self.layer.residual_scale
+        aux = None
         for sub in self.layer.sublayers:
             module, name = _SUBLAYERS[sub.kind]
             sizes = dict(sub.sizes)
@@ -662,12 +808,15 @@ class Block(nn.Module):
             with jax.named_scope(sub.scope) if sub.scope else \
                     contextlib.nullcontext():
                 y = module(dtype=self.dtype, name=name, **sizes)(h)
+            if isinstance(y, tuple):
+                y, more = y
+                aux = more if aux is None else aux + more
             if sub.post_norm:
                 y = norm(name=sub.post_norm)(y)
             if scale != 1.0:
                 y = (y.astype(jnp.float32) * scale).astype(self.dtype)
             x = x + y
-        return x
+        return x if aux is None else (x, aux)
 
 
 class DecoderLM(nn.Module):
@@ -675,7 +824,9 @@ class DecoderLM(nn.Module):
     in float32. ``logits[:, t]`` scores ``tok_{t+1}``; ``mtp_logits[:, t]``
     scores ``tok_{t+2}`` (its last position, whose input token lies beyond
     the sequence, is fed token 0 and left out of the loss). With no MTP
-    module ``mtp_logits`` is None. Trained by block diffusion
+    module ``mtp_logits`` is None; where the blocks return an auxiliary
+    loss (learned sparse attention's ``L_I``) it stands in its place,
+    (batch,), the mean over the blocks. Trained by block diffusion
     (``diffusion_block``) it returns ``(logits, m / p)`` instead: the noisy
     copy's logits, which score each position's own token, and the loss's
     weight of each position (:func:`block_diffusion_loss`).
@@ -704,10 +855,13 @@ class DecoderLM(nn.Module):
     diffusion_block: int = 0
     noise_eps: float = 1e-3
     noise_seed: int = 0
+    # the weight of the blocks' auxiliary loss in the loss (None: they
+    # return none)
+    indexer_loss_weight: Optional[float] = None
 
     @classmethod
     def from_config(cls, cfg: Dict[str, Any]) -> "DecoderLM":
-        """From a ``config.json`` of one of the five families, chosen by its
+        """From a ``config.json`` of one of the six families, chosen by its
         ``model_type`` (``MODEL_TYPES``); a config that names none is read
         by its keys (``hybrid_override_pattern``: ``nemotron_h``;
         ``layer_types``: ``afmoe``; else the DeepSeek-V3 family). Beside the
@@ -718,7 +872,7 @@ class DecoderLM(nn.Module):
         ``mixer_parallel_size`` / ``mixer_parallel_rank`` (this rank's share
         of each Mamba-2 mixer's and attention layer's heads; by default the
         whole layer); for ``sdar_moe`` ``block_length``, ``noise_eps`` and
-        ``noise_seed``."""
+        ``noise_seed``; for ``KeyeVL2`` ``indexer_loss_weight``."""
         family = cfg.get("model_type")
         if family is None:
             family = "nemotron_h" if "hybrid_override_pattern" in cfg else \
@@ -727,7 +881,7 @@ class DecoderLM(nn.Module):
             if family in types:
                 return cls(**_FIELDS[name](cfg))
         raise ValueError(
-            f"model_type {family!r} is none of DecoderLM's five families: "
+            f"model_type {family!r} is none of DecoderLM's six families: "
             + "; ".join(f"{name} ({', '.join(types)})"
                         for name, types in MODEL_TYPES.items()))
 
@@ -735,6 +889,9 @@ class DecoderLM(nn.Module):
         """The loss to hand the estimator beside this module."""
         if self.diffusion_block:
             return block_diffusion_loss
+        if self.indexer_loss_weight is not None:
+            return functools.partial(sparse_attention_loss,
+                                     indexer_weight=self.indexer_loss_weight)
         return functools.partial(next_token_loss,
                                  mtp_weight=self.mtp_loss_weight)
 
@@ -819,8 +976,15 @@ class DecoderLM(nn.Module):
         if self.embed_scale != 1.0:
             x = x * jnp.asarray(self.embed_scale, x.dtype)
         windows = self.layer_windows or (None,) * len(self.layers)
+        aux = []
         for i, (layer, window) in enumerate(zip(self.layers, windows)):
             x = self._block(layer, f"layers_{i}", window)(x)
+            if isinstance(x, tuple):
+                x, more = x
+                aux.append(more)
+        if aux:
+            # the blocks' auxiliary loss beside the logits, in MTP's place
+            return logits_of(norm(name="norm")(x)), sum(aux) / len(aux)
         if self.diffusion_block:
             # the noisy half's logits; the loss's weights beside them
             return logits_of(norm(name="norm")(x[:, :length])), weight
@@ -1154,7 +1318,79 @@ def _sdar_fields(cfg: Dict[str, Any]) -> Dict[str, Any]:
         noise_seed=int(cfg.get("noise_seed", 0)))
 
 
-# the five families by the model_type names each is published under, and
+def _keye_fields(cfg: Dict[str, Any]) -> Dict[str, Any]:
+    """``DecoderLM``'s fields from the ``KeyeVL2`` family's language model
+    keys (a Qwen3-MoE-style decoder: ``num_experts``,
+    ``num_key_value_heads``, ``head_dim``, ``norm_topk_prob``,
+    ``rope_scaling.mrope_section``, and DeepSeek Sparse Attention's
+    ``sa_config``: ``indexer_num_heads``, ``indexer_head_dim``,
+    ``indexer_num_kv_heads``, ``topk``, ``q_chunk_size``,
+    ``kv_chunk_size``) plus this repo's ``experts_held`` / ``first_expert``
+    / ``compute_dtype`` / ``indexer_loss_weight`` (lambda, 1 by default).
+    Every block is learned sparse attention (q/k norms, MRoPE, the indexer,
+    the selected keys), then the expert layer routed by softmax. The vision
+    tower is not built: the ids are text."""
+    if int(cfg.get("decoder_sparse_step", 1)) != 1 or \
+            cfg.get("mlp_only_layers"):
+        raise ValueError("DecoderLM builds every KeyeVL2 block with the "
+                         "expert layer (decoder_sparse_step 1, no "
+                         "mlp_only_layers)")
+    if not cfg.get("norm_topk_prob", True) or \
+            cfg.get("hidden_act", "silu") != "silu":
+        raise ValueError("DecoderLM routes this family by softmax with the "
+                         "chosen probabilities renormalised and SiLU experts")
+    if cfg.get("use_sliding_window") or cfg.get("tie_word_embeddings") or \
+            cfg.get("attention_bias"):
+        raise ValueError("DecoderLM applies no window, keeps an untied head "
+                         "and no bias")
+    scaling = dict(cfg.get("rope_scaling") or {})
+    section = tuple(int(n) for n in scaling.pop("mrope_section", ()))
+    if {scaling.get("rope_type", "default"),
+            scaling.get("type", "default")} != {"default"}:
+        raise ValueError(f"DecoderLM applies MRoPE with no scaling, got "
+                         f"{cfg.get('rope_scaling')}")
+    sa = dict(cfg["sa_config"])
+    if int(sa.get("indexer_num_kv_heads", 1)) != 1 or \
+            int(sa.get("kv_chunk_size", sa["q_chunk_size"])) != \
+            int(sa["q_chunk_size"]):
+        raise ValueError("DecoderLM's indexer has one key head and works in "
+                         "equal query and key chunks")
+    hidden, heads = int(cfg["hidden_size"]), int(cfg["num_attention_heads"])
+    d = int(cfg.get("head_dim", hidden // heads))
+    if section and sum(section) != d // 2:
+        raise ValueError(f"mrope_section {section} does not cover the "
+                         f"{d // 2} frequency pairs of a head")
+    e = int(cfg["num_experts"])
+    eps = float(cfg.get("rms_norm_eps", 1e-6))
+    layer = _two_sublayers(
+        ("gqa", FrozenDict(
+            num_heads=heads,
+            num_kv_heads=int(cfg.get("num_key_value_heads", heads)),
+            head_dim=d, rope_theta=float(cfg["rope_theta"]), eps=eps,
+            gated=False, qk_norm=True,
+            indexer_heads=int(sa["indexer_num_heads"]),
+            indexer_head_dim=int(sa["indexer_head_dim"]),
+            indexer_topk=int(sa["topk"]),
+            indexer_rows=int(sa["q_chunk_size"]),
+            mrope_section=section or None)),
+        ("experts", FrozenDict(
+            n_routed_experts=e,
+            experts_held=int(cfg.get("experts_held", e)),
+            first_expert=int(cfg.get("first_expert", 0)),
+            num_experts_per_tok=int(cfg["num_experts_per_tok"]),
+            moe_intermediate_size=int(cfg["moe_intermediate_size"]),
+            n_shared_experts=0, routed_scaling_factor=1.0,
+            bias_update_rate=0.0, scoring="softmax")),
+        _KEPT_WITH_SELECTION)
+    return dict(
+        vocab_size=int(cfg["vocab_size"]), hidden_size=hidden,
+        layers=(layer,) * int(cfg["num_hidden_layers"]),
+        rms_norm_eps=eps,
+        dtype=jnp.dtype(cfg.get("compute_dtype", "bfloat16")),
+        indexer_loss_weight=float(cfg.get("indexer_loss_weight", 1.0)))
+
+
+# the six families by the model_type names each is published under, and
 # the readers of their keys
 MODEL_TYPES = {
     "deepseek_v3": ("deepseek_v3", "joyai_llm_flash"),
@@ -1162,10 +1398,12 @@ MODEL_TYPES = {
     "nemotron_h": ("nemotron_h",),
     "granitemoehybrid": ("granitemoehybrid",),
     "sdar_moe": ("sdar_moe",),
+    "keye_vl2": ("KeyeVL2",),
 }
 _FIELDS = {"deepseek_v3": _deepseek_fields, "afmoe": _afmoe_fields,
            "nemotron_h": _nemotron_h_fields,
-           "granitemoehybrid": _granite_fields, "sdar_moe": _sdar_fields}
+           "granitemoehybrid": _granite_fields, "sdar_moe": _sdar_fields,
+           "keye_vl2": _keye_fields}
 
 
 def block_diffusion_noise(key, batch: int, length: int, block: int,
@@ -1217,6 +1455,50 @@ def next_token_loss(ids, preds, mtp_weight: float = 0.3):
     if mtp_logits is not None:
         loss = loss + mtp_weight * _shifted_nll(mtp_logits, ids, 2)
     return loss
+
+
+def sparse_attention_loss(ids, preds, indexer_weight: float = 1.0):
+    """``CE(logits, tok_{t+1}) + indexer_weight * L_I`` for each sequence
+    (the engine takes the mean over the batch), ``preds`` the model's
+    ``(logits, L_I)``: ``L_I`` carries gradient to the indexers alone, the
+    cross-entropy to everything else (the indexers read their input
+    detached, their target is not differentiated)."""
+    logits, indexer_loss = preds
+    return _shifted_nll(logits, ids.astype(jnp.int32), 1) + \
+        indexer_weight * indexer_loss
+
+
+def sa_counters(extra_vars: Dict[str, Any]) -> Dict[str, Any]:
+    """The selected-key layers' last step from a step's outputs (the
+    engine's ``extra_vars``), one host fetch: ``sa_indexer_kl`` (the mean
+    ``L_I`` over the layers), ``sa_layer_kl`` (each layer's),
+    ``sa_entries_computed`` / ``sa_entries_needed`` (summed over the
+    layers, a head and a forward pass), ``sa_key_bins`` (each layer's
+    ``key_bins``), ``sa_steps``. Each call adds the step's entries to
+    ``zoo_attention_sa_entries_total`` and sets the ``zoo_sa_indexer_kl``
+    gauge."""
+    stats = jax.device_get(extra_vars.get(SA_STATS, {}))
+    layers = {k: v["self_attn"] for k, v in stats.items()
+              if "self_attn" in v}
+    if not layers:
+        return {}
+    out = {
+        "sa_layer_kl": {k: float(v["kl"]) for k, v in layers.items()},
+        "sa_entries_computed": float(sum(float(v["entries_computed"])
+                                         for v in layers.values())),
+        "sa_entries_needed": float(sum(float(v["entries_needed"])
+                                       for v in layers.values())),
+        "sa_key_bins": {k: np.asarray(v["key_bins"])
+                        for k, v in layers.items()},
+        "sa_steps": max(int(v["steps"]) for v in layers.values()),
+    }
+    out["sa_indexer_kl"] = sum(out["sa_layer_kl"].values()) / len(layers)
+    SA_ENTRIES.labels(kind="computed").inc(out["sa_entries_computed"])
+    SA_ENTRIES.labels(kind="needed").inc(out["sa_entries_needed"])
+    _REGISTRY.gauge("zoo_sa_indexer_kl", "the indexer's KL loss L_I, the "
+                    "mean over the selected-key layers, last step read"
+                    ).set(out["sa_indexer_kl"])
+    return out
 
 
 def moe_counters(extra_vars: Dict[str, Any]) -> Dict[str, float]:

@@ -384,12 +384,12 @@ def test_from_config_refuses_what_the_model_does_not_compute(key, value,
 
 def test_an_unknown_model_type_is_refused_by_name():
     """A config that names a family this model does not build is refused
-    with the five it does, not read as the DeepSeek-V3 family's keys."""
-    with pytest.raises(ValueError, match="five families") as err:
+    with the six it does, not read as the DeepSeek-V3 family's keys."""
+    with pytest.raises(ValueError, match="six families") as err:
         DecoderLM.from_config(dict(CFG, model_type="qwen3_moe"))
     for name in MODEL_TYPES:
         assert name in str(err.value)
-    assert len(MODEL_TYPES) == 5
+    assert len(MODEL_TYPES) == 6
 
 
 def test_trains_through_the_estimator_on_arrays():
