@@ -19,31 +19,40 @@ sublayer's normed input, detached):
   main attention's probabilities over S_t summed over its heads and divided
   by their number, not differentiated.
 
-Everything works a chunk of ``rows`` queries at a time (the configuration's
-``q_chunk_size``): no (L, L) array of scores is ever made, the chunk's
-(rows, L) ones are. The indexer's work lies under the named scope
+The selection works a chunk of ``rows`` queries at a time (the
+configuration's ``q_chunk_size``): no (L, L) array of scores is ever made,
+the chunk's (rows, L) ones are. The loss and its gradients are one Pallas
+kernel over the causal (rows, rows) tiles (``_kl_kernel``), whose score
+tiles never leave VMEM. The indexer's work lies under the named scope
 ``sa.indexer``.
 """
 
 from __future__ import annotations
 
 import functools
+import math
 
 import jax
 import jax.numpy as jnp
 from jax import lax
 from jax.ad_checkpoint import checkpoint_name
 
-from .attention import LOG2_E, pack_selection, unpack_selection
+from . import attention as _attention
+from .attention import (
+    _DQ_LAST, _FIRST, _HEAD, _K, _Q, _SCOPED_VMEM_BYTES, LOG2_E, NEG_INF,
+    _causal_walk, _count_grid, _launch, _sel_keep, _selection_spec,
+    _selection_words, _walk_at, _walk_flag, pack_selection, unpack_selection)
 
 INDEXER_SCOPE = "sa.indexer"
 # the indexer's gradients, made beside its loss (``indexer_kl``), as
 # ``checkpoint_name`` marks them: a remat policy that keeps them does not
-# run the loss's chunks a second time in the backward pass
+# run the loss's kernel a second time in the backward pass
 INDEXER_GRAD_NAME = "sa_indexer_grads"
 # the packed selection, as the caller marks it: kept, the selection is not
 # made again in the backward pass
 SELECTION_NAME = "sa_selection"
+# the KL pass's launches in ``zoo_attention_grid_steps_total``
+SA_KL = "sa_kl"
 
 
 def _chunk_scores(q_i, w, k_i, t0):
@@ -136,81 +145,164 @@ def selection_key_bins(bits, rows: int, bins: int):
     return jnp.moveaxis(out, 0, 1).reshape(b, length, bins)
 
 
-def _chunk_target(q2, k, lse, keep):
-    """The KL's target on a chunk: (1/H) sum_h exp2(q2_h . k_g(h) - lse_h)
-    at the selected entries, 0 elsewhere. q2 (B, rows, H, d) the main
-    queries times ``sm_scale * log2 e`` (as the flash kernel takes them), k
-    (B, L, H_kv, d), lse (B, H, rows) the flash kernel's base-2 logsumexp,
-    keep (B, rows, L). A kv head's group of query heads at a time."""
-    b, rows, heads, d = q2.shape
-    kv = k.shape[2]
-    qg = q2.reshape(b, rows, kv, heads // kv, d)
-    lg = lse.reshape(b, kv, heads // kv, rows)
-    total = jnp.zeros(keep.shape, jnp.float32)
-    for g in range(kv):
-        s = jnp.einsum("bqnd,bsd->bnqs", qg[:, :, g], k[:, :, g],
-                       preferred_element_type=jnp.float32)
-        s = jnp.where(keep[:, None], s - lg[:, g, :, :, None], -jnp.inf)
-        total = total + jnp.sum(jnp.exp2(s), axis=1)
-    return total / heads
+def _kl_kernel(walk, words_ref, qi_ref, w_ref, ki_ref, q_ref, k_ref, lse_ref,
+               kl_ref, dq_ref, dw_ref, dk_ref, m_ref, l_ref, *, length):
+    """One sequence's KL pass, grid (batch, entries of
+    ``_causal_walk(n, "q", group=2)``): each q tile's causal k tiles twice,
+    the entry's head row the sweep. Sweep 0 carries the running max and sum
+    of the indexer's logits over the selected keys, and at the q tile's
+    diagonal leaves their logsumexp in ``m_ref``. Sweep 1 makes the
+    indexer's per-head products again, once, kept for the logits and their
+    backward; the target from the main queries and keys, the KL terms, the
+    logits' gradient and the indexer's backward: dq^I and dw into the q
+    tile's output blocks, dk^I into the sequence's, resident over the walk
+    (a tile's made (d^I, block), the products' natural layout, and
+    transposed into place).
+    ``refs``: the tile's selection words; q^I (1, h^I, block, d^I), w (1,
+    block, h^I) float32, k^I (1, block, d^I); the main queries times
+    ``sm_scale * log2 e`` (1, H, block, d), keys (1, H_kv, block, d) and
+    base-2 logsumexp (1, block, H); the outputs each query's KL (1, block, 1),
+    dq^I, dw, dk^I (1, L, d^I), all float32; the scratch."""
+    import jax.experimental.pallas as pl
+
+    e = pl.program_id(1)
+    sweep = _walk_at(walk, e, _HEAD)
+    block, heads, hi = lse_ref.shape[1], lse_ref.shape[2], w_ref.shape[2]
+    group = heads // k_ref.shape[1]
+    keep = _sel_keep(words_ref[0], (block, block))
+    ki, w = ki_ref[0], w_ref[0]
+
+    def products(a, b):
+        return lax.dot_general(a, b, (((1,), (1,)), ((), ())),
+                               preferred_element_type=jnp.float32)
+
+    def scores():
+        return (products(qi_ref[0, j], ki) for j in range(hi))
+
+    def logits(s):
+        total = jnp.zeros((block, block), jnp.float32)
+        for j, s_j in enumerate(s):
+            total += jax.nn.relu(s_j) * w[:, j:j + 1]
+        return total
+
+    @pl.when(e == 0)
+    def _init_dk():
+        dk_ref[...] = jnp.zeros_like(dk_ref)
+
+    @pl.when(_walk_flag(walk, e, _FIRST))
+    def _init():
+        m_ref[...] = jnp.full_like(m_ref, NEG_INF)
+        for ref in (l_ref, kl_ref, dq_ref, dw_ref):
+            ref[...] = jnp.zeros_like(ref)
+
+    @pl.when(sweep == 0)
+    def _normaliser():
+        x = jnp.where(keep, logits(scores()), NEG_INF)
+        m_prev = m_ref[:, :1]
+        m_new = jnp.maximum(m_prev, jnp.max(x, axis=-1, keepdims=True))
+        l_new = l_ref[:, :1] * jnp.exp(m_prev - m_new) + jnp.sum(
+            jnp.exp(x - m_new), axis=-1, keepdims=True)
+        m_ref[...] = jnp.broadcast_to(m_new, m_ref.shape)
+        l_ref[...] = jnp.broadcast_to(l_new, l_ref.shape)
+
+        @pl.when(_walk_flag(walk, e, _DQ_LAST))
+        def _lse():
+            m_ref[...] += jnp.log(l_ref[...])
+
+    @pl.when(sweep == 1)
+    def _loss_and_grads():
+        s = list(scores())
+        logq = logits(s) - m_ref[:, :1]
+        lse = lse_ref[0]
+        total = jnp.zeros((block, block), jnp.float32)
+        for h in range(heads):
+            total += jnp.exp2(products(q_ref[0, h], k_ref[0, h // group])
+                              - lse[:, h:h + 1])
+        p = jnp.where(keep, total / heads, 0.0)
+        plogp = jnp.where(p > 0, p * jnp.log(jnp.where(p > 0, p, 1.0)), 0.0)
+        kl_ref[0] += jnp.sum(jnp.where(keep, plogp - p * logq, 0.0), axis=-1,
+                             keepdims=True)
+        d = jnp.where(keep, jnp.exp(logq) - p, 0.0) / length
+        lane = lax.broadcasted_iota(jnp.int32, (block, hi), 1)
+        dw = jnp.zeros((block, hi), jnp.float32)
+        dk = jnp.zeros((dk_ref.shape[2], block), jnp.float32)
+        for j, s_j in enumerate(s):
+            q_j = qi_ref[0, j]
+            dw += jnp.where(lane == j, jnp.sum(d * jax.nn.relu(s_j), axis=-1,
+                                               keepdims=True), 0.0)
+            ds = jnp.where(s_j > 0, d * w[:, j:j + 1], 0.0).astype(q_j.dtype)
+            dq_ref[0, j] += jnp.dot(ds, ki, preferred_element_type=jnp.float32)
+            dk += lax.dot_general(q_j, ds, (((0,), (0,)), ((), ())),
+                                  preferred_element_type=jnp.float32)
+        dw_ref[0] += dw
+        rows = pl.ds(pl.multiple_of(_walk_at(walk, e, _K) * block, block),
+                     block)
+        dk_ref[0, rows, :] += dk.T
 
 
-def _chunk_kl(scores, keep, p):
-    """Each row's ``KL(p || softmax over the selected of scores)`` and its
-    gradient in the scores, 0 off the selection."""
-    logits = jnp.where(keep, scores, -jnp.inf)
-    logq = logits - jax.scipy.special.logsumexp(logits, axis=-1,
-                                                keepdims=True)
-    plogp = jnp.where(p > 0, p * jnp.log(jnp.where(p > 0, p, 1.0)), 0.0)
-    kl = jnp.sum(jnp.where(keep, plogp - p * logq, 0.0), axis=-1)
-    return kl, jnp.where(keep, jnp.exp(logq) - p, 0.0)
+def _vmem_bytes(shape, dtype):
+    """A block's bytes in VMEM, its last two dims padded to whole tiles."""
+    size = jnp.dtype(dtype).itemsize
+    *lead, r, c = shape
+    return math.prod(lead) * -(-r // (32 // size)) * (32 // size) * \
+        -(-c // 128) * 128 * size
 
 
-def _chunk_indexer_grads(q_i, w, k_i, d_scores):
-    """The scores' gradient ``d_scores`` (B, rows, L) carried back to the
-    chunk's q^I and w and to every k^I: ``(dq (B, rows, h^I, d^I), dw (B,
-    rows, h^I), dk (B, L, d^I))`` in float32, the products in the compute
-    dtype with float32 sums."""
-    s = jnp.einsum("bqjd,bsd->bqjs", q_i, k_i,
-                   preferred_element_type=jnp.float32)
-    dw = jnp.einsum("bqs,bqjs->bqj", d_scores, jax.nn.relu(s),
-                    preferred_element_type=jnp.float32)
-    ds = jnp.where(s > 0, d_scores[:, :, None, :] * w[..., None], 0.0
-                   ).astype(q_i.dtype)
-    dq = jnp.einsum("bqjs,bsd->bqjd", ds, k_i,
-                    preferred_element_type=jnp.float32)
-    dk = jnp.einsum("bqjs,bqjd->bsd", ds, q_i,
-                    preferred_element_type=jnp.float32)
-    return dq, dw, dk
-
-
-def _kl_and_grads(rows, sm_scale, q_i, w, k_i, q, k, lse, bits):
+def _kl_and_grads(block, sm_scale, q_i, w, k_i, q, k, lse, bits):
     """``L_I`` of each sequence (B,) and its gradients in q_i, w and k_i,
-    one pass over the chunks."""
-    b, length = q_i.shape[:2]
-    n = _chunks(length, rows)
+    float32: one launch of :func:`_kl_kernel` over the causal tiles of side
+    ``block``."""
+    import jax.experimental.pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    b, length, hi, di = q_i.shape
+    heads, d = q.shape[2:]
+    kv = k.shape[2]
+    walk = _causal_walk(_chunks(length, block), "q", group=2)
+    grid = (b, walk.shape[0] // 4)
+    _count_grid(SA_KL, grid, math.prod(grid))
     q2 = q * jnp.asarray(sm_scale * LOG2_E, q.dtype)
+    operands = (_selection_words(bits), jnp.moveaxis(q_i, 2, 1), w, k_i,
+                jnp.moveaxis(q2, 2, 1), jnp.moveaxis(k, 2, 1),
+                jnp.moveaxis(lse, 1, 2))
 
-    def chunk(dk, c):
-        t0 = c * rows
+    def spec(shape, tile, lead=0):
+        """A block of ``shape`` at the entry's q (``tile`` _Q) or k tile,
+        ``lead`` whole dims between the batch and the sequence."""
+        def index(bi, e, walk):
+            return (bi, *(0,) * lead, _walk_at(walk, e, tile),
+                    *(0,) * (len(shape) - lead - 1))
+        return pl.BlockSpec((1, *shape), index)
 
-        def part(a, axis=1, size=rows, at=t0):
-            return lax.dynamic_slice_in_dim(a, at, size, axis)
-        qc, wc = part(q_i), part(w)
-        keep = unpack_selection(part(bits, size=rows // 32,
-                                     at=c * (rows // 32)), rows)
-        scores = _chunk_scores(qc, wc, k_i, t0)
-        p = _chunk_target(part(q2), k, part(lse, axis=2), keep)
-        kl, d_scores = _chunk_kl(scores, keep, p)
-        dq, dw, dk_c = _chunk_indexer_grads(qc, wc, k_i, d_scores / length)
-        return dk + dk_c, (jnp.sum(kl, axis=-1), dq, dw)
-
-    dk, (kl, dq, dw) = lax.scan(
-        chunk, jnp.zeros(k_i.shape, jnp.float32),
-        jnp.arange(n, dtype=jnp.int32))
-    dq = jnp.moveaxis(dq, 0, 1).reshape(q_i.shape)
-    dw = jnp.moveaxis(dw, 0, 1).reshape(w.shape)
-    return jnp.sum(kl, axis=0) / length, (dq, dw, dk)
+    in_specs = [_selection_spec(block, block, 1),
+                spec((hi, block, di), _Q, 1), spec((block, hi), _Q),
+                spec((block, di), _K), spec((heads, block, d), _Q, 1),
+                spec((kv, block, d), _K, 1), spec((block, heads), _Q)]
+    out_specs = [spec((block, 1), _Q), spec((hi, block, di), _Q, 1),
+                 spec((block, hi), _Q),
+                 pl.BlockSpec((1, length, di), lambda bi, *_: (bi, 0, 0))]
+    f32 = jnp.float32
+    out_shape = [jax.ShapeDtypeStruct(s, f32) for s in (
+        (b, length, 1), (b, hi, length, di), (b, length, hi),
+        (b, length, di))]
+    scratch = [pltpu.VMEM((block, 128), f32) for _ in range(2)]   # m, l
+    # the blocks, double-buffered, and sweep 1's kept (block, block) products
+    held = 2 * sum(_vmem_bytes(s.block_shape, a.dtype) for s, a in zip(
+        in_specs + out_specs, operands + tuple(out_shape))) + \
+        hi * _vmem_bytes((block, block), f32)
+    interpret = _attention._interpret()
+    kl, dq, dw, dk = _launch(
+        functools.partial(_kl_kernel, length=length), operands, walk,
+        grid=grid, in_specs=in_specs, out_specs=out_specs,
+        out_shape=out_shape, scratch_shapes=scratch,
+        # dk^I is carried across the whole walk, a q tile's state across
+        # its entries: only the batch is parallel
+        compiler_params=None if interpret else pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary"),
+            vmem_limit_bytes=_SCOPED_VMEM_BYTES + held),
+        interpret=interpret)
+    return jnp.sum(kl[..., 0], axis=-1) / length, (
+        jnp.moveaxis(dq, 1, 2), dw, dk)
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(0, 1))

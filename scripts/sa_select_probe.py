@@ -18,8 +18,9 @@ Times, in milliseconds of the host's clock around calls that end in
   of the bisection (what a sort costs, for comparison; the same bits);
 * ``attention``: the selected-key flash kernels, forward and backward
   (``jax.grad`` of a weighted sum of the output);
-* ``indexer_loss``: the KL loss with its gradient (the one pass that
-  ``indexer_kl`` makes in the forward);
+* ``indexer_loss``: the KL loss with its gradients, one launch of the
+  kernel over the causal tiles (``_kl_and_grads``, what ``indexer_kl``
+  runs in the forward);
 
 and the selected-key walk's entries computed over those selected. Prints a
 line a part; ``--out`` writes them as JSON too. Refuses any platform but

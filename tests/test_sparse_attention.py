@@ -3,8 +3,11 @@
 selected-key flash kernels (interpret mode) against a dense masked softmax,
 forward and backward, on every backward path, and against the plain causal
 kernels where every causal key is selected; the tile lists they walk; the
-entries they count; the indexer's KL loss and its gradient against plain
-autodiff; the kernels lowered for the chip."""
+entries they count; the indexer's KL loss and its gradients, one kernel
+over the causal tiles, against plain autodiff; the kernels lowered for the
+chip."""
+
+import re
 
 import jax
 import jax.numpy as jnp
@@ -308,6 +311,35 @@ def test_the_selected_key_kernels_lower_to_mosaic_for_tpu(monkeypatch):
         assert text.count("tpu_custom_call") == calls
 
 
+def test_the_kl_kernel_lowers_to_mosaic_for_tpu(monkeypatch):
+    """Cross-lowered for platform tpu at the cell's tile (512) and indexer
+    widths (16 heads of 64), bf16: the loss and its gradients are one
+    custom call, under the ``sa.indexer`` scope."""
+    monkeypatch.setattr(attn, "_interpret", lambda: False)
+    s, bf = 1024, jnp.bfloat16
+    shapes = [((1, s, 16, 64), bf), ((1, s, 16), jnp.float32),
+              ((1, s, 64), bf), ((1, s, 8, 128), bf), ((1, s, 2, 128), bf),
+              ((1, 8, s), jnp.float32), ((1, s // 32, s), jnp.uint32)]
+
+    def grad(q_i, w, k_i, q, k, lse, bits):
+        return jax.grad(lambda *a: jnp.sum(sa.indexer_kl(
+            *a, q, k, lse, bits, sm_scale=128 ** -0.5, rows=512)),
+            (0, 1, 2))(q_i, w, k_i)
+
+    exported = jax.export.export(jax.jit(grad), platforms=["tpu"])(
+        *(jax.ShapeDtypeStruct(*a) for a in shapes))
+    text = exported.mlir_module()
+    assert text.count("tpu_custom_call") == 1
+    call, = [line for line in text.splitlines() if "tpu_custom_call" in line]
+    assert "_kl_kernel" in call
+    # the op's name, which the profiler's tf_op stat carries, is under
+    # the scope
+    loc = re.search(r"loc\((#loc\d+)\)\s*$", call).group(1)
+    name, = [line for line in text.splitlines()
+             if line.startswith(loc + " ")]
+    assert "sa.indexer)/pallas_call" in name
+
+
 def test_the_kernels_walk_the_causal_tiles_with_the_words_beside():
     """A selected-key call's launches walk the causal tiles' static list,
     the selection's words the operand after it."""
@@ -324,3 +356,88 @@ def test_the_kernels_walk_the_causal_tiles_with_the_words_beside():
         walk, words = (x.aval for x in e.invars[:2])
         assert walk.shape == (4 * e.params["grid_mapping"].grid[1],)
         assert words.shape == (b, s // 32, s) and words.dtype == jnp.int32
+
+
+def _indexer_case(b, s, top, seed, block=32, underflow=False):
+    """The indexer's and the main attention's inputs of a toy layer (4
+    indexer heads of 16; 4 query heads on 2 kv heads of 16), the selection
+    ``indexer_select`` makes from them, the flash kernel's logsumexp over
+    it and the selection unpacked. With ``underflow`` query 5's every head
+    scores key 2 so low that the target's entry there is 0 in float32:
+    an early row selects all of its keys, that one among them."""
+    hi, di, h, hk, d = 4, 16, 4, 2, 16
+    ks = jax.random.split(jax.random.PRNGKey(seed), 3)
+    q_i = jax.random.normal(ks[0], (b, s, hi, di))
+    k_i = jax.random.normal(ks[1], (b, s, di))
+    w = jax.random.normal(ks[2], (b, s, hi))
+    q, k, _, _ = _gqa(b, s, h, hk, d, seed=seed)
+    if underflow:
+        u = np.ones(d, np.float32) / np.sqrt(d)
+        q = q.at[:, 5].set(40 * u)
+        k = k.at[:, 2].set(-40 * u)
+    bits = sa.indexer_select(q_i, w, k_i, top, block)
+    _, lse = flash_attention(q, k, k, mask=SELECTED, selection=bits,
+                             block=block, return_lse=True)
+    return (q_i, w, k_i, q, k, lse, bits), unpack_selection(bits, block)
+
+
+@pytest.mark.parametrize("s,top,underflow", [
+    (64, 24, False), (64, 48, False), (128, 24, False), (128, 48, False),
+    (128, 48, True)])
+def test_the_kl_kernel_is_plain_autodiff_of_the_dense_loss(s, top,
+                                                           underflow):
+    """``_kl_and_grads``, one kernel over 2 or 4 causal tiles of 32 with
+    two sequences: each sequence's ``L_I`` and the gradients of their sum
+    in q^I, w and k^I are plain autodiff's of the dense float32 loss, with
+    ``top`` under and over the tile's side (the early rows hold fewer keys
+    than ``top``), and with a selected entry whose target is 0."""
+    b, d = 2, 16
+    args, keep = _indexer_case(b, s, top, seed=s + top, underflow=underflow)
+    q_i, w, k_i, q, k, lse, bits = args
+    if underflow:
+        att = jnp.einsum("bhd,bhd->bh", q[:, 5],
+                         jnp.repeat(k[:, 2], 2, axis=1)) / np.sqrt(d)
+        assert np.all(np.asarray(keep[:, 5, 2]))
+        assert not np.any(np.asarray(jnp.exp2(att * attn.LOG2_E
+                                              - lse[:, :, 5])))
+    value, grads = sa._kl_and_grads(32, d ** -0.5, *args)
+    want, want_grads = jax.value_and_grad(
+        lambda *a: jnp.sum(_plain_indexer(*a, q, k, keep)), (0, 1, 2))(
+        q_i, w, k_i)
+    np.testing.assert_allclose(np.asarray(value),
+                               np.asarray(_plain_indexer(q_i, w, k_i, q, k,
+                                                         keep)),
+                               rtol=1e-5)
+    np.testing.assert_allclose(float(jnp.sum(value)), float(want), rtol=1e-5)
+    for g, r in zip(grads, want_grads):
+        assert g.shape == r.shape and g.dtype == jnp.float32
+        np.testing.assert_allclose(np.asarray(g), np.asarray(r), rtol=1e-4,
+                                   atol=1e-6)
+
+
+def test_the_kl_kernel_walks_each_q_tiles_causal_tiles_twice():
+    """One launch a call: grid (batch, 2 x n(n+1)/2 entries) over
+    ``_causal_walk(n, "q", group=2)``, the tile list its first operand and
+    the selection's words beside it; ``zoo_attention_grid_steps_total``
+    counts every step under ``sa_kl``, issued and computed alike."""
+    b, s, block = 1, 128, 32
+    n = s // block
+    args, _ = _indexer_case(b, s, 24, seed=0)
+    before = _grid_steps_of(sa.SA_KL)
+    jaxpr = jax.make_jaxpr(lambda *a: sa._kl_and_grads(block, 0.25, *a))(
+        *args).jaxpr
+    after = _grid_steps_of(sa.SA_KL)
+    launches = [e for e in equations(jaxpr)
+                if e.primitive.name == "pallas_call"]
+    assert pallas_kernels(jaxpr) == ["_kl_kernel"]
+    assert tuple(launches[0].params["grid_mapping"].grid) == (b, n * (n + 1))
+    walk, words = (x.aval for x in launches[0].invars[:2])
+    assert walk.shape == (4 * n * (n + 1),)
+    assert words.shape == (b, s // 32, s) and words.dtype == jnp.int32
+    assert {kind: after[kind] - before[kind] for kind in after} == {
+        "issued": 2 * n * (n + 1) // 2, "computed": 2 * n * (n + 1) // 2}
+
+
+def _grid_steps_of(mask):
+    return {kind: attn._GRID_STEPS.labels(mask=mask, kind=kind).value
+            for kind in ("issued", "computed")}
